@@ -17,6 +17,34 @@ from pathlib import Path
 from typing import Optional
 
 
+# Persistent XLA compilation cache: elastic re-meshes recompile per worker
+# count, and standalone job runners and every chip-tool call are fresh
+# processes — all of them read this disk cache instead of recompiling. The
+# directory is part of the cache key, so it is ONE fixed path inside the
+# checkout (git-ignored), never derived from data_root, a pid or a temp name.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".cache" / "xla"
+
+
+def enable_compilation_cache() -> Path:
+    """Turn jax's persistent compilation cache on for this process and
+    return its directory (idempotent; every entry point that compiles calls
+    it before its first jit). ``JAX_COMPILATION_CACHE_DIR`` places the cache
+    from outside: jax reads that variable itself, so when it is set no
+    directory is set here."""
+    import jax
+
+    # cache every program, not only those over jax's one-second floor: on a
+    # TPU even a one-op program costs ~0.2 s to compile, and a warm process
+    # spent 87 s recompiling the 382 of its 409 programs under that floor
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return Path(placed)
+    COMPILE_CACHE_DIR.mkdir(parents=True, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return COMPILE_CACHE_DIR
+
+
 def _env_bool(name: str, default: bool = False) -> bool:
     v = os.environ.get(name)
     if v is None:
@@ -60,7 +88,6 @@ class Config:
     standalone_jobs: bool = field(default_factory=lambda: _env_bool("STANDALONE_JOBS"))
 
     # --- TPU execution ---
-    platform: Optional[str] = field(default_factory=lambda: os.environ.get("KUBEML_PLATFORM"))
     # max workers the scheduler may allocate; None -> len(jax.devices())
     max_parallelism: Optional[int] = field(
         default_factory=lambda: (
@@ -253,8 +280,8 @@ class Config:
     serving_chunk_steps: int = field(default_factory=lambda: _env_int("KUBEML_SERVING_CHUNK", 16))
     # weight-only int8 decode ("int8"; empty = off): halves the per-step
     # weight HBM traffic and the weight footprint (serving/quant.py;
-    # chip-measured +4-11% decode at batch 1 for 124M-774M classes,
-    # ~neutral at batch >= 8 — results/QUANT_R5_NOTE.md). Composes with
+    # round-5 builder-measured +4-11% decode at batch 1 for 124M-774M
+    # classes, ~neutral at batch >= 8). Composes with
     # the serving mesh: flat-checkpoint loads quantize BEFORE placement
     # (int8-sized per-device peak), q/scales shard with the tp specs.
     serving_quantize: str = field(
@@ -278,8 +305,7 @@ class Config:
     # dispatch-chain depth: decode programs the device may run ahead of the
     # host's processed state. Must be >= serving_fetchers to saturate the
     # fetch pool; deeper delays completion detection (dead rows burn steps
-    # on long requests). 6/6 is the chip-measured balance
-    # (results/SERVING_R5_NOTE.md).
+    # on long requests). 6/6 was the round-5 builder-measured balance.
     serving_pipeline: int = field(
         default_factory=lambda: _env_int("KUBEML_SERVING_PIPELINE", 6))
     # concurrent result-fetch threads (each fetch pays the host<->device
@@ -474,35 +500,6 @@ class Config:
     # for links whose transfer time exceeds a round's compute
     dataplane_prefetch: int = field(
         default_factory=lambda: _env_int("KUBEML_DATAPLANE_PREFETCH", 1))
-
-    # persistent XLA compilation cache: elastic re-meshes recompile per worker
-    # count and standalone job runners are fresh processes — both hit this disk
-    # cache instead of recompiling (SURVEY §7 "elastic parallelism vs XLA").
-    # Default on, under data_root; KUBEML_COMPILE_CACHE=0 disables, or set a path.
-    compile_cache: str = field(
-        default_factory=lambda: os.environ.get("KUBEML_COMPILE_CACHE", "1")
-    )
-
-    @property
-    def compile_cache_dir(self) -> Optional[Path]:
-        v = self.compile_cache.lower()  # match _env_bool's case handling
-        if v in ("0", "false", "no", ""):
-            return None
-        if v in ("1", "true", "yes"):
-            return self.data_root / "xla-cache"
-        return Path(self.compile_cache).expanduser()
-
-    def enable_compilation_cache(self) -> None:
-        """Point jax's persistent compilation cache at the configured dir
-        (idempotent; call at service/runner startup)."""
-        d = self.compile_cache_dir
-        if d is None:
-            return
-        import jax
-
-        d.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(d))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
     @property
     def datasets_dir(self) -> Path:

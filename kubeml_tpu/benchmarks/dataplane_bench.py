@@ -13,13 +13,13 @@ stayed convergent.
 
 Three rows (raw / delta / delta-int8) append to
 ``results/dataplane_bench.jsonl``, plus one ``projected-e2e`` row per lossy
-codec: the measured bytes-per-round reduction applied to the BENCH_r05
+codec: the measured bytes-per-round reduction applied to the round-5
 recorded staging budget (54.8% of each end-to-end round is staging at
 83 MB/s over ~3.2 MB/round — results/profile_demo.jsonl), giving the
 end-to-end samples/sec the r05 chip run would sustain if the weight channel
 shipped this codec's bytes. The projection is labeled as such; the row is
 shaped like a bench record so ``scripts/bench_compare.py`` gates it against
-BENCH_r05 — a codec that REGRESSES bytes projects an e2e below baseline and
+the r05 row — a codec that REGRESSES bytes projects an e2e below baseline and
 fails the gate loudly (scripts/dataplane_bench.sh).
 """
 
@@ -34,7 +34,7 @@ import numpy as np
 
 from ..engine.dataplane import CODECS, DeltaDecoder, DeltaEncoder
 
-# BENCH_r05 recorded gap (results/profile_demo.jsonl, recorded-chip-gap row):
+# the round-5 driver row's gap (results/profile_demo.jsonl, recorded-chip-gap):
 # the baseline this harness projects codec wins onto
 R05_DEVICE_SPS = 32791.3
 R05_E2E_SPS = 14810.5

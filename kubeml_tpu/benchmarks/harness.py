@@ -21,8 +21,8 @@ from typing import Dict, Optional, Tuple
 # the bench regression gate's metric vocabulary (scripts/bench_compare.py):
 # normalized key -> (source field in the bench JSON line, direction).
 # serving_fraction_of_one_shot rides SERVING rows (benchmarks/serving.py
-# fraction_of_batchN — the long-workload continuous-batching ratio that used
-# to live only as a note in results/SERVING_R5_NOTE.md); train rows don't
+# fraction_of_batchN — the long-workload continuous-batching ratio); train
+# rows don't
 # carry the field, so the gate skips it there instead of failing.
 #
 # DIRECTION is per metric, not assumed: "higher" means a drop beyond the
@@ -71,8 +71,8 @@ def metric_direction(key: str) -> str:
 
 def normalize_bench_row(doc: dict) -> Dict[str, Optional[float]]:
     """One normalized metric row from a bench record — either the driver's
-    raw one-JSON-line output of ``bench.py`` or the ``BENCH_r0N.json``
-    wrapper holding it under ``parsed``. Missing/unreported metrics come
+    raw one-JSON-line output of ``bench.py`` or the driver's wrapper
+    holding it under ``parsed``. Missing/unreported metrics come
     back None (the regression gate skips them rather than failing on an
     unknown-hardware MFU); an error row keeps its ``error`` so the gate can
     fail a broken candidate outright."""
@@ -128,30 +128,17 @@ def baseline_for(fs: Flagship) -> Tuple[float, dict]:
 
 
 def flagship(dtype=None) -> Flagship:
-    """The headline benchmark model: ResNet-18/CIFAR-10 when the resnet family
-    is available (BASELINE.md target #2), else LeNet/MNIST (target #1).
-    ``KUBEML_FLAGSHIP=lenet`` forces the light flagship — a diagnostic knob
-    (e.g. driving the full bench body on a CPU dev box, where the ResNet
-    round is minutes of compute per rep).
+    """The headline benchmark model: ResNet-18/CIFAR-10 (BASELINE.md target
+    #2). ``KUBEML_FLAGSHIP=lenet`` selects LeNet/MNIST (target #1) instead —
+    a diagnostic knob (e.g. driving the full bench body on a CPU dev box,
+    where the ResNet round is minutes of compute per rep).
 
     ``dtype`` selects the computation precision (e.g. ``jnp.bfloat16`` for the
     MXU's native mixed-precision passes); None = model default (f32)."""
     import os
 
     kw = {} if dtype is None else {"dtype": dtype}
-    try:
-        if os.environ.get("KUBEML_FLAGSHIP", "").lower() == "lenet":
-            raise ImportError("KUBEML_FLAGSHIP=lenet")
-        from ..models.resnet import ResNet18
-
-        return Flagship(
-            module=ResNet18(num_classes=10, **kw),
-            sample_shape=(32, 32, 3),
-            name="resnet18-cifar10",
-            num_classes=10,
-            baseline_sps=1000.0,  # ResNet-class model, single 2020-era GPU
-        )
-    except ImportError:
+    if os.environ.get("KUBEML_FLAGSHIP", "").lower() == "lenet":
         from ..models.lenet import LeNet
 
         return Flagship(
@@ -161,6 +148,15 @@ def flagship(dtype=None) -> Flagship:
             num_classes=10,
             baseline_sps=20000.0,  # LeNet is tiny; GPUs push O(10k) samples/sec
         )
+    from ..models.resnet import ResNet18
+
+    return Flagship(
+        module=ResNet18(num_classes=10, **kw),
+        sample_shape=(32, 32, 3),
+        name="resnet18-cifar10",
+        num_classes=10,
+        baseline_sps=1000.0,  # ResNet-class model, single 2020-era GPU
+    )
 
 
 def make_synthetic_model(module, dataset_name: str = "synthetic",

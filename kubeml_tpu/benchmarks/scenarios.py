@@ -1812,6 +1812,9 @@ def run_serving_recovery(config: Optional[Config] = None,
     row["chaos"] = chaos
 
     # --- half 2: graceful drain, restored by a process born later ---
+    # (CPU-box only: both hops boot a PS in a child while this process,
+    # which served half 1, still holds the accelerator — and a chip belongs
+    # to one process at a time)
     snap_dir = str(Path(cfg.data_root) / "serving_snapshots_demo")
     shutil.rmtree(snap_dir, ignore_errors=True)
     env = dict(os.environ, KUBEML_DATA_ROOT=str(cfg.data_root),

@@ -322,7 +322,7 @@ def main(argv=None) -> int:
     p.add_argument("--long-workload", action="store_true",
                    help="the gated long row: 256 new tokens over "
                         "mixed-length prompts — the ~0.53 fraction "
-                        "results/SERVING_R5_NOTE.md measured, now tracked "
+                        "round 5 measured, tracked "
                         "through scripts/bench_compare.py "
                         "(serving_fraction_of_one_shot)")
     p.add_argument("--long-context", action="store_true",

@@ -15,7 +15,7 @@ from __future__ import annotations
 import logging
 from typing import Optional
 
-from .api.config import Config, get_config
+from .api.config import Config, enable_compilation_cache, get_config
 from .controller.controller import Controller
 from .functions.registry import FunctionRegistry
 from .ps.parameter_server import ParameterServer
@@ -90,7 +90,7 @@ class LocalCluster:
         self.ps_api: Optional[PSAPI] = None
 
     def start(self, recover: bool = True) -> "LocalCluster":
-        self.cfg.enable_compilation_cache()
+        enable_compilation_cache()
         self.scheduler.start()
         # serving SLO observability: sample the registry into the embedded
         # time-series store and evaluate the SLO engine on each tick

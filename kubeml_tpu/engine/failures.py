@@ -111,16 +111,14 @@ class WorkerHealth:
 
 
 # Error substrings that mark a TRANSIENT accelerator/runtime fault rather than
-# a program bug: XLA/PJRT RPC-layer failures (remote compile service drops,
-# preempted/unavailable backends). Rounds hitting these are retried with
+# a program bug: XLA/PJRT RPC-layer failures (preempted/unavailable backends,
+# reset transports). Rounds hitting these are retried with
 # backoff (engine/job.py) the way the reference retries its start-task RPC
 # 10x with backoff (reference: ml/pkg/ps/api.go:192-207); anything else
 # propagates immediately.
 TRANSIENT_ERROR_MARKERS = (
     "UNAVAILABLE:",
     "DEADLINE_EXCEEDED",
-    "remote_compile",
-    "response body closed",
     "Connection reset",
     "preempted",
 )

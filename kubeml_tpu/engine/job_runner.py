@@ -28,32 +28,11 @@ from __future__ import annotations
 
 import argparse
 import logging
-import os
 import sys
 import threading
 from typing import Optional
 
 log = logging.getLogger("kubeml.jobrunner")
-
-
-def _apply_platform_env() -> None:
-    """Honor KUBEML_PLATFORM / KUBEML_NUM_CPU_DEVICES before any device use.
-
-    Env vars alone are not enough when a sitecustomize pre-imports jax, so the
-    config.update path (which works post-import, pre-backend-init) is used."""
-    platform = os.environ.get("KUBEML_PLATFORM")
-    if platform:
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", platform)
-            n = os.environ.get("KUBEML_NUM_CPU_DEVICES")
-            if n and platform == "cpu":
-                from ..utils.jax_compat import set_cpu_devices
-
-                set_cpu_devices(int(n))
-        except RuntimeError:
-            log.warning("backends already initialized; platform env ignored")
 
 
 class JobRunner:
@@ -541,7 +520,6 @@ def main(argv=None) -> int:
     # merged trace, its log lines carry the bound trace/task ids
     tracing.get_tracer().service = "worker"
     tracing.add_log_context()
-    _apply_platform_env()
     from ..api.config import get_config
 
     cfg = get_config()
@@ -560,7 +538,9 @@ def main(argv=None) -> int:
         log.warning("per-job log file unavailable: %s", e)
 
     # fresh process: the persistent XLA cache turns the cold jit into a read
-    cfg.enable_compilation_cache()
+    from ..api.config import enable_compilation_cache
+
+    enable_compilation_cache()
     runner = JobRunner(args.job_id, port=args.port).start()
     # the parent reads this line to learn the bound port (job_pod readiness)
     print(f"LISTENING {runner.service.port}", flush=True)

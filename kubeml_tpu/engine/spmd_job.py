@@ -35,7 +35,6 @@ from typing import Optional
 
 import jax
 
-from ..utils import jax_compat  # noqa: F401  (jax.set_mesh shim)
 import numpy as np
 
 from ..api.errors import KubeMLError

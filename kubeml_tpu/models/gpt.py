@@ -21,7 +21,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..utils import jax_compat  # noqa: F401  (jax.shard_map shim)
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
@@ -55,8 +54,10 @@ class CausalSelfAttention(nn.Module):
     rope_theta: float = 10000.0
     # PAGED KV cache (kubeml_tpu.serving.kvpool): when a block table is
     # passed at call time the cache collection holds one shared physical
-    # arena ``[kv_pages, page_tokens, H, D]`` instead of per-row
-    # ``[B, max_len, ...]`` stripes; rows address it through per-row page
+    # arena ``[kv_pages, H, page_tokens, D]`` instead of per-row
+    # ``[B, max_len, ...]`` stripes (head-major, so one head's page is a
+    # whole trailing ``(page_tokens, D)`` tile — the only page block the
+    # Pallas TPU lowering accepts, ops/paged_attention.py); rows address it through per-row page
     # tables, so rows of different lengths share one step program without
     # padding every row to max_len. 0/0 (default) = dense cache only.
     # This page-granular layout is also what makes a live request's decode
@@ -120,7 +121,7 @@ class CausalSelfAttention(nn.Module):
                                  "parallelism; use an sp=1 mesh for serving")
             if pages is not None:
                 # PAGED decode (serving.kvpool): the cache is one shared
-                # physical arena [kv_pages, pt, H, D]; each row addresses
+                # physical arena [kv_pages, H, pt, D]; each row addresses
                 # its own logical window through ``pages`` [B, P] (logical
                 # page j of row b lives at physical page pages[b, j]).
                 # ``positions`` [B] is the logical position of each row's
@@ -153,9 +154,9 @@ class CausalSelfAttention(nn.Module):
                 kvq = resolve_kv_quant(self.kv_quant)
                 store_dtype = jnp.int8 if kvq == "int8" else k.dtype
                 ck = self.variable("cache", "k_pages", jnp.zeros,
-                                   (npg, pt, H, D), store_dtype)
+                                   (npg, H, pt, D), store_dtype)
                 cv = self.variable("cache", "v_pages", jnp.zeros,
-                                   (npg, pt, H, D), store_dtype)
+                                   (npg, H, pt, D), store_dtype)
                 if kvq == "int8":
                     # per-page-per-head running absmax: a page's int8 value
                     # q reconstructs as q * scale / 127. Scales live in the
@@ -209,22 +210,24 @@ class CausalSelfAttention(nn.Module):
                         ratio = jnp.where(new_at > 0.0,
                                           old_at / jnp.maximum(new_at, 1e-30),
                                           1.0)
-                        old_q = arena[phys].astype(jnp.float32)  # [B,L,pt,H,D]
+                        old_q = arena[phys].astype(jnp.float32)  # [B,L,H,pt,D]
                         req = jnp.clip(
-                            jnp.round(old_q * ratio[:, :, None, :, None]),
+                            jnp.round(old_q * ratio[..., None, None]),
                             -127, 127).astype(jnp.int8)
                         arena = arena.at[phys].set(req)
                         qv = jnp.clip(
                             jnp.round(xf * 127.0
                                       / jnp.maximum(new_at, 1e-30)[..., None]),
                             -127, 127).astype(jnp.int8)
-                        return arena.at[phys, off].set(qv), new_s
+                        return arena.at[phys, :, off].set(qv), new_s
 
                     ck.value, ks.value = _quant_write(ck.value, ks.value, k)
                     cv.value, vs.value = _quant_write(cv.value, vs.value, v)
                 else:
-                    ck.value = ck.value.at[phys, off].set(k)
-                    cv.value = cv.value.at[phys, off].set(v)
+                    # (page, :, offset) — the two index arrays straddle
+                    # the head slice, so the update is [B, L, H, D] like k
+                    ck.value = ck.value.at[phys, :, off].set(k)
+                    cv.value = cv.value.at[phys, :, off].set(v)
                 from ..ops.paged_attention import resolve_paged_attn
 
                 if resolve_paged_attn(self.paged_attn) == "pallas":
@@ -244,20 +247,21 @@ class CausalSelfAttention(nn.Module):
                         out = paged_attention(q, ck.value, cv.value, pages,
                                               positions)
                 else:
-                    kg = ck.value[pages]  # [B, tw, pt, H, D]
+                    kg = ck.value[pages]  # [B, tw, H, pt, D]
                     vg = cv.value[pages]
                     if kvq == "int8":
                         # gather-path dequant: the parity oracle for the
                         # quantized STORAGE format itself (same q*s/127
                         # reconstruction as the kernel's VMEM dequant)
                         kg = (kg.astype(jnp.float32)
-                              * (ks.value[pages] / 127.0)[:, :, None, :, None]
+                              * (ks.value[pages] / 127.0)[..., None, None]
                               ).astype(q.dtype)
                         vg = (vg.astype(jnp.float32)
-                              * (vs.value[pages] / 127.0)[:, :, None, :, None]
+                              * (vs.value[pages] / 127.0)[..., None, None]
                               ).astype(q.dtype)
-                    kg = kg.reshape(B, tw * pt, H, D)
-                    vg = vg.reshape(B, tw * pt, H, D)
+                    # head-major pages back to token-major rows
+                    kg = kg.transpose(0, 1, 3, 2, 4).reshape(B, tw * pt, H, D)
+                    vg = vg.transpose(0, 1, 3, 2, 4).reshape(B, tw * pt, H, D)
                     k_pos = jnp.arange(tw * pt)[None, None, None, :]
                     # [B, 1, L, tw*pt]
                     mask = k_pos <= pos_full[:, None, :, None]

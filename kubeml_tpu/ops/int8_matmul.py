@@ -3,8 +3,7 @@
 Weight-only int8 (serving/quant.py) halves the per-step weight HBM bytes,
 but the round-5 decode path dequantized to a dense bf16 tree BEFORE every
 matmul — the convert+scale sat between the HBM read and the MXU, and the
-measured win stalled at +4-11% at batch 1 (results/QUANT_R5_NOTE.md,
-VERDICT r5 weak-2). These routines contract the activations against the
+measured win stalled at +4-11% at batch 1 (round 5, VERDICT r5 weak-2). These routines contract the activations against the
 int8 values DIRECTLY and fold the per-output-channel scale into the f32
 accumulator AFTER the contraction:
 

@@ -3,7 +3,7 @@ page table, no contiguous K/V copy, KV traffic that scales with occupancy.
 
 The paged serving engine (serving/kvpool.py + the paged decode branch in
 models/gpt.py) stores K/V in one shared physical arena
-``[kv_pages, page_tokens, H, D]`` addressed through per-row page tables.
+``[kv_pages, H, page_tokens, D]`` addressed through per-row page tables.
 The original decode read was gather-then-attend: every step, every layer,
 each row's WHOLE table is gathered into a contiguous ``[B, tw*pt, H, D]``
 HBM block and plain attention runs over it — so a row 64 tokens into a
@@ -14,8 +14,18 @@ SOSP 2023): stream the row's pages through VMEM with the online-softmax
 recurrence, so no contiguous copy ever exists and reads stop at the row's
 live depth.
 
-Grid layout — the kv axis WALKS THE PAGE TABLE: grid ``(B, H, P)`` with the
-page index innermost (sequential on TPU). The page table, per-row positions
+Arena layout — HEAD-MAJOR pages. The Pallas TPU lowering takes a block
+only if its last two dims are (8, 128)-aligned or equal the array's, and
+at the serving defaults (16-token pages, head dim 64) no page-sized window
+of a token-major ``[N, pt, H, D]`` arena is either. With ``[N, H, pt, D]``
+a whole page ``(1, H, pt, D)`` is one block whose trailing ``(pt, D)``
+dims ARE the array's, each head's ``[pt, D]`` slice is a plain leading-dim
+index in the kernel, and the page is one contiguous DMA. Mosaic pads the
+sub-tile ``(16, 64)`` window itself, in bf16, f32 and int8 alike.
+
+Grid layout — the kv axis WALKS THE PAGE TABLE: grid ``(B, Lt, P)`` (rows,
+query tiles, logical pages) with the page index innermost (sequential on
+TPU); every program covers all heads. The page table, per-row positions
 and per-row live-page counts ride ``PrefetchScalarGridSpec`` scalar
 prefetch, so the K/V BlockSpec index maps translate the LOGICAL page index
 ``i`` into the row's PHYSICAL arena page before the block is fetched — the
@@ -28,7 +38,7 @@ written once at the final step.
 Per-row depth clamp — grid steps past a row's last live page repeat the
 previous physical index (the index map clamps at ``live[b] - 1``, the same
 trick the flash kernels use at the causal diagonal), so Pallas elides their
-HBM->VMEM copies, and ``pl.when(i < live[b])`` skips their compute: HBM
+HBM->VMEM copies, and ``pl.when(i < live)`` skips their compute: HBM
 reads and FLOPs scale with the row's ACTUAL ``positions + L``, not the
 reserved table width. Dead rows the host already retired point at the
 pool's trash page 0; their output is garbage the engine discards anyway
@@ -110,25 +120,47 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+# queries per program: L <= _Q_TILE runs as one tile (decode steps, verify
+# windows, short suffixes); longer prefills walk the table once per tile so
+# the acc/m/l scratch stays a fixed few hundred KiB whatever the prompt
+# bucket (a 1024-token prefill as ONE tile would need ~18 MiB of scratch)
+_Q_TILE = 128
+
+
+def _tile_live(pos_b, live_b, j, tq: int, pt: int):
+    """Pages query tile ``j`` of a row can see: the row's live depth,
+    further clamped at the tile's own last query position (causality — an
+    early tile of a long prefill never streams the pages later tiles
+    write). At least one page, like ``live`` itself."""
+    return jnp.maximum(
+        jnp.minimum(live_b, (pos_b + (j + 1) * tq + pt - 1) // pt), 1)
+
+
 def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
                page_tokens: int, n_pages: int, scale: float,
                quantized: bool):
-    """One (batch row, head, logical page) program. The page axis is the
-    innermost (sequential) grid dimension; acc/m/l carry across it in VMEM
-    scratch, and the output is written at the final page step.
+    """One (batch row, query tile, logical page) program covering ALL
+    heads. The page axis is the innermost (sequential) grid dimension;
+    acc/m/l carry across it in VMEM scratch, and the output is written at
+    the final page step. Heads are a static loop of plain 2-d
+    ``[tq, D] x [pt, D]`` contractions over the head-major page block
+    ``[H, pt, D]`` — one contiguous page DMA per step serves every head.
 
-    When ``quantized`` the K/V blocks arrive int8 with their page's
-    per-head absmax scales as two extra ``(1, 1)`` inputs riding the same
-    clamped index map; dequant happens here in VMEM, int8_matmul-style —
-    contract the raw int8 values (cast is exact, |q| <= 127), then fold
-    the per-block scalar ``s/127`` into the f32 result after the matmul."""
+    When ``quantized`` the K/V blocks arrive int8 and the page's per-head
+    absmax scales ride two extra ``[H, pt]`` inputs (each head's scalar
+    repeated along the page's tokens, so it multiplies a ``[tq, pt]``
+    score tile as an ordinary row broadcast); dequant happens here in
+    VMEM, int8_matmul-style — contract the raw int8 values (the cast is
+    exact, |q| <= 127), fold ``s/127`` into the f32 scores (K) and the
+    f32 probabilities (V) instead of into a dense page."""
     if quantized:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
+    j = pl.program_id(1)
     i = pl.program_id(2)
-    lq = q_ref.shape[2]
+    n_heads, tq = q_ref.shape[1], q_ref.shape[2]
     pt = page_tokens
 
     @pl.when(i == 0)
@@ -137,59 +169,66 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # pages at or past the row's live depth contribute nothing: their copies
-    # were elided by the clamped index map, their compute is skipped here
-    @pl.when(i < live_ref[b])
+    # pages at or past the tile's live depth contribute nothing: their
+    # copies were elided by the clamped index map, their compute is
+    # skipped here
+    @pl.when(i < _tile_live(pos_ref[b], live_ref[b], j, tq, pt))
     def _step():
-        q = q_ref[0, 0]           # [Lq, D] (storage dtype; f32 accumulate)
-        k_pg = k_ref[0, :, 0, :]  # [pt, D] — one physical page, this head
-        v_pg = v_ref[0, :, 0, :]
-        if quantized:
-            k_pg = k_pg.astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k_pg, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [Lq, pt]
-        if quantized:
-            s = s * (ks_ref[0, 0] / _KV_QMAX)
         # purely positional mask, identical to the gather path: query l sits
         # at logical position positions[b] + l and attends every key at or
         # before it (prompts are dense, decode writes contiguous — every
         # earlier position is real by construction). Padded query rows
         # (l >= the caller's true L) produce garbage that is sliced off.
-        q_pos = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (lq, pt), 0)
-        k_pos = i * pt + jax.lax.broadcasted_iota(jnp.int32, (lq, pt), 1)
-        s = jnp.where(k_pos <= q_pos, s, _NEG)
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s <= _NEG / 2, 0.0, p)  # masked keys stay exactly 0
-        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-        if quantized:
-            # contract p against the raw int8 page, fold the scale after
-            pv = jax.lax.dot_general(
-                p, v_pg.astype(p.dtype), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            pv = pv * (vs_ref[0, 0] / _KV_QMAX)
-        else:
-            pv = jax.lax.dot_general(
-                p.astype(v_pg.dtype), v_pg, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        q_pos = (pos_ref[b] + j * tq
+                 + jax.lax.broadcasted_iota(jnp.int32, (tq, pt), 0))
+        k_pos = i * pt + jax.lax.broadcasted_iota(jnp.int32, (tq, pt), 1)
+        visible = k_pos <= q_pos
+        for h in range(n_heads):
+            q = q_ref[0, h]      # [tq, D] (storage dtype; f32 accumulate)
+            k_pg = k_ref[0, h]   # [pt, D] — one physical page, this head
+            v_pg = v_ref[0, h]
+            if quantized:
+                k_pg = k_pg.astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, k_pg, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [tq, pt]
+            if quantized:
+                s = s * (ks_ref[0, 0, h:h + 1, :] / _KV_QMAX)
+            s = jnp.where(visible, s, _NEG)
+            m_prev = m_ref[h, :, 0:1]
+            l_prev = l_ref[h, :, 0:1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            p = jnp.where(visible, p, 0.0)  # masked keys stay exactly 0
+            l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+            if quantized:
+                # contract p against the raw int8 page; the page scale
+                # folds into p first (one scalar per page — same sum)
+                pv = jax.lax.dot_general(
+                    p * (vs_ref[0, 0, h:h + 1, :] / _KV_QMAX),
+                    v_pg.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            else:
+                pv = jax.lax.dot_general(
+                    p.astype(v_pg.dtype), v_pg, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            acc_ref[h] = acc_ref[h] * alpha + pv
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(i == n_pages - 1)
     def _finalize():
-        l = l_ref[:, 0:1]
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-9)).astype(o_ref.dtype)
+        for h in range(n_heads):
+            l = l_ref[h, :, 0:1]
+            o_ref[0, h] = (acc_ref[h] / jnp.maximum(l, 1e-9)
+                           ).astype(o_ref.dtype)
 
 
 def paged_attention(
     q: jnp.ndarray,         # [B, L, H, D] this call's queries
-    k_pages: jnp.ndarray,   # [N, pt, H, D] physical K arena (post-write)
-    v_pages: jnp.ndarray,   # [N, pt, H, D] physical V arena (post-write)
+    k_pages: jnp.ndarray,   # [N, H, pt, D] physical K arena (post-write)
+    v_pages: jnp.ndarray,   # [N, H, pt, D] physical V arena (post-write)
     pages: jnp.ndarray,     # [B, P] int32 per-row page table
     positions: jnp.ndarray,  # [B] int32 logical position of q[:, 0]
     interpret: Optional[bool] = None,
@@ -206,18 +245,20 @@ def paged_attention(
     models/gpt.py writes first, then attends).
 
     With ``k_scale``/``v_scale`` the arenas are int8 (KUBEML_KV_QUANT=int8)
-    and each page's per-head absmax rides the same clamped page-walk index
-    map as its K/V block; dequant happens in the kernel's VMEM blocks
-    before the QK^T/PV matmuls — the arenas are never materialized wide."""
+    and each page's per-head absmax rides the same clamped page walk as
+    its K/V block; dequant happens in the kernel's VMEM blocks around the
+    QK^T/PV matmuls — the arenas are never materialized wide."""
     B, L, H, D = q.shape
-    pt = int(k_pages.shape[1])
+    pt = int(k_pages.shape[2])
     P = int(pages.shape[1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    # queries move to [B, H, Lp, D] so the block's trailing dims are a clean
-    # (Lp, D) tile; L pads up to the f32 sublane minimum (padded rows are
-    # sliced off — L is 1 on the decode step path)
-    lqp = _round_up(max(L, 8), 8)
+    # queries move to [B, H, Lp, D] so a block's trailing dims are a clean
+    # (tq, D) tile per head; L pads up to the storage dtype's sublane
+    # minimum (8 rows of f32, 16 of bf16 — padded rows are sliced off; L
+    # is 1 on the decode step path) and, past one tile, to whole tiles
+    tq = min(_round_up(L, 32 // q.dtype.itemsize), _Q_TILE)
+    lqp = _round_up(L, tq)
     qt = jnp.moveaxis(q, 2, 1)
     qt = jnp.pad(qt, ((0, 0), (0, 0), (0, lqp - L), (0, 0)))
     pages = pages.astype(jnp.int32)
@@ -233,42 +274,55 @@ def paged_attention(
     if quantized and v_scale is None:
         raise ValueError("k_scale and v_scale must be passed together")
 
-    def q_map(b, h, i, pages_ref, pos_ref, live_ref):
-        return (b, h, 0, 0)
+    def q_map(b, j, i, pages_ref, pos_ref, live_ref):
+        return (b, 0, j, 0)
 
-    def kv_map(b, h, i, pages_ref, pos_ref, live_ref):
-        # logical->physical through the prefetched table; steps past the
-        # row's live depth repeat the previous physical page so Pallas
-        # elides their copies (the flash kernels' causal-diagonal trick,
-        # applied to per-row occupancy)
-        pg = jnp.maximum(jnp.minimum(i, live_ref[b] - 1), 0)
-        return (pages_ref[b, pg], 0, h, 0)
+    def _logical(b, j, i, pos_ref, live_ref):
+        # steps past the tile's live depth repeat the previous page so
+        # Pallas elides their copies (the flash kernels' causal-diagonal
+        # trick, applied to per-row occupancy)
+        return jnp.minimum(
+            i, _tile_live(pos_ref[b], live_ref[b], j, tq, pt) - 1)
 
-    def scale_map(b, h, i, pages_ref, pos_ref, live_ref):
-        # the page's [N, H] absmax rides the same clamped page walk
-        pg = jnp.maximum(jnp.minimum(i, live_ref[b] - 1), 0)
-        return (pages_ref[b, pg], h)
+    def kv_map(b, j, i, pages_ref, pos_ref, live_ref):
+        # logical->physical through the prefetched table
+        return (pages_ref[b, _logical(b, j, i, pos_ref, live_ref)], 0, 0, 0)
+
+    def scale_map(b, j, i, pages_ref, pos_ref, live_ref):
+        # scales are pre-gathered per row (below): indexed by LOGICAL page
+        return (b, _logical(b, j, i, pos_ref, live_ref), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, lqp, D), q_map),
-        pl.BlockSpec((1, pt, 1, D), kv_map),
-        pl.BlockSpec((1, pt, 1, D), kv_map),
+        pl.BlockSpec((1, H, tq, D), q_map),
+        pl.BlockSpec((1, H, pt, D), kv_map),
+        pl.BlockSpec((1, H, pt, D), kv_map),
     ]
     operands = [qt, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), scale_map),
-                     pl.BlockSpec((1, 1), scale_map)]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # a [N, H] arena cannot be blocked one page at a time (a (1, H)
+        # block's second-minor dim is neither 8-aligned nor the array's),
+        # and a per-head scalar in VMEM would need a lane->sublane
+        # relayout to meet its [tq, pt] score tile. So the row's scales
+        # are gathered through its table here (B*P*H floats — noise next
+        # to the pages) and repeated along the page's tokens: the block
+        # (1, 1, H, pt) is legal (trailing dims == the array's) and row h
+        # of it broadcasts over a score tile as is.
+        def rows(s):
+            return jnp.broadcast_to(
+                s.astype(jnp.float32)[pages][..., None], (B, P, H, pt))
+
+        in_specs += [pl.BlockSpec((1, 1, H, pt), scale_map),
+                     pl.BlockSpec((1, 1, H, pt), scale_map)]
+        operands += [rows(k_scale), rows(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # pages, positions, live
-        grid=(B, H, P),
+        grid=(B, lqp // tq, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, lqp, D), q_map),
+        out_specs=pl.BlockSpec((1, H, tq, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((lqp, D), jnp.float32),       # acc
-            pltpu.VMEM((lqp, _LANES), jnp.float32),  # m (row max)
-            pltpu.VMEM((lqp, _LANES), jnp.float32),  # l (row sum)
+            pltpu.VMEM((H, tq, D), jnp.float32),       # acc
+            pltpu.VMEM((H, tq, _LANES), jnp.float32),  # m (row max)
+            pltpu.VMEM((H, tq, _LANES), jnp.float32),  # l (row sum)
         ],
     )
     out = pl.pallas_call(

@@ -45,6 +45,19 @@ log = logging.getLogger("kubeml.distributed")
 _initialized = False
 
 
+def _pod_hosts(env) -> int:
+    """How many worker hosts the Cloud TPU environment names (0 when it
+    names none): the comma-separated host lists libtpu itself reads, or a
+    multislice coordinator (always more than one host)."""
+    if env.get("MEGASCALE_COORDINATOR_ADDRESS"):
+        return 2
+    for var in ("TPU_WORKER_HOSTNAMES", "TPU_PROCESS_ADDRESSES"):
+        hosts = [h for h in env.get(var, "").split(",") if h.strip()]
+        if hosts:
+            return len(hosts)
+    return 0
+
+
 def init_distributed(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -66,27 +79,21 @@ def init_distributed(
         env = os.environ.get("KUBEML_PROCESS_ID")
         process_id = int(env) if env else None
     if coordinator_address is None and num_processes in (None, 1):
-        # no explicit config: on a Cloud TPU pod the no-arg initialize()
-        # auto-detects the process group from the TPU metadata; elsewhere
-        # (laptops, single TPU VMs, CI) stay single-process
-        if any(os.environ.get(v) for v in (
-            "TPU_WORKER_HOSTNAMES", "TPU_PROCESS_ADDRESSES",
-            "MEGASCALE_COORDINATOR_ADDRESS", "CLOUD_TPU_TASK_ID",
-        )):
-            try:
-                jax.distributed.initialize()
-                _initialized = True
-                log.info("distributed (auto-detected TPU pod): process %d/%d",
-                         jax.process_index(), jax.process_count())
-                return jax.process_count() > 1
-            except Exception as e:
-                log.warning("TPU-pod auto-detect failed (%s); single-process", e)
+        # no explicit config: on a Cloud TPU pod — an environment that names
+        # MORE THAN ONE worker host — the no-arg initialize() auto-detects
+        # the process group from the TPU metadata, and a failure there is
+        # fatal (N hosts each booting their own single-process cluster is
+        # not a degraded pod, it is N wrong clusters). A single TPU VM
+        # exports the same variables with one host in them; it, laptops and
+        # CI stay single-process.
+        if _pod_hosts(os.environ) > 1:
+            jax.distributed.initialize()
+            _initialized = True
+            log.info("distributed (auto-detected TPU pod): process %d/%d",
+                     jax.process_index(), jax.process_count())
+            return jax.process_count() > 1
         log.info("single-process mode (no KUBEML_COORDINATOR)")
         return False
-    if jax.config.jax_platforms and "cpu" in str(jax.config.jax_platforms):
-        from ..utils.jax_compat import enable_cpu_gloo
-
-        enable_cpu_gloo()
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -28,7 +28,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..utils import jax_compat  # noqa: F401  (shard_map/set_mesh shims)
 import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

@@ -18,7 +18,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..utils import jax_compat  # noqa: F401  (jax.lax.pcast shim)
 
 _NEG = -1e30  # large-negative instead of -inf: keeps exp() NaN-free for fully
 # masked rows (standard flash-attention trick)

@@ -22,7 +22,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..utils import jax_compat  # noqa: F401  (jax.lax.pcast shim)
 
 
 def ulysses_attention(

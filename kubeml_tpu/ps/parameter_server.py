@@ -310,14 +310,25 @@ class ParameterServer:
 
         placeholder = self._reserve_slot(task)
         try:
+            import jax
+
+            if jax.default_backend() == "tpu":
+                # measured on a v5e host: the runner binds its port, then
+                # every /start dies in its first device call ("Unable to
+                # initialize backend 'tpu': ... libtpu multi-process
+                # lockfile") and the hand-over gives up after ten tries
+                raise KubeMLError(
+                    "STANDALONE_JOBS cannot run on a TPU host: a chip "
+                    "belongs to one process, and this one (scheduler, PS, "
+                    "serving) already holds it, so the job's runner process "
+                    "could never open the device. Unset STANDALONE_JOBS to "
+                    "train in-process (the default)", 400)
             env = dict(
                 __import__("os").environ,
                 KUBEML_DATA_ROOT=str(self.cfg.data_root),
                 KUBEML_SCHEDULER_PORT=str(self.cfg.scheduler_port),
                 KUBEML_PS_PORT=str(self.cfg.ps_port),
             )
-            if self.cfg.platform:
-                env["KUBEML_PLATFORM"] = self.cfg.platform
             proc = subprocess.Popen(
                 [sys.executable, "-m", "kubeml_tpu.engine.job_runner",
                  "--job-id", task.job_id, "--port", "0"],

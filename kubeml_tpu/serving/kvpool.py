@@ -4,8 +4,8 @@ shared-prefix trie (the host side of the paged serving engine).
 The slot-based batcher gives every decode row a full ``[max_len, H, D]``
 KV stripe, so a 16-token chat request holds the same device memory as a
 2048-token one and admission can only happen when a whole stripe frees —
-``results/SERVING_R5_NOTE.md`` measured the cost (256-token workloads at
-~0.53 of the one-shot batch rate). This module carves the device KV arena
+round 5 measured the cost (256-token workloads at ~0.53 of the one-shot
+batch rate). This module carves the device KV arena
 into fixed-size pages of ``page_tokens`` tokens (vLLM's PagedAttention,
 Kwon et al. 2023) and owns all the HOST bookkeeping:
 

@@ -167,8 +167,9 @@ def gather_pages(cache: dict, pages: Sequence[int]) -> List[LayerSnapshot]:
     idx = np.asarray(list(pages), dtype=np.int32)
     out: List[LayerSnapshot] = []
     for name, attn in paged_cache_layers(cache):
-        k = np.asarray(attn["k_pages"][idx])
-        v = np.asarray(attn["v_pages"][idx])
+        # the arena is head-major [N, H, pt, D]; the frame is token-major
+        k = np.asarray(attn["k_pages"][idx]).swapaxes(1, 2)
+        v = np.asarray(attn["v_pages"][idx]).swapaxes(1, 2)
         ks = vs = None
         if "k_scale" in attn:
             ks = np.asarray(attn["k_scale"][idx], dtype=np.float32)
@@ -191,9 +192,9 @@ def scatter_pages(cache: dict, pages: Sequence[int],
     for (name, attn), layer in zip(blocks, layers):
         a = dict(attn)
         a["k_pages"] = attn["k_pages"].at[idx].set(
-            layer.k.astype(attn["k_pages"].dtype))
+            layer.k.swapaxes(1, 2).astype(attn["k_pages"].dtype))
         a["v_pages"] = attn["v_pages"].at[idx].set(
-            layer.v.astype(attn["v_pages"].dtype))
+            layer.v.swapaxes(1, 2).astype(attn["v_pages"].dtype))
         if "k_scale" in attn:
             if layer.k_scale is None or layer.v_scale is None:
                 raise SnapshotError(

@@ -7,7 +7,7 @@ chip). The dequantize runs INSIDE the step program (int8 leaves the HBM;
 verified in the compiled HLO — the weights stay s8, nothing is hoisted
 out of the scan).
 
-Chip-measured reality (results/QUANT_R5_NOTE.md): with the DEQUANTIZE
+Round-5 builder-measured reality: with the DEQUANTIZE
 path (dense bf16 rebuilt inside the step program before each matmul) the
 throughput win stalled at +4-11% at batch 1, ~0 at batch 8-16 — per-op
 overhead and the convert+scale absorbed most of the saved stream time.
@@ -255,8 +255,7 @@ def quality_report(module, variables, tokens) -> dict:
 # checkpoint-storage form: QuantizedTensor nodes become a marker dict so
 # the (dict-recursing) checkpoint stores persist them unchanged — and a
 # sharded restore can place q/s straight onto the serving mesh with no
-# dense transient (the "quantized checkpoint storage" follow-up of
-# results/QUANT_R5_NOTE.md)
+# dense transient (round 5's "quantized checkpoint storage" follow-up)
 Q8_Q = "__q8_q__"
 Q8_S = "__q8_s__"
 

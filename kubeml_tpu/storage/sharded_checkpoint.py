@@ -242,8 +242,6 @@ class ShardedCheckpointStore:
         models.gpt_pipeline.flat_serving_remap builds this plan)."""
         import jax
 
-        from ..utils.jax_compat import make_array_from_callback
-
         t_restore = time.perf_counter()
         d = self._dir(job_id, tag)
         mpath = d / MANIFEST
@@ -319,7 +317,7 @@ class ShardedCheckpointStore:
                             for s, dim in zip(index, shape))
                         return sub_assemble(src, spec, pre, index, out)
 
-                    pairs[tgt] = make_array_from_callback(
+                    pairs[tgt] = jax.make_array_from_callback(
                         shape, target, cb, dtype=dtype)
         finally:
             readers.close()

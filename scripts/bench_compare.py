@@ -3,12 +3,12 @@
 
 Usage::
 
-    python scripts/bench_compare.py BENCH_r04.json BENCH_r05.json
-    python scripts/bench_compare.py BENCH_r0*.json          # trajectory form
+    python scripts/bench_compare.py base.json cand.json
+    python scripts/bench_compare.py run_*.json              # trajectory form
     python scripts/bench_compare.py --threshold 0.05 base.json cand.json
 
 Each file is a bench record — the driver's raw one-JSON-line output of
-``bench.py`` or the ``BENCH_r0N.json`` wrapper holding it under ``parsed``.
+``bench.py`` or the driver's wrapper holding it under ``parsed``.
 With two files the first is the baseline and the second the candidate; with
 more, the LAST file is the candidate and the second-to-last the baseline (the
 "did this change regress the bench" question), and the earlier files print as

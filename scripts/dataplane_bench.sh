@@ -1,8 +1,9 @@
 #!/usr/bin/env bash
 # Weight-movement data-plane bench: per-round PS<->runner weight-exchange
 # bytes by codec (raw / delta / delta-int8), appended to
-# results/dataplane_bench.jsonl, then gated against the BENCH_r05 baseline
-# through scripts/bench_compare.py so a codec regression fails loudly.
+# results/dataplane_bench.jsonl, then gated against the round-5 baseline row
+# (benchmarks/dataplane_bench.py R05_*) through scripts/bench_compare.py so a
+# codec regression fails loudly.
 #
 #   scripts/dataplane_bench.sh [rounds]     (default 12)
 #
@@ -14,7 +15,7 @@
 #     feedback stayed convergent. Also emits per-codec projected-e2e rows
 #     (the r05 staging budget scaled by the measured byte ratio — labeled a
 #     projection; the real number comes from the next chip bench).
-#  2. bench_compare: BENCH_r05 as baseline vs the delta-int8 projected row
+#  2. bench_compare: the r05 row as baseline vs the delta-int8 projected row
 #     as candidate — exits non-zero (failing this script) if the codec's
 #     projected end-to-end throughput regresses the recorded 14.8k.
 #  3. The acceptance check itself: delta-int8 bytes/round must be >= 3x
@@ -37,14 +38,19 @@ python -m kubeml_tpu.benchmarks.dataplane_bench --rounds "$ROUNDS" \
 python - <<'EOF'
 import json
 
+from kubeml_tpu.benchmarks import dataplane_bench as db
+
 rows = [json.loads(l) for l in open("/tmp/dataplane_bench_rows.jsonl")]
 cand = next(r for r in rows if r["kind"] == "projected-e2e"
             and r["codec"] == "delta-int8")
 json.dump(cand, open("/tmp/dataplane_candidate.json", "w"))
+json.dump({"metric": cand["metric"], "value": db.R05_DEVICE_SPS,
+           "unit": "samples/sec", "end_to_end": db.R05_E2E_SPS},
+          open("/tmp/dataplane_baseline.json", "w"))
 EOF
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-python scripts/bench_compare.py BENCH_r05.json /tmp/dataplane_candidate.json \
-  --out /tmp/dataplane_gate.json
+python scripts/bench_compare.py /tmp/dataplane_baseline.json \
+  /tmp/dataplane_candidate.json --out /tmp/dataplane_gate.json
 
 # --- act 3: acceptance — >=3x bytes cut at unchanged final loss ---
 python - <<'EOF'

@@ -5,7 +5,7 @@
 #  1. A PROFILED flagship bench run (KUBEML_BENCH_PROFILE=1): per-phase
 #     byte/FLOP attribution of the bench itself, including the gap row that
 #     quantifies the staging share of the device-vs-end-to-end throughput
-#     difference (BENCH_r05: 32.8k on-device vs 14.8k end-to-end).
+#     difference.
 #  2. A traced train task through the live control plane, folded into a
 #     per-phase report by `kubeml profile <task-id>` with a Perfetto
 #     counter-track trace next to it.
@@ -24,39 +24,8 @@ mkdir -p results
 OUT_DIR="${1:-$(mktemp -d)}"
 mkdir -p "$OUT_DIR"
 
-# --- act 0: the recorded-chip gap, attributed ---
-# BENCH_r05 measured 32.8k samples/sec on-device vs 14.8k end-to-end on the
-# chip host; fold the recorded row through the same gap attribution the
-# profiled bench uses, so results/ carries the chip-regime staging budget
-# even when this script runs on a CPU dev box (where device == end-to-end
-# and the live gap is ~0).
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python - <<'EOF'
-import json, time
-from kubeml_tpu.benchmarks.harness import normalize_bench_row
-from kubeml_tpu.utils.profiler import gap_attribution
-
-doc = json.load(open("BENCH_r05.json"))
-row = normalize_bench_row(doc)
-parsed = doc["parsed"]
-# the flagship bench config (bench.py): 1 worker x k=8 x batch=128,
-# uint8-staged 32x32x3 images + int64 labels + f32 mask
-samples_per_round = 8 * 128
-bytes_per_round = 8 * 128 * (32 * 32 * 3) + 8 * 128 * 8 + 8 * 128 * 4
-gap = gap_attribution(row["device_samples_per_sec"],
-                      row["end_to_end_samples_per_sec"],
-                      samples_per_round, bytes_per_round,
-                      flops_per_round=parsed.get("flops_per_round"))
-out = {"ts": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-       "kind": "recorded-chip-gap", "source": "BENCH_r05.json",
-       "metric": parsed.get("metric"), "gap": gap}
-with open("results/profile_demo.jsonl", "a") as f:
-    f.write(json.dumps(out) + "\n")
-print(f"BENCH_r05 gap: staging is {gap['staging_share']:.1%} of each "
-      f"end-to-end round at {gap['staging_bandwidth_bps'] / 1e6:.1f} MB/s")
-EOF
-
 # --- act 1: profiled bench -> per-phase attribution + gap row ---
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" KUBEML_BENCH_FORCE_CPU="${KUBEML_BENCH_FORCE_CPU:-1}" \
+JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
 KUBEML_FLAGSHIP="${KUBEML_FLAGSHIP:-lenet}" \
 KUBEML_BENCH_ROUNDS="${KUBEML_BENCH_ROUNDS:-4}" KUBEML_BENCH_REPS="${KUBEML_BENCH_REPS:-1}" \
 KUBEML_BENCH_PROFILE=1 \

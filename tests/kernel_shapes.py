@@ -1,0 +1,58 @@
+"""The Pallas kernels at the shapes the chip runs them (GPT-2-small widths,
+the serving defaults), as ``name -> (fn, abstract args)`` — shared by the
+cross-lowering test and its Mosaic subprocess (tests/mosaic_compile_proc.py).
+Every kernel is built with ``interpret=False``: this is the program the TPU
+gets, not the interpreter's."""
+
+import jax
+import jax.numpy as jnp
+
+B, H, D = 8, 12, 64          # decode rows, heads, head dim
+PT, PAGES, TABLE = 16, 513, 64  # page tokens, arena pages, table width
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+def kernel_cases():
+    from kubeml_tpu.ops.flash_attention import flash_attention
+    from kubeml_tpu.ops.int8_matmul import int8_matmul
+    from kubeml_tpu.ops.paged_attention import paged_attention
+
+    cases = {}
+    table = _sds((B, TABLE), jnp.int32)
+    pos = _sds((B,), jnp.int32)
+    scales = _sds((PAGES, H), jnp.float32)
+    # L: a decode step, a speculative verify window, one query tile of
+    # suffix prefill, and a prefill that spans several tiles
+    for L in (1, 5, 128, 512):
+        for name, q_dt, kv_dt in (("bf16", jnp.bfloat16, jnp.bfloat16),
+                                  ("f32", jnp.float32, jnp.float32),
+                                  ("int8", jnp.bfloat16, jnp.int8)):
+            q = _sds((B, L, H, D), q_dt)
+            arena = _sds((PAGES, H, PT, D), kv_dt)
+            if name == "int8":
+                fn = lambda q, k, v, t, p, ks, vs: paged_attention(
+                    q, k, v, t, p, interpret=False, k_scale=ks, v_scale=vs)
+                args = (q, arena, arena, table, pos, scales, scales)
+            else:
+                fn = lambda q, k, v, t, p: paged_attention(
+                    q, k, v, t, p, interpret=False)
+                args = (q, arena, arena, table, pos)
+            cases[f"paged_attention-{name}-L{L}"] = (fn, args)
+    # the MLP up-projection and the lm_head (vocab 50257: not a tile multiple)
+    for K, N in ((768, 3072), (768, 50257)):
+        cases[f"int8_matmul-{K}x{N}"] = (
+            lambda x, q, s: int8_matmul(x, q, s, interpret=False),
+            (_sds((B, K), jnp.bfloat16), _sds((K, N), jnp.int8),
+             _sds((1, N), jnp.float32)))
+    qkv = _sds((1, 2048, H, D), jnp.bfloat16)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            interpret=False)
+    cases["flash_attention-fwd"] = (flash, (qkv, qkv, qkv))
+    cases["flash_attention-bwd"] = (
+        jax.grad(lambda q, k, v: flash(q, k, v).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2)),
+        (qkv, qkv, qkv))
+    return cases

@@ -12,12 +12,7 @@ def main() -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from kubeml_tpu.utils.jax_compat import set_cpu_devices
-
-    set_cpu_devices(1)
-    from kubeml_tpu.utils.jax_compat import enable_cpu_gloo
-
-    enable_cpu_gloo()
+    jax.config.update("jax_num_cpu_devices", 1)
     jax.distributed.initialize(f"127.0.0.1:{port}", 2, pid)
 
     from kubeml_tpu.parallel.distributed import get_dist_context

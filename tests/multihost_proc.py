@@ -377,12 +377,8 @@ def main() -> int:
     jax.config.update("jax_platforms", "cpu")
     # default 2 local devices (4 global in the 2-proc tests); the 4-proc
     # tests run 1/process so the group stays light on a small CI box
-    from kubeml_tpu.utils.jax_compat import set_cpu_devices
-
-    set_cpu_devices(int(os.environ.get("KUBEML_TEST_LOCAL_DEVICES", "2")))
-    from kubeml_tpu.utils.jax_compat import enable_cpu_gloo
-
-    enable_cpu_gloo()
+    jax.config.update("jax_num_cpu_devices",
+                      int(os.environ.get("KUBEML_TEST_LOCAL_DEVICES", "2")))
     jax.distributed.initialize(
         coordinator_address=coordinator, num_processes=nprocs, process_id=rank
     )

@@ -1,5 +1,7 @@
-"""The bench regression gate (scripts/bench_compare.py) over the checked-in
-BENCH_r0*.json trajectory — the fast tier-1 wiring the gate is meant for."""
+"""The bench regression gate (scripts/bench_compare.py) over a trajectory of
+driver-wrapper records (``{"n", "rc", "parsed": <bench.py row>}``) — the
+fast tier-1 wiring the gate is meant for. The rows are fixtures with made-up
+values, not measurements."""
 
 import json
 import subprocess
@@ -20,8 +22,24 @@ def _run(*files, threshold=None):
     return subprocess.run(cmd, capture_output=True, text=True, cwd=str(REPO))
 
 
-def test_real_r04_to_r05_pair_passes():
-    p = _run(REPO / "BENCH_r04.json", REPO / "BENCH_r05.json")
+def _wrapper(tmp_path, n, value, end_to_end, mfu):
+    """One driver-wrapper record the way the driver stores a bench.py row."""
+    f = tmp_path / f"BENCH_r{n:02d}.json"
+    f.write_text(json.dumps({"n": n, "rc": 0, "parsed": {
+        "metric": "resnet18-cifar10-kavg-train-throughput", "value": value,
+        "unit": "samples/sec", "mfu": mfu, "end_to_end": end_to_end}}))
+    return f
+
+
+@pytest.fixture
+def trajectory(tmp_path):
+    return [_wrapper(tmp_path, 3, 900.0, 300.0, 0.30),
+            _wrapper(tmp_path, 4, 1000.0, 400.0, 0.40),
+            _wrapper(tmp_path, 5, 1200.0, 600.0, 0.48)]
+
+
+def test_improving_wrapper_pair_passes(trajectory):
+    p = _run(*trajectory[-2:])
     assert p.returncode == 0, p.stderr
     report = json.loads(p.stdout)
     assert report["pass"] is True
@@ -29,9 +47,8 @@ def test_real_r04_to_r05_pair_passes():
         "device_samples_per_sec", "end_to_end_samples_per_sec", "mfu"}
 
 
-def test_full_trajectory_compares_last_pair():
-    files = sorted(REPO.glob("BENCH_r0*.json"))
-    assert len(files) >= 3, "trajectory fixture missing"
+def test_full_trajectory_compares_last_pair(trajectory):
+    files = trajectory
     p = _run(*files)
     assert p.returncode == 0, p.stderr
     report = json.loads(p.stdout)
@@ -40,27 +57,28 @@ def test_full_trajectory_compares_last_pair():
     assert len(report["trajectory"]) == len(files)
 
 
-def test_synthetic_regression_fails_the_gate(tmp_path):
-    base = json.loads((REPO / "BENCH_r05.json").read_text())
+def test_synthetic_regression_fails_the_gate(tmp_path, trajectory):
+    last = trajectory[-1]
+    base = json.loads(last.read_text())
     cand = {"parsed": dict(base["parsed"])}
     cand["parsed"]["value"] = base["parsed"]["value"] * 0.85  # -15% device
     f = tmp_path / "cand.json"
     f.write_text(json.dumps(cand))
-    p = _run(REPO / "BENCH_r05.json", f)
+    p = _run(last, f)
     assert p.returncode == 1
     report = json.loads(p.stdout)
     assert report["pass"] is False
     assert report["regressions"][0]["metric"] == "device_samples_per_sec"
     # inside the threshold the same delta passes
-    assert _run(REPO / "BENCH_r05.json", f, threshold=0.20).returncode == 0
+    assert _run(last, f, threshold=0.20).returncode == 0
 
 
-def test_error_row_candidate_fails(tmp_path):
+def test_error_row_candidate_fails(tmp_path, trajectory):
     f = tmp_path / "err.json"
     f.write_text(json.dumps({"metric": "x", "value": 0.0,
                              "unit": "samples/sec", "vs_baseline": 0.0,
                              "error": "accelerator backend unreachable"}))
-    p = _run(REPO / "BENCH_r05.json", f)
+    p = _run(trajectory[-1], f)
     assert p.returncode == 1
     assert "error row" in p.stderr
 
@@ -167,14 +185,14 @@ def test_metric_direction_table():
                for _f, d in GATE_METRICS.values())
 
 
-def test_normalize_bench_row_handles_both_forms():
+def test_normalize_bench_row_handles_both_forms(trajectory):
     from kubeml_tpu.benchmarks.harness import normalize_bench_row
 
-    wrapper = json.loads((REPO / "BENCH_r05.json").read_text())
+    wrapper = json.loads(trajectory[-1].read_text())
     row = normalize_bench_row(wrapper)
-    assert row["device_samples_per_sec"] == pytest.approx(32791.3)
-    assert row["end_to_end_samples_per_sec"] == pytest.approx(14810.5)
-    assert row["mfu"] == pytest.approx(0.4857)
+    assert row["device_samples_per_sec"] == pytest.approx(1200.0)
+    assert row["end_to_end_samples_per_sec"] == pytest.approx(600.0)
+    assert row["mfu"] == pytest.approx(0.48)
     raw = normalize_bench_row(wrapper["parsed"])
     assert raw == row
     err = normalize_bench_row({"metric": "m", "value": 0.0, "error": "boom"})

@@ -6,32 +6,43 @@ import jax.numpy as jnp
 import pytest
 
 
-def test_compile_cache_dir_resolution(tmp_path):
-    from kubeml_tpu.api.config import Config
+def test_compile_cache_is_one_fixed_path_in_the_checkout(tmp_path, monkeypatch):
+    """Unset JAX_COMPILATION_CACHE_DIR -> the cache lands at the fixed
+    in-checkout path whatever the data root is (the directory is part of
+    the cache key: a path that moves with KUBEML_DATA_ROOT never hits)."""
+    import pathlib
 
-    cfg = Config(data_root=tmp_path, compile_cache="1")
-    assert cfg.compile_cache_dir == tmp_path / "xla-cache"
-    cfg = Config(data_root=tmp_path, compile_cache="0")
-    assert cfg.compile_cache_dir is None
-    cfg = Config(data_root=tmp_path, compile_cache=str(tmp_path / "elsewhere"))
-    assert cfg.compile_cache_dir == tmp_path / "elsewhere"
+    from kubeml_tpu.api import config
 
-
-def test_enable_compilation_cache_populates_dir(tmp_path):
-    from kubeml_tpu.api.config import Config
-
-    cfg = Config(data_root=tmp_path, compile_cache="1")
-    cfg.enable_compilation_cache()
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert config.COMPILE_CACHE_DIR == repo / ".cache" / "xla"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
     try:
-        assert cfg.compile_cache_dir.exists()
-        # a slow-enough compile lands an entry on disk
-        f = jax.jit(lambda x: jnp.sin(x) @ jnp.cos(x).T)
-        jax.block_until_ready(f(jnp.ones((256, 256))))
-        # cache write is best-effort/async-ish; entries may need a distinct,
-        # costly computation — assert the config took, not XLA internals
-        assert jax.config.jax_compilation_cache_dir == str(cfg.compile_cache_dir)
+        for root in (tmp_path / "a", tmp_path / "b"):
+            monkeypatch.setenv("KUBEML_DATA_ROOT", str(root))
+            assert config.enable_compilation_cache() == config.COMPILE_CACHE_DIR
+            assert (jax.config.jax_compilation_cache_dir
+                    == str(config.COMPILE_CACHE_DIR))
+            assert config.COMPILE_CACHE_DIR.is_dir()
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_placed_from_outside_is_left_to_jax(tmp_path,
+                                                          monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set -> jax reads it itself and the code
+    sets no directory (a sentinel in the config must survive the call)."""
+    from kubeml_tpu.api import config
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert config.enable_compilation_cache() == tmp_path / "placed"
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_remat_model_matches_plain(rng):

@@ -327,7 +327,18 @@ class TestClusterEndToEnd:
             if any(t.job_id == job_id for t in tasks):
                 break
             time.sleep(0.1)
-        client.tasks().stop(job_id)
+        # a task is listed from the moment its slot is reserved; until the
+        # job object exists the PS answers a stop with 409 "still starting"
+        from kubeml_tpu.api.errors import KubeMLError
+
+        while True:
+            try:
+                client.tasks().stop(job_id)
+                break
+            except KubeMLError as e:
+                if e.status_code != 409 or time.time() > deadline:
+                    raise
+                time.sleep(0.05)
         _wait_done(client, job_id)
 
     def test_prometheus_metrics_endpoint(self, cluster):

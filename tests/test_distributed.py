@@ -25,6 +25,30 @@ def test_init_distributed_single_process_noop(monkeypatch):
     assert jax.process_count() == 1
 
 
+def test_one_host_tpu_vm_is_not_a_pod(monkeypatch):
+    """A single TPU VM exports the pod variables with ONE host in them: that
+    must stay single-process without ever calling jax.distributed (only an
+    environment naming several hosts is auto-detected, and its failure is
+    then fatal, not swallowed)."""
+    from kubeml_tpu.parallel import distributed
+
+    assert distributed._pod_hosts({}) == 0
+    assert distributed._pod_hosts({"TPU_WORKER_HOSTNAMES": "localhost"}) == 1
+    assert distributed._pod_hosts(
+        {"TPU_WORKER_HOSTNAMES": "10.0.0.2,10.0.0.3"}) == 2
+    assert distributed._pod_hosts(
+        {"TPU_PROCESS_ADDRESSES": "a:8476,b:8476,c:8476,d:8476"}) == 4
+    assert distributed._pod_hosts(
+        {"MEGASCALE_COORDINATOR_ADDRESS": "10.0.0.2"}) > 1
+    monkeypatch.delenv("KUBEML_COORDINATOR", raising=False)
+    monkeypatch.delenv("KUBEML_NUM_PROCESSES", raising=False)
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    monkeypatch.setenv("CLOUD_TPU_TASK_ID", "0")
+    monkeypatch.setattr(jax.distributed, "initialize", lambda *a, **k: (
+        _ for _ in ()).throw(AssertionError("initialize() on one host")))
+    assert init_distributed() is False
+
+
 def test_num_slices_cpu_is_one():
     assert num_slices() == 1
 

@@ -173,14 +173,13 @@ def test_non_divisor_batch_size_trains(mnist_store, tmp_config):
 
 
 def test_transient_accelerator_error_retried(mnist_store, tmp_config):
-    """A round that fails with a transient RPC-style fault (e.g. the remote
-    compile service dropping the connection) is retried and the job completes;
-    a non-transient error still fails the job immediately."""
+    """A round that fails with a transient RPC-style fault (e.g. a
+    preempted backend dropping the connection) is retried and the job
+    completes; a non-transient error still fails the job immediately."""
     from kubeml_tpu.engine.failures import is_transient_accelerator_error
 
     assert is_transient_accelerator_error(
-        RuntimeError("INTERNAL: http://x/remote_compile: read body: "
-                     "response body closed before all bytes were read"))
+        RuntimeError("UNAVAILABLE: Connection reset by peer"))
     assert not is_transient_accelerator_error(ValueError("bad shapes"))
     # bare INTERNAL is how genuine XLA program/compiler bugs present — NOT
     # transient unless corroborated by an RPC/transport-layer marker

@@ -57,6 +57,14 @@ def gather_reference(q, k_pages, v_pages, pages, positions):
     return dot_product_attention(q, kg, vg, mask=mask)
 
 
+def paged(q, k_tok, v_tok, pages, positions, **kw):
+    """The op-level cases build their arenas token-major ``[N, pt, H, D]``
+    (a page reads as rows of tokens, like the gather reference above); the
+    device arena the kernel takes is head-major ``[N, H, pt, D]``."""
+    return paged_attention(q, jnp.swapaxes(k_tok, 1, 2),
+                           jnp.swapaxes(v_tok, 1, 2), pages, positions, **kw)
+
+
 # --- op-level kernel parity (interpret mode) ---
 
 
@@ -85,7 +93,25 @@ def test_kernel_logit_parity(L, positions):
     pages = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
     pos = jnp.asarray(positions, jnp.int32)
-    out = paged_attention(q, k_pages, v_pages, pages, pos)
+    out = paged(q, k_pages, v_pages, pages, pos)
+    ref = gather_reference(q, k_pages, v_pages, pages, pos)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.kernel
+def test_kernel_query_tiles_long_prefill():
+    """A prefill longer than one query tile (128) walks the table once
+    per tile, each tile clamped at its own causal depth; rows start at
+    different bases so the tile/page boundaries do not line up."""
+    rng = np.random.default_rng(3)
+    B, H, D, pt, P, N, L = 2, 2, 16, 8, 24, 50, 136
+    k_pages = jnp.asarray(rng.normal(size=(N, pt, H, D)), jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=(N, pt, H, D)), jnp.float32)
+    pages = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
+    pos = jnp.asarray([0, 51], jnp.int32)
+    out = paged(q, k_pages, v_pages, pages, pos)
     ref = gather_reference(q, k_pages, v_pages, pages, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
@@ -102,7 +128,7 @@ def test_kernel_bf16_storage_dtype():
     pages = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.bfloat16)
     pos = jnp.asarray([7, 11], jnp.int32)
-    out = paged_attention(q, k_pages, v_pages, pages, pos)
+    out = paged(q, k_pages, v_pages, pages, pos)
     assert out.dtype == jnp.bfloat16
     ref = gather_reference(q, k_pages, v_pages, pages, pos)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -140,10 +166,9 @@ def test_kernel_poisoned_trash_page_cannot_leak():
     q = jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
     pos = jnp.asarray(positions, jnp.int32)
     pages = jnp.asarray(pages)
-    out_clean = paged_attention(q, jnp.asarray(clean), jnp.asarray(clean),
-                                pages, pos)
-    out_poison = paged_attention(q, jnp.asarray(poisoned),
-                                 jnp.asarray(poisoned), pages, pos)
+    out_clean = paged(q, jnp.asarray(clean), jnp.asarray(clean), pages, pos)
+    out_poison = paged(q, jnp.asarray(poisoned), jnp.asarray(poisoned),
+                       pages, pos)
     np.testing.assert_array_equal(np.asarray(out_clean),
                                   np.asarray(out_poison))
 
@@ -418,7 +443,7 @@ def test_kernel_int8_parity_and_bounded_divergence(L, positions):
     pages = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
     pos = jnp.asarray(positions, jnp.int32)
-    out = paged_attention(q, kq, vq, pages, pos, k_scale=ks, v_scale=vs)
+    out = paged(q, kq, vq, pages, pos, k_scale=ks, v_scale=vs)
     deq_ref = gather_reference(q, jnp.asarray(dequantize_pages(kq, ks)),
                                jnp.asarray(dequantize_pages(vq, vs)),
                                pages, pos)
@@ -467,11 +492,9 @@ def test_kernel_int8_poisoned_arena_cannot_leak():
     q = jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
     pos = jnp.asarray(positions, jnp.int32)
     pages = jnp.asarray(pages)
-    out_clean = paged_attention(q, kq, kq, pages, pos,
-                                k_scale=ks, v_scale=ks)
-    out_poison = paged_attention(q, jnp.asarray(kq_p), jnp.asarray(kq_p),
-                                 pages, pos, k_scale=jnp.asarray(ks_p),
-                                 v_scale=jnp.asarray(ks_p))
+    out_clean = paged(q, kq, kq, pages, pos, k_scale=ks, v_scale=ks)
+    out_poison = paged(q, jnp.asarray(kq_p), jnp.asarray(kq_p), pages, pos,
+                       k_scale=jnp.asarray(ks_p), v_scale=jnp.asarray(ks_p))
     np.testing.assert_array_equal(np.asarray(out_clean),
                                   np.asarray(out_poison))
 

@@ -663,7 +663,7 @@ def test_threaded_preempt_requeues_and_completes(tmp_config, capsys):
 
 @pytest.mark.preempt
 @pytest.mark.chaos
-def test_sigkill_mid_yield_resumes_uncorrupted(tmp_config, monkeypatch):
+def test_sigkill_mid_yield_resumes_uncorrupted(tmp_config):
     """The acceptance scenario: a standalone job is preempted and its runner
     SIGKILLed mid-yield/mid-checkpoint. Because checkpoint publish is atomic
     and the journal entry was kept, the PS marks it `preempted` (not failed),
@@ -673,8 +673,6 @@ def test_sigkill_mid_yield_resumes_uncorrupted(tmp_config, monkeypatch):
     from kubeml_tpu.ps.journal import JobJournal
 
     tmp_config.standalone_jobs = True
-    tmp_config.platform = "cpu"
-    monkeypatch.setenv("KUBEML_NUM_CPU_DEVICES", "8")
     epochs = 30
     with LocalCluster(config=tmp_config) as cluster:
         x, y = make_blobs(256, shape=(8, 8, 1))

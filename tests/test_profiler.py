@@ -120,8 +120,8 @@ def test_profile_session_dump_appends_jsonl(tmp_path):
 
 
 def test_gap_attribution_quantifies_staging_share():
-    """The BENCH_r05 question: 32.8k device vs 14.8k end-to-end means ~55%
-    of every end-to-end round is staging."""
+    """32.8k device vs 14.8k end-to-end means ~55% of every end-to-end
+    round is staging."""
     g = profiler.gap_attribution(32791.3, 14810.5, 8192, 12_582_912,
                                  flops_per_round=3e12)
     assert g["staging_share"] == pytest.approx(0.548, abs=0.01)
@@ -135,6 +135,9 @@ def test_classify_roofline_terms():
     assert profiler.classify(0, 0) == "host"
     assert profiler.classify(1e9, 0) == "transfer-bound"
     assert profiler.classify(0, 1e9) == "compute-bound"
+    # both terms nonzero on a device with no entry in the peaks table (this
+    # CPU): no machine to draw a roofline for
+    assert profiler.classify(1e9, 1e9) == "unknown"
 
 
 # --- flight recorder ---

@@ -34,13 +34,11 @@ class Model(KubeModel):
 
 
 @pytest.fixture
-def standalone_cluster(tmp_config, monkeypatch):
+def standalone_cluster(tmp_config):
     from conftest import make_blobs
     from kubeml_tpu.cluster import LocalCluster
 
     tmp_config.standalone_jobs = True
-    tmp_config.platform = "cpu"
-    monkeypatch.setenv("KUBEML_NUM_CPU_DEVICES", "8")
     with LocalCluster(config=tmp_config) as cluster:
         store = cluster.store
         x, y = make_blobs(256, shape=(8, 8, 1))
@@ -97,6 +95,37 @@ def test_standalone_job_end_to_end(standalone_cluster):
     # runner pushed per-epoch metrics through POST /metrics/{jobId}
     text = cluster.ps.metrics.render()
     assert "kubeml_job" in text or hist.train_loss  # gauges cleared at finish
+
+
+def test_standalone_refused_on_a_tpu_backend(tmp_config, monkeypatch):
+    """A chip belongs to one process: on a TPU host the cluster holds it, so
+    a runner child could never open the device (measured on a v5e: it binds
+    its port, then every /start dies in libtpu's lockfile). The start is
+    refused up front, with the reason, and no child is spawned."""
+    import subprocess
+
+    import jax
+
+    from conftest import make_blobs
+    from kubeml_tpu.api.types import TrainOptions, TrainRequest
+    from kubeml_tpu.cluster import LocalCluster
+
+    tmp_config.standalone_jobs = True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(subprocess, "Popen", lambda *a, **k: (
+        _ for _ in ()).throw(AssertionError("spawned a runner")))
+    with LocalCluster(config=tmp_config, serve_http=False) as cluster:
+        x, y = make_blobs(64, shape=(8, 8, 1))
+        cluster.store.create("blobs", x, y, x[:16], y[:16])
+        cluster.registry.create("tiny", FN_SOURCE)
+        job_id = cluster.scheduler.submit_train(TrainRequest(
+            function_name="tiny", dataset="blobs", epochs=1, batch_size=16,
+            options=TrainOptions(default_parallelism=1,
+                                 static_parallelism=True, k=2)))
+        assert _wait_done(cluster, job_id, timeout=60)
+        error = cluster.history_store.get(job_id).task["error"]
+    assert "STANDALONE_JOBS cannot run on a TPU host" in error
+    assert "one process" in error
 
 
 def test_standalone_per_job_logs_via_cli(standalone_cluster, capsys):
