@@ -33,6 +33,7 @@ one resident program.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import queue
 import threading
@@ -48,6 +49,7 @@ import numpy as np
 from ..api.errors import KubeMLError
 from ..models.generation import GenerationInputError, init_cache
 from ..models.gpt import PAD_ID
+from ..utils import tracing
 
 log = logging.getLogger("kubeml.serving")
 
@@ -55,6 +57,9 @@ log = logging.getLogger("kubeml.serving")
 # are applied by thresholding against the k-th of these. Requests cap top_k
 # at this bound (api.types.GENERATE_MAX_TOP_K mirrors it on the wire).
 TOP_K_MAX = 128
+
+# what _run_program enters around a jitted call when the tracer is off
+_NO_ANNOTATION = contextlib.nullcontext()
 
 # default decode-row count shared by both engines: PagedBatchingDecoder must
 # size its arena BEFORE the base __init__ resolves slots, so the fallback
@@ -281,11 +286,10 @@ class _Entry:
     # a row still QUEUED past it fails fast with 504 instead of taking a slot
     deadline: Optional[float] = None
     # lifecycle attribution: a per-request id (returned in the result so
-    # `kubeml trace <request-id>` finds the serving span tree), the wall
-    # clock at submit (span anchor), and the submitter's trace context
-    # (the HTTP server span — serving spans parent under it)
+    # `kubeml trace <request-id>` finds the serving span tree) and the
+    # submitter's trace context (the HTTP server span — serving spans
+    # parent under it)
     request_id: str = ""
-    wall0: float = 0.0
     trace_ctx: Optional[object] = None
 
     def finished(self) -> bool:
@@ -424,11 +428,33 @@ def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
     return int(depth) * 2 * int(page_tokens) * int(embed) * int(itemsize)
 
 
+def service_interval(dispatched: float, done: float,
+                     prev_done: float) -> tuple:
+    """``(svc_s, wait_s, done)`` of one program, taken where it completes.
+
+    Programs run in dispatch order on one device stream, so a program
+    starts when it has been dispatched AND the one before it is done:
+    ``start = max(dispatched, prev_done)``. ``svc_s = done - start`` is its
+    own time on the device (plus the trip of its result to the host) and
+    ``wait_s = start - dispatched`` the time it sat behind programs
+    dispatched earlier. The wall of the value fetch covers both, so with
+    six programs in flight it reads six steps where this reads one.
+    ``done`` comes back as a running maximum: fetcher threads may stamp two
+    completions a little out of order, and the later program then reads 0,
+    never a negative time. Call in dispatch order, feeding ``done`` back
+    as the next ``prev_done``; all three are one clock's readings."""
+    done = max(done, prev_done)
+    start = max(dispatched, prev_done)
+    return done - start, start - dispatched, done
+
+
 class _FetchPool:
     """The result-fetch thread pool both engine loops share: dispatched
     device programs are materialized off-thread (each fetch pays the
     host<->device round trip), the engine consumes them in dispatch order.
-    ``stats`` hooks feed the kubeml_serving_fetch* observability."""
+    ``stats`` hooks feed the kubeml_serving_fetch* observability. A result
+    waits in ``done`` as ``(record, fetch start, fetch end, thread)``,
+    clocks monotonic: the end is where the program is known complete."""
 
     def __init__(self, decoder, n: int):
         self.q: queue.Queue = queue.Queue()
@@ -457,9 +483,10 @@ class _FetchPool:
             except Exception as e:  # surfaces on the engine thread
                 out = ("error", e)
             finally:
-                dec.stats.fetch_finished(time.monotonic() - t0)
+                t1 = time.monotonic()
+                dec.stats.fetch_finished(t1 - t0)
             with self.cv:
-                self.done[seq] = out
+                self.done[seq] = (out, t0, t1, threading.get_ident())
                 self.cv.notify_all()
 
     def submit(self, seq: int, rec: tuple) -> None:
@@ -468,6 +495,7 @@ class _FetchPool:
     def clear(self) -> None:
         with self.cv:
             self.done.clear()
+        self._decoder._inflight.clear()
 
     def stop(self) -> None:
         for _ in self._threads:
@@ -665,6 +693,21 @@ class BatchingDecoder:
         # steps already in the dispatch chain per slot (gates chunk dispatch)
         self._steps_ahead: List[int] = [0] * self.slots
         self._thread: Optional[threading.Thread] = None
+        # --- the dispatch timeline (engine thread only) ---
+        # sequence number the next dispatched program gets: one counter for
+        # the loop's pipeline arithmetic, the fetch pool's ordering and the
+        # engine.* spans
+        self._next_seq = 0
+        # seq -> (end of its jitted call on the monotonic clock, program
+        # kind, ids of the requests it admits) of every program dispatched
+        # and not yet consumed; with the completion stamps of the fetch
+        # pool, what service_interval needs
+        self._inflight: Dict[int, tuple] = {}
+        self._prev_done = 0.0   # running maximum of completions consumed
+        # tracer clock at which the admission work not yet inside an
+        # engine.admit span began (0 = tracing was off then)
+        self._admit_from = 0.0
+        self._tracer = tracing.get_tracer()
         # programs are built lazily on the engine thread (first submit);
         # the slab is donated through every link of the dispatch chain
         # (on every backend: the CPU suite runs the chip's buffer lifetimes)
@@ -908,7 +951,6 @@ class BatchingDecoder:
                        submitted_at=time.monotonic(),
                        deadline=resilience.current_deadline(),
                        request_id=self._next_request_id(),
-                       wall0=time.time(),
                        trace_ctx=tracing.current_context())
         for i in range(B):
             key = (np.asarray(jax.random.fold_in(base_key, i))
@@ -1035,9 +1077,7 @@ class BatchingDecoder:
         does for train tasks — with queue-wait/prefill/decode child spans
         reconstructed from the row timeline. Called exactly once per entry,
         by whichever site claimed the telemetry outcome."""
-        from ..utils import tracing
-
-        tracer = tracing.get_tracer()
+        tracer = self._tracer
         if not tracer.enabled:
             return
         try:
@@ -1049,10 +1089,9 @@ class BatchingDecoder:
             first = min((r.first_emit_at for r in entry.rows
                          if r.first_emit_at), default=0.0)
             last = max((r.last_emit_at for r in entry.rows), default=0.0)
-            wall = entry.wall0 - sub  # monotonic -> wall anchor
             ctx = entry.trace_ctx
             req = tracer.add_span(
-                "serving.request", entry.wall0, (last or now) - sub,
+                "serving.request", tracer.at(sub), (last or now) - sub,
                 trace_id=ctx.trace_id if ctx is not None else None,
                 parent_id=ctx.span_id if ctx is not None else None,
                 job=entry.request_id, model=self.name,
@@ -1068,13 +1107,13 @@ class BatchingDecoder:
             kw = dict(trace_id=req.trace_id, parent_id=req.span_id,
                       job=entry.request_id)
             if slot_at:
-                tracer.add_span("serving.queue_wait", entry.wall0,
+                tracer.add_span("serving.queue_wait", tracer.at(sub),
                                 slot_at - sub, **kw)
                 if first:
-                    tracer.add_span("serving.prefill", wall + slot_at,
+                    tracer.add_span("serving.prefill", tracer.at(slot_at),
                                     first - slot_at, **kw)
             if first and last > first:
-                tracer.add_span("serving.decode", wall + first,
+                tracer.add_span("serving.decode", tracer.at(first),
                                 last - first, **kw)
         except Exception:  # span emission must never fail the serving path
             log.debug("serving timeline emission failed", exc_info=True)
@@ -1254,7 +1293,6 @@ class BatchingDecoder:
             return
 
         pool = _FetchPool(self, self.fetchers)
-        next_seq = 0       # next dispatch sequence number
         process_seq = 0    # next result to consume (in dispatch order)
         self._steps_ahead = [0] * self.slots
 
@@ -1264,17 +1302,19 @@ class BatchingDecoder:
             self._sweep_expired()
             with self._cond:
                 while (not self._closed and not self._pending
-                       and not self._busy() and process_seq == next_seq):
+                       and not self._busy()
+                       and process_seq == self._next_seq):
                     if self._retired:
                         self._slab = None  # free the KV slab's HBM
                         pool.stop()
                         return
-                    self._cond.wait()
+                    self._wait_work()
                 if self._closed:
                     pool.stop()
                     return
                 admits = []
-                if next_seq - process_seq < self.pipeline_depth:
+                self._admit_from = self._span_clock()
+                if self._next_seq - process_seq < self.pipeline_depth:
                     while self._free and self._pending:
                         admits.append((self._free.pop(0),
                                        self._pending.popleft()))
@@ -1289,7 +1329,7 @@ class BatchingDecoder:
                     live_admits.append((slot, row))
                 groups = self._group_admits(live_admits)
                 for gi, group in enumerate(groups):
-                    if next_seq - process_seq >= self.pipeline_depth:
+                    if self._next_seq - process_seq >= self.pipeline_depth:
                         # backpressure mid-wave (multi-bucket admissions):
                         # requeue the untouched remainder
                         rest = [p for g in groups[gi:] for p in g]
@@ -1298,27 +1338,26 @@ class BatchingDecoder:
                                 self._free.insert(0, slot)
                                 self._pending.appendleft(row)
                         break
-                    pool.submit(next_seq, self._dispatch_admits(group))
-                    next_seq += 1
+                    self._submit_program(pool, self._dispatch_admits(group))
                     dispatched = True
                 self._evict_canceled()
                 self._free_drained_slots()
-                if (next_seq - process_seq < self.pipeline_depth
+                if (self._next_seq - process_seq < self.pipeline_depth
                         and (needed := self._chunk_wanted()) > 0):
-                    pool.submit(next_seq, self._dispatch_chunk(needed))
-                    next_seq += 1
+                    self._submit_program(pool, self._dispatch_chunk(needed))
                     dispatched = True
                 # consume materialized results in order; block only when the
                 # pipe is full or nothing else can make progress
-                must_wait = (next_seq - process_seq >= self.pipeline_depth
-                             or (not dispatched and process_seq < next_seq))
+                must_wait = (
+                    self._next_seq - process_seq >= self.pipeline_depth
+                    or (not dispatched and process_seq < self._next_seq))
                 process_seq = self._consume_ready(pool, process_seq,
-                                                  next_seq, must_wait)
+                                                  must_wait)
             except Exception as e:
                 log.exception("%s: decode loop failed", self.name)
                 # drain whatever the fetchers still owe so seqs stay aligned
                 pool.clear()
-                process_seq = next_seq
+                process_seq = self._next_seq
                 self._fail_all(e, wrap=True)
                 with self._cond:
                     if self._closed:
@@ -1343,23 +1382,81 @@ class BatchingDecoder:
         slab is initialized (the paged engine rebuilds its page pool here —
         a zeroed arena invalidates every cached page)."""
 
+    # --- the dispatch timeline: engine.* spans (utils.tracing) ---
+    #
+    # Every site reads ``self._tracer.enabled`` and does nothing more when
+    # it is off. What is always on is what feeds the stats: the clock read
+    # at the end of each jitted call and the fetcher's two, through
+    # service_interval.
+
+    def _span_clock(self) -> float:
+        """The tracer's clock when it is on, else 0."""
+        return self._tracer.now() if self._tracer.enabled else 0.0
+
+    def _wait_work(self) -> None:
+        """``self._cond.wait()`` of a loop with nothing pending, no live
+        row and nothing in flight, as an ``engine.wait_work`` span: the
+        device's idle time inside one is nobody's fault."""
+        t0 = self._span_clock()
+        self._cond.wait()
+        if t0:
+            self._tracer.add_span("engine.wait_work", t0,
+                                  self._tracer.now() - t0)
+
+    def _engine_span(self, name: str, start: float, duration: float,
+                     requests: Optional[str], **attrs: Any) -> None:
+        """One engine.* span; ``requests`` (ids, comma-separated) rides on
+        the spans of an admitting program so that ``kubeml trace <id>``
+        finds them."""
+        if requests:
+            attrs["requests"] = requests
+        self._tracer.add_span(name, start, duration, **attrs)
+
+    def _submit_program(self, pool: _FetchPool, rec: tuple) -> None:
+        """Hand the record of the program just dispatched to the fetchers
+        under the sequence number ``_run_program`` gave it."""
+        pool.submit(self._next_seq, rec)
+        self._next_seq += 1
+
     def _consume_ready(self, pool: _FetchPool, process_seq: int,
-                       next_seq: int, must_wait: bool) -> int:
+                       must_wait: bool) -> int:
         """Consume materialized results in dispatch order; blocks only while
         ``must_wait`` (pipe full, or nothing else can make progress) and
         returns the advanced ``process_seq``. A fetch error re-raises on the
-        engine thread."""
-        while process_seq < next_seq:
+        engine thread. Each result's completion stamp becomes its program's
+        service time here, where the one before it is known."""
+        tracer = self._tracer
+        waiting = 0.0   # tracer clock since which the engine has blocked
+        while process_seq < self._next_seq:
             with pool.cv:
                 if process_seq not in pool.done:
                     if not must_wait:
                         break
+                    waiting = waiting or self._span_clock()
                     pool.cv.wait(timeout=1.0)
                     continue
-                rec = pool.done.pop(process_seq)
+                rec, t0, t1, thread = pool.done.pop(process_seq)
+            dispatched, kind, requests = self._inflight.pop(
+                process_seq, (t0, rec[0], None))
+            svc_s, wait_s, self._prev_done = service_interval(
+                dispatched, t1, self._prev_done)
+            if tracer.enabled:
+                if waiting:
+                    tracer.add_span("engine.wait_result", waiting,
+                                    tracer.now() - waiting, seq=process_seq)
+                    waiting = 0.0
+                self._engine_span("engine.fetch", tracer.at(t0), t1 - t0,
+                                  requests, thread=thread, seq=process_seq,
+                                  program=kind, svc_s=svc_s, wait_s=wait_s)
             if rec[0] == "error":
                 raise rec[1]
-            self._process_record(rec)
+            began = self._span_clock()
+            tokens = self._process_record(rec, svc_s)
+            if began:
+                self._engine_span("engine.process", began,
+                                  tracer.now() - began,
+                                  requests, seq=process_seq, program=kind,
+                                  tokens=tokens)
             process_seq += 1
             must_wait = False  # one result is progress enough
         return process_seq
@@ -1384,22 +1481,55 @@ class BatchingDecoder:
             return 0
         return max(self._remaining_steps(), default=0)
 
-    def _run_program(self, program: str, sig: tuple, fn, *args):
+    def _run_program(self, program: str, sig: tuple, fn, *args, kind: str,
+                     steps: int = 0, width: int = 0, group=None):
         """Dispatch one jitted program through the compile tracker: the
         first call per (program, shape signature) traces + XLA-compiles
         synchronously before the async dispatch, so its wall here IS the
         compile wall — measured into kubeml_serving_compile_seconds and
-        flagged cold so the dispatch record's fetch wall lands in the
+        flagged cold so the dispatch record's service time lands in the
         cold-start series, never the steady-state decode_step/first_token
-        histograms. Cache hits skip the clock entirely. Returns
-        ``(fn(*args), cold)``."""
+        histograms. Returns ``(fn(*args), cold)``.
+
+        The end of the call is where the program's wait for the device
+        begins (service_interval), so it is stamped every time. With the
+        tracer on the call is an ``engine.dispatch`` span — ``kind`` is the
+        record's (admit, step, spec, pchunk), ``steps`` the decode steps in
+        the program, ``width`` its page-table width — under a profiler
+        annotation of the same name, and an admitting program (``group``
+        set) closes the ``engine.admit`` span that began where its rows
+        were taken from the queue."""
         cold = self.stats.compile_begin(program, sig)
-        if not cold:
-            return fn(*args), False
+        tracer, seq, requests = self._tracer, self._next_seq, None
+        traced, annotation = tracer.enabled, _NO_ANNOTATION
         t0 = time.monotonic()
-        out = fn(*args)
-        self.stats.compiled(program, time.monotonic() - t0)
-        return out, True
+        if traced:
+            if group is not None:
+                requests = ",".join(dict.fromkeys(
+                    row.entry.request_id for _, row in group))
+                began = self._admit_from or tracer.at(t0)
+                tracer.add_span("engine.admit", began, tracer.at(t0) - began,
+                                rows=len(group), requests=requests)
+            annotation = jax.profiler.TraceAnnotation("engine.dispatch",
+                                                      seq=seq)
+        # ONE call site, traced or not: a program's source locations are
+        # part of what its compile-cache entry is found by, and a second
+        # site compiled every program again when tracing came on (+49 s of
+        # set-up on the chip, PR 24)
+        with annotation:
+            out = fn(*args)
+        t1 = time.monotonic()
+        if traced:
+            self._engine_span(
+                "engine.dispatch", tracer.at(t0), t1 - t0, requests, seq=seq,
+                program=kind, steps=steps, width=width, cold=cold,
+                rows_live=sum(r is not None for r in self._slot_rows))
+            # a later group of the same wave is prepared from here on
+            self._admit_from = tracer.at(t1)
+        if cold:
+            self.stats.compiled(program, t1 - t0)
+        self._inflight[seq] = (t1, kind, requests)
+        return out, cold
 
     def _stalled_rows(self) -> List[_Row]:
         """Live decoding rows with host-known work NOT yet in the dispatch
@@ -1414,15 +1544,12 @@ class BatchingDecoder:
     def _materialize(self, rec: tuple) -> tuple:
         """Runs on a fetcher thread: the value fetch (the host needs the
         tokens, and fetching them waits for the program), returning a
-        host-data record. The fetch wall time rides the record — it is the
-        chunk's device execution barrier, so wall/steps is the decode-step
-        latency and kv_bytes/wall the achieved KV-read bandwidth."""
-        t0 = time.monotonic()
+        host-data record. Its return is the program's execution barrier:
+        the pool stamps it, and _consume_ready turns the stamp into the
+        program's service time."""
         if rec[0] == "admit":
-            return ("admit", rec[1], np.asarray(rec[2]), rec[3], rec[4],
-                    rec[5], time.monotonic() - t0)
-        return ("chunk", np.asarray(rec[1]), rec[2], rec[3], rec[4], rec[5],
-                time.monotonic() - t0)
+            return ("admit", rec[1], np.asarray(rec[2])) + rec[3:]
+        return ("chunk", np.asarray(rec[1])) + rec[2:]
 
     def _group_admits(self, admits: List[tuple]) -> List[List[tuple]]:
         """Split an admission wave into same-prompt-bucket groups (each group
@@ -1476,7 +1603,7 @@ class BatchingDecoder:
             self._variables, self._slab, jnp.asarray(prompts),
             jnp.asarray(plens), jnp.asarray(slots), jnp.asarray(max_news),
             jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(eoss),
-            jnp.asarray(keys))
+            jnp.asarray(keys), kind="admit", group=group)
         now = time.monotonic()
         real_tokens = 0
         for slot, row in group:
@@ -1527,7 +1654,8 @@ class BatchingDecoder:
         # cause=prefill_colocated in the decode-step histogram
         coloc = self._admits_inflight > 0
         (self._slab, packed), cold = self._run_program(
-            "step", (size,), self._steps[size], self._variables, self._slab)
+            "step", (size,), self._steps[size], self._variables, self._slab,
+            kind="step", steps=size)
         for slot in range(self.slots):
             self._steps_ahead[slot] += size
         self.stats.chunk()
@@ -1536,32 +1664,36 @@ class BatchingDecoder:
                 size * self.slots * self.max_len * self._kv_token_bytes,
                 cold, coloc)
 
-    def _process_record(self, rec: tuple) -> None:
-        """Fetch one in-flight program's packed results (ONE np.asarray —
-        each fetch pays a host round trip) and route its tokens."""
+    def _charge_stall(self, stalled: List[_Row], svc_s: float) -> None:
+        """Head-of-line attribution: a prefill-carrying program's service
+        time was decode time every stalled row lost; charge it to each."""
+        if svc_s > 0 and stalled:
+            self.stats.hol_stall(svc_s, len(stalled))
+            for r in stalled:
+                r.hol_stall += svc_s
+
+    def _process_record(self, rec: tuple, svc_s: float) -> int:
+        """Route one materialized program's results to their rows.
+        ``svc_s`` is the program's own time (service_interval): it feeds the
+        decode-step, KV-bandwidth, head-of-line and cold-start series.
+        Returns the tokens routed."""
         if rec[0] == "admit":
-            _, group, packed, kv_bytes, cold, stalled, fetch_s = rec
-            packed = np.asarray(packed)  # [k, 2] (first, live0)
+            _, group, packed, kv_bytes, cold, stalled = rec
             self._admits_inflight = max(0, self._admits_inflight - 1)
             # prefill KV reads count toward the byte total; the per-chunk
             # bandwidth observation stays a DECODE-path signal
             self.stats.kv_read(kv_bytes)
-            # head-of-line attribution: this prefill dispatch's wall (the
-            # blocking fetch — its execution barrier) was decode time every
-            # stalled row lost; charge it to each of them
-            if fetch_s > 0 and stalled:
-                self.stats.hol_stall(fetch_s, len(stalled))
-                for r in stalled:
-                    r.hol_stall += fetch_s
+            self._charge_stall(stalled, svc_s)
             if cold:
-                # first-call wall = trace + compile + execute: quarantined
-                self.stats.cold_start(fetch_s)
+                # first execution of a fresh program: quarantined
+                self.stats.cold_start(svc_s)
             # first processed result of EITHER kind flips the cold-start
             # allowance off: admit-only traffic (max_new_tokens=1) must not
             # keep inflating client timeouts forever; a later first chunk
             # compile fits inside the normal request-scaled timeout
             self._warmed = True
             now = time.monotonic()
+            tokens = 0
             for i, (slot, row) in enumerate(group):
                 if row.canceled:
                     continue  # _evict_canceled owns the slot bookkeeping
@@ -1571,20 +1703,19 @@ class BatchingDecoder:
                 first = int(packed[i, 0])
                 row.out.append(first)
                 self._emit_delta(row, [first], cold=cold)
+                tokens += 1
                 if not bool(packed[i, 1]):
                     self._complete_row(slot, row)
-            return
-        _, packed, snapshot, kv_bytes, cold, coloc, fetch_s = rec
-        packed = np.asarray(packed)  # [T, S]; -1 = not emitted
-        # decode-step histogram feed: the blocking fetch (measured in
-        # _materialize, where the np.asarray actually waits on the device)
-        # is the chunk's execution barrier, so wall/steps is the per-step
-        # decode latency — and kv_bytes/wall the achieved KV bandwidth.
-        # Cold first-call walls quarantine to the cold-start series; steps
-        # colocated with in-flight prefill split to cause=prefill_colocated
-        self.stats.chunk_fetched(fetch_s, packed.shape[0],
+            return tokens
+        _, packed, snapshot, kv_bytes, cold, coloc = rec
+        # decode-step histogram feed: the chunk's service time over its
+        # steps is the per-step decode latency, and kv_bytes over it the
+        # achieved KV bandwidth. Cold first executions quarantine to the
+        # cold-start series; steps colocated with in-flight prefill split
+        # to cause=prefill_colocated
+        self.stats.chunk_fetched(svc_s, packed.shape[0],
                                  colocated=coloc, cold=cold)
-        self.stats.kv_read(kv_bytes, fetch_s)
+        self.stats.kv_read(kv_bytes, svc_s)
         self._warmed = True
         # batch-occupancy truth, per device step: live = the device emitted
         # a token (its live flag was up), dead = a row was resident in this
@@ -1602,15 +1733,16 @@ class BatchingDecoder:
         self.stats.chunk_occupancy(
             T, live_steps, dead_steps, T * S - live_steps - dead_steps,
             capacity=S)
-        self._route_chunk_tokens(packed, snapshot, cold=cold)
+        return self._route_chunk_tokens(packed, snapshot, cold=cold)
 
-    def _route_chunk_tokens(self, packed, snapshot, cold: bool = False) -> None:
+    def _route_chunk_tokens(self, packed, snapshot, cold: bool = False) -> int:
         """Route one packed [T, S] emission block to its rows (shared by
         the plain chunk path and the paged engine's spec records): fresh
         tokens append in order, -1 ends a row's block, eos/max_new close
         the row, and tokens for an already-done row count as waste so
         goodput + wasted stays the exact partition of every emitted
-        token."""
+        token. Returns the tokens that reached a row."""
+        routed = 0
         for slot, row in enumerate(snapshot):
             if row is None:
                 continue
@@ -1640,9 +1772,11 @@ class BatchingDecoder:
                     break
             if fresh:
                 self._emit_delta(row, fresh, cold=cold)
+                routed += len(fresh)
             if ((row.eos >= 0 and row.out and row.out[-1] == row.eos)
                     or len(row.out) >= row.max_new):
                 self._complete_row(slot, row)
+        return routed
 
     def _evict_canceled(self) -> None:
         """Free slots whose rows were abandoned (wait() timeout / cancel):
@@ -2265,7 +2399,8 @@ class PagedBatchingDecoder(BatchingDecoder):
             "spec_step", (k, w), self._spec_steps[k],
             self._variables, self._slab,
             jnp.asarray(self._table[:, :w].copy()),
-            self._draft_variables, self._draft_cache)
+            self._draft_variables, self._draft_cache,
+            kind="spec", steps=k + 1, width=w)
         if self.spec == "draft":
             self._draft_cache = dc
         # KV model: drafter iteration i reads i positions past the cursor
@@ -2290,45 +2425,38 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     def _materialize(self, rec: tuple) -> tuple:
         if rec[0] == "spec":
-            t0 = time.monotonic()
-            return ("spec", np.asarray(rec[1]), np.asarray(rec[2]),
-                    rec[3], rec[4], rec[5], rec[6], time.monotonic() - t0)
+            return ("spec", np.asarray(rec[1]), np.asarray(rec[2])) + rec[3:]
         if rec[0] == "pchunk":
             # the fetch is the dispatch's execution barrier, same as an
-            # admit record — the wall is what any stalled row lost
-            t0 = time.monotonic()
-            return ("pchunk", rec[1], np.asarray(rec[2]), rec[3], rec[4],
-                    rec[5], time.monotonic() - t0)
+            # admit record
+            return ("pchunk", rec[1], np.asarray(rec[2])) + rec[3:]
         return super()._materialize(rec)
 
-    def _process_record(self, rec: tuple) -> None:
+    def _process_record(self, rec: tuple, svc_s: float) -> int:
         if rec[0] == "pchunk":
             # an intermediate prefill chunk emits nothing and routes
             # nothing; its accounting mirrors the admit branch (KV reads,
             # HOL charge to the snapshot's stalled rows, cold-start
             # quarantine) minus the token lifecycle
-            _, batch, _packed, kv_bytes, cold, stalled, fetch_s = rec
+            _, batch, _packed, kv_bytes, cold, stalled = rec
             self._admits_inflight = max(0, self._admits_inflight - 1)
             self.stats.kv_read(kv_bytes)
-            if fetch_s > 0 and stalled:
-                self.stats.hol_stall(fetch_s, len(stalled))
-                for r in stalled:
-                    r.hol_stall += fetch_s
+            self._charge_stall(stalled, svc_s)
             if cold:
-                self.stats.cold_start(fetch_s)
+                self.stats.cold_start(svc_s)
             self._warmed = True
-            return
+            return 0
         if rec[0] != "spec":
-            return super()._process_record(rec)
-        _, packed, stats_arr, snapshot, k, kv_bytes, cold, fetch_s = rec
+            return super()._process_record(rec, svc_s)
+        _, packed, stats_arr, snapshot, k, kv_bytes, cold = rec
         self._warmed = True
         if cold:
-            # a spec macro-step never feeds decode_step, but its first-call
-            # compile wall still belongs in the cold-start series
-            self.stats.cold_start(fetch_s)
+            # a spec macro-step never feeds decode_step, but its first
+            # execution still belongs in the cold-start series
+            self.stats.cold_start(svc_s)
         # no decode-step observation (a macro-step is k+1 tokens wide, not
         # a per-token step) — but the KV reads and their bandwidth are real
-        self.stats.kv_read(kv_bytes, fetch_s)
+        self.stats.kv_read(kv_bytes, svc_s)
         emitted_mask = packed >= 0  # [k+1, S]
         live_steps = int(emitted_mask.sum())
         resident = [s for s, r in enumerate(snapshot) if r is not None]
@@ -2363,7 +2491,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 continue
             row.spec_proposed += int(drafted[slot]) + 1
             row.spec_accepted += int(accepted[slot])
-        self._route_chunk_tokens(packed, snapshot, cold=cold)
+        return self._route_chunk_tokens(packed, snapshot, cold=cold)
 
     # --- admission (engine thread; caller holds self._cond) ---
 
@@ -2482,11 +2610,12 @@ class PagedBatchingDecoder(BatchingDecoder):
             (self._slab, self._draft_cache, packed), cold = self._run_program(
                 "prefill", (bucket, wa), self._prefill_admit,
                 self._variables, self._draft_variables, self._draft_cache,
-                self._slab, *args)
+                self._slab, *args, kind="admit", width=wa, group=group)
         else:
             (self._slab, packed), cold = self._run_program(
                 "prefill", (bucket, wa), self._prefill_admit,
-                self._variables, self._slab, *args)
+                self._variables, self._slab, *args,
+                kind="admit", width=wa, group=group)
         now = time.monotonic()
         real_tokens = 0
         for slot, row in group:
@@ -2543,8 +2672,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         self._slot_rows[slot] = row
         self._prefill_pending.append((slot, row))
 
-    def _advance_prefills(self, pool, next_seq: int,
-                          process_seq: int) -> tuple:
+    def _advance_prefills(self, pool, process_seq: int) -> bool:
         """One engine-loop turn of the chunked-prefill schedule: every
         pending row advances AT MOST one chunk per iteration — rows whose
         remaining suffix fits a chunk run REAL admission (first token,
@@ -2553,9 +2681,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         intermediate chunk in a single batched dispatch. Decode chunks
         dispatch in the same iteration, which is the whole point: a long
         prompt no longer monopolizes the device for its full length.
-        Returns (next_seq, dispatched_anything)."""
+        Returns whether anything was dispatched."""
         if not self._prefill_pending:
-            return next_seq, False
+            return False
         cap = self.prefill_chunk
         finals: List[tuple] = []
         chunkable: List[tuple] = []
@@ -2569,7 +2697,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 chunkable.append((slot, row))
         dispatched = False
         for group in self._group_admits(finals):
-            if next_seq - process_seq >= self.pipeline_depth:
+            if self._next_seq - process_seq >= self.pipeline_depth:
                 keep.extend(group)
                 continue
             rec = self._dispatch_admits(group)
@@ -2582,18 +2710,16 @@ class PagedBatchingDecoder(BatchingDecoder):
                 row.prefill_chunks += 1
                 n_tok += len(row.prompt) - row.lease.prefill_pos
             self.stats.prefill_chunk(len(group), n_tok)
-            pool.submit(next_seq, rec)
-            next_seq += 1
+            self._submit_program(pool, rec)
             dispatched = True
         if chunkable:
-            if next_seq - process_seq < self.pipeline_depth:
-                pool.submit(next_seq,
-                            self._dispatch_prefill_chunk(chunkable))
-                next_seq += 1
+            if self._next_seq - process_seq < self.pipeline_depth:
+                self._submit_program(
+                    pool, self._dispatch_prefill_chunk(chunkable))
                 dispatched = True
             keep.extend(chunkable)
         self._prefill_pending = keep
-        return next_seq, dispatched
+        return dispatched
 
     def _dispatch_prefill_chunk(self, batch: List[tuple]) -> tuple:
         """One page-aligned intermediate chunk for every mid-prefill row,
@@ -2648,11 +2774,13 @@ class PagedBatchingDecoder(BatchingDecoder):
                 self._run_program(
                     "prefill", (bucket, wa), self._prefill_admit,
                     self._variables, self._draft_variables,
-                    self._draft_cache, self._slab, *args)
+                    self._draft_cache, self._slab, *args,
+                    kind="pchunk", width=wa)
         else:
             (self._slab, packed), cold = self._run_program(
                 "prefill", (bucket, wa), self._prefill_admit,
-                self._variables, self._slab, *args)
+                self._variables, self._slab, *args,
+                kind="pchunk", width=wa)
         for slot, row in batch:
             row.lease.prefill_pos += cap
             row.pos_cap = row.lease.prefill_pos
@@ -2765,7 +2893,8 @@ class PagedBatchingDecoder(BatchingDecoder):
         (self._slab, packed), cold = self._run_program(
             "step", (size, w), self._steps[size],
             self._variables, self._slab,
-            jnp.asarray(self._table[:, :w].copy()))
+            jnp.asarray(self._table[:, :w].copy()),
+            kind="step", steps=size, width=w)
         # one span per step: step s's query sits s positions past pos_cap
         kv_bytes = sum(self._chunk_kv_tokens(w, s)
                        for s in range(1, size + 1)) * self._kv_token_bytes
@@ -2903,7 +3032,6 @@ class PagedBatchingDecoder(BatchingDecoder):
                        submitted_at=time.monotonic(),
                        deadline=resilience.current_deadline(),
                        request_id=snap.request_id or self._next_request_id(),
-                       wall0=time.time(),
                        trace_ctx=tracing.current_context())
         row = _Row(entry=entry, index=0,
                    prompt=np.asarray(snap.prompt, np.int32),
@@ -3013,6 +3141,16 @@ class PagedBatchingDecoder(BatchingDecoder):
             row.slot_at = now
             self.stats.phase("queue_wait", now - row.entry.submitted_at)
         self.stats.snapshot_restore(kvsnap.snapshot_nbytes(snap), now - t0)
+        tracer = self._tracer
+        if tracer.enabled:
+            # no program of the chain, so no seq: the scatter and the
+            # cursor writes thread into the slab as they stand
+            tracer.add_span("engine.dispatch", tracer.at(t0), now - t0,
+                            program="restore", seq=None, steps=0,
+                            width=len(row.lease.pages), cold=False,
+                            rows_live=sum(r is not None
+                                          for r in self._slot_rows),
+                            requests=row.entry.request_id)
 
     def _snapshot_row(self, row: _Row) -> Optional[object]:
         """Capture one resident row's portable state (host side of KMS1):
@@ -3171,8 +3309,7 @@ class PagedBatchingDecoder(BatchingDecoder):
             return []
         return list(req.frames)
 
-    def _drain_quiesce(self, pool, req: _DrainReq, process_seq: int,
-                       next_seq: int) -> int:
+    def _drain_quiesce(self, pool, req: _DrainReq, process_seq: int) -> int:
         """Engine-thread half of :meth:`drain`: settle the dispatch chain
         (host row state must equal device truth before gathering), encode
         one KMS1 frame per straggler single-row request (zero emissions →
@@ -3183,14 +3320,13 @@ class PagedBatchingDecoder(BatchingDecoder):
         from ..api.errors import EngineFaultError
 
         try:
-            while process_seq < next_seq:
-                process_seq = self._consume_ready(pool, process_seq,
-                                                  next_seq, True)
+            while process_seq < self._next_seq:
+                process_seq = self._consume_ready(pool, process_seq, True)
         except Exception:
             log.exception("%s: drain could not settle the dispatch chain",
                           self.name)
             pool.clear()
-            process_seq = next_seq
+            process_seq = self._next_seq
         with self._cond:
             resident = [r for r in self._slot_rows if r is not None]
             queued = list(self._pending)
@@ -3292,23 +3428,24 @@ class PagedBatchingDecoder(BatchingDecoder):
             self._fail_all(e)
             return
         pool = _FetchPool(self, self.fetchers)
-        next_seq = 0
         process_seq = 0
         while True:
             self._sweep_expired()
             with self._cond:
                 while (not self._closed and not self._pending
-                       and not self._busy() and process_seq == next_seq
+                       and not self._busy()
+                       and process_seq == self._next_seq
                        and self._drain_req is None):
                     if self._retired:
                         self._slab = None  # free the arena's HBM
                         pool.stop()
                         return
-                    self._cond.wait()
+                    self._wait_work()
                 if self._closed:
                     pool.stop()
                     return
-                room = self.pipeline_depth - (next_seq - process_seq)
+                room = self.pipeline_depth - (self._next_seq - process_seq)
+                self._admit_from = self._span_clock()
                 admits = (self._take_admissions_locked(room)
                           if room > 0 and self._drain_req is None else [])
             try:
@@ -3316,9 +3453,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 if req is not None and not admits:
                     # graceful drain: quiesce, snapshot stragglers, hand
                     # the KMS1 frames back to the drain() caller
-                    process_seq = self._drain_quiesce(pool, req,
-                                                      process_seq, next_seq)
-                    next_seq = process_seq
+                    process_seq = self._drain_quiesce(pool, req, process_seq)
                     continue
                 if (self.pool_audit_interval > 0
                         and time.monotonic() >= self._next_audit):
@@ -3347,8 +3482,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                         continue
                     live_admits.append((slot, row))
                 for group in self._group_admits(live_admits):
-                    pool.submit(next_seq, self._dispatch_admits(group))
-                    next_seq += 1
+                    self._submit_program(pool, self._dispatch_admits(group))
                     dispatched = True
                 self._evict_canceled()
                 # fair interleave (ISSUE 19): when the pipeline has room
@@ -3360,16 +3494,15 @@ class PagedBatchingDecoder(BatchingDecoder):
                 prefill_now = True
                 if (self._prefill_pending
                         and self.pipeline_depth
-                        - (next_seq - process_seq) == 1
+                        - (self._next_seq - process_seq) == 1
                         and self._paged_chunk_size() > 0):
                     prefill_now = self._prefill_turn
                     self._prefill_turn = not self._prefill_turn
                 if prefill_now:
-                    next_seq, adv = self._advance_prefills(
-                        pool, next_seq, process_seq)
+                    adv = self._advance_prefills(pool, process_seq)
                     dispatched = dispatched or adv
                 self._retire_dispatched()
-                if (next_seq - process_seq < self.pipeline_depth
+                if (self._next_seq - process_seq < self.pipeline_depth
                         and (size := self._paged_chunk_size()) > 0):
                     # spec mode verifies k drafts per dispatch instead of
                     # stepping one token; the adaptive controller may have
@@ -3378,26 +3511,26 @@ class PagedBatchingDecoder(BatchingDecoder):
                     spec_k_now = (self._spec_ctl.current()
                                   if self._spec_ctl is not None else 0)
                     if spec_k_now > 0:
-                        pool.submit(next_seq,
-                                    self._dispatch_spec_chunk(spec_k_now))
+                        self._submit_program(
+                            pool, self._dispatch_spec_chunk(spec_k_now))
                     else:
-                        pool.submit(next_seq,
-                                    self._dispatch_chunk_paged(size))
+                        self._submit_program(
+                            pool, self._dispatch_chunk_paged(size))
                         if self._spec_ctl is not None:
                             self._spec_ctl.on_plain_chunk()
-                    next_seq += 1
                     dispatched = True
                     # the chunk may have fully dispatched rows: free their
                     # program rows + pages for the NEXT chunk edge
                     self._retire_dispatched()
-                must_wait = (next_seq - process_seq >= self.pipeline_depth
-                             or (not dispatched and process_seq < next_seq))
+                must_wait = (
+                    self._next_seq - process_seq >= self.pipeline_depth
+                    or (not dispatched and process_seq < self._next_seq))
                 process_seq = self._consume_ready(pool, process_seq,
-                                                  next_seq, must_wait)
+                                                  must_wait)
             except Exception as e:
                 log.exception("%s: paged decode loop failed", self.name)
                 pool.clear()
-                process_seq = next_seq
+                process_seq = self._next_seq
                 # snapshot-what-you-can BEFORE the arena reinitializes —
                 # resident rows' pages still hold their written history;
                 # unsalvageable entries fail retryably inside (ISSUE 20).

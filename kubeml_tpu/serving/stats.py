@@ -261,8 +261,9 @@ class DecoderStats:
     def kv_read(self, nbytes: int, seconds: float = 0.0) -> None:
         """One dispatched program's modeled KV-cache read traffic:
         ``nbytes`` accumulates the counter; with ``seconds`` (the decode
-        chunk's fetch wall time) the achieved-bandwidth histogram gets one
-        observation. Prefill programs report bytes only (seconds 0)."""
+        chunk's service time, batcher.service_interval) the
+        achieved-bandwidth histogram gets one observation. Prefill programs
+        report bytes only (seconds 0)."""
         if nbytes <= 0:
             return
         with self._lock:
@@ -298,8 +299,10 @@ class DecoderStats:
     def chunk_fetched(self, seconds: float, steps: int,
                       colocated: bool = False, cold: bool = False) -> None:
         """A decode chunk's results landed on the host: ``seconds`` is the
-        blocking fetch wall time, ``steps`` the decode steps it covered —
-        the per-step quotient is the decode-step latency distribution.
+        chunk's own service time (batcher.service_interval: not the wall of
+        its fetch, which covers the programs queued ahead of it as well),
+        ``steps`` the decode steps it covered — the per-step quotient is
+        the decode-step latency distribution.
         ``colocated`` routes the observation to the
         ``{cause="prefill_colocated"}`` series (the chunk shared the device
         with admission/prefill work); ``cold`` quarantines a first-call
@@ -326,9 +329,10 @@ class DecoderStats:
             self._hist_itl.observe(g)
 
     def hol_stall(self, seconds: float, rows: int) -> None:
-        """Charge one prefill-carrying dispatch's wall to the ``rows`` live
-        decoding rows that sat behind it (head-of-line blocking): the
-        counter accumulates seconds x rows — total decode-seconds lost."""
+        """Charge one prefill-carrying program's service time to the
+        ``rows`` live decoding rows that sat behind it (head-of-line
+        blocking): the counter accumulates seconds x rows — total
+        decode-seconds lost."""
         if rows <= 0 or seconds <= 0:
             return
         with self._lock:

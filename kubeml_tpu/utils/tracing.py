@@ -16,8 +16,13 @@ ElapsedTime job.go:321-322). This subsystem is the TPU-native upgrade:
   (utils.traced_http) stamp the current context onto the request — so a
   train request's spans stitch into one tree across CLI → controller →
   scheduler → PS → job runner.
-* :func:`device_profile` — wraps ``jax.profiler.trace`` so a job (or bench run)
-  can capture a TensorBoard/XProf device trace of the XLA execution itself.
+* **One clock**: every span is stamped with :meth:`Tracer.now`, a wall anchor
+  taken once at import plus ``time.monotonic()``. Spans of one process never
+  step backwards with the wall clock, and a timestamp the program took with
+  ``time.monotonic()`` lands on the same clock through :meth:`Tracer.at`.
+* :func:`device_profile` — ``jax.profiler.trace`` with the tracer on, a
+  ``kubeml.clock_tie`` annotation that maps the profiler's clock onto the
+  tracer's, and the block's spans written beside the profiler's files.
 
 The process-wide tracer is enabled with ``KUBEML_TRACE=<dir>`` (spans are
 flushed to ``<dir>/kubeml-trace-<pid>.json`` at exit or on ``flush()``), or
@@ -41,6 +46,15 @@ from pathlib import Path
 from typing import Any, Dict, Iterator, List, Optional
 
 log = logging.getLogger("kubeml.trace")
+
+# the tracer's clock: wall time at import less the monotonic clock then, so
+# that ``_WALL_ANCHOR + time.monotonic()`` is a wall clock that only moves
+# forwards (the serving engine names millisecond gaps with it)
+_WALL_ANCHOR = time.time() - time.monotonic()
+
+# the annotation (and span) ``device_profile`` writes first, so a reader can
+# put the profiler's clock and the tracer's side by side
+CLOCK_TIE = "kubeml.clock_tie"
 
 # hard cap: a runaway loop must not eat the host's RAM. The cap is a RING —
 # past it the OLDEST span evicts — so a long-lived traced service (weeks of
@@ -191,7 +205,7 @@ def add_log_context(logger: Optional[logging.Logger] = None) -> None:
 @dataclass
 class Span:
     name: str
-    start: float  # time.time() seconds
+    start: float  # Tracer.now() seconds (wall, monotonic-derived)
     duration: float  # seconds
     thread: int
     attrs: Dict[str, Any] = field(default_factory=dict)
@@ -266,6 +280,19 @@ class Tracer:
         with self._lock:
             return self._dropped
 
+    # --- the clock ---
+
+    @staticmethod
+    def now() -> float:
+        """The tracer's clock: wall seconds that never step backwards."""
+        return _WALL_ANCHOR + time.monotonic()
+
+    @staticmethod
+    def at(monotonic: float) -> float:
+        """A ``time.monotonic()`` reading of this process on the tracer's
+        clock."""
+        return _WALL_ANCHOR + monotonic
+
     # --- recording ---
 
     def _append(self, s: Span) -> None:
@@ -301,14 +328,14 @@ class Tracer:
             return
         s = self._identify(attrs)
         s.name = name
-        s.start = time.time()
+        s.start = self.now()
         stack = _ctx_stack()
         stack.append(TraceContext(s.trace_id, s.span_id))
         try:
             yield s
         finally:
             stack.pop()
-            s.duration = time.time() - s.start
+            s.duration = self.now() - s.start
             self._append(s)
 
     def record(self, name: str, duration: float, **attrs: Any) -> None:
@@ -317,7 +344,7 @@ class Tracer:
             return
         s = self._identify(attrs)
         s.name = name
-        s.start = time.time() - duration
+        s.start = self.now() - duration
         s.duration = duration
         self._append(s)
 
@@ -326,18 +353,21 @@ class Tracer:
                  span_id: Optional[str] = None,
                  parent_id: Optional[str] = None,
                  service: Optional[str] = None,
+                 thread: Optional[int] = None,
                  **attrs: Any) -> Optional[Span]:
-        """Record a fully-explicit span: wall start, duration, and (when
-        given) explicit trace identity. The serving batcher reconstructs a
-        request's phase timeline AFTER the fact — at completion, on the
-        engine thread, where no context manager ever wrapped the phases —
-        so it needs to name the parent/ids itself. Returns the Span (None
-        when disabled) so callers can hang children off its ``span_id``."""
+        """Record a fully-explicit span: start (on :meth:`now`'s clock),
+        duration, and (when given) explicit trace identity. The serving
+        batcher reconstructs a request's phase timeline AFTER the fact — at
+        completion, on the engine thread, where no context manager ever
+        wrapped the phases — so it needs to name the parent/ids itself, and
+        ``thread`` where the work ran on another thread than the one that
+        records it. Returns the Span (None when disabled) so callers can
+        hang children off its ``span_id``."""
         if not self.enabled:
             return None
         s = Span(
             name=name, start=float(start), duration=max(0.0, float(duration)),
-            thread=threading.get_ident(), attrs=attrs,
+            thread=thread or threading.get_ident(), attrs=attrs,
             trace_id=trace_id or new_trace_id(),
             span_id=span_id or new_span_id(),
             parent_id=parent_id, service=service or self.service,
@@ -357,13 +387,17 @@ class Tracer:
     def task_spans(self, task_id: str) -> List[Span]:
         """Every span belonging to a task: spans tagged ``job=task_id`` plus
         every other span sharing one of those spans' trace ids (the HTTP hop
-        spans of the same request flow)."""
+        spans of the same request flow), plus the serving engine's spans of
+        the program that admitted it (``requests`` lists the ids a batched
+        admit program served, comma-separated)."""
         spans = self.spans()
         trace_ids = {s.trace_id for s in spans
                      if s.trace_id and s.attrs.get("job") == task_id}
         return [s for s in spans
                 if s.attrs.get("job") == task_id
-                or (s.trace_id and s.trace_id in trace_ids)]
+                or (s.trace_id and s.trace_id in trace_ids)
+                or ("requests" in s.attrs
+                    and task_id in s.attrs["requests"].split(","))]
 
     def task_dicts(self, task_id: str) -> List[Dict[str, Any]]:
         return [s.to_dict() for s in self.task_spans(task_id)]
@@ -385,33 +419,19 @@ class Tracer:
 
     # --- export ---
 
-    def to_chrome_trace(self) -> List[Dict[str, Any]]:
-        """Chrome trace-event format ('X' complete events, µs timestamps)."""
-        return [
-            {
-                "name": s.name,
-                "ph": "X",
-                "ts": s.start * 1e6,
-                "dur": s.duration * 1e6,
-                "pid": os.getpid(),
-                "tid": s.thread % (1 << 31),
-                "args": {k: _json_safe(v) for k, v in s.attrs.items()},
-            }
-            for s in self.spans()
-        ]
-
     def flush(self, path: Optional[Path] = None) -> Optional[Path]:
         """Write the Chrome trace JSON; returns the path (None if nothing to do)."""
         if path is None:
             if self.out_dir is None:
                 return None
             path = self.out_dir / f"kubeml-trace-{os.getpid()}.json"
-        events = self.to_chrome_trace()
-        if not events:
+        spans = self.spans()
+        if not spans:
             return None
         path = Path(path)
         path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(json.dumps({"traceEvents": events}))
+        path.write_text(json.dumps(
+            merge_chrome_trace([s.to_dict() for s in spans])))
         if self._dropped:
             log.warning("trace evicted %d oldest spans past the %d cap",
                         self._dropped, MAX_SPANS)
@@ -512,11 +532,32 @@ def get_tracer() -> Tracer:
 
 @contextmanager
 def device_profile(log_dir: Path) -> Iterator[None]:
-    """Capture a TensorBoard/XProf device trace of everything inside the block
-    (compile + execute on the attached TPU/CPU backend)."""
+    """Capture a TensorBoard/XProf device trace of everything inside the
+    block together with the program's own spans of it.
+
+    The tracer is on inside the block (and left as it was found). The first
+    thing in the profile is a ``kubeml.clock_tie`` annotation, recorded as a
+    span of the same name too: the difference of their starts maps the
+    profiler's clock onto the tracer's, so a device idle gap can be named by
+    the span that covers it. On exit the block's spans are written to
+    ``<log_dir>/kubeml-spans.json`` (Chrome trace-event JSON)."""
     import jax
 
     log_dir = Path(log_dir)
     log_dir.mkdir(parents=True, exist_ok=True)
-    with jax.profiler.trace(str(log_dir)):
-        yield
+    tracer = get_tracer()
+    was_on = tracer.enabled
+    tracer.enable()
+    entered = tracer.now()
+    try:
+        with jax.profiler.trace(str(log_dir)):
+            tie = tracer.now()
+            with jax.profiler.TraceAnnotation(CLOCK_TIE):
+                pass
+            tracer.add_span(CLOCK_TIE, tie, tracer.now() - tie)
+            yield
+    finally:
+        tracer.enabled = was_on
+        spans = [s.to_dict() for s in tracer.spans() if s.start >= entered]
+        (log_dir / "kubeml-spans.json").write_text(
+            json.dumps(merge_chrome_trace(spans)))

@@ -53,9 +53,14 @@ def test_tracer_chrome_export_and_flush(tmp_path):
         pass
     path = t.flush(tmp_path / "trace.json")
     data = json.loads(path.read_text())
-    (ev,) = data["traceEvents"]
+    # one process-name row per service, then the spans with their identity
+    row, ev = data["traceEvents"]
+    assert row["ph"] == "M" and row["args"] == {"name": t.service}
     assert ev["name"] == "epoch" and ev["ph"] == "X"
-    assert ev["dur"] >= 0 and ev["args"] == {"epoch": 0}
+    assert ev["dur"] >= 0 and ev["args"]["epoch"] == 0
+    (s,) = t.spans()
+    assert ev["args"]["trace_id"] == s.trace_id
+    assert ev["args"]["span_id"] == s.span_id
 
 
 def test_tracer_thread_safety():
@@ -417,7 +422,10 @@ def test_job_emits_trace_spans(mnist_store, tmp_config, tmp_path):
         assert {"job.epoch", "job.round", "job.validate"} <= names
         path = tracer.flush()
         data = json.loads(path.read_text())
-        assert len(data["traceEvents"]) == len(tracer.spans())
+        events = data["traceEvents"]
+        rows = [e for e in events if e["ph"] == "M"]
+        assert len(rows) == len({s.service for s in tracer.spans()})
+        assert len(events) - len(rows) == len(tracer.spans())
     finally:
         tracer.disable()
         tracer.clear()
@@ -427,8 +435,28 @@ def test_device_profile_writes_trace(tmp_path):
     import jax
     import jax.numpy as jnp
 
-    from kubeml_tpu.utils.tracing import device_profile
+    from kubeml_tpu.utils.tracing import (CLOCK_TIE, device_profile,
+                                          get_tracer)
 
-    with device_profile(tmp_path / "prof"):
-        jax.block_until_ready(jax.jit(lambda x: x * 2)(jnp.ones(8)))
-    assert any((tmp_path / "prof").rglob("*"))  # xprof/tensorboard artifacts
+    tracer = get_tracer()
+    was_on = tracer.enabled
+    tracer.enabled = False
+    try:
+        with tracer.span("before"):   # tracer off: not recorded
+            pass
+        with device_profile(tmp_path / "prof"):
+            with tracer.span("inside", k=1):   # the block turns it on
+                jax.block_until_ready(jax.jit(lambda x: x * 2)(jnp.ones(8)))
+        assert not tracer.enabled   # and leaves it as it found it
+    finally:
+        tracer.enabled = was_on
+        tracer.clear()
+    # the profiler's files, with the clock tie as a host annotation
+    (pb,) = (tmp_path / "prof").rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(pb))
+    assert any(e.name == CLOCK_TIE for plane in data.planes
+               for line in plane.lines for e in line.events)
+    # and beside them the block's spans, the tie first
+    chrome = json.loads((tmp_path / "prof" / "kubeml-spans.json").read_text())
+    names = [e["name"] for e in chrome["traceEvents"] if e["ph"] == "X"]
+    assert names == [CLOCK_TIE, "inside"]
