@@ -302,10 +302,12 @@ class Config:
     int8_matmul_impl: str = field(
         default_factory=lambda: os.environ.get("KUBEML_INT8_MATMUL_IMPL",
                                                "auto"))
-    # dispatch-chain depth: decode programs the device may run ahead of the
-    # host's processed state. Must be >= serving_fetchers to saturate the
-    # fetch pool; deeper delays completion detection (dead rows burn steps
-    # on long requests). 6/6 was the round-5 builder-measured balance.
+    # dispatch-chain depth: the CEILING on the programs a serving engine
+    # keeps in flight ahead of the host's processed state. The paged engine
+    # picks its depth under it from the host's turnaround and a decode
+    # program's time (batcher.run_ahead_depth: 2 at a 50 ms step on a local
+    # chip), because every place past that only delays a new request's
+    # prefill and the detection of completions; the slot engine runs at it.
     serving_pipeline: int = field(
         default_factory=lambda: _env_int("KUBEML_SERVING_PIPELINE", 6))
     # concurrent result-fetch threads (each fetch pays the host<->device

@@ -23,7 +23,9 @@ Why this shape on TPU:
   (``models.generation.make_generate_fn`` keys its LRU by knobs).
 * The dispatch chain is PIPELINED: results are fetched up to
   ``pipeline_depth`` programs behind the newest dispatch, so the device
-  never idles on host round trips (see _loop).
+  never idles on host round trips (see _loop). The paged engine runs only
+  as far ahead as its host's turnaround needs (``run_ahead_depth``), with
+  ``pipeline_depth`` as the ceiling.
 
 The reference has no serving runtime at all to compare against; the closest
 analogue is its one-pod-per-function Fission serving
@@ -35,7 +37,9 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import math
 import queue
+import statistics
 import threading
 import time
 from collections import deque
@@ -448,6 +452,47 @@ def service_interval(dispatched: float, done: float,
     return done - start, start - dispatched, done
 
 
+# run_ahead_depth keeps this many turnarounds of the host queued behind the
+# running program. 5 also puts a host as slow as its program at 1 + 5 = 6,
+# the default ceiling: an engine the host bounds runs as deep as it always has
+_RUN_AHEAD_MARGIN = 5
+
+
+def run_ahead_depth(host_s: Optional[float], svc_s: Optional[float],
+                    cap: int) -> int:
+    """Programs the paged engine keeps in flight, the running one counted.
+
+    One program queued behind the running one keeps the device fed for as
+    long as the host takes to answer a completion with the next dispatch,
+    so the depth is 1 + the programs that ``_RUN_AHEAD_MARGIN`` such
+    turnarounds (``host_s``) cover at ``svc_s`` a decode program. Every
+    further place only stands between a new request's prefill and the
+    device: at 6 one-step programs a first token waited five steps. Never
+    under 2 (a depth of 1 idles the device for every turnaround), never
+    over ``cap`` (``pipeline_depth``: a cap of 1 still gives 1), and the
+    cap itself while either estimate has no sample. Pure: the estimates
+    are its only inputs."""
+    if host_s is None or svc_s is None or svc_s <= 0.0:
+        return cap
+    need = 1 + math.ceil(_RUN_AHEAD_MARGIN * host_s / svc_s)
+    return min(cap, max(2, need))
+
+
+class _Recent:
+    """Running estimate of a duration: the median of its last 8 samples
+    (None before the first). One slow turn among them (an admission, a
+    collector's pause) moves nothing; a changed regime shows after five."""
+
+    def __init__(self):
+        self._samples: deque = deque(maxlen=8)
+
+    def add(self, seconds: float) -> None:
+        self._samples.append(seconds)
+
+    def value(self) -> Optional[float]:
+        return statistics.median(self._samples) if self._samples else None
+
+
 class _FetchPool:
     """The result-fetch thread pool both engine loops share: dispatched
     device programs are materialized off-thread (each fetch pays the
@@ -552,10 +597,12 @@ class BatchingDecoder:
         self.mesh = mesh
         # dispatch pipelining: the device may run up to pipeline_depth
         # programs ahead of the host's processed state, so a value fetch's
-        # host round trip never idles it. Defaults live in Config (depth
-        # must be >= fetchers to saturate the pool; deeper delays
-        # completion detection and burns dead steps on long requests).
-        # Explicit args win; None falls back to the process config.
+        # host round trip never idles it. Deeper delays completion
+        # detection, burns dead steps on long requests and stands between
+        # a new request's prefill and the device, so the paged engine
+        # takes this as the ceiling of run_ahead_depth; the slot engine
+        # runs at it. Defaults live in Config. Explicit args win; None
+        # falls back to the process config.
         from ..api.config import get_config
 
         cfg = get_config()
@@ -699,11 +746,27 @@ class BatchingDecoder:
         # engine.* spans
         self._next_seq = 0
         # seq -> (end of its jitted call on the monotonic clock, program
-        # kind, ids of the requests it admits) of every program dispatched
-        # and not yet consumed; with the completion stamps of the fetch
-        # pool, what service_interval needs
+        # kind, ids of the requests it admits, cold) of every program
+        # dispatched and not yet consumed; with the completion stamps of
+        # the fetch pool, what service_interval needs
         self._inflight: Dict[int, tuple] = {}
         self._prev_done = 0.0   # running maximum of completions consumed
+        # --- run-ahead: what run_ahead_depth reads, both from those same
+        # clock reads. A warm decode program's service time, and the
+        # engine thread's turnaround: from where it could have refilled
+        # the device (a result complete and its own last jitted call
+        # over) to the end of the jitted call that did
+        self._svc_s = _Recent()
+        self._host_s = _Recent()
+        self._dispatch_end = 0.0   # end of the last jitted call
+        # where the turnaround now running began: set by the first result
+        # consumed since the last dispatch, 0 when the next dispatch
+        # answers no completion (the engine was idle, or a turn had
+        # nothing to dispatch)
+        self._turn_from = 0.0
+        # programs the loop keeps in flight: the paged loop sets it from
+        # the estimates once a turn, the slot loop never
+        self._depth = self.pipeline_depth
         # tracer clock at which the admission work not yet inside an
         # engine.admit span began (0 = tracing was off then)
         self._admit_from = 0.0
@@ -1398,6 +1461,7 @@ class BatchingDecoder:
         row and nothing in flight, as an ``engine.wait_work`` span: the
         device's idle time inside one is nobody's fault."""
         t0 = self._span_clock()
+        self._turn_from = 0.0
         self._cond.wait()
         if t0:
             self._tracer.add_span("engine.wait_work", t0,
@@ -1427,6 +1491,7 @@ class BatchingDecoder:
         service time here, where the one before it is known."""
         tracer = self._tracer
         waiting = 0.0   # tracer clock since which the engine has blocked
+        self._turn_from = 0.0   # the turn just over dispatched nothing
         while process_seq < self._next_seq:
             with pool.cv:
                 if process_seq not in pool.done:
@@ -1436,10 +1501,13 @@ class BatchingDecoder:
                     pool.cv.wait(timeout=1.0)
                     continue
                 rec, t0, t1, thread = pool.done.pop(process_seq)
-            dispatched, kind, requests = self._inflight.pop(
-                process_seq, (t0, rec[0], None))
+            dispatched, kind, requests, cold = self._inflight.pop(
+                process_seq, (t0, rec[0], None, True))
             svc_s, wait_s, self._prev_done = service_interval(
                 dispatched, t1, self._prev_done)
+            if kind == "step" and not cold:
+                self._svc_s.add(svc_s)
+            self._turn_from = self._turn_from or max(t1, self._dispatch_end)
             if tracer.enabled:
                 if waiting:
                     tracer.add_span("engine.wait_result", waiting,
@@ -1523,12 +1591,17 @@ class BatchingDecoder:
             self._engine_span(
                 "engine.dispatch", tracer.at(t0), t1 - t0, requests, seq=seq,
                 program=kind, steps=steps, width=width, cold=cold,
-                rows_live=sum(r is not None for r in self._slot_rows))
+                rows_live=sum(r is not None for r in self._slot_rows),
+                depth=self._depth, ahead=len(self._inflight))
             # a later group of the same wave is prepared from here on
             self._admit_from = tracer.at(t1)
         if cold:
             self.stats.compiled(program, t1 - t0)
-        self._inflight[seq] = (t1, kind, requests)
+        elif self._turn_from:
+            self._host_s.add(t1 - self._turn_from)
+        self._turn_from = 0.0
+        self._dispatch_end = t1
+        self._inflight[seq] = (t1, kind, requests, cold)
         return out, cold
 
     def _stalled_rows(self) -> List[_Row]:
@@ -2697,7 +2770,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 chunkable.append((slot, row))
         dispatched = False
         for group in self._group_admits(finals):
-            if self._next_seq - process_seq >= self.pipeline_depth:
+            if self._next_seq - process_seq >= self._depth:
                 keep.extend(group)
                 continue
             rec = self._dispatch_admits(group)
@@ -2713,7 +2786,7 @@ class PagedBatchingDecoder(BatchingDecoder):
             self._submit_program(pool, rec)
             dispatched = True
         if chunkable:
-            if self._next_seq - process_seq < self.pipeline_depth:
+            if self._next_seq - process_seq < self._depth:
                 self._submit_program(
                     pool, self._dispatch_prefill_chunk(chunkable))
                 dispatched = True
@@ -3405,6 +3478,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         # not yet decoding) — the engine-thread snapshot is racy by a loop
         # iteration, which is fine for a gauge
         snap["prefills_in_progress"] = float(len(self._prefill_pending))
+        # programs the loop keeps in flight now (run_ahead_depth): under
+        # pipeline_depth wherever the host answers well inside a step
+        snap["run_ahead_depth"] = float(self._depth)
         if self._spec_ctl is not None:
             # current adaptive speculation depth (0 = retreated to plain
             # decode) + the controller's EWMA acceptance estimate
@@ -3431,6 +3507,12 @@ class PagedBatchingDecoder(BatchingDecoder):
         process_seq = 0
         while True:
             self._sweep_expired()
+            # every gate of this turn reads the one depth; when it falls
+            # below what is in flight the turn dispatches nothing and
+            # consumes, and nothing is ever un-admitted
+            self._depth = run_ahead_depth(
+                self._host_s.value(), self._svc_s.value(),
+                self.pipeline_depth)
             with self._cond:
                 while (not self._closed and not self._pending
                        and not self._busy()
@@ -3444,7 +3526,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 if self._closed:
                     pool.stop()
                     return
-                room = self.pipeline_depth - (self._next_seq - process_seq)
+                room = self._depth - (self._next_seq - process_seq)
                 self._admit_from = self._span_clock()
                 admits = (self._take_admissions_locked(room)
                           if room > 0 and self._drain_req is None else [])
@@ -3493,7 +3575,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 # slices), decode-first would starve TTFT instead
                 prefill_now = True
                 if (self._prefill_pending
-                        and self.pipeline_depth
+                        and self._depth
                         - (self._next_seq - process_seq) == 1
                         and self._paged_chunk_size() > 0):
                     prefill_now = self._prefill_turn
@@ -3502,7 +3584,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                     adv = self._advance_prefills(pool, process_seq)
                     dispatched = dispatched or adv
                 self._retire_dispatched()
-                if (self._next_seq - process_seq < self.pipeline_depth
+                if (self._next_seq - process_seq < self._depth
                         and (size := self._paged_chunk_size()) > 0):
                     # spec mode verifies k drafts per dispatch instead of
                     # stepping one token; the adaptive controller may have
@@ -3523,7 +3605,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                     # program rows + pages for the NEXT chunk edge
                     self._retire_dispatched()
                 must_wait = (
-                    self._next_seq - process_seq >= self.pipeline_depth
+                    self._next_seq - process_seq >= self._depth
                     or (not dispatched and process_seq < self._next_seq))
                 process_seq = self._consume_ready(pool, process_seq,
                                                   must_wait)
