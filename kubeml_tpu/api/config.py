@@ -286,6 +286,15 @@ class Config:
     # (int8-sized per-device peak), q/scales shard with the tp specs.
     serving_quantize: str = field(
         default_factory=lambda: os.environ.get("KUBEML_SERVING_QUANTIZE", ""))
+    # the type the parameter server HOLDS a served checkpoint's floating
+    # leaves in on the device ("bfloat16" | "float32"; empty = as the
+    # checkpoint has them). A model published in bfloat16 is served from
+    # half the bytes of its float32 checkpoint files: the leaves are cast
+    # one by one as they reach the device (serving.quant.cast_tree), so the
+    # wide tree is never resident. Int8 leaves (serving_quantize) are left.
+    serving_param_dtype: str = field(
+        default_factory=lambda: os.environ.get(
+            "KUBEML_SERVING_PARAM_DTYPE", ""))
     # NATIVE int8 decode matmuls (with serving_quantize=int8): contract the
     # activations against the int8 weights directly and fold the per-channel
     # scale into the f32 accumulator AFTER the contraction

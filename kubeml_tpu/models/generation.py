@@ -130,6 +130,14 @@ def supports_paged_decode(module) -> bool:
     return "pages" in params and "seq_lens" in params and "positions" in params
 
 
+def has_recurrent_state(module) -> bool:
+    """Whether ``module`` keeps per-row recurrent state in its cache beside
+    the attention's K/V (a Mamba-2 mixer, ``ssm`` set): the serving layer
+    then has to carry that state with a row, and refuses what moves pages
+    alone (prefix sharing, speculative rollback, KMS1 frames)."""
+    return getattr(module, "ssm", None) is not None
+
+
 def _sample(logits, rng, temperature: float, top_k: Optional[int]):
     """One next-token draw per row from [B, V] logits (f32)."""
     if temperature <= 0.0:

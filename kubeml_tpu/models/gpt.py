@@ -9,13 +9,21 @@ as ring attention over the ``sp`` axis when a mesh with sp > 1 is attached
 (jax.shard_map inside jit), else as plain full attention.
 
 ``dtype`` is the computation dtype (bf16 compute / f32 params mixed precision):
-matmuls run in ``dtype``, LayerNorm and attention softmax stay f32, parameters
-are always stored f32, and logits are returned f32 for the loss.
+matmuls run in ``dtype``, the norms and attention softmax stay f32, parameters
+are initialised and trained f32 (a server may hold them narrower:
+``Config.serving_param_dtype``), and logits are returned f32 for the loss.
+
+One block, configured: LayerNorm or RMSNorm; a GELU MLP at a ratio or SwiGLU at
+a width; multi-head or grouped-query attention at a head size of its own;
+learned or rotary positions; optionally a Mamba-2 mixer in parallel with
+attention (models/mamba2.py) and a muP checkpoint's multipliers. GPT-2 is the
+defaults; Falcon-H1 is rmsnorm + swiglu + GQA + rope + ssm + mup.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from dataclasses import dataclass
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -26,8 +34,43 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..ops.attention import dot_product_attention
 from ..parallel.ring import ring_attention
 from .layers import QuantizableDense
+from .mamba2 import Mamba2Mixer, SSMConfig
 
 PAD_ID = 0
+
+
+@dataclass(frozen=True)
+class MuP:
+    """The constant multipliers a muP-parametrised checkpoint is served
+    with, under the names of Falcon-H1's ``config`` (``ssm_multipliers``
+    ride :class:`SSMConfig`). All 1.0 = none."""
+
+    embedding: float = 1.0       # embedding_multiplier
+    lm_head: float = 1.0         # lm_head_multiplier
+    attention_in: float = 1.0    # attention_in_multiplier
+    attention_out: float = 1.0   # attention_out_multiplier
+    key: float = 1.0             # key_multiplier
+    ssm_in: float = 1.0          # ssm_in_multiplier
+    ssm_out: float = 1.0         # ssm_out_multiplier
+    mlp: Tuple[float, float] = (1.0, 1.0)   # mlp_multipliers: gate, down
+
+
+def _scaled(t, m: float):
+    """``t`` times a muP constant, the product taken in float32 (a constant
+    rounded to bfloat16 would be off by up to 0.2%, the same way for every
+    element); 1.0 is no operation at all."""
+    if m == 1.0:
+        return t
+    return (t.astype(jnp.float32) * m).astype(t.dtype)
+
+
+def _norm(kind: str, name: str, eps: float):
+    """The block's normalisation, float32: ``layernorm`` or ``rmsnorm``."""
+    if kind == "layernorm":
+        return nn.LayerNorm(name=name, dtype=jnp.float32, epsilon=eps)
+    if kind == "rmsnorm":
+        return nn.RMSNorm(name=name, dtype=jnp.float32, epsilon=eps)
+    raise ValueError(f"unknown norm {kind!r} (valid: 'layernorm', 'rmsnorm')")
 
 
 def _part(names):
@@ -80,6 +123,14 @@ class CausalSelfAttention(nn.Module):
     # quantizes, both read paths dequantize, and the same arena byte budget
     # holds 2-4x the tokens (ops/paged_attention.resolve_kv_quant)
     kv_quant: str = "off"
+    # grouped-query attention: ``num_kv_heads`` K/V heads, each shared by
+    # num_heads / num_kv_heads query heads (query head h reads K/V head
+    # h // that ratio); caches and the paged arena hold the K/V heads only.
+    # 0 = one K/V head per query head. ``head_dim`` 0 = embed_dim / num_heads
+    # (Falcon-H1: 20 query heads of 128 on a 5120-wide stream).
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    key_mult: float = 1.0   # a muP multiplier on the keys (key_multiplier)
 
     @nn.compact
     def __call__(self, x, valid, decode: bool = False, positions=None,
@@ -90,7 +141,15 @@ class CausalSelfAttention(nn.Module):
             )
         B, L, E = x.shape
         H = self.num_heads
-        D = E // H
+        D = self.head_dim or E // H
+        Hkv = self.num_kv_heads or H
+        if H % Hkv:
+            raise ValueError(f"num_heads {H} is not a multiple of "
+                             f"num_kv_heads {Hkv}")
+        # each K/V head once per query head that shares it, for the read
+        # paths that take one K/V head per query head
+        shared = ((lambda t: t) if Hkv == H
+                  else (lambda t: jnp.repeat(t, H // Hkv, axis=2)))
         # 2-D kernels with manual head reshape: column-sharding [E, H*D] over
         # tp IS head-sharding (heads are the leading factor of the columns).
         # QuantizableDense == nn.Dense until the serving layer hands it an
@@ -100,10 +159,10 @@ class CausalSelfAttention(nn.Module):
             kernel_init=_part(names)(nn.initializers.lecun_normal()),
             use_bias=self.use_bias, dtype=self.dtype,
         )
-        heads = lambda t: t.reshape(B, L, H, D)
-        q = heads(dense(H * D, (None, "tp"), "query")(x))
-        k = heads(dense(H * D, (None, "tp"), "key")(x))
-        v = heads(dense(H * D, (None, "tp"), "value")(x))
+        q = dense(H * D, (None, "tp"), "query")(x).reshape(B, L, H, D)
+        k = dense(Hkv * D, (None, "tp"), "key")(x).reshape(B, L, Hkv, D)
+        v = dense(Hkv * D, (None, "tp"), "value")(x).reshape(B, L, Hkv, D)
+        k = _scaled(k, self.key_mult)
         out_proj = dense(E, ("tp", None), "proj")
 
         if decode:
@@ -154,9 +213,9 @@ class CausalSelfAttention(nn.Module):
                 kvq = resolve_kv_quant(self.kv_quant)
                 store_dtype = jnp.int8 if kvq == "int8" else k.dtype
                 ck = self.variable("cache", "k_pages", jnp.zeros,
-                                   (npg, H, pt, D), store_dtype)
+                                   (npg, Hkv, pt, D), store_dtype)
                 cv = self.variable("cache", "v_pages", jnp.zeros,
-                                   (npg, H, pt, D), store_dtype)
+                                   (npg, Hkv, pt, D), store_dtype)
                 if kvq == "int8":
                     # per-page-per-head running absmax: a page's int8 value
                     # q reconstructs as q * scale / 127. Scales live in the
@@ -164,9 +223,9 @@ class CausalSelfAttention(nn.Module):
                     # page, so shared prefix pages carry their scales with
                     # them — trie reuse stays free.
                     ks = self.variable("cache", "k_scale", jnp.zeros,
-                                       (npg, H), jnp.float32)
+                                       (npg, Hkv), jnp.float32)
                     vs = self.variable("cache", "v_scale", jnp.zeros,
-                                       (npg, H), jnp.float32)
+                                       (npg, Hkv), jnp.float32)
                 pos_full = positions[:, None] + jnp.arange(L)  # [B, L]
                 if self.rope:
                     from ..ops.rotary import apply_rope
@@ -260,16 +319,19 @@ class CausalSelfAttention(nn.Module):
                               * (vs.value[pages] / 127.0)[..., None, None]
                               ).astype(q.dtype)
                     # head-major pages back to token-major rows
-                    kg = kg.transpose(0, 1, 3, 2, 4).reshape(B, tw * pt, H, D)
-                    vg = vg.transpose(0, 1, 3, 2, 4).reshape(B, tw * pt, H, D)
+                    kg = kg.transpose(0, 1, 3, 2, 4).reshape(B, tw * pt, Hkv, D)
+                    vg = vg.transpose(0, 1, 3, 2, 4).reshape(B, tw * pt, Hkv, D)
                     k_pos = jnp.arange(tw * pt)[None, None, None, :]
                     # [B, 1, L, tw*pt]
                     mask = k_pos <= pos_full[:, None, :, None]
-                    out = dot_product_attention(q, kg, vg, mask=mask)
+                    out = dot_product_attention(q, shared(kg), shared(vg),
+                                                mask=mask)
                 return out_proj(out.reshape(B, L, H * D))
             Lc = self.cache_len
-            ck = self.variable("cache", "k", jnp.zeros, (B, Lc, H, D), k.dtype)
-            cv = self.variable("cache", "v", jnp.zeros, (B, Lc, H, D), v.dtype)
+            ck = self.variable("cache", "k", jnp.zeros, (B, Lc, Hkv, D),
+                               k.dtype)
+            cv = self.variable("cache", "v", jnp.zeros, (B, Lc, Hkv, D),
+                               v.dtype)
             cvalid = self.variable("cache", "valid", jnp.zeros, (B, Lc), jnp.bool_)
             cursor = self.variable("cache", "index",
                                    lambda: jnp.zeros((), jnp.int32))
@@ -302,7 +364,8 @@ class CausalSelfAttention(nn.Module):
                 k_pos = jnp.arange(Lc)[None, None, None, :]
                 mask = cvalid.value[:, None, None, :] & (
                     k_pos <= positions[:, None, None, None])
-                out = dot_product_attention(q, ck.value, cv.value, mask=mask)
+                out = dot_product_attention(q, shared(ck.value),
+                                            shared(cv.value), mask=mask)
                 return out_proj(out.reshape(B, L, H * D))
             i0 = cursor.value
             if self.rope:
@@ -323,7 +386,8 @@ class CausalSelfAttention(nn.Module):
             k_pos = jnp.arange(Lc)[None, None, None, :]
             q_pos = (i0 + jnp.arange(L))[None, None, :, None]
             mask = cvalid.value[:, None, None, :] & (k_pos <= q_pos)
-            out = dot_product_attention(q, ck.value, cv.value, mask=mask)
+            out = dot_product_attention(q, shared(ck.value), shared(cv.value),
+                                        mask=mask)
             return out_proj(out.reshape(B, L, H * D))
 
         if self.rope:
@@ -332,6 +396,7 @@ class CausalSelfAttention(nn.Module):
             pos = jnp.arange(L)
             q = apply_rope(q, pos, self.rope_theta)
             k = apply_rope(k, pos, self.rope_theta)
+        k, v = shared(k), shared(v)
 
         if self.mesh is not None and self.mesh.shape.get("sp", 1) > 1:
             if self.sp_impl == "ulysses":
@@ -378,12 +443,21 @@ class GPTBlock(nn.Module):
     kv_pages: int = 0
     paged_attn: str = "auto"
     kv_quant: str = "off"
+    # --- the block's kinds (CausalTransformer documents them) ---
+    norm: str = "layernorm"
+    mlp: str = "gelu"
+    mlp_dim: int = 0
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    ssm: Optional[SSMConfig] = None
+    mup: Optional[MuP] = None
+    state_rows: int = 0
 
     @nn.compact
     def __call__(self, x, valid, train: bool = False, decode: bool = False,
-                 positions=None, pages=None, seq_lens=None):
-        y = nn.LayerNorm(name="ln1", dtype=jnp.float32,
-                         epsilon=self.ln_eps)(x).astype(self.dtype)
+                 positions=None, pages=None, seq_lens=None, rows=None):
+        mup = self.mup or MuP()
+        u = _norm(self.norm, "ln1", self.ln_eps)(x).astype(self.dtype)
         y = CausalSelfAttention(self.num_heads, mesh=self.mesh,
                                 sp_impl=self.sp_impl, dtype=self.dtype,
                                 use_bias=self.attn_bias,
@@ -393,22 +467,53 @@ class GPTBlock(nn.Module):
                                 kv_pages=self.kv_pages,
                                 paged_attn=self.paged_attn,
                                 kv_quant=self.kv_quant,
-                                name="attn")(y, valid, decode=decode,
+                                num_kv_heads=self.num_kv_heads,
+                                head_dim=self.head_dim, key_mult=mup.key,
+                                name="attn")(_scaled(u, mup.attention_in),
+                                             valid, decode=decode,
                                              positions=positions,
                                              pages=pages, seq_lens=seq_lens)
+        y = _scaled(y, mup.attention_out)
         y = nn.Dropout(self.dropout, deterministic=not train)(y)
         x = x + y
-        y = nn.LayerNorm(name="ln2", dtype=jnp.float32,
-                         epsilon=self.ln_eps)(x).astype(self.dtype)
+        if self.ssm is not None:
+            # the mixer reads the SAME normed input as attention and both
+            # land on the residual together (Falcon-H1's parallel block)
+            m = Mamba2Mixer(self.ssm, dtype=self.dtype,
+                            state_rows=self.state_rows, name="mixer")(
+                _scaled(u, mup.ssm_in), decode=decode, positions=positions,
+                seq_lens=seq_lens, rows=rows)
+            x = x + _scaled(m, mup.ssm_out)
+        y = _norm(self.norm, "ln2", self.ln_eps)(x).astype(self.dtype)
         E = x.shape[-1]
-        y = QuantizableDense(
-            E * self.mlp_ratio, name="mlp_in", dtype=self.dtype,
-            kernel_init=_part((None, "tp"))(nn.initializers.lecun_normal()),
-            bias_init=_part(("tp",))(nn.initializers.zeros))(y)
-        y = nn.gelu(y)
-        y = QuantizableDense(
-            E, name="mlp_out", dtype=self.dtype,
-            kernel_init=_part(("tp", None))(nn.initializers.lecun_normal()))(y)
+        if self.mlp == "gelu":
+            y = QuantizableDense(
+                self.mlp_dim or E * self.mlp_ratio, name="mlp_in",
+                dtype=self.dtype,
+                kernel_init=_part((None, "tp"))(
+                    nn.initializers.lecun_normal()),
+                bias_init=_part(("tp",))(nn.initializers.zeros))(y)
+            y = nn.gelu(y)
+            y = QuantizableDense(
+                E, name="mlp_out", dtype=self.dtype,
+                kernel_init=_part(("tp", None))(
+                    nn.initializers.lecun_normal()))(y)
+        elif self.mlp == "swiglu":
+            # silu(gate) * up -> down, no biases, at a width of its own
+            wide = lambda name: QuantizableDense(
+                self.mlp_dim or E * self.mlp_ratio, name=name,
+                use_bias=False, dtype=self.dtype,
+                kernel_init=_part((None, "tp"))(
+                    nn.initializers.lecun_normal()))
+            y = nn.silu(_scaled(wide("mlp_gate")(y), mup.mlp[0])) \
+                * wide("mlp_up")(y)
+            y = _scaled(QuantizableDense(
+                E, name="mlp_out", use_bias=False, dtype=self.dtype,
+                kernel_init=_part(("tp", None))(
+                    nn.initializers.lecun_normal()))(y), mup.mlp[1])
+        else:
+            raise ValueError(f"unknown mlp {self.mlp!r} (valid: 'gelu', "
+                             f"'swiglu')")
         y = nn.Dropout(self.dropout, deterministic=not train)(y)
         return x + y
 
@@ -464,11 +569,31 @@ class CausalTransformer(nn.Module):
     kv_pages: int = 0
     paged_attn: str = "auto"
     kv_quant: str = "off"
+    # --- the block's kinds. ``norm``: "layernorm" | "rmsnorm" (also the
+    # final norm). ``mlp``: "gelu" (biased, at mlp_ratio) | "swiglu" (gated,
+    # no bias); ``mlp_dim`` > 0 sets the MLP's width outright.
+    # ``num_kv_heads`` / ``head_dim``: grouped-query attention at a head size
+    # of its own (0 = num_heads, embed_dim / num_heads). ``ssm``: a Mamba-2
+    # mixer beside attention in every block (models/mamba2.py); its
+    # recurrent state lives in the cache collection, ``state_rows`` rows of
+    # it (the serving layer clones that in with the arena's sizes; 0 = the
+    # batch). ``mup``: the constant multipliers of a muP checkpoint. ---
+    norm: str = "layernorm"
+    mlp: str = "gelu"
+    mlp_dim: int = 0
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    ssm: Optional[SSMConfig] = None
+    mup: Optional[MuP] = None
+    state_rows: int = 0
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, decode: bool = False,
                  return_hidden: bool = False, positions=None, pages=None,
-                 seq_lens=None, exit_layer: Optional[int] = None):
+                 seq_lens=None, exit_layer: Optional[int] = None, rows=None):
+        # ``rows`` [B] (recurrent models, decode only): each batch row's
+        # place in the cache's per-row state, for programs whose rows are
+        # not the state's (an admission); None = row b is state row b
         # ``exit_layer`` (a TRACE-TIME int in [1, depth]) runs only the
         # first ``exit_layer`` blocks, then ln_f + lm_head — the early-exit
         # self-drafting head for speculative decoding (models.generation /
@@ -493,6 +618,8 @@ class CausalTransformer(nn.Module):
         use_rope = self.pos == "rope"
         x = nn.Embed(self.vocab_size, self.embed_dim, name="token_embed",
                      embedding_init=_part((None, "tp"))(nn.initializers.normal(0.02)))(token_ids)
+        mup = self.mup or MuP()
+        x = _scaled(x, mup.embedding)
         if not use_rope:
             pos = self.param("pos_embed",
                              _part((None, None, "tp"))(nn.initializers.normal(0.02)),
@@ -577,15 +704,22 @@ class CausalTransformer(nn.Module):
                                   kv_pages=self.kv_pages,
                                   paged_attn=self.paged_attn,
                                   kv_quant=self.kv_quant,
+                                  norm=self.norm, mlp=self.mlp,
+                                  mlp_dim=self.mlp_dim,
+                                  num_kv_heads=self.num_kv_heads,
+                                  head_dim=self.head_dim, ssm=self.ssm,
+                                  mup=self.mup, state_rows=self.state_rows,
                                   name=f"block_{i}")
                 # positions only exists on the decode path, which never remats
                 # — keeping the training call positional preserves the remat
                 # wrapper's static_argnums contract
-                x = (block(x, valid, train, decode, positions=positions,
-                           pages=pages, seq_lens=seq_lens)
-                     if decode else block(x, valid, train, decode))
-        x = nn.LayerNorm(name="ln_f", dtype=jnp.float32,
-                         epsilon=self.ln_eps)(x).astype(self.dtype)
+                if decode:
+                    at = {} if self.ssm is None else {"rows": rows}
+                    x = block(x, valid, train, decode, positions=positions,
+                              pages=pages, seq_lens=seq_lens, **at)
+                else:
+                    x = block(x, valid, train, decode)
+        x = _norm(self.norm, "ln_f", self.ln_eps)(x).astype(self.dtype)
         if return_hidden:
             # final hidden states [B, L, E] for a chunked lm_head+loss
             # (parallel.trainer.chunked_lm_loss): at very long context the
@@ -597,7 +731,8 @@ class CausalTransformer(nn.Module):
         logits = QuantizableDense(
             self.vocab_size, name="lm_head", use_bias=False, dtype=self.dtype,
             kernel_init=_part((None, "tp"))(nn.initializers.lecun_normal()))(x)
-        return logits.astype(jnp.float32)
+        logits = logits.astype(jnp.float32)
+        return logits if mup.lm_head == 1.0 else logits * mup.lm_head
 
 
 def GPTTiny(vocab_size: int = 1000, max_len: int = 128, mesh=None,

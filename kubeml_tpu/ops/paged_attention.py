@@ -3,7 +3,8 @@ page table, no contiguous K/V copy, KV traffic that scales with occupancy.
 
 The paged serving engine (serving/kvpool.py + the paged decode branch in
 models/gpt.py) stores K/V in one shared physical arena
-``[kv_pages, H, page_tokens, D]`` addressed through per-row page tables.
+``[kv_pages, Hkv, page_tokens, D]`` (``Hkv`` K/V heads: the query heads, or
+fewer under grouped-query attention) addressed through per-row page tables.
 The original decode read was gather-then-attend: every step, every layer,
 each row's WHOLE table is gathered into a contiguous ``[B, tw*pt, H, D]``
 HBM block and plain attention runs over it — so a row 64 tokens into a
@@ -161,6 +162,9 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
     j = pl.program_id(1)
     i = pl.program_id(2)
     n_heads, tq = q_ref.shape[1], q_ref.shape[2]
+    # grouped-query attention: the page block holds the K/V heads only and
+    # query head h reads K/V head h // share (share 1: a head each)
+    share = n_heads // k_ref.shape[1]
     pt = page_tokens
 
     @pl.when(i == 0)
@@ -185,15 +189,16 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
         visible = k_pos <= q_pos
         for h in range(n_heads):
             q = q_ref[0, h]      # [tq, D] (storage dtype; f32 accumulate)
-            k_pg = k_ref[0, h]   # [pt, D] — one physical page, this head
-            v_pg = v_ref[0, h]
+            hk = h // share
+            k_pg = k_ref[0, hk]  # [pt, D] — one physical page, this head
+            v_pg = v_ref[0, hk]
             if quantized:
                 k_pg = k_pg.astype(q.dtype)
             s = jax.lax.dot_general(
                 q, k_pg, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [tq, pt]
             if quantized:
-                s = s * (ks_ref[0, 0, h:h + 1, :] / _KV_QMAX)
+                s = s * (ks_ref[0, 0, hk:hk + 1, :] / _KV_QMAX)
             s = jnp.where(visible, s, _NEG)
             m_prev = m_ref[h, :, 0:1]
             l_prev = l_ref[h, :, 0:1]
@@ -206,7 +211,7 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
                 # contract p against the raw int8 page; the page scale
                 # folds into p first (one scalar per page — same sum)
                 pv = jax.lax.dot_general(
-                    p * (vs_ref[0, 0, h:h + 1, :] / _KV_QMAX),
+                    p * (vs_ref[0, 0, hk:hk + 1, :] / _KV_QMAX),
                     v_pg.astype(jnp.float32), (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
             else:
@@ -227,13 +232,13 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
 
 def paged_attention(
     q: jnp.ndarray,         # [B, L, H, D] this call's queries
-    k_pages: jnp.ndarray,   # [N, H, pt, D] physical K arena (post-write)
-    v_pages: jnp.ndarray,   # [N, H, pt, D] physical V arena (post-write)
+    k_pages: jnp.ndarray,   # [N, Hkv, pt, D] physical K arena (post-write)
+    v_pages: jnp.ndarray,   # [N, Hkv, pt, D] physical V arena (post-write)
     pages: jnp.ndarray,     # [B, P] int32 per-row page table
     positions: jnp.ndarray,  # [B] int32 logical position of q[:, 0]
     interpret: Optional[bool] = None,
-    k_scale: Optional[jnp.ndarray] = None,  # [N, H] f32 per-page absmax (int8)
-    v_scale: Optional[jnp.ndarray] = None,  # [N, H] f32 per-page absmax (int8)
+    k_scale: Optional[jnp.ndarray] = None,  # [N, Hkv] f32 per-page absmax (int8)
+    v_scale: Optional[jnp.ndarray] = None,  # [N, Hkv] f32 per-page absmax (int8)
 ) -> jnp.ndarray:
     """Paged decode attention; returns ``[B, L, H, D]``.
 
@@ -247,8 +252,15 @@ def paged_attention(
     With ``k_scale``/``v_scale`` the arenas are int8 (KUBEML_KV_QUANT=int8)
     and each page's per-head absmax rides the same clamped page walk as
     its K/V block; dequant happens in the kernel's VMEM blocks around the
-    QK^T/PV matmuls — the arenas are never materialized wide."""
+    QK^T/PV matmuls — the arenas are never materialized wide.
+
+    The arena may hold fewer heads than ``q`` (grouped-query attention):
+    with ``Hkv`` K/V heads, query head ``h`` reads K/V head
+    ``h // (H / Hkv)``, and a page's block is the K/V heads' alone."""
     B, L, H, D = q.shape
+    Hkv = int(k_pages.shape[1])
+    if H % Hkv:
+        raise ValueError(f"{H} query heads over {Hkv} K/V heads")
     pt = int(k_pages.shape[2])
     P = int(pages.shape[1])
     if interpret is None:
@@ -294,8 +306,8 @@ def paged_attention(
 
     in_specs = [
         pl.BlockSpec((1, H, tq, D), q_map),
-        pl.BlockSpec((1, H, pt, D), kv_map),
-        pl.BlockSpec((1, H, pt, D), kv_map),
+        pl.BlockSpec((1, Hkv, pt, D), kv_map),
+        pl.BlockSpec((1, Hkv, pt, D), kv_map),
     ]
     operands = [qt, k_pages, v_pages]
     if quantized:
@@ -309,10 +321,10 @@ def paged_attention(
         # of it broadcasts over a score tile as is.
         def rows(s):
             return jnp.broadcast_to(
-                s.astype(jnp.float32)[pages][..., None], (B, P, H, pt))
+                s.astype(jnp.float32)[pages][..., None], (B, P, Hkv, pt))
 
-        in_specs += [pl.BlockSpec((1, 1, H, pt), scale_map),
-                     pl.BlockSpec((1, 1, H, pt), scale_map)]
+        in_specs += [pl.BlockSpec((1, 1, Hkv, pt), scale_map),
+                     pl.BlockSpec((1, 1, Hkv, pt), scale_map)]
         operands += [rows(k_scale), rows(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # pages, positions, live

@@ -1854,7 +1854,8 @@ class ParameterServer:
                 from ..storage.sharded_checkpoint import apply_remap_host
 
                 variables = apply_remap_host(variables, remap)
-            return (model, variables, mtime, self._serving_mesh_for(model))
+            return (model, self._held(variables), mtime,
+                    self._serving_mesh_for(model))
         store = self._serving_sharded_store()
         try:
             manifest = store.read_manifest(model_id, tag)
@@ -1892,7 +1893,26 @@ class ParameterServer:
         variables = ck.variables
         if quantized:
             variables = from_storage_tree(variables)
+        if mesh is None:
+            variables = self._held(variables)
         return (model, variables, mtime, mesh)
+
+    def _held(self, variables):
+        """The served tree in ``Config.serving_param_dtype`` (empty: as the
+        checkpoint has it), cast leaf by leaf on the device. A tree already
+        placed on a serving mesh keeps its type: its shardings were derived
+        for the leaves as restored."""
+        want = (self.cfg.serving_param_dtype or "").lower()
+        if not want:
+            return variables
+        if want not in ("bfloat16", "float32"):
+            log.warning("KUBEML_SERVING_PARAM_DTYPE=%r not recognized "
+                        "(valid: bfloat16, float32) - serving the "
+                        "checkpoint's own type", want)
+            return variables
+        from ..serving.quant import cast_tree
+
+        return cast_tree(variables, want)
 
     def _load_serving(self, model_id: str):
         """(model, variables, mtime, serving mesh) for a FINISHED job from

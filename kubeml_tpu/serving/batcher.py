@@ -75,6 +75,20 @@ class DecoderClosed(KubeMLError):
         super().__init__("decoder is shut down", 503)
 
 
+class RecurrentStateUnsupported(KubeMLError):
+    """What the engine does not do for a model that carries recurrent state
+    beside its pages (a Mamba-2 mixer): speculative decoding rolls back
+    pages, a KMS1 frame carries pages, the slot engine prefills padded rows
+    — none of them moves the state. Refused by name, never served from a
+    state that was not rolled back or carried."""
+
+    def __init__(self, what: str):
+        super().__init__(
+            f"{what} is not supported for a model with recurrent state: "
+            f"only its KV pages would be moved, not the state beside them",
+            409)
+
+
 def _param_shardings(module, mesh):
     """NamedSharding pytree for a causal-LM module's variables, derived from
     its own ``nn.with_partitioning`` annotations (the same derivation the
@@ -394,9 +408,8 @@ def _kv_token_bytes(module, layers: Optional[int] = None) -> int:
     import jax.numpy as jnp
 
     depth = layers if layers is not None else getattr(module, "depth", None)
-    heads = getattr(module, "num_heads", None)
-    embed = getattr(module, "embed_dim", None)
-    if not depth or not heads or not embed:
+    width = _kv_width(module)
+    if not depth or not width:
         return 0
     itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
     # the accounting models STORAGE bytes: an int8-quantized arena
@@ -409,7 +422,21 @@ def _kv_token_bytes(module, layers: Optional[int] = None) -> int:
 
     if resolve_kv_quant(getattr(module, "kv_quant", "off")) == "int8":
         itemsize = 1
-    return int(depth) * 2 * int(embed) * int(itemsize)
+    return int(depth) * 2 * width * int(itemsize)
+
+
+def _kv_width(module) -> int:
+    """Elements of K (or of V) one token holds in one layer: the K/V heads
+    (``num_kv_heads``, under grouped-query attention fewer than the query
+    heads) times the head size. 0 when the module doesn't expose the
+    transformer geometry."""
+    heads = getattr(module, "num_heads", None)
+    embed = getattr(module, "embed_dim", None)
+    if not heads or not embed:
+        return 0
+    kv_heads = getattr(module, "num_kv_heads", 0) or heads
+    return int(kv_heads) * int(getattr(module, "head_dim", 0)
+                               or int(embed) // int(heads))
 
 
 def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
@@ -421,15 +448,16 @@ def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
     import jax.numpy as jnp
 
     depth = getattr(module, "depth", None)
-    heads = getattr(module, "num_heads", None)
-    embed = getattr(module, "embed_dim", None)
-    if not depth or not heads or not embed:
+    width = _kv_width(module)
+    if not depth or not width:
         return 0
     if kv_quant == "int8":
-        return int(depth) * 2 * (int(page_tokens) * int(embed) * 1
-                                 + int(heads) * 4)
+        kv_heads = (getattr(module, "num_kv_heads", 0)
+                    or getattr(module, "num_heads"))
+        return int(depth) * 2 * (int(page_tokens) * width * 1
+                                 + int(kv_heads) * 4)
     itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
-    return int(depth) * 2 * int(page_tokens) * int(embed) * int(itemsize)
+    return int(depth) * 2 * int(page_tokens) * width * int(itemsize)
 
 
 def service_interval(dispatched: float, done: float,
@@ -555,6 +583,10 @@ class BatchingDecoder:
     chip. One background thread owns the device loop.
     """
 
+    # whether this engine carries a recurrent model's per-row state through
+    # admission, chunked prefill and the decode step (the paged engine does)
+    _recurrent_ok = False
+
     def __init__(self, module, variables, *, slots: int = DEFAULT_SLOTS,
                  chunk_steps: int = 8, bucket_min: int = 16,
                  pipeline_depth: Optional[int] = None, name: str = "decoder",
@@ -569,6 +601,13 @@ class BatchingDecoder:
             raise GenerationInputError(
                 "model exposes no max_len attribute; batched decode requires "
                 "a declared KV-cache capacity")
+        from ..models.generation import has_recurrent_state
+
+        # a model with recurrent state beside its K/V (a Mamba-2 mixer):
+        # only the paged engine carries a row's state (_recurrent_ok)
+        self._recurrent = has_recurrent_state(module)
+        if self._recurrent and not self._recurrent_ok:
+            raise RecurrentStateUnsupported("the slot engine")
         self.module = module
         self.max_len = int(cap)
         self.slots = int(slots)
@@ -805,9 +844,15 @@ class BatchingDecoder:
 
     # --- device programs ---
 
-    def _apply_step(self, variables, cache, tok, pos, pages=None):
+    def _apply_step(self, variables, cache, tok, pos, pages=None, live=None):
         variables = self._dense_vars(variables)
         kw = {} if pages is None else {"pages": pages}
+        if self._recurrent:
+            # a row that is not live (retired, or mid-chunked-prefill) must
+            # keep its recurrent state: a step is one position, and a
+            # sequence length of 0 leaves state and convolution tail alone
+            # (its K/V write goes to the trash page as before)
+            kw["seq_lens"] = live.astype(jnp.int32)
         logits, vs = self.module.apply(
             {**variables, "cache": cache}, tok[:, None], decode=True,
             positions=pos, mutable=["cache"], **kw)
@@ -839,7 +884,7 @@ class BatchingDecoder:
 
         def one(s, _):
             logits, cache = self._apply_step(variables, s.cache, s.tok, s.pos,
-                                             pages=pages)
+                                             pages=pages, live=s.live)
             use, nxt_keys = _split_rows(s.keys)
             nxt = _sample_rows(logits, use, s.temp, s.topk, active=s.live)
             was_live = s.live
@@ -1550,7 +1595,8 @@ class BatchingDecoder:
         return max(self._remaining_steps(), default=0)
 
     def _run_program(self, program: str, sig: tuple, fn, *args, kind: str,
-                     steps: int = 0, width: int = 0, group=None):
+                     steps: int = 0, width: int = 0, group=None,
+                     state_rows: int = 0):
         """Dispatch one jitted program through the compile tracker: the
         first call per (program, shape signature) traces + XLA-compiles
         synchronously before the async dispatch, so its wall here IS the
@@ -1563,7 +1609,8 @@ class BatchingDecoder:
         begins (service_interval), so it is stamped every time. With the
         tracer on the call is an ``engine.dispatch`` span — ``kind`` is the
         record's (admit, step, spec, pchunk), ``steps`` the decode steps in
-        the program, ``width`` its page-table width — under a profiler
+        the program, ``width`` its page-table width, ``state_rows`` the rows
+        whose recurrent state it writes (0 for a model without one) — under a profiler
         annotation of the same name, and an admitting program (``group``
         set) closes the ``engine.admit`` span that began where its rows
         were taken from the queue."""
@@ -1577,7 +1624,8 @@ class BatchingDecoder:
                     row.entry.request_id for _, row in group))
                 began = self._admit_from or tracer.at(t0)
                 tracer.add_span("engine.admit", began, tracer.at(t0) - began,
-                                rows=len(group), requests=requests)
+                                rows=len(group), requests=requests,
+                                state_rows=state_rows)
             annotation = jax.profiler.TraceAnnotation("engine.dispatch",
                                                       seq=seq)
         # ONE call site, traced or not: a program's source locations are
@@ -1591,6 +1639,7 @@ class BatchingDecoder:
             self._engine_span(
                 "engine.dispatch", tracer.at(t0), t1 - t0, requests, seq=seq,
                 program=kind, steps=steps, width=width, cold=cold,
+                state_rows=state_rows,
                 rows_live=sum(r is not None for r in self._slot_rows),
                 depth=self._depth, ahead=len(self._inflight))
             # a later group of the same wave is prepared from here on
@@ -2040,7 +2089,22 @@ class PagedBatchingDecoder(BatchingDecoder):
     Quantized weights (int8 / native int8 matmul) compose unchanged — the
     arena is cache state, not weights. A mesh does not: sharded serving
     stays on the dense engine until the arena learns a head-sharded layout.
+
+    **Recurrent state beside the pages** — a model with a Mamba-2 mixer
+    (``models.generation.has_recurrent_state``) keeps, per layer, one
+    fixed-size state per program row (``ssm_state`` ``[slots, H, N, P]``
+    float32 and ``conv_tail``) in the same ``cache`` tree as the arena:
+    donated, rebuilt and freed with it. An admission names its rows' places
+    (``rows``) and the model starts them from zeros (a reused slot), or from
+    the row's own state where a chunked prefill continues, and writes the
+    state at each prompt's true length; the decode step advances the state
+    of live rows only, in place (ops/ssm.py ``ssm_update``). Prefix sharing
+    is off for such a model (shared pages have no state to go with them),
+    and speculation and KMS1 snapshot / restore are refused by name
+    (:class:`RecurrentStateUnsupported`).
     """
+
+    _recurrent_ok = True
 
     def __init__(self, module, variables, *, page_tokens: Optional[int] = None,
                  pages: Optional[int] = None,
@@ -2058,13 +2122,18 @@ class PagedBatchingDecoder(BatchingDecoder):
             raise ValueError(
                 "paged serving does not run on a mesh yet; use the dense "
                 "BatchingDecoder for sharded serving")
-        from ..models.generation import supports_paged_decode
+        from ..models.generation import (has_recurrent_state,
+                                         supports_paged_decode)
 
         if not supports_paged_decode(module):
             raise GenerationInputError(
                 "module has no paged decode path (pages/seq_lens decode "
                 "kwargs + page_tokens/kv_pages fields); serve it through "
                 "the dense BatchingDecoder")
+        recurrent = has_recurrent_state(module)
+        if recurrent and spec not in ("", "off", None):
+            raise RecurrentStateUnsupported(
+                f"speculative decoding (spec={spec!r})")
         cap = getattr(module, "max_len", None)
         if cap is None:
             raise GenerationInputError(
@@ -2109,6 +2178,16 @@ class PagedBatchingDecoder(BatchingDecoder):
                 npages = max(npages, budget // bytes_q + 1)
         use_trie = bool(prefix_cache if prefix_cache is not None
                         else cfg.serving_prefix_cache)
+        # a prefix hit hands a row the PAGES of a shared prefix; the
+        # recurrent state after that prefix is nowhere, so for such a model
+        # sharing is wrong, not slow: off, said once, flagged in telemetry
+        self._prefix_off_recurrent = bool(recurrent and use_trie)
+        if self._prefix_off_recurrent:
+            log.warning(
+                "%s: prefix sharing is off: the model has recurrent state "
+                "and the prefix trie holds pages only",
+                kw.get("name", "decoder"))
+            use_trie = False
         self._pool = KVPool(npages, pt, prefix_cache=use_trie)
         # --- paged-attention read path (KUBEML_PAGED_ATTN=auto|pallas|
         # gather, ops/paged_attention.py): resolved HERE and cloned onto
@@ -2200,6 +2279,9 @@ class PagedBatchingDecoder(BatchingDecoder):
             clone_kw["paged_attn"] = impl
         if hasattr(module, "kv_quant"):
             clone_kw["kv_quant"] = kvq
+        if recurrent:
+            # one recurrent state per program row, beside the arena
+            clone_kw["state_rows"] = slots
         module = module.clone(**clone_kw)
         super().__init__(module, variables, mesh=None, **kw)
         # drafter KV-read constant for the spec accounting: the early-exit
@@ -2289,6 +2371,12 @@ class PagedBatchingDecoder(BatchingDecoder):
         # thread quiesces the dispatch chain, snapshots stragglers, and
         # hands the KMS1 frames back through it
         self._drain_req: Optional[_DrainReq] = None
+        self._param_bytes = sum(
+            int(l.size) * np.dtype(l.dtype).itemsize
+            for l in jax.tree.leaves(self._variables) if hasattr(l, "dtype"))
+        # recurrent state beside the pages, counted off the slab once it is
+        # built (the engine thread's first turn)
+        self._recurrent_layers = self._recurrent_bytes = 0
 
     # --- capacity & programs ---
 
@@ -2312,6 +2400,12 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     def _init_slab(self) -> _Slab:
         slab = super()._init_slab()
+        state = [l for path, l in
+                 jax.tree_util.tree_leaves_with_path(slab.cache)
+                 if getattr(path[-1], "key", None) in ("ssm_state",
+                                                       "conv_tail")]
+        self._recurrent_layers = len(state) // 2
+        self._recurrent_bytes = sum(int(l.nbytes) for l in state)
         if self.spec == "draft":
             # the drafter's own paged arena (same page ids, its own
             # head/depth dims) — rebuilt with the slab on fault recovery,
@@ -2336,9 +2430,14 @@ class PagedBatchingDecoder(BatchingDecoder):
         identical bytes — idempotent scatter), exactly like the dense
         engine's admit."""
         variables = self._dense_vars(variables)
+        # a recurrent model scatters each row's state into its program row
+        # (zeros first where base is 0: a reused slot; the row's own state
+        # where a chunked prefill goes on). Repeated rows write equal bytes.
+        kw = {"rows": rowids} if self._recurrent else {}
         logits, vs = self.module.apply(
             {**variables, "cache": slab.cache}, suffix, decode=True,
-            positions=base, pages=ptbl, seq_lens=slens, mutable=["cache"])
+            positions=base, pages=ptbl, seq_lens=slens, mutable=["cache"],
+            **kw)
         cache = vs["cache"]
         last = jnp.take_along_axis(
             logits, (slens - 1)[:, None, None], axis=1)[:, 0].astype(
@@ -2688,7 +2787,8 @@ class PagedBatchingDecoder(BatchingDecoder):
             (self._slab, packed), cold = self._run_program(
                 "prefill", (bucket, wa), self._prefill_admit,
                 self._variables, self._slab, *args,
-                kind="admit", width=wa, group=group)
+                kind="admit", width=wa, group=group,
+                state_rows=n if self._recurrent else 0)
         now = time.monotonic()
         real_tokens = 0
         for slot, row in group:
@@ -2853,7 +2953,8 @@ class PagedBatchingDecoder(BatchingDecoder):
             (self._slab, packed), cold = self._run_program(
                 "prefill", (bucket, wa), self._prefill_admit,
                 self._variables, self._slab, *args,
-                kind="pchunk", width=wa)
+                kind="pchunk", width=wa,
+                state_rows=n if self._recurrent else 0)
         for slot, row in batch:
             row.lease.prefill_pos += cap
             row.pos_cap = row.lease.prefill_pos
@@ -2967,7 +3068,10 @@ class PagedBatchingDecoder(BatchingDecoder):
             "step", (size, w), self._steps[size],
             self._variables, self._slab,
             jnp.asarray(self._table[:, :w].copy()),
-            kind="step", steps=size, width=w)
+            kind="step", steps=size, width=w,
+            state_rows=(sum(r is not None and not r.prefilling
+                            for r in self._slot_rows)
+                        if self._recurrent else 0))
         # one span per step: step s's query sits s positions past pos_cap
         kv_bytes = sum(self._chunk_kv_tokens(w, s)
                        for s in range(1, size + 1)) * self._kv_token_bytes
@@ -3065,6 +3169,8 @@ class PagedBatchingDecoder(BatchingDecoder):
         done = bool(snap.out) and (
             len(snap.out) >= snap.max_new
             or (snap.eos >= 0 and snap.out[-1] == snap.eos))
+        if snap.out and not done and self._recurrent:
+            raise RecurrentStateUnsupported("restoring a mid-stream snapshot")
         if snap.out and not done:
             # mid-stream state only restores into a byte-compatible arena
             if int(snap.page_tokens) != self.page_tokens:
@@ -3235,6 +3341,12 @@ class PagedBatchingDecoder(BatchingDecoder):
         from . import kvsnap
 
         if self.spec == "draft":
+            self.stats.snapshot_fail()
+            return None
+        if self._recurrent:
+            log.warning("%s: %s (request %s)", self.name,
+                        RecurrentStateUnsupported("a mid-stream snapshot"),
+                        row.entry.request_id)
             self.stats.snapshot_fail()
             return None
         t0 = time.monotonic()
@@ -3481,6 +3593,15 @@ class PagedBatchingDecoder(BatchingDecoder):
         # programs the loop keeps in flight now (run_ahead_depth): under
         # pipeline_depth wherever the host answers well inside a step
         snap["run_ahead_depth"] = float(self._depth)
+        # bytes of parameters resident for this model (every leaf, in the
+        # type it is held in: Config.serving_param_dtype)
+        snap["param_bytes"] = float(self._param_bytes)
+        # recurrent state beside the pages: layers that keep one, its bytes
+        # over all program rows, and whether it switched prefix sharing off
+        snap["recurrent_layers"] = float(self._recurrent_layers)
+        snap["recurrent_state_bytes"] = float(self._recurrent_bytes)
+        snap["prefix_cache_off_recurrent"] = (
+            1.0 if self._prefix_off_recurrent else 0.0)
         if self._spec_ctl is not None:
             # current adaptive speculation depth (0 = retreated to plain
             # decode) + the controller's EWMA acceptance estimate

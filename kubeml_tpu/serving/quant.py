@@ -120,6 +120,23 @@ def dequantize_tree(variables: dict, dtype=jnp.bfloat16) -> dict:
     return jax.tree.map(one, variables, is_leaf=_is_q)
 
 
+def cast_tree(variables: dict, dtype) -> dict:
+    """The tree with every floating leaf held in ``dtype`` on the device
+    (``Config.serving_param_dtype``): each leaf is put on the device and
+    cast there by itself, and its wide copy dropped before the next one
+    arrives, so the peak is the narrow tree plus one wide leaf. Quantized
+    leaves and integer leaves pass through."""
+    dtype = jnp.dtype(dtype)
+
+    def one(leaf):
+        if _is_q(leaf) or not jnp.issubdtype(
+                getattr(leaf, "dtype", jnp.int32), jnp.floating):
+            return leaf
+        return jax.block_until_ready(jnp.asarray(leaf).astype(dtype))
+
+    return jax.tree.map(one, variables, is_leaf=_is_q)
+
+
 def quantized_dot(x, qt: QuantizedTensor, *, dtype=None, impl: str = None):
     """``x @ dequant(qt)`` WITHOUT materializing the dense weight: the
     contraction runs on the int8 values and the per-output-channel scale

@@ -19,6 +19,7 @@ def kernel_cases():
     from kubeml_tpu.ops.flash_attention import flash_attention
     from kubeml_tpu.ops.int8_matmul import int8_matmul
     from kubeml_tpu.ops.paged_attention import paged_attention
+    from kubeml_tpu.ops.ssm import ssm_update
 
     cases = {}
     table = _sds((B, TABLE), jnp.int32)
@@ -41,6 +42,25 @@ def kernel_cases():
                     q, k, v, t, p, interpret=False)
                 args = (q, arena, arena, table, pos)
             cases[f"paged_attention-{name}-L{L}"] = (fn, args)
+    # Falcon-H1-34B's published shapes: 20 query heads on 4 K/V heads of
+    # 128 over a 32-row slab (a decode step and one prefill tile), and the
+    # mixer's state update, 32 heads of [256, 128] float32 in 2 groups
+    rows, hq, hkv, d = 32, 20, 4, 128
+    for L, width in ((1, 32), (128, 8)):
+        cases[f"paged_attention-gqa-bf16-L{L}"] = (
+            lambda q, k, v, t, p: paged_attention(q, k, v, t, p,
+                                                  interpret=False),
+            (_sds((rows, L, hq, d), jnp.bfloat16),
+             _sds((2049, hkv, PT, d), jnp.bfloat16),
+             _sds((2049, hkv, PT, d), jnp.bfloat16),
+             _sds((rows, width), jnp.int32), _sds((rows,), jnp.int32)))
+    cases["ssm_update-falcon-h1-34b"] = (
+        lambda s, x, dt, a, b, c: ssm_update(s, x, dt, a, b, c,
+                                             interpret=False),
+        (_sds((rows, 32, 256, 128), jnp.float32),
+         _sds((rows, 32, 128), jnp.float32), _sds((rows, 32), jnp.float32),
+         _sds((32,), jnp.float32), _sds((rows, 2, 256), jnp.float32),
+         _sds((rows, 2, 256), jnp.float32)))
     # the MLP up-projection and the lm_head (vocab 50257: not a tile multiple)
     for K, N in ((768, 3072), (768, 50257)):
         cases[f"int8_matmul-{K}x{N}"] = (
