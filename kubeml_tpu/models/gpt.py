@@ -22,6 +22,8 @@ defaults; Falcon-H1 is rmsnorm + swiglu + GQA + rope + ssm + mup.
 
 from __future__ import annotations
 
+import functools
+import threading
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
@@ -518,6 +520,49 @@ class GPTBlock(nn.Module):
         return x + y
 
 
+_block_traces = 0   # times _decode_block's body has run: once per trace
+_block_traces_lock = threading.Lock()   # engines trace on their own threads
+
+
+def block_traces() -> int:
+    """How often this process has traced :func:`_decode_block`. A decode
+    program costs about one, whatever its depth; a count that grows by the
+    depth says the layers stopped sharing a trace (serving telemetry shows
+    it as ``block_traces`` beside ``compiled_programs``)."""
+    return _block_traces
+
+
+@functools.partial(jax.jit, static_argnums=0, inline=True)
+def _decode_block(block, variables, x, positions, pages, seq_lens, rows):
+    """One layer of a decode apply: ``block``, a detached :class:`GPTBlock`,
+    on ``variables`` = that layer's ``params`` and, once it exists, its
+    ``cache``; returns the stream and the layer's new cache subtree.
+
+    Jitted with the module static, so a program's layers, whose parameters
+    and cache differ in value only, share one trace: this body runs for the
+    first layer, and jax copies the equations it recorded into the program
+    for every other. A Python ``for`` over bound submodules ran the block's
+    Python and traced its kernels again for every layer, 1.4 s a layer and
+    program on a v5e host, found in no cache. ``inline=True`` makes the
+    copy land in the caller's own equations, so the program XLA is handed
+    is the unrolled one, operation for operation; left as a function called
+    ``depth`` times, XLA's TPU pipeline simplifies the body alone before it
+    inlines it and compiles a slightly different decode step (PERF.md, PR
+    29). The layers' kernel equations are then one object, which is what
+    lets jax lower the kernel to Mosaic once a program and not once a layer.
+    Training keeps the loop over bound blocks: its remat wrapper, dropout
+    rngs and sown MoE losses thread through the parent's scope."""
+    global _block_traces
+    with _block_traces_lock:
+        _block_traces += 1
+    # decode trusts every token as real (CausalTransformer.__call__)
+    valid = jnp.ones(x.shape[:2], jnp.bool_)
+    x, mutated = block.apply(variables, x, valid, False, True,
+                             positions=positions, pages=pages,
+                             seq_lens=seq_lens, rows=rows, mutable=["cache"])
+    return x, mutated["cache"]
+
+
 class CausalTransformer(nn.Module):
     """Decoder-only LM over int32 token ids [B, L]; id 0 = padding.
 
@@ -672,7 +717,24 @@ class CausalTransformer(nn.Module):
                 raise ValueError("early-exit drafting does not cover "
                                  "MoE-interleaved models")
         run_depth = self.depth if exit_layer is None else int(exit_layer)
+        fields = dict(
+            mesh=self.mesh, sp_impl=self.sp_impl, dtype=self.dtype,
+            ln_eps=self.ln_eps, attn_bias=self.attn_bias,
+            cache_len=self.max_len if decode else 0,
+            rope=use_rope, rope_theta=self.rope_theta,
+            page_tokens=self.page_tokens, kv_pages=self.kv_pages,
+            paged_attn=self.paged_attn, kv_quant=self.kv_quant,
+            norm=self.norm, mlp=self.mlp, mlp_dim=self.mlp_dim,
+            num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+            ssm=self.ssm, mup=self.mup, state_rows=self.state_rows)
+        # a decode apply sends every layer through the one trace of
+        # _decode_block: this block, detached from the module and so equal
+        # for all layers, is its static argument
+        detached = (GPTBlock(self.num_heads, self.mlp_ratio, self.dropout,
+                             parent=None, **fields)
+                    if decode and not self.is_initializing() else None)
         for i in range(run_depth):
+            name = f"block_{i}"
             if self.moe_every > 0 and (i + 1) % self.moe_every == 0:
                 from ..parallel.moe import MoEBlock
 
@@ -682,43 +744,32 @@ class CausalTransformer(nn.Module):
                              sp_impl=self.sp_impl, dtype=self.dtype,
                              rope=use_rope, rope_theta=self.rope_theta,
                              cache_len=self.max_len if decode else 0,
-                             name=f"block_{i}")(x, valid, train=train,
-                                                decode=decode,
-                                                positions=positions)
+                             name=name)(x, valid, train=train, decode=decode,
+                                        positions=positions)
+            elif detached is not None:
+                # the layer's own subtrees go in as arguments, and its new
+                # cache lands where a bound block_i would have left it (it
+                # is absent on the way in while the cache is being sized)
+                vs = {"params": self.get_variable("params", name)}
+                if self.has_variable("cache", name):
+                    vs["cache"] = self.get_variable("cache", name)
+                x, cache = _decode_block(detached, vs, x, positions, pages,
+                                         seq_lens, rows)
+                self.put_variable("cache", name, cache)
             else:
                 # static_argnums counts self as 0, so `train` (a trace-time
                 # bool steering dropout determinism) is positional arg 3 and
-                # `decode` arg 4; decode never needs remat (no backward), so
-                # the remat wrapper only serves the training path
+                # `decode` arg 4; decode has no backward, so only training
+                # takes the remat wrapper, and its call stays positional
                 block_cls = (
                     GPTBlock if decode or not self.remat
                     else nn.remat(GPTBlock, static_argnums=(3, 4))
                 )
-                block = block_cls(self.num_heads, self.mlp_ratio, self.dropout,
-                                  mesh=self.mesh, sp_impl=self.sp_impl,
-                                  dtype=self.dtype, ln_eps=self.ln_eps,
-                                  attn_bias=self.attn_bias,
-                                  cache_len=self.max_len if decode else 0,
-                                  rope=use_rope, rope_theta=self.rope_theta,
-                                  page_tokens=self.page_tokens,
-                                  kv_pages=self.kv_pages,
-                                  paged_attn=self.paged_attn,
-                                  kv_quant=self.kv_quant,
-                                  norm=self.norm, mlp=self.mlp,
-                                  mlp_dim=self.mlp_dim,
-                                  num_kv_heads=self.num_kv_heads,
-                                  head_dim=self.head_dim, ssm=self.ssm,
-                                  mup=self.mup, state_rows=self.state_rows,
-                                  name=f"block_{i}")
-                # positions only exists on the decode path, which never remats
-                # — keeping the training call positional preserves the remat
-                # wrapper's static_argnums contract
-                if decode:
-                    at = {} if self.ssm is None else {"rows": rows}
-                    x = block(x, valid, train, decode, positions=positions,
-                              pages=pages, seq_lens=seq_lens, **at)
-                else:
-                    x = block(x, valid, train, decode)
+                at = dict(positions=positions, pages=pages,
+                          seq_lens=seq_lens, rows=rows) if decode else {}
+                x = block_cls(self.num_heads, self.mlp_ratio, self.dropout,
+                              name=name, **fields)(x, valid, train, decode,
+                                                   **at)
         x = _norm(self.norm, "ln_f", self.ln_eps)(x).astype(self.dtype)
         if return_hidden:
             # final hidden states [B, L, E] for a chunked lm_head+loss

@@ -52,7 +52,7 @@ import numpy as np
 
 from ..api.errors import KubeMLError
 from ..models.generation import GenerationInputError, init_cache
-from ..models.gpt import PAD_ID
+from ..models.gpt import PAD_ID, block_traces
 from ..utils import tracing
 
 log = logging.getLogger("kubeml.serving")
@@ -3596,6 +3596,10 @@ class PagedBatchingDecoder(BatchingDecoder):
         # bytes of parameters resident for this model (every leaf, in the
         # type it is held in: Config.serving_param_dtype)
         snap["param_bytes"] = float(self._param_bytes)
+        # traces of the model's block in this process (models/gpt.py
+        # _decode_block; engines share them): about one per compiled
+        # program, not one per layer of each
+        snap["block_traces"] = float(block_traces())
         # recurrent state beside the pages: layers that keep one, its bytes
         # over all program rows, and whether it switched prefix sharing off
         snap["recurrent_layers"] = float(self._recurrent_layers)
