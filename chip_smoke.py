@@ -58,7 +58,7 @@ class Sizes:
     """The shapes of one pass. The defaults ARE the smoke; another value is
     only ever passed by a debugging driver."""
 
-    # train: the shapes bench.py runs (ResNet-18, CIFAR, batch 128, K=8)
+    # train: ResNet-18, CIFAR, batch 128, K=8
     batch: int = 128
     k: int = 8
     rounds_per_epoch: int = 3
@@ -159,13 +159,13 @@ def phase_device(cache_dir) -> dict:
         raise SystemExit(
             f"chip_smoke: devices are TPUs but jax.default_backend() is "
             f"{jax.default_backend()!r}")
-    from kubeml_tpu.benchmarks.mfu import hbm_bandwidth, peak_flops
+    from kubeml_tpu.utils.roofline import hbm_bandwidth, peak_flops
 
     peak, bw = peak_flops(d0), hbm_bandwidth(d0)
     if not peak or not bw:
         raise SystemExit(
             f"chip_smoke: device kind {d0.device_kind!r} has no entry in "
-            f"the peaks table (kubeml_tpu/benchmarks/mfu.py)")
+            f"the peaks table (kubeml_tpu/utils/roofline.py)")
     from importlib.metadata import PackageNotFoundError, version
 
     try:
@@ -453,8 +453,8 @@ RESNET_FN = '''
 import jax.numpy as jnp
 import optax
 
-from kubeml_tpu.benchmarks.harness import flagship
 from kubeml_tpu.data.dataset import KubeDataset
+from kubeml_tpu.models.resnet import ResNet18
 from kubeml_tpu.runtime.model import KubeModel
 
 
@@ -468,7 +468,7 @@ class Model(KubeModel):
         super().__init__(Cifar())
 
     def build(self):
-        return flagship(dtype=jnp.bfloat16).module
+        return ResNet18(num_classes=10, dtype=jnp.bfloat16)
 
     def preprocess(self, x):
         # images cross host->HBM as uint8 and dequantize on device
@@ -635,7 +635,7 @@ def phase_serve(sz: Sizes, cfg, cluster, client) -> dict:
     from kubeml_tpu.storage.checkpoint import FINAL_TAG, CheckpointStore
 
     # a servable "finished job": seeded weights exported as the final
-    # checkpoint of a deployed LM function (benchmarks/serving.py's recipe)
+    # checkpoint of a deployed LM function
     registry = FunctionRegistry(config=cfg)
     registry.create("smoke-gpt2", gpt_fn_source(sz, sz.vocab, False))
     module = registry.load("smoke-gpt2").module

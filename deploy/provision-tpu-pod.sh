@@ -48,10 +48,10 @@ echo "syncing repo to all workers..."
 # the SSH login user BEFORE the unprivileged scp
 gcloud compute tpus tpu-vm ssh "$NAME" --zone "$ZONE" --worker=all \
   --command 'sudo mkdir -p /opt/kubeml-tpu && sudo chown "$USER" /opt/kubeml-tpu'
-# ship SOURCE, not history/artifacts (.git + results/ dominate repo bytes)
+# ship SOURCE, not history/artifacts (.git dominates repo bytes)
 STAGE=$(mktemp -d)
 trap 'rm -rf "$STAGE"' EXIT
-tar -C "$REPO" --exclude=.git --exclude=results --exclude='__pycache__' \
+tar -C "$REPO" --exclude=.git --exclude='__pycache__' \
     --exclude='*.pyc' -cf - . | tar -C "$STAGE" -xf -
 gcloud compute tpus tpu-vm scp --recurse "$STAGE"/. "$NAME":/opt/kubeml-tpu \
   --zone "$ZONE" --worker=all

@@ -1,8 +1,6 @@
 """Weight-movement data plane: delta-encoded, compressed round updates.
 
-The round-5 driver row ran 32.8k samples/sec on-device but 14.8k end-to-end,
-and the PR-6 ``gap_attribution`` puts ~55% of every end-to-end round in
-staging — the
+What a K-AVG round pays end to end over its time on the device is staging: the
 reimagined RedisAI weight hop (the reference publishes the FULL model through
 RedisAI every K-AVG round, ml/pkg/model/model.go:135-161) plus host->HBM slab
 staging. This module attacks the weight bytes themselves, in the spirit of

@@ -443,7 +443,7 @@ class KAvgTrainer:
 
     def _build_sync_round(self, n_workers: int, steps: int, lr: float, epoch: int):
         """Static-hyperparameter build: lr/epoch burned into the executable
-        (the fallback for untraceable schedules; also what round_flops lowers
+        (the fallback for untraceable schedules; also what round_costs lowers
         — FLOPs don't depend on hyperparameter plumbing)."""
         model = self.model
         model.lr = lr
@@ -749,11 +749,6 @@ class KAvgTrainer:
         self._precompile_thread.start()
         return True
 
-    def round_flops(self, stacked_vars, x, y, mask, lr: float,
-                    epoch: int = 0) -> Optional[float]:
-        """FLOPs of one sync round (see ``round_costs``)."""
-        return self.round_costs(stacked_vars, x, y, mask, lr, epoch)["flops"]
-
     def round_costs(self, stacked_vars, x, y, mask, lr: float,
                     epoch: int = 0) -> dict:
         """{'flops', 'bytes_accessed'} of one sync round, from XLA's own cost
@@ -765,7 +760,7 @@ class KAvgTrainer:
         multiplying by the (static) trip count, since a 1-step program is the
         same either way. The merge's own FLOPs (~3 x params) are counted k
         times; negligible against the conv/matmul body. ``bytes_accessed``
-        feeds the roofline ceiling (benchmarks.mfu.roofline_mfu)."""
+        feeds the roofline ceiling (utils.roofline.roofline_mfu)."""
         n, k = x.shape[0], x.shape[1]
         fn1 = self._build_sync_round(n, 1, float(lr), int(epoch))
         sharded, replicated = self._shardings(n)
@@ -782,7 +777,7 @@ class KAvgTrainer:
         wm = sds((n,), jnp.float32, replicated)
         rng_ex = jax.random.PRNGKey(0)
         rngs = sds(rng_ex.shape, rng_ex.dtype, replicated)
-        from ..benchmarks.mfu import compiled_costs
+        from ..utils.roofline import compiled_costs
 
         costs = compiled_costs(fn1, vars_spec, x1, y1, m1, wm, rngs)
         return {

@@ -30,13 +30,12 @@ import jax.numpy as jnp
 # backward, ops/flash_attention.py) now win at EVERY measured length after
 # the round-3 tuning (bf16 MXU matmuls, 512x1024 blocks, causal copy-skip):
 # measured end-to-end tokens/sec 2026-07-31, same-day XLA vs pallas
-# (canonical rows: results/longcontext_r3_{xla,flash}.jsonl):
+# (round 3, on a shared chip that is gone; no ledger line holds these):
 # L=1024: 127.7k/152.7k, L=2048: 92.3k/144.2k, L=4096: 15.2k/119.0k (7.8x),
 # L=8192: 4.0k/84.3k (20.9x), L=16384: 18.2k/53.8k (3.0x), L=32768: XLA OOMs
 # (the bf16[8,32k,32k] scores want 16 GB HBM) vs 34.8k. Below 1024 XLA keeps
 # the tail and that IS measured: forcing the kernel at BERT-base's seq 128
-# dropped training MFU 43.6% -> 32.3% (results/transformers_r3_vit_sweep.jsonl
-# last row) — at tiny KV the kernel's per-program overhead beats its locality
+# dropped training MFU 43.6% -> 32.3% (same round, same chip) — at tiny KV the kernel's per-program overhead beats its locality
 # win. Structured-mask callers at KV length >= this threshold get the kernel;
 # None disables.
 FLASH_MIN_KV_LEN = 1024

@@ -2,10 +2,9 @@
 
 The reference serves classifier forward passes one request at a time
 (/root/reference/ml/pkg/scheduler/api.go:119-162); LM decode has no
-counterpart there. On TPU, decode throughput is a near-linear function of
-batch (chip-measured 459 -> 6,517 tokens/sec at batch 1 -> 16,
-results/generation_r3_decode.jsonl), so serving one request per program
-execution leaves ~93% of the chip idle. :class:`BatchingDecoder` coalesces
+counterpart there. On TPU a decode step streams the weights whatever the
+batch, so serving one request per program execution leaves most of the chip
+idle. :class:`BatchingDecoder` coalesces
 concurrent requests into one slot-based batched decode loop;
 :class:`PagedBatchingDecoder` (the default for capable models) replaces the
 per-row ``[max_len, H, D]`` cache stripes with a paged KV arena + block

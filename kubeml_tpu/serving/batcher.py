@@ -369,9 +369,7 @@ _MIN_TABLE_BUCKET = 8
 def _bucket_width(need: int, cap: int) -> int:
     """THE live-table-width bucket: ``need`` pages rounded up the pow2
     ladder from the ``_MIN_TABLE_BUCKET`` floor, capped at the full table.
-    One definition shared by chunk dispatch, admission and the microbench
-    (benchmarks/paged_attn_bench.py) so the bench always measures the
-    widths the engine actually ships."""
+    One definition shared by chunk dispatch and admission."""
     need = max(need, min(cap, _MIN_TABLE_BUCKET))
     w = 1
     while w < need:

@@ -20,7 +20,7 @@ The draft-model backend never suspends (``allow_off=False``): its
 separate KV cache is only coherent while the drafter sees every decoded
 token, and plain chunks would starve it — k floors at 1 instead. That
 floor is also its failure mode: a mismatched draft checkpoint
-(results/spec_decode.jsonl measured acceptance 0.003-0.25) pins k=1 and
+(ROADMAP D4: its acceptance was low wherever it was counted) pins k=1 and
 pays a full drafter forward per step forever. ``min_accept`` is the
 retreat for THAT backend — sustained EWMA acceptance below the floor
 after the warm-up cooldown **permanently disables** drafting

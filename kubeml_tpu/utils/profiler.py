@@ -16,7 +16,7 @@ measurement substrate the weight-movement data-plane work needs:
 * :class:`ProfileSession` — phase-scoped profiling: wrap the phases of a run
   (``with session.phase("stage", bytes=n):``), get a per-phase report with
   achieved bandwidth/FLOP rate and a roofline-based compute-bound vs
-  transfer-bound classification (cost model: benchmarks/mfu.py). When a
+  transfer-bound classification (cost model: utils/roofline.py). When a
   device-trace dir is given the whole session also captures a
   TensorBoard/XProf device trace via ``jax.profiler`` (pure-Python timeline
   fallback when jax/the backend is unavailable).
@@ -219,7 +219,7 @@ def render_metrics() -> List[str]:
     return lines
 
 
-# --- roofline classification (cost model: benchmarks/mfu.py) ---
+# --- roofline classification (cost model: utils/roofline.py) ---
 
 
 def classify(nbytes: float, flops: float) -> str:
@@ -228,14 +228,14 @@ def classify(nbytes: float, flops: float) -> str:
     ``transfer-bound`` when the bytes dominate, ``host`` when the phase
     moved no bytes and ran no FLOPs (control/bookkeeping), ``unknown`` when
     both terms are nonzero and the device's peaks are not in the table
-    (benchmarks/mfu.py — e.g. a CPU box): a roofline needs a machine."""
+    (utils/roofline.py — e.g. a CPU box): a roofline needs a machine."""
     if not nbytes and not flops:
         return "host"
     if not flops:
         return "transfer-bound"
     if not nbytes:
         return "compute-bound"
-    from ..benchmarks.mfu import hbm_bandwidth, peak_flops
+    from .roofline import hbm_bandwidth, peak_flops
 
     try:
         peak, bw = peak_flops(), hbm_bandwidth()
@@ -488,7 +488,7 @@ class ProfileSession:
 
     def dump(self, path: Path, **extra: Any) -> Path:
         """Append the report (one JSON line) to ``path``; ``extra`` fields
-        merge into the row (e.g. the bench rider's ``gap`` attribution)."""
+        merge into the row."""
         path = Path(path)
         path.parent.mkdir(parents=True, exist_ok=True)
         row = self.report()
@@ -497,36 +497,6 @@ class ProfileSession:
         with path.open("a") as f:
             f.write(json.dumps(row) + "\n")
         return path
-
-
-def gap_attribution(device_sps: float, e2e_sps: float,
-                    samples_per_round: float, bytes_per_round: float,
-                    flops_per_round: Optional[float] = None) -> Dict[str, Any]:
-    """Quantify the device-vs-end-to-end throughput gap as a per-round byte
-    budget: the extra wall time an end-to-end round pays over a device-only
-    round is the staging share, and the staged bytes over that time is the
-    achieved staging bandwidth."""
-    out: Dict[str, Any] = {
-        "device_samples_per_sec": device_sps,
-        "end_to_end_samples_per_sec": e2e_sps,
-        "bytes_per_round": bytes_per_round,
-    }
-    if flops_per_round:
-        out["flops_per_round"] = flops_per_round
-    if device_sps <= 0 or e2e_sps <= 0 or samples_per_round <= 0:
-        return out
-    t_device = samples_per_round / device_sps
-    t_e2e = samples_per_round / e2e_sps
-    staging_s = max(t_e2e - t_device, 0.0)
-    out.update({
-        "device_round_seconds": t_device,
-        "end_to_end_round_seconds": t_e2e,
-        "staging_seconds_per_round": staging_s,
-        "staging_share": staging_s / t_e2e if t_e2e > 0 else 0.0,
-    })
-    if staging_s > 0 and bytes_per_round > 0:
-        out["staging_bandwidth_bps"] = bytes_per_round / staging_s
-    return out
 
 
 # --- span-tree attribution (the `kubeml profile` report) ---

@@ -1,8 +1,9 @@
-"""Model-FLOPs-utilization accounting from first principles.
+"""The device's peaks and a compiled program's costs: what a roofline needs.
 
-Round 1 claimed "~44% MXU" from a rough analytic FLOPs model; the honest
-number computed here from the COMPILER'S own cost model was ~half that
-(VERDICT round 1). Every MFU figure in BASELINE.md now comes from this module:
+Two halves. The device description: published bf16 peak FLOP/s and HBM
+bandwidth per chip generation, keyed by ``device_kind`` (``peak_flops``,
+``hbm_bandwidth``; ``KUBEML_PEAK_FLOPS`` in TFLOP/s and ``KUBEML_HBM_BW`` in
+GB/s describe hardware the tables do not list). The compiled-cost readers:
 
     flops/step  = XLA cost_analysis of the exact compiled executable
     MFU         = flops/step * steps/sec / chip peak FLOPs
@@ -12,8 +13,8 @@ rematerialization recompute), so MFU here is *hardware* utilization of the
 executed program — the standard "model FLOPs" MFU (forward+backward only, no
 remat double-count) would read slightly lower on rematerialized models.
 
-Peak numbers are the published bf16 dense figures per chip generation;
-override with ``KUBEML_PEAK_FLOPS`` (in TFLOP/s) for unlisted hardware.
+Read by ``engine/kavg.py`` (``round_costs``), ``utils/profiler.py``
+(``classify``) and ``chip_smoke.py`` (the device phase).
 """
 
 from __future__ import annotations
@@ -238,7 +239,8 @@ def compiled_costs(jitted_fn, *args, **kwargs) -> dict:
     absent -> None). ``flops`` / ``bytes_accessed`` come from the compiled
     executable's cost analysis (pre-fusion per-op accounting); ``bytes_hbm``
     is the post-fusion traffic parse of the optimized HLO — feed THAT to
-    ``roofline_mfu``. Same lax.scan caveat as ``compiled_flops``."""
+    ``roofline_mfu``. XLA counts a ``lax.scan`` body once whatever its trip
+    count (``KAvgTrainer.round_costs`` lowers one step and scales)."""
     out = {"flops": None, "bytes_accessed": None, "bytes_hbm": None}
     compiled = jitted_fn.lower(*args, **kwargs).compile()
     analysis = compiled.cost_analysis()
@@ -253,12 +255,6 @@ def compiled_costs(jitted_fn, *args, **kwargs) -> dict:
     except Exception:
         out["bytes_hbm"] = None  # serialization quirk: keep flops
     return out
-
-
-def compiled_flops(jitted_fn, *args, **kwargs) -> Optional[float]:
-    """FLOPs of one invocation — the flops view of ``compiled_costs`` (same
-    lax.scan caveat; lowering an already-jitted fn hits the compile cache)."""
-    return compiled_costs(jitted_fn, *args, **kwargs)["flops"]
 
 
 def mfu_from(flops_per_step: Optional[float], steps_per_sec: float,

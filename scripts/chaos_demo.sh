@@ -5,13 +5,13 @@
 # the chaos, then drive a serving burst past a tiny admission limit and show
 # the overload path (429 + Retry-After, bounded queue, zero hung requests).
 # Retry/breaker/chaos/shed counters are read back off /metrics and a summary
-# row is appended to results/chaos_demo.jsonl.
+# row is printed and appended to chaos_demo.jsonl in the output directory.
 #
-#   scripts/chaos_demo.sh [out_dir]      (default: a temp dir; metrics text
-#                                         lands there)
+#   scripts/chaos_demo.sh [out_dir]      (default: a new directory under
+#                                         $TMPDIR; the summary and the
+#                                         metrics text land there)
 set -euo pipefail
 cd "$(dirname "$0")/.."
-mkdir -p results
 
 OUT_DIR="${1:-$(mktemp -d)}"
 mkdir -p "$OUT_DIR"
@@ -191,7 +191,7 @@ assert row["metrics"]["chaos_injected_total"] > 0
 assert row["metrics"]["http_retries_total"] > 0
 row["elapsed_s"] = round(time.time() - t_start, 2)
 
-with open("results/chaos_demo.jsonl", "a") as f:
+with open(out_dir / "chaos_demo.jsonl", "a") as f:
     f.write(json.dumps(row) + "\n")
 print(json.dumps(row, indent=2))
 print(f"\nfull /metrics text: {out_dir / 'metrics.txt'}")

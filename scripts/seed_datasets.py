@@ -41,9 +41,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
 def load_digits_real():
-    # the ONE split definition, shared with the digits-real scenario so seeded
-    # clusters and scenario-created datasets always partition identically
-    from kubeml_tpu.benchmarks.scenarios import load_digits_real as _load
+    # the ONE split definition, shared with the tests' digits-real scenario so
+    # seeded clusters and test-created datasets always partition identically
+    from kubeml_tpu.data.digits import load_digits_real as _load
 
     return _load()
 

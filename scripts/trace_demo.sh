@@ -2,13 +2,13 @@
 # End-to-end distributed-tracing demo: boot the single-process cluster with
 # tracing on, run a tiny train task, fetch the merged trace through the
 # `kubeml trace` CLI, verify the new latency histograms on /metrics, and
-# append a summary row to results/trace_demo.jsonl.
+# print a summary row, appended to trace_demo.jsonl in the output directory.
 #
-#   scripts/trace_demo.sh [out_dir]      (default: a temp dir; trace JSON +
+#   scripts/trace_demo.sh [out_dir]      (default: a new directory under
+#                                         $TMPDIR; summary, trace JSON and
 #                                         metrics text land there)
 set -euo pipefail
 cd "$(dirname "$0")/.."
-mkdir -p results
 
 OUT_DIR="${1:-$(mktemp -d)}"
 mkdir -p "$OUT_DIR"
@@ -112,7 +112,7 @@ row = {
     "histogram_bucket_series": hist_series,
     "trace_file": str(trace_path),
 }
-with open("results/trace_demo.jsonl", "a") as f:
+with open(out_dir / "trace_demo.jsonl", "a") as f:
     f.write(json.dumps(row) + "\n")
 print(json.dumps(row, indent=2))
 print(f"\nopen {trace_path} in chrome://tracing or https://ui.perfetto.dev")

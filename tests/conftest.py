@@ -68,6 +68,28 @@ def make_blobs(n, shape=(8, 8, 1), classes=10, seed=0):
     return x, y
 
 
+def wait_job_done(client, job_id, timeout=120):
+    """Poll like the reference experiment harness polls ``task list``
+    (ml/experiments/common/experiment.py:82-182). Done = the history record
+    exists (a job persists one at exit, success or failure) AND the task has
+    left the index: a freshly queued job is in neither yet."""
+    import time
+
+    from kubeml_tpu.api.errors import KubeMLError
+
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        try:
+            client.histories().get(job_id)
+        except KubeMLError:
+            time.sleep(0.2)
+            continue
+        if all(t.job_id != job_id for t in client.tasks().list()):
+            return
+        time.sleep(0.2)
+    raise TimeoutError(f"job {job_id} did not finish")
+
+
 def pytest_collection_modifyitems(config, items):
     """Apply the measured ``slow`` tier (VERDICT r2 weak #1: the suite must
     have a quick tier). ``tests/slow_tests.txt`` lists every test whose call

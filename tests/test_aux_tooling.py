@@ -1,5 +1,5 @@
-"""Auxiliary ops tooling (round 5, VERDICT r4 missing 1-3): the resource
-sampler, the error-report webhook, and the container packaging assets."""
+"""Auxiliary ops tooling (round 5, VERDICT r4 missing 2-3): the error-report
+webhook and the container packaging assets."""
 
 import json
 import time
@@ -8,40 +8,6 @@ from pathlib import Path
 import pytest
 
 REPO = Path(__file__).resolve().parent.parent
-
-
-def test_resource_sampler_writes_timeline(tmp_path):
-    from kubeml_tpu.benchmarks.sampler import ResourceSampler
-
-    out = tmp_path / "usage.jsonl"
-    with ResourceSampler(out, interval=0.2, tag="t1", devices=False):
-        # some busy work so cpu_util has something to see; the 2s window
-        # gives the sampler thread ~10 nominal ticks of margin — under gVisor
-        # CPU contention a 1s window occasionally yielded <3 samples (flake)
-        t0 = time.time()
-        while time.time() - t0 < 2.0:
-            sum(i * i for i in range(10000))
-    rows = [json.loads(l) for l in out.read_text().splitlines()]
-    assert len(rows) >= 3
-    for r in rows:
-        assert r["tag"] == "t1"
-        assert 0.0 <= r["cpu_util"] <= 1.0
-        assert 0.0 <= r["mem_used_frac"] <= 1.0
-        assert r["rss_bytes"] > 0
-
-
-def test_sampler_cli_wraps_command(tmp_path):
-    import subprocess
-    import sys
-
-    out = tmp_path / "u.jsonl"
-    rc = subprocess.call(
-        [sys.executable, "-m", "kubeml_tpu.benchmarks.sampler",
-         "--out", str(out), "--interval", "0.2", "--",
-         sys.executable, "-c", "import time; time.sleep(0.8)"],
-        cwd=str(REPO))
-    assert rc == 0
-    assert len(out.read_text().splitlines()) >= 2
 
 
 def test_error_webhook_fires(tmp_path, monkeypatch):

@@ -75,7 +75,7 @@ def test_precompile_async_matches_live_compile(rng):
     to a fresh compile — the compile-cost-aware elasticity mechanism."""
     import time
 
-    from kubeml_tpu.benchmarks.harness import make_synthetic_model
+    from kubeml_tpu.runtime.model import make_synthetic_model
     from kubeml_tpu.engine.kavg import KAvgTrainer
     from kubeml_tpu.models.lenet import LeNet
 

@@ -15,7 +15,7 @@ from kubeml_tpu.api.types import JobState, TrainOptions, TrainRequest, TrainTask
 from kubeml_tpu.scheduler.policy import ThroughputBasedPolicy, next_power_down, next_power_up
 from kubeml_tpu.scheduler.queue import TaskQueue
 
-from conftest import make_blobs
+from conftest import make_blobs, wait_job_done as _wait_done
 
 # A complete user function source: tiny MLP KubeModel (fast to compile).
 FN_SOURCE = '''
@@ -224,17 +224,6 @@ def cluster(tmp_config):
 
     with LocalCluster(config=tmp_config) as c:
         yield c
-
-
-def _wait_done(client, job_id, timeout=120):
-    """Poll the task list like the reference experiment harness
-    (ml/experiments/common/experiment.py:82-182)."""
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        if all(t.job_id != job_id for t in client.tasks().list()):
-            return
-        time.sleep(0.2)
-    raise TimeoutError(f"job {job_id} did not finish")
 
 
 class TestClusterEndToEnd:

@@ -506,13 +506,11 @@ def test_async_publish_drains_latest(tmp_config):
 
 
 def test_toy_job_converges_through_delta_int8():
-    """The full feedback loop of the dataplane bench: K-AVG training that
+    """The full feedback loop of the weight channel: K-AVG training that
     continues every round from the DECODED tree must reach (numerically)
     the same loss as training that never left the device — the error
     feedback keeps the quantized chain convergent."""
     import jax
-
-    from kubeml_tpu.benchmarks import dataplane_bench
 
     # tiny toy: 2 workers x k=2 x batch=8 on the kavg test model
     import optax
@@ -566,8 +564,6 @@ def test_toy_job_converges_through_delta_int8():
     baseline = run(None)
     quantized = run("delta-int8")
     assert quantized == pytest.approx(baseline, abs=0.05)
-    assert dataplane_bench.project_e2e(1.0, 4.0, "delta-int8")[
-        "end_to_end"] > dataplane_bench.R05_E2E_SPS
 
 
 def _wire_header(payload):

@@ -16,7 +16,6 @@ Three layers of coverage:
 """
 
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -554,7 +553,7 @@ def test_decisions_route_end_to_end(cluster):
     import sys
 
     sys.path.insert(0, str(__import__("pathlib").Path(__file__).parent))
-    from conftest import make_blobs
+    from conftest import make_blobs, wait_job_done
 
     from kubeml_tpu.cli import main as cli_main
     from kubeml_tpu.controller.client import KubemlClient
@@ -569,13 +568,7 @@ def test_decisions_route_end_to_end(cluster):
         options=TrainOptions(default_parallelism=2, k=2,
                              static_parallelism=False, validate_every=0))
     job_id = client.networks().train(req)
-    deadline = time.time() + 120
-    while time.time() < deadline:
-        if all(t.job_id != job_id for t in client.tasks().list()):
-            break
-        time.sleep(0.2)
-    else:
-        raise TimeoutError(f"job {job_id} did not finish")
+    wait_job_done(client, job_id)
 
     data = client.tasks().decisions(job_id)
     decisions = data["decisions"]

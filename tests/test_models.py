@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from kubeml_tpu.engine.kavg import KAvgTrainer
-from kubeml_tpu.benchmarks.harness import make_synthetic_model
+from kubeml_tpu.runtime.model import make_synthetic_model
 
 
 def _forward(module, x, train=False, seed=0):

@@ -8,7 +8,6 @@ histograms. (Tracer unit tests live in test_tracing_failures.py.)
 """
 
 import json
-import time
 
 import pytest
 
@@ -16,7 +15,7 @@ from kubeml_tpu.api.types import TrainOptions, TrainRequest
 from kubeml_tpu.ps.traces import TraceStore
 from kubeml_tpu.utils import tracing
 
-from conftest import make_blobs
+from conftest import make_blobs, wait_job_done
 from test_controlplane import FN_SOURCE
 
 
@@ -103,12 +102,8 @@ def _train_traced(cluster):
     # the CLI's root span: everything downstream becomes its child
     with tracing.get_tracer().span("cli.train", service="cli"):
         job_id = client.networks().train(req)
-    deadline = time.time() + 180
-    while time.time() < deadline:
-        if all(t.job_id != job_id for t in client.tasks().list()):
-            return client, job_id
-        time.sleep(0.2)
-    raise TimeoutError(f"job {job_id} did not finish")
+    wait_job_done(client, job_id, timeout=180)
+    return client, job_id
 
 
 def test_train_request_yields_one_stitched_trace(traced_cluster):

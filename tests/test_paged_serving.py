@@ -634,32 +634,3 @@ class Model(KubeModel):
     dec2 = ps2._decoders["pagedjob"][0]
     assert isinstance(dec2, BatchingDecoder)
     assert not isinstance(dec2, PagedBatchingDecoder)
-
-
-def test_serving_bench_row_gates_fraction():
-    """The long-workload serving row's fraction_of_batchN is a gated
-    metric: bench_compare fails a candidate whose fraction regressed."""
-    import json
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    root = Path(__file__).resolve().parent.parent
-    base = {"metric": "serving-long-workload-throughput", "value": 1000.0,
-            "fraction_of_batchN": 0.85}
-    cand = {**base, "value": 990.0, "fraction_of_batchN": 0.53}
-    good = {**base, "value": 1010.0, "fraction_of_batchN": 0.88}
-
-    def run(b, c, tmp=root / "results"):
-        import tempfile
-
-        with tempfile.TemporaryDirectory() as d:
-            pb, pc = Path(d) / "b.json", Path(d) / "c.json"
-            pb.write_text(json.dumps(b))
-            pc.write_text(json.dumps(c))
-            return subprocess.run(
-                [sys.executable, str(root / "scripts" / "bench_compare.py"),
-                 str(pb), str(pc)], capture_output=True, text=True).returncode
-
-    assert run(base, cand) == 1   # 0.85 -> 0.53 regresses the gate
-    assert run(base, good) == 0

@@ -4,7 +4,6 @@ controller's overload decisions, the `kubeml jobs` operator view, journal
 quarantine, and the heavy end-to-end proofs (SIGKILL mid-yield resume, the
 colocation scenario) on the slow tier."""
 
-import json
 import threading
 import time
 
@@ -721,10 +720,10 @@ def test_sigkill_mid_yield_resumes_uncorrupted(tmp_config):
 @pytest.mark.preempt
 def test_colocation_burst_preempts_and_training_resumes(tmp_config,
                                                         monkeypatch):
-    """benchmarks.scenarios.run_colocation under burst-sized thresholds: the
+    """scenario_support.run_colocation under burst-sized thresholds: the
     preemption controller reclaims the training job, serving keeps being
     served, and the resumed run reaches final-loss parity with the
-    uninterrupted baseline (the row scripts/preempt_demo.sh records)."""
+    uninterrupted baseline."""
     monkeypatch.setenv("KUBEML_PREEMPT_MONITOR", "1")
     monkeypatch.setenv("KUBEML_PREEMPT_INTERVAL", "0.2")
     monkeypatch.setenv("KUBEML_PREEMPT_QUEUE_DEPTH", "3")
@@ -735,7 +734,7 @@ def test_colocation_burst_preempts_and_training_resumes(tmp_config,
     monkeypatch.setenv("KUBEML_SERVING_SLOTS", "2")
     monkeypatch.setenv("KUBEML_SERVING_QUEUE_LIMIT", "6")
     from kubeml_tpu.api.config import Config, set_config
-    from kubeml_tpu.benchmarks.scenarios import run_colocation
+    from scenario_support import run_colocation
 
     cfg = Config(
         data_root=tmp_config.data_root,
@@ -746,7 +745,7 @@ def test_colocation_burst_preempts_and_training_resumes(tmp_config,
     )
     assert cfg.preempt_monitor
     set_config(cfg)
-    row = run_colocation(config=cfg, quick=True, epochs=16)
+    row = run_colocation(config=cfg, epochs=16)
     assert row["metrics"]["preemptions"] >= 1
     assert row["metrics"]["preemptions_total_visible"]
     assert row["metrics"]["yield_histogram_visible"]
@@ -754,5 +753,3 @@ def test_colocation_burst_preempts_and_training_resumes(tmp_config,
     assert row["resumed"]["epochs"] == 16
     assert row["resumed"]["loss_parity"], row["resumed"]
     assert row["serving"]["requests_after_reclaim"] > 0
-    # jsonl row shape: what the demo script appends must serialize
-    json.dumps(row)

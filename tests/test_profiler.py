@@ -119,18 +119,6 @@ def test_profile_session_dump_appends_jsonl(tmp_path):
     assert len(rows) == 2 and rows[0]["session"] == "d"
 
 
-def test_gap_attribution_quantifies_staging_share():
-    """32.8k device vs 14.8k end-to-end means ~55% of every end-to-end
-    round is staging."""
-    g = profiler.gap_attribution(32791.3, 14810.5, 8192, 12_582_912,
-                                 flops_per_round=3e12)
-    assert g["staging_share"] == pytest.approx(0.548, abs=0.01)
-    assert g["staging_bandwidth_bps"] > 0
-    assert g["flops_per_round"] == 3e12
-    # degenerate inputs never divide by zero
-    assert "staging_share" not in profiler.gap_attribution(0, 0, 0, 0)
-
-
 def test_classify_roofline_terms():
     assert profiler.classify(0, 0) == "host"
     assert profiler.classify(1e9, 0) == "transfer-bound"

@@ -21,7 +21,7 @@ from kubeml_tpu.utils import resilience
 from kubeml_tpu.utils import traced_http
 from kubeml_tpu.utils.httpd import Router, Service
 
-from conftest import make_blobs
+from conftest import make_blobs, wait_job_done
 
 
 @pytest.fixture(autouse=True)
@@ -673,13 +673,7 @@ def test_train_completes_under_injected_network_faults(chaos_cluster):
         options=TrainOptions(default_parallelism=2, k=2,
                              static_parallelism=True))
     job_id = client.networks().train(req)
-    deadline = time.time() + 240
-    while time.time() < deadline:
-        if all(t.job_id != job_id for t in client.tasks().list()):
-            break
-        time.sleep(0.2)
-    else:
-        raise TimeoutError(f"job {job_id} did not finish under chaos")
+    wait_job_done(client, job_id, timeout=240)
     hist = client.histories().get(job_id)
     assert len(hist.train_loss) == 2
     assert all(np.isfinite(l) for l in hist.train_loss)

@@ -1,13 +1,14 @@
-"""Benchmark scenario suite tests — the BASELINE.md configs run (quick-sized)
-through the real scheduler -> PS -> TrainJob path (port of the reference's
-experiment harness, ml/experiments/common/experiment.py)."""
+"""The BASELINE.md job shapes, small, through the real scheduler -> PS ->
+TrainJob path (port of the reference's experiment harness,
+ml/experiments/common/experiment.py; the driver is tests/scenario_support.py)."""
 
 import numpy as np
 import pytest
 
-from kubeml_tpu.benchmarks.scenarios import (
+from scenario_support import (
     ExperimentDriver,
-    run_all,
+    Scenario,
+    _req,
     scenarios,
     synth_images,
     synth_tokens,
@@ -41,14 +42,14 @@ def test_digits_real_is_real_data_and_converges(tmp_config):
     UCI corpus, not a synthetic band task) and learns them through the live
     control plane — the in-environment real-data convergence check."""
     sc = {s.name: s for s in scenarios()}["digits-real"]
-    xtr, ytr, xte, yte = sc.make_data(quick=True)
+    xtr, ytr, xte, yte = sc.make_data()
     assert len(xtr) + len(xte) == 1797  # the real corpus, nothing synthetic
     assert xtr.shape[1:] == (8, 8, 1) and xtr.max() <= 16
     assert set(np.unique(ytr)) == set(range(10))
     with ExperimentDriver(tmp_config) as driver:
-        result = driver.run(sc, quick=True)
+        result = driver.run(sc)
     assert result.status == "ok", result.error
-    # real learning: 5 quick epochs beat the 10% chance floor by a wide margin
+    # real learning: 5 epochs beat the 10% chance floor by a wide margin
     assert result.accuracy and result.accuracy[-1] > 60.0, result.accuracy
 
 
@@ -56,16 +57,16 @@ def test_digits_real_is_real_data_and_converges(tmp_config):
 def test_single_scenario_quick(tmp_config, name):
     sc = {s.name: s for s in scenarios()}[name]
     with ExperimentDriver(tmp_config) as driver:
-        result = driver.run(sc, quick=True)
+        result = driver.run(sc)
     assert result.status == "ok", result.error
     assert result.epochs >= 1
     assert all(np.isfinite(l) for l in result.train_loss)
-    assert result.samples_per_sec > 0
+    assert len(result.epoch_seconds) == result.epochs
 
 
 def test_elastic_multijob_quick(tmp_config):
     with ExperimentDriver(tmp_config, max_parallelism=4) as driver:
-        result = driver.run_elastic_multijob(quick=True)
+        result = driver.run_elastic_multijob()
     assert result.status == "ok", result.error
     # two jobs, >= 2 epochs each
     assert result.epochs >= 4
@@ -75,9 +76,7 @@ def test_elastic_multijob_quick(tmp_config):
 
 def test_failed_job_reported_as_failed(tmp_config):
     """A job that errors must surface status='failed' with the recorded error —
-    a broken benchmark run must never look green."""
-    from kubeml_tpu.benchmarks.scenarios import Scenario, _req, synth_images
-
+    a broken run must never look green."""
     # imports cleanly (passes create-time validation) but fails at job start
     broken_src = (
         "from kubeml_tpu.runtime.model import KubeModel\n"
@@ -93,24 +92,11 @@ def test_failed_job_reported_as_failed(tmp_config):
     )
     broken = Scenario(
         "broken", broken_src,
-        lambda quick: synth_images(64, (8, 8, 1), 4, 0) + synth_images(32, (8, 8, 1), 4, 1),
-        request=_req("broken", "broken-ds"),
-        quick_request=_req("broken", "broken-ds", epochs=1,
-                           options=dict(default_parallelism=1, static_parallelism=True)),
+        lambda: synth_images(64, (8, 8, 1), 4, 0) + synth_images(32, (8, 8, 1), 4, 1),
+        _req("broken", "broken-ds", epochs=1,
+             options=dict(default_parallelism=1, static_parallelism=True)),
     )
     with ExperimentDriver(tmp_config) as driver:
-        result = driver.run(broken, quick=True)
+        result = driver.run(broken)
     assert result.status in ("failed", "error"), result
     assert result.error
-
-
-def test_run_all_filter_and_json(tmp_config, capsys):
-    from kubeml_tpu.benchmarks.scenarios import main
-
-    rc = main(["--quick", "--only", "lenet-mnist"])
-    assert rc == 0
-    import json
-
-    out = json.loads(capsys.readouterr().out)
-    assert [r["name"] for r in out] == ["lenet-mnist"]
-    assert out[0]["status"] == "ok"

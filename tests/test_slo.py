@@ -557,11 +557,11 @@ def test_slo_overload_end_to_end(tmp_path, monkeypatch):
                  ("KUBEML_TRACE", str(tmp_path / "traces"))):
         monkeypatch.setenv(k, v)
     from kubeml_tpu.api.config import Config
-    from kubeml_tpu.benchmarks.scenarios import run_slo_overload
+    from scenario_support import run_slo_overload
     from kubeml_tpu.utils import tracing
 
     tracing.get_tracer()  # picks up KUBEML_TRACE before the cluster boots
-    row = run_slo_overload(config=Config(), quick=True)
+    row = run_slo_overload(config=Config())
     assert row["status"] == "ok"
     kinds = {(t["from"], t["to"]) for t in row["transitions"]}
     assert {("inactive", "pending"), ("pending", "firing"),
