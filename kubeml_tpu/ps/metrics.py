@@ -169,7 +169,7 @@ SERVING_COUNTERS = {
         "requests_deadline_expired",
         "Queued generate requests failed on an expired deadline"),
     "kubeml_serving_admission_waves_total": (
-        "admission_waves", "Batched prefill+admit programs dispatched"),
+        "admission_waves", "Prefill+admit programs dispatched"),
     "kubeml_serving_chunks_total": ("chunks",
                                     "Decode chunk programs dispatched"),
     # fetcher pool (short-request workloads can be fetch-pipeline-bound —

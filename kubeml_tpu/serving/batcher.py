@@ -2083,6 +2083,10 @@ class PagedBatchingDecoder(BatchingDecoder):
     * **Page-budget overload truth** — a request that could never fit the
       arena 400s at submit; one that merely can't fit NOW queues at the
       head of the line until pages free (or its deadline expires).
+    * **One real row per admission program** — the suffix-prefill program
+      is handed the one request it admits (``_run_prefill``); a wave of n
+      rows is n programs, and the row count is no axis of the program's
+      key. The slot engine pads a wave to ``slots`` rows instead.
 
     Quantized weights (int8 / native int8 matmul) compose unchanged — the
     arena is cache state, not weights. A mesh does not: sharded serving
@@ -2092,10 +2096,10 @@ class PagedBatchingDecoder(BatchingDecoder):
     (``models.generation.has_recurrent_state``) keeps, per layer, one
     fixed-size state per program row (``ssm_state`` ``[slots, H, N, P]``
     float32 and ``conv_tail``) in the same ``cache`` tree as the arena:
-    donated, rebuilt and freed with it. An admission names its rows' places
-    (``rows``) and the model starts them from zeros (a reused slot), or from
+    donated, rebuilt and freed with it. An admission names its row's place
+    (``rows``) and the model starts it from zeros (a reused slot), or from
     the row's own state where a chunked prefill continues, and writes the
-    state at each prompt's true length; the decode step advances the state
+    state at the prompt's true length; the decode step advances the state
     of live rows only, in place (ops/ssm.py ``ssm_update``). Prefix sharing
     is off for such a model (shared pages have no state to go with them),
     and speculation and KMS1 snapshot / restore are refused by name
@@ -2419,18 +2423,19 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     def _prefill_admit_impl(self, variables, slab, ptbl, suffix, base, slens,
                             rowids, max_news, temps, topks, eoss, keys):
-        """ONE program per (suffix-length bucket): prefill k UNSHARED
-        suffixes together straight into the paged arena (a prefix hit's
-        cached pages are already there — only the suffix runs, the FLOP
-        saving behind kubeml_serving_prefix_tokens_saved_total), scatter
-        each row's cursors/knobs into its program row, and sample first
-        tokens. Row-count padding repeats the last row (identical pages,
-        identical bytes — idempotent scatter), exactly like the dense
-        engine's admit."""
+        """ONE program per (suffix-length bucket, table width): prefill
+        the UNSHARED suffix of each row it is handed straight into the
+        paged arena (a prefix hit's cached pages are already there — only
+        the suffix runs, the FLOP saving behind
+        kubeml_serving_prefix_tokens_saved_total), scatter the row's
+        cursors/knobs into its program row, and sample its first token.
+        The engine hands it exactly ONE row, the request it admits
+        (``_run_prefill``): the row count is a constant of the program,
+        not an axis of its key, and no row is a copy of another."""
         variables = self._dense_vars(variables)
-        # a recurrent model scatters each row's state into its program row
+        # a recurrent model scatters the row's state into its program row
         # (zeros first where base is 0: a reused slot; the row's own state
-        # where a chunked prefill goes on). Repeated rows write equal bytes.
+        # where a chunked prefill goes on)
         kw = {"rows": rowids} if self._recurrent else {}
         logits, vs = self.module.apply(
             {**variables, "cache": slab.cache}, suffix, decode=True,
@@ -2704,19 +2709,6 @@ class PagedBatchingDecoder(BatchingDecoder):
             admits.append((slot, row))
         return admits
 
-    def _group_admits(self, admits: List[tuple]) -> List[List[tuple]]:
-        """Group by UNPREFILLED-SUFFIX length bucket (the prefill
-        program's shape) — a prefix hit's bucket shrinks with its suffix,
-        and a chunked prefill's final chunk buckets by what its earlier
-        chunks left (``prefill_pos == prefix_tokens`` until a chunk moves
-        it, so monolithic admission is bit-identical to before)."""
-        by_bucket: Dict[int, List[tuple]] = {}
-        for slot, row in admits:
-            sfx = max(len(row.prompt) - row.lease.prefill_pos, 1)
-            b = _pow2_bucket(sfx, self.bucket_min, self.max_len)
-            by_bucket.setdefault(b, []).append((slot, row))
-        return list(by_bucket.values())
-
     def _stalled_rows(self) -> List[_Row]:
         """Paged flavor: undispatched work reads from the per-row
         ``dispatched`` accounting (a row `_retire_dispatched` already
@@ -2730,99 +2722,107 @@ class PagedBatchingDecoder(BatchingDecoder):
                 and not row.prefilling
                 and row.max_new - 1 - row.dispatched > 0]
 
-    def _dispatch_admits(self, group: List[tuple]) -> tuple:
-        n = len(group)
-        k = self.slots
-        bucket = _pow2_bucket(
-            max(max(len(r.prompt) - r.lease.prefill_pos for _, r in group),
-                1), self.bucket_min, self.max_len)
-        # HOL snapshot before the new rows take program rows (base class
+    def _run_prefill(self, slot: int, row: _Row, take: int, kind: str
+                     ) -> tuple:
+        """Dispatch the suffix-prefill program for ONE row: ``take`` prompt
+        tokens from the row's prefill cursor, through the row's own pages.
+        Every argument has one row — ``(1, bucket)`` tokens under a ``(1,
+        table width)`` page table — whoever calls: an admission, an
+        intermediate chunk, with or without a draft arena. So the key
+        ``("prefill", (bucket, wa))`` names ONE compiled program whatever
+        the waves that came before, and the program computes nothing but
+        the request it was handed: a wave of n rows is n of these, one
+        after another under the run-ahead gate. (Until PR 31 the row count
+        was padded to ``slots`` with copies of the last row: 82-98% of the
+        positions a cell prefilled.) ``kind`` is the record's: an ``admit``
+        scatters the row's own sampling knobs, a ``pchunk`` a dead
+        placeholder. Returns the record's tail ``(packed, kv_bytes, cold,
+        stalled)``."""
+        pre = row.lease.prefill_pos
+        pt = self.page_tokens
+        bucket = _pow2_bucket(max(take, 1), self.bucket_min, self.max_len)
+        # HOL snapshot before the row takes its program row (base class
         # comment applies: these are the rows this prefill delays)
         stalled = self._stalled_rows()
-        padded_group = group + [group[-1]] * (k - n)
-        suffix = np.zeros((k, bucket), np.int32)
-        base = np.zeros((k,), np.int32)
-        slens = np.ones((k,), np.int32)
-        rowids = np.zeros((k,), np.int32)
-        max_news = np.zeros((k,), np.int32)
-        temps = np.zeros((k,), np.float32)
-        topks = np.zeros((k,), np.int32)
-        eoss = np.zeros((k,), np.int32)
-        keys = np.zeros((k, 2), np.uint32)
-        # prefill touches only positions < prompt_len: the page table ships
-        # clamped to the live width (the shared pow2-with-floor bucket),
-        # not the full worst-case reservation
-        pt = self.page_tokens
-        wa = _bucket_width(
-            max(-(-len(r.prompt) // pt) for _, r in group), self.table_pages)
-        ptbl = np.zeros((k, wa), np.int32)
-        for i, (slot, row) in enumerate(padded_group):
-            pre = row.lease.prefill_pos
-            sfx = row.prompt[pre:]
-            suffix[i, :len(sfx)] = sfx
-            base[i] = pre
-            slens[i] = len(sfx)
-            rowids[i] = slot
-            pgs = row.lease.pages[:wa]
-            ptbl[i, :len(pgs)] = pgs
-            max_news[i] = row.max_new
-            temps[i] = row.temp
-            topks[i] = row.topk
-            eoss[i] = row.eos
-            keys[i] = row.key
-        args = (jnp.asarray(ptbl), jnp.asarray(suffix), jnp.asarray(base),
-                jnp.asarray(slens), jnp.asarray(rowids),
-                jnp.asarray(max_news), jnp.asarray(temps),
-                jnp.asarray(topks), jnp.asarray(eoss), jnp.asarray(keys))
+        # prefill touches only positions < pre + take: the page table ships
+        # clamped to that width (the shared pow2-with-floor bucket), not
+        # the full worst-case reservation
+        depth = -(-(pre + take) // pt)
+        wa = _bucket_width(depth, self.table_pages)
+        suffix = np.zeros((bucket,), np.int32)
+        suffix[:take] = row.prompt[pre:pre + take]
+        ptbl = np.zeros((wa,), np.int32)
+        pgs = row.lease.pages[:wa]
+        ptbl[:len(pgs)] = pgs
+        if kind == "admit":
+            max_new, temp, topk, eos, key = (row.max_new, row.temp, row.topk,
+                                             row.eos, row.key)
+        else:   # max_new 1 => dead scatter, no emission
+            max_new, temp, topk, eos, key = 1, 0.0, 0, -1, (0, 0)
+        # ptbl, suffix, base, slens, rowids, max_news, temps, topks, eoss,
+        # keys: each gets its one row here
+        i32 = np.int32
+        args = tuple(jnp.asarray(np.asarray(value, dtype)[None])
+                     for value, dtype in (
+                         (ptbl, i32), (suffix, i32), (pre, i32), (take, i32),
+                         (slot, i32), (max_new, i32), (temp, np.float32),
+                         (topk, i32), (eos, i32), (key, np.uint32)))
+        span = dict(kind=kind, width=wa,
+                    group=[(slot, row)] if kind == "admit" else None,
+                    state_rows=1 if self._recurrent else 0)
         # the prefill program is keyed (suffix bucket, table width) — both
-        # are compile shapes on the paged engine
+        # are compile shapes on the paged engine. A draft backend's program
+        # prefills the drafter's arena through the same one-row arguments
         if self.spec == "draft":
             (self._slab, self._draft_cache, packed), cold = self._run_program(
                 "prefill", (bucket, wa), self._prefill_admit,
                 self._variables, self._draft_variables, self._draft_cache,
-                self._slab, *args, kind="admit", width=wa, group=group)
+                self._slab, *args, **span)
         else:
             (self._slab, packed), cold = self._run_program(
                 "prefill", (bucket, wa), self._prefill_admit,
-                self._variables, self._slab, *args,
-                kind="admit", width=wa, group=group,
-                state_rows=n if self._recurrent else 0)
-        now = time.monotonic()
-        real_tokens = 0
-        for slot, row in group:
-            self._slot_rows[slot] = row
-            self._table[slot, :] = 0
-            self._table[slot, :len(row.lease.pages)] = row.lease.pages
-            row.dispatched = 0
-            row.pos_cap = len(row.prompt)  # device cursor lands at plen
-            if not row.slot_at:
-                # a chunked row took its slot (and paid queue_wait) at
-                # _begin_chunked_prefill; only monolithic admits land here
-                row.slot_at = now
-                self.stats.phase("queue_wait", now - row.entry.submitted_at)
-            real_tokens += len(row.prompt) - row.lease.prefill_pos
-            # cache the FULL prompt blocks for future sharers. At dispatch
-            # time, not admission: device programs run in dispatch order,
-            # so a later match is guaranteed to read pages already written
-            self._pool.register_prefix(row.prompt, row.lease)
-        self.stats.admitted_wave()
-        # prefill accounting: only the unshared suffixes are computed —
-        # prefix-cached tokens are the measured FLOP saving, padding is the
-        # bucket + repeated-row compute
-        self.stats.admit_tokens(real_tokens, k * bucket - real_tokens)
-        # KV model for the prefill forward(s): gather reads every program
-        # row's clamped table, the kernel stops at each row's prompt depth;
-        # a draft-backend admission prefills the drafter's arena too
-        if self.paged_attn == "pallas":
-            span = sum(min(-(-len(r.prompt) // pt), wa) * pt
-                       for _, r in padded_group)
-        else:
-            span = k * wa * pt
-        kv_bytes = span * self._kv_token_bytes
+                self._variables, self._slab, *args, **span)
+        # prefill accounting: only the unshared suffix is computed —
+        # prefix-cached tokens are the measured FLOP saving, padding is
+        # what the bucket adds to the row's own tokens
+        self.stats.admit_tokens(take, bucket - take)
+        # KV model for the prefill forward(s): gather reads the row's
+        # clamped table, the kernel stops at the depth the row has reached;
+        # a draft backend prefills the drafter's arena too
+        span_tokens = (min(depth, wa) if self.paged_attn == "pallas"
+                       else wa) * pt
+        kv_bytes = span_tokens * self._kv_token_bytes
         if self.spec == "draft":
-            kv_bytes += span * self._kv_draft_token_bytes
+            kv_bytes += span_tokens * self._kv_draft_token_bytes
         self._admits_inflight += 1
-        return ("admit", group, packed, kv_bytes, cold, stalled)
+        return (packed, kv_bytes, cold, stalled)
+
+    def _dispatch_admit(self, slot: int, row: _Row) -> tuple:
+        """Admit ONE row: prefill what is left of its prompt (all of the
+        unshared suffix, or the last chunk of a chunked prefill), sample
+        its first token, and give it its program row. One admission
+        program per admitted request (``_run_prefill``); the loop takes no
+        more rows from the queue than it has room to dispatch, so nothing
+        is ever un-admitted. Returns the in-flight record."""
+        tail = self._run_prefill(
+            slot, row, len(row.prompt) - row.lease.prefill_pos, "admit")
+        self._slot_rows[slot] = row
+        self._table[slot, :] = 0
+        self._table[slot, :len(row.lease.pages)] = row.lease.pages
+        row.dispatched = 0
+        row.pos_cap = len(row.prompt)  # device cursor lands at plen
+        if not row.slot_at:
+            # a chunked row took its slot (and paid queue_wait) at
+            # _begin_chunked_prefill; only monolithic admits land here
+            row.slot_at = time.monotonic()
+            self.stats.phase("queue_wait",
+                             row.slot_at - row.entry.submitted_at)
+        # cache the FULL prompt blocks for future sharers. At dispatch
+        # time, not admission: device programs run in dispatch order,
+        # so a later match is guaranteed to read pages already written
+        self._pool.register_prefix(row.prompt, row.lease)
+        self.stats.admitted_wave()
+        return ("admit", [(slot, row)]) + tail
 
     # --- chunked prefill (Sarathi-style, interleaved with decode) ---
 
@@ -2845,134 +2845,65 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     def _advance_prefills(self, pool, process_seq: int) -> bool:
         """One engine-loop turn of the chunked-prefill schedule: every
-        pending row advances AT MOST one chunk per iteration — rows whose
-        remaining suffix fits a chunk run REAL admission (first token,
+        pending row, oldest first, advances AT MOST one chunk per iteration
+        while the run-ahead has room for its program — a row whose
+        remaining suffix fits a chunk runs REAL admission (first token,
         sampling state, prefix registration: byte-identical to a
-        monolithic admit at that cursor), the rest advance one
-        intermediate chunk in a single batched dispatch. Decode chunks
-        dispatch in the same iteration, which is the whole point: a long
-        prompt no longer monopolizes the device for its full length.
-        Returns whether anything was dispatched."""
+        monolithic admit at that cursor), the rest advance one intermediate
+        chunk, one program a row. Decode chunks dispatch in the same
+        iteration, which is the whole point: a long prompt no longer
+        monopolizes the device for its full length. Returns whether
+        anything was dispatched."""
         if not self._prefill_pending:
             return False
-        cap = self.prefill_chunk
-        finals: List[tuple] = []
-        chunkable: List[tuple] = []
         keep: List[tuple] = []
+        dispatched = False
         for slot, row in self._prefill_pending:
             if row.done or row.canceled:
                 continue  # _evict_canceled owned the slot + lease
-            if len(row.prompt) - row.lease.prefill_pos <= cap:
-                finals.append((slot, row))
-            else:
-                chunkable.append((slot, row))
-        dispatched = False
-        for group in self._group_admits(finals):
+            left = len(row.prompt) - row.lease.prefill_pos
             if self._next_seq - process_seq >= self._depth:
-                keep.extend(group)
-                continue
-            rec = self._dispatch_admits(group)
-            # clear ``prefilling`` only AFTER the dispatch: its internal
-            # _stalled_rows snapshot must not count a final-chunk row as
-            # its own head-of-line victim
-            n_tok = 0
-            for _, row in group:
+                keep.append((slot, row))
+            elif left <= self.prefill_chunk:
+                rec = self._dispatch_admit(slot, row)
+                # clear ``prefilling`` only AFTER the dispatch: its
+                # _stalled_rows snapshot must not count a final-chunk row
+                # as its own head-of-line victim
                 row.prefilling = False
                 row.prefill_chunks += 1
-                n_tok += len(row.prompt) - row.lease.prefill_pos
-            self.stats.prefill_chunk(len(group), n_tok)
-            self._submit_program(pool, rec)
-            dispatched = True
-        if chunkable:
-            if self._next_seq - process_seq < self._depth:
-                self._submit_program(
-                    pool, self._dispatch_prefill_chunk(chunkable))
+                self.stats.prefill_chunk(1, left)
+                self._submit_program(pool, rec)
                 dispatched = True
-            keep.extend(chunkable)
+            else:
+                self._submit_program(
+                    pool, self._dispatch_prefill_chunk(slot, row))
+                keep.append((slot, row))
+                dispatched = True
         self._prefill_pending = keep
         return dispatched
 
-    def _dispatch_prefill_chunk(self, batch: List[tuple]) -> tuple:
-        """One page-aligned intermediate chunk for every mid-prefill row,
-        batched into a single suffix-prefill dispatch (SAME program as
-        admission — keyed ("prefill", (bucket, wa)), so chunking adds no
-        new XLA programs beyond the widths it exercises). ``max_new=1``
-        turns the program's admission scatter into a frozen dead row
-        (live0 False, remaining 0): the chunk writes its cap tokens of
-        K/V into the row's own pages and parks; the FINAL chunk re-runs
-        real admission with the row's own key/temp/topk/eos, overwriting
-        every placeholder — which is why the PRNG chain and sampled
-        tokens are bit-identical to monolithic prefill. Chunks are whole
-        pages (``_chunk_cap`` floors at page_tokens), so each arena page
-        — and each int8 page's scatter-max scale — derives from exactly
-        one dispatch's tokens, monolithic or chunked."""
+    def _dispatch_prefill_chunk(self, slot: int, row: _Row) -> tuple:
+        """One page-aligned intermediate chunk of one mid-prefill row: the
+        SAME one-row program as admission (``_run_prefill``, keyed
+        ("prefill", (bucket, wa))), so chunking adds no XLA program beyond
+        the widths it exercises. ``max_new=1`` turns the program's
+        admission scatter into a frozen dead row (live0 False, remaining
+        0): the chunk writes its cap tokens of K/V into the row's own
+        pages and parks; the FINAL chunk re-runs real admission with the
+        row's own key/temp/topk/eos, overwriting every placeholder — which
+        is why the PRNG chain and sampled tokens are bit-identical to
+        monolithic prefill. Chunks are whole pages (``_chunk_cap`` floors
+        at page_tokens), so each arena page — and each int8 page's
+        scatter-max scale — derives from exactly one dispatch's tokens,
+        monolithic or chunked. No admitted_wave / register_prefix: those
+        belong to the final chunk's real admission."""
         cap = self.prefill_chunk
-        n = len(batch)
-        k = self.slots
-        bucket = _pow2_bucket(cap, self.bucket_min, self.max_len)
-        stalled = self._stalled_rows()
-        padded = batch + [batch[-1]] * (k - n)
-        suffix = np.zeros((k, bucket), np.int32)
-        base = np.zeros((k,), np.int32)
-        slens = np.ones((k,), np.int32)
-        rowids = np.zeros((k,), np.int32)
-        max_news = np.ones((k,), np.int32)  # 1 => dead scatter, no emission
-        temps = np.zeros((k,), np.float32)
-        topks = np.zeros((k,), np.int32)
-        eoss = np.full((k,), -1, np.int32)
-        keys = np.zeros((k, 2), np.uint32)
-        pt = self.page_tokens
-        wa = _bucket_width(
-            max(-(-(r.lease.prefill_pos + cap) // pt) for _, r in batch),
-            self.table_pages)
-        ptbl = np.zeros((k, wa), np.int32)
-        for i, (slot, row) in enumerate(padded):
-            pre = row.lease.prefill_pos
-            suffix[i, :cap] = row.prompt[pre:pre + cap]
-            base[i] = pre
-            slens[i] = cap
-            rowids[i] = slot
-            pgs = row.lease.pages[:wa]
-            ptbl[i, :len(pgs)] = pgs
-        args = (jnp.asarray(ptbl), jnp.asarray(suffix), jnp.asarray(base),
-                jnp.asarray(slens), jnp.asarray(rowids),
-                jnp.asarray(max_news), jnp.asarray(temps),
-                jnp.asarray(topks), jnp.asarray(eoss), jnp.asarray(keys))
-        if self.spec == "draft":
-            # the drafter's arena must hold the chunk's K/V too, or the
-            # final chunk's draft prefill would leave a gap
-            (self._slab, self._draft_cache, packed), cold = \
-                self._run_program(
-                    "prefill", (bucket, wa), self._prefill_admit,
-                    self._variables, self._draft_variables,
-                    self._draft_cache, self._slab, *args,
-                    kind="pchunk", width=wa)
-        else:
-            (self._slab, packed), cold = self._run_program(
-                "prefill", (bucket, wa), self._prefill_admit,
-                self._variables, self._slab, *args,
-                kind="pchunk", width=wa,
-                state_rows=n if self._recurrent else 0)
-        for slot, row in batch:
-            row.lease.prefill_pos += cap
-            row.pos_cap = row.lease.prefill_pos
-            row.prefill_chunks += 1
-        real = n * cap
-        self.stats.admit_tokens(real, k * bucket - real)
-        self.stats.prefill_chunk(n, real)
-        # KV model mirrors _dispatch_admits at the chunk's (advanced)
-        # depth; no admitted_wave / register_prefix — those belong to the
-        # final chunk's real admission
-        if self.paged_attn == "pallas":
-            span = sum(min(-(-r.lease.prefill_pos // pt), wa) * pt
-                       for _, r in padded)
-        else:
-            span = k * wa * pt
-        kv_bytes = span * self._kv_token_bytes
-        if self.spec == "draft":
-            kv_bytes += span * self._kv_draft_token_bytes
-        self._admits_inflight += 1
-        return ("pchunk", batch, packed, kv_bytes, cold, stalled)
+        tail = self._run_prefill(slot, row, cap, "pchunk")
+        row.lease.prefill_pos += cap
+        row.pos_cap = row.lease.prefill_pos
+        row.prefill_chunks += 1
+        self.stats.prefill_chunk(1, cap)
+        return ("pchunk", [(slot, row)]) + tail
 
     # --- the decode chunk (pow2 ladder to the earliest completion) ---
 
@@ -3666,7 +3597,6 @@ class PagedBatchingDecoder(BatchingDecoder):
                                         + self.pool_audit_interval)
                     self._audit_pool()
                 dispatched = False
-                live_admits = []
                 for slot, row in admits:
                     if row.canceled:  # canceled between admit and dispatch
                         self._pool.release(row.lease)
@@ -3685,9 +3615,9 @@ class PagedBatchingDecoder(BatchingDecoder):
                         # interleaved with decode instead of one program
                         self._begin_chunked_prefill(slot, row)
                         continue
-                    live_admits.append((slot, row))
-                for group in self._group_admits(live_admits):
-                    self._submit_program(pool, self._dispatch_admits(group))
+                    # one admission program a row: ``room`` bounded the
+                    # rows taken by the dispatches there is room for
+                    self._submit_program(pool, self._dispatch_admit(slot, row))
                     dispatched = True
                 self._evict_canceled()
                 # fair interleave (ISSUE 19): when the pipeline has room

@@ -77,7 +77,7 @@ class DecoderStats:
         self.requests_shed = 0        # shed oldest-first after queueing
         self.requests_deadline_expired = 0  # expired while queued (504)
         self.tokens_emitted = 0
-        self.admission_waves = 0      # batched prefill+admit programs
+        self.admission_waves = 0      # prefill+admit programs dispatched
         self.chunks = 0               # decode chunk programs
         # --- occupancy / goodput (per-device-step truth, chunk loop) ---
         self.device_steps = 0         # decode steps executed (sum of T)
@@ -281,7 +281,8 @@ class DecoderStats:
     def admit_tokens(self, real: int, padding: int) -> None:
         """Prefill token accounting for one admission program: ``real``
         prompt tokens vs ``padding`` computed-but-useless tokens (prompt
-        bucket padding + the repeated rows padding the program to S)."""
+        bucket padding; in the slot engine also the repeated rows padding
+        the program to S — a paged admission program carries one row)."""
         with self._lock:
             self.prefill_tokens += int(real)
             self.prefill_pad_tokens += int(padding)

@@ -254,7 +254,8 @@ def paged(module):
 
 def admit(m, tree, cache, rows, seqs, bucket, base=None):
     """One admission program as the engine calls it: ``seqs`` padded to
-    ``bucket``, row i of the batch living in slab row ``rows[i]``."""
+    ``bucket``, row i of the batch living in slab row ``rows[i]``. (The
+    engine hands it one row a program; the model takes any number.)"""
     n = len(seqs)
     ids = np.zeros((n, bucket), np.int32)
     for i, s in enumerate(seqs):
@@ -375,7 +376,7 @@ def served_gap(cfg, weights, prompt, toks):
 
 def test_engine_serves_the_reference_tokens(model):
     """More requests than rows, lengths all different: every served token
-    is the reference's first choice (to 1e-4 of a logit), through padded
+    is the reference's first choice (to 1e-4 of a logit), through one-row
     admits, slot reuse and decode steps beside rows that ended."""
     cfg, weights, module, tree = model
     ps = prompts(7, 3, 30, seed=9)
