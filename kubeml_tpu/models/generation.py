@@ -116,7 +116,10 @@ def supports_paged_decode(module) -> bool:
     """Whether ``module`` can serve through the paged KV-cache engine:
     it must expose the ``pages``/``seq_lens`` decode kwargs plus the
     clonable ``page_tokens``/``kv_pages`` arena fields, and not interleave
-    MoE blocks (their expert attention has no paged path)."""
+    ``moe_every`` blocks (parallel/moe.py's training-side block: its
+    attention has no paged path). A block's own kinds all serve: latent
+    attention (``mla``) and routed experts behind dense layers
+    (``mlp="experts"``) among them."""
     import inspect
 
     if getattr(module, "moe_every", 0):
@@ -128,6 +131,24 @@ def supports_paged_decode(module) -> bool:
     except (TypeError, ValueError):
         return False
     return "pages" in params and "seq_lens" in params and "positions" in params
+
+
+def has_latent_cache(module) -> bool:
+    """Whether ``module``'s paged arena holds latents (``mla`` set:
+    multi-head latent attention, one vector a token and layer, no K/V
+    heads): the serving layer then refuses what is laid out by K/V heads
+    (int8 page scales, KMS1 frames) and the slot engine's dense cache."""
+    return getattr(module, "mla", None) is not None
+
+
+def expert_layers(module) -> int:
+    """Layers of ``module`` whose feed-forward is routed experts
+    (``mlp="experts"``: all but the ``dense_layers`` leading ones); 0 for
+    every other model, the training-side ``moe_every`` interleaving among
+    them (it has no paged path at all)."""
+    if getattr(module, "mlp", None) != "experts":
+        return 0
+    return max(0, int(module.depth) - int(getattr(module, "dense_layers", 0)))
 
 
 def has_recurrent_state(module) -> bool:

@@ -13,11 +13,15 @@ matmuls run in ``dtype``, the norms and attention softmax stay f32, parameters
 are initialised and trained f32 (a server may hold them narrower:
 ``Config.serving_param_dtype``), and logits are returned f32 for the loss.
 
-One block, configured: LayerNorm or RMSNorm; a GELU MLP at a ratio or SwiGLU at
-a width; multi-head or grouped-query attention at a head size of its own;
-learned or rotary positions; optionally a Mamba-2 mixer in parallel with
-attention (models/mamba2.py) and a muP checkpoint's multipliers. GPT-2 is the
-defaults; Falcon-H1 is rmsnorm + swiglu + GQA + rope + ssm + mup.
+One block, configured: LayerNorm or RMSNorm; a GELU MLP at a ratio, SwiGLU at
+a width, or routed experts with a shared one (models/experts.py); multi-head
+or grouped-query attention at a head size of its own, or multi-head latent
+attention over a latent cache (models/mla.py); learned or rotary positions;
+optionally a Mamba-2 mixer in parallel with attention (models/mamba2.py) and
+a muP checkpoint's multipliers. The stack may be a pattern: ``dense_layers``
+leading SwiGLU layers before the expert layers. GPT-2 is the defaults;
+Falcon-H1 is rmsnorm + swiglu + GQA + rope + ssm + mup; GLM-4.7-Flash is
+rmsnorm + rope + mla + experts behind one dense layer.
 """
 
 from __future__ import annotations
@@ -35,8 +39,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
 from ..parallel.ring import ring_attention
+from .experts import ExpertMLP, ExpertsConfig
 from .layers import QuantizableDense
 from .mamba2 import Mamba2Mixer, SSMConfig
+from .mla import MLAConfig, MLAttention
 
 PAD_ID = 0
 
@@ -454,27 +460,31 @@ class GPTBlock(nn.Module):
     ssm: Optional[SSMConfig] = None
     mup: Optional[MuP] = None
     state_rows: int = 0
+    mla: Optional[MLAConfig] = None
+    experts: Optional[ExpertsConfig] = None
 
     @nn.compact
     def __call__(self, x, valid, train: bool = False, decode: bool = False,
                  positions=None, pages=None, seq_lens=None, rows=None):
         mup = self.mup or MuP()
         u = _norm(self.norm, "ln1", self.ln_eps)(x).astype(self.dtype)
-        y = CausalSelfAttention(self.num_heads, mesh=self.mesh,
-                                sp_impl=self.sp_impl, dtype=self.dtype,
-                                use_bias=self.attn_bias,
-                                cache_len=self.cache_len,
-                                rope=self.rope, rope_theta=self.rope_theta,
-                                page_tokens=self.page_tokens,
-                                kv_pages=self.kv_pages,
-                                paged_attn=self.paged_attn,
-                                kv_quant=self.kv_quant,
-                                num_kv_heads=self.num_kv_heads,
-                                head_dim=self.head_dim, key_mult=mup.key,
-                                name="attn")(_scaled(u, mup.attention_in),
-                                             valid, decode=decode,
-                                             positions=positions,
-                                             pages=pages, seq_lens=seq_lens)
+        if self.mla is not None:
+            attn = MLAttention(self.num_heads, self.mla, dtype=self.dtype,
+                               rope_theta=self.rope_theta,
+                               page_tokens=self.page_tokens,
+                               kv_pages=self.kv_pages,
+                               paged_attn=self.paged_attn, name="attn")
+        else:
+            attn = CausalSelfAttention(
+                self.num_heads, mesh=self.mesh, sp_impl=self.sp_impl,
+                dtype=self.dtype, use_bias=self.attn_bias,
+                cache_len=self.cache_len, rope=self.rope,
+                rope_theta=self.rope_theta, page_tokens=self.page_tokens,
+                kv_pages=self.kv_pages, paged_attn=self.paged_attn,
+                kv_quant=self.kv_quant, num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim, key_mult=mup.key, name="attn")
+        y = attn(_scaled(u, mup.attention_in), valid, decode=decode,
+                 positions=positions, pages=pages, seq_lens=seq_lens)
         y = _scaled(y, mup.attention_out)
         y = nn.Dropout(self.dropout, deterministic=not train)(y)
         x = x + y
@@ -513,9 +523,17 @@ class GPTBlock(nn.Module):
                 E, name="mlp_out", use_bias=False, dtype=self.dtype,
                 kernel_init=_part(("tp", None))(
                     nn.initializers.lecun_normal()))(y), mup.mlp[1])
+        elif self.mlp == "experts":
+            # the tokens given to experts: a decode apply's real positions
+            # (a dead row, a bucket's padding are not), else the non-pad ids
+            real = (jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
+                    if decode and seq_lens is not None
+                    else valid.astype(jnp.bool_))
+            y = ExpertMLP(self.experts, dtype=self.dtype, name="experts")(
+                y, real, decode=decode)
         else:
             raise ValueError(f"unknown mlp {self.mlp!r} (valid: 'gelu', "
-                             f"'swiglu')")
+                             f"'swiglu', 'experts')")
         y = nn.Dropout(self.dropout, deterministic=not train)(y)
         return x + y
 
@@ -526,9 +544,10 @@ _block_traces_lock = threading.Lock()   # engines trace on their own threads
 
 def block_traces() -> int:
     """How often this process has traced :func:`_decode_block`. A decode
-    program costs about one, whatever its depth; a count that grows by the
-    depth says the layers stopped sharing a trace (serving telemetry shows
-    it as ``block_traces`` beside ``compiled_programs``)."""
+    program costs about one for each kind of layer in the stack (one; two
+    with ``dense_layers`` before expert layers), whatever its depth; a count
+    that grows by the depth says the layers stopped sharing a trace (serving
+    telemetry shows it as ``block_traces`` beside ``compiled_programs``)."""
     return _block_traces
 
 
@@ -538,10 +557,10 @@ def _decode_block(block, variables, x, positions, pages, seq_lens, rows):
     on ``variables`` = that layer's ``params`` and, once it exists, its
     ``cache``; returns the stream and the layer's new cache subtree.
 
-    Jitted with the module static, so a program's layers, whose parameters
-    and cache differ in value only, share one trace: this body runs for the
-    first layer, and jax copies the equations it recorded into the program
-    for every other. A Python ``for`` over bound submodules ran the block's
+    Jitted with the module static, so a program's layers of one kind,
+    whose parameters and cache differ in value only, share one trace: this
+    body runs for the first layer of each kind, and jax copies the equations
+    it recorded into the program for every other. A Python ``for`` over bound submodules ran the block's
     Python and traced its kernels again for every layer, 1.4 s a layer and
     program on a v5e host, found in no cache. ``inline=True`` makes the
     copy land in the caller's own equations, so the program XLA is handed
@@ -568,7 +587,8 @@ class CausalTransformer(nn.Module):
 
     ``moe_every > 0`` replaces every ``moe_every``-th block's MLP with routed
     experts (kubeml_tpu.parallel.moe, sharded over the ``ep`` mesh axis),
-    GShard-style interleaving; 0 (default) is the dense model."""
+    GShard-style interleaving for training; 0 (default) is the dense model.
+    The expert layer that serves is ``mlp="experts"`` (below)."""
 
     vocab_size: int = 32000
     max_len: int = 2048
@@ -622,7 +642,13 @@ class CausalTransformer(nn.Module):
     # mixer beside attention in every block (models/mamba2.py); its
     # recurrent state lives in the cache collection, ``state_rows`` rows of
     # it (the serving layer clones that in with the arena's sizes; 0 = the
-    # batch). ``mup``: the constant multipliers of a muP checkpoint. ---
+    # batch). ``mup``: the constant multipliers of a muP checkpoint.
+    # ``mla``: multi-head latent attention in place of K/V heads
+    # (models/mla.py; rotary positions; its paged arena holds one latent a
+    # token, ``mla.latent_width`` values, and it has no dense decode cache).
+    # ``mlp="experts"`` with ``experts``: routed experts and a shared one
+    # (models/experts.py), after ``dense_layers`` leading layers whose MLP
+    # is a SwiGLU of ``mlp_dim``: the one layer pattern a stack can have. ---
     norm: str = "layernorm"
     mlp: str = "gelu"
     mlp_dim: int = 0
@@ -631,6 +657,9 @@ class CausalTransformer(nn.Module):
     ssm: Optional[SSMConfig] = None
     mup: Optional[MuP] = None
     state_rows: int = 0
+    mla: Optional[MLAConfig] = None
+    experts: Optional[ExpertsConfig] = None
+    dense_layers: int = 0
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, decode: bool = False,
@@ -713,9 +742,9 @@ class CausalTransformer(nn.Module):
                 raise ValueError(
                     f"exit_layer must be in [1, depth={self.depth}], got "
                     f"{exit_layer}")
-            if self.moe_every > 0:
+            if self.moe_every > 0 or self.mlp == "experts":
                 raise ValueError("early-exit drafting does not cover "
-                                 "MoE-interleaved models")
+                                 "expert models")
         run_depth = self.depth if exit_layer is None else int(exit_layer)
         fields = dict(
             mesh=self.mesh, sp_impl=self.sp_impl, dtype=self.dtype,
@@ -726,15 +755,27 @@ class CausalTransformer(nn.Module):
             paged_attn=self.paged_attn, kv_quant=self.kv_quant,
             norm=self.norm, mlp=self.mlp, mlp_dim=self.mlp_dim,
             num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
-            ssm=self.ssm, mup=self.mup, state_rows=self.state_rows)
-        # a decode apply sends every layer through the one trace of
-        # _decode_block: this block, detached from the module and so equal
-        # for all layers, is its static argument
-        detached = (GPTBlock(self.num_heads, self.mlp_ratio, self.dropout,
-                             parent=None, **fields)
+            ssm=self.ssm, mup=self.mup, state_rows=self.state_rows,
+            mla=self.mla, experts=self.experts)
+        if (self.mlp == "experts") != (self.experts is not None):
+            raise ValueError("mlp='experts' and an ExpertsConfig go together")
+        if self.mla is not None and not use_rope:
+            raise ValueError("latent attention takes rotary positions "
+                             "(pos='rope')")
+        # the stack's pattern: layer i's MLP kind
+        kind_of = lambda i: ("swiglu" if self.mlp == "experts"
+                             and i < self.dense_layers else self.mlp)
+        # a decode apply sends every layer of a kind through the one trace
+        # of _decode_block: the kind's block, detached from the module and
+        # so equal for all its layers, is the static argument
+        detached = ({kind: GPTBlock(self.num_heads, self.mlp_ratio,
+                                    self.dropout, parent=None,
+                                    **{**fields, "mlp": kind})
+                     for kind in {kind_of(i) for i in range(run_depth)}}
                     if decode and not self.is_initializing() else None)
         for i in range(run_depth):
             name = f"block_{i}"
+            fields["mlp"] = kind_of(i)
             if self.moe_every > 0 and (i + 1) % self.moe_every == 0:
                 from ..parallel.moe import MoEBlock
 
@@ -753,8 +794,8 @@ class CausalTransformer(nn.Module):
                 vs = {"params": self.get_variable("params", name)}
                 if self.has_variable("cache", name):
                     vs["cache"] = self.get_variable("cache", name)
-                x, cache = _decode_block(detached, vs, x, positions, pages,
-                                         seq_lens, rows)
+                x, cache = _decode_block(detached[kind_of(i)], vs, x,
+                                         positions, pages, seq_lens, rows)
                 self.put_variable("cache", name, cache)
             else:
                 # static_argnums counts self as 0, so `train` (a trace-time

@@ -12,6 +12,13 @@ The router's load-balancing auxiliary loss (mean over experts of
 fraction-routed x mean-gate, scaled by E, the Switch formulation) is sown into
 the ``"aux_loss"`` collection; :class:`kubeml_tpu.parallel.trainer.SPMDTrainer`
 collects it during the loss computation.
+
+This is the TRAINING-side expert layer (``CausalTransformer(moe_every=...)``):
+a capacity, dropped overflow and dense one-hot dispatch suit 8 experts on a
+mesh and have no paged decode path (``supports_paged_decode`` refuses the
+model). The expert layer that SERVES is ``models/experts.py``
+(``mlp="experts"``): dropless, sorted assignments through grouped products,
+sigmoid scores with a selection bias and a shared expert.
 """
 
 from __future__ import annotations

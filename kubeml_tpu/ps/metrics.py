@@ -280,6 +280,13 @@ SERVING_COUNTERS = {
     "kubeml_serving_pool_audit_failures_total": (
         "pool_audit_failures", "Pool audits that found a broken invariant "
                                "and triggered fault recovery"),
+    "kubeml_serving_moe_assignments_total": (
+        "moe_assignments", "Token-to-expert assignments made in decode steps "
+                           "(live rows x experts per token x expert layers)"),
+    "kubeml_serving_moe_experts_touched_total": (
+        "moe_experts_touched", "Distinct experts chosen by a decode step's "
+                               "live rows, summed over expert layers and "
+                               "steps: the expert weights the steps read"),
 }
 # XLA compile counter, labeled {model, program} — rendered from the
 # snapshot's per-program compile-count dict rather than the scalar tables
@@ -453,6 +460,17 @@ SERVING_GAUGES = {
         "kv_quant", "1 when KV-cache pages are stored int8 with per-page "
                     "scale arenas (KUBEML_KV_QUANT), 0 for compute-dtype "
                     "storage"),
+    "kubeml_serving_kv_latent_width": (
+        "kv_latent_width", "Values one cached token holds in one layer of a "
+                           "latent (MLA) arena, once for all heads and for K "
+                           "and V; 0 for a model that pages K/V heads"),
+    "kubeml_serving_moe_layers": (
+        "moe_layers", "Layers of the served model whose feed-forward is "
+                      "routed experts (0: none)"),
+    "kubeml_serving_expert_param_bytes": (
+        "expert_param_bytes", "Bytes of the routed experts' stacked weights "
+                              "resident for this model; a decode step reads "
+                              "the share its rows chose"),
     "kubeml_serving_prefills_in_progress": (
         "prefills_in_progress",
         "Rows currently mid-chunked-prefill: holding a slot and pages but "

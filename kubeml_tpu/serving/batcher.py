@@ -89,6 +89,37 @@ class RecurrentStateUnsupported(KubeMLError):
             409)
 
 
+class LatentCacheUnsupported(KubeMLError):
+    """What the engine does not do for a model whose pages hold latents
+    (multi-head latent attention: one vector a token, no heads, no V arena):
+    int8 page storage scales a page per K/V head, a KMS1 frame is laid out
+    as K and V pages, the slot engine keeps a dense per-row cache the model
+    does not have. Refused by name until each learns the latent layout."""
+
+    def __init__(self, what: str):
+        super().__init__(
+            f"{what} is not supported for a model with a latent KV cache: "
+            f"its pages hold one latent vector a token, not K and V heads",
+            409)
+
+
+class ExpertLayersUnsupported(KubeMLError):
+    """What the engine does not do for a model with routed-expert layers:
+    an early-exit drafter (``spec=self``) stops inside the stack, where the
+    layers that differ most between tokens have not run."""
+
+    def __init__(self, what: str):
+        super().__init__(
+            f"{what} is not supported for a model with routed-expert "
+            f"layers", 409)
+
+
+def _leaves_named(tree, *names) -> list:
+    """The leaves of ``tree`` whose last path key is one of ``names``."""
+    return [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+            if getattr(path[-1], "key", None) in names]
+
+
 def _param_shardings(module, mesh):
     """NamedSharding pytree for a causal-LM module's variables, derived from
     its own ``nn.with_partitioning`` annotations (the same derivation the
@@ -398,7 +429,8 @@ def _chunk_cap(tokens: int, page_tokens: int) -> int:
 
 def _kv_token_bytes(module, layers: Optional[int] = None) -> int:
     """HBM bytes attention reads per CACHED TOKEN per forward pass: every
-    layer reads the token's K and V rows once. The KV-read accounting
+    layer reads the token's K and V rows once — or, under latent attention,
+    its one latent vector once (``_kv_copies``). The KV-read accounting
     (kubeml_serving_kv_read_bytes_total) multiplies this by the
     host-modeled gathered-token count per dispatch — a geometry model of
     the device's read traffic, not a hardware counter. 0 when the module
@@ -420,14 +452,24 @@ def _kv_token_bytes(module, layers: Optional[int] = None) -> int:
 
     if resolve_kv_quant(getattr(module, "kv_quant", "off")) == "int8":
         itemsize = 1
-    return int(depth) * 2 * width * int(itemsize)
+    return int(depth) * _kv_copies(module) * width * int(itemsize)
+
+
+def _kv_copies(module) -> int:
+    """Arenas a layer keeps: K and V, or the one latent arena both are made
+    from (``models/mla.py``)."""
+    return 1 if getattr(module, "mla", None) is not None else 2
 
 
 def _kv_width(module) -> int:
     """Elements of K (or of V) one token holds in one layer: the K/V heads
     (``num_kv_heads``, under grouped-query attention fewer than the query
-    heads) times the head size. 0 when the module doesn't expose the
-    transformer geometry."""
+    heads) times the head size; under latent attention the latent's width
+    (compressed K/V + the shared rope key), whatever the heads. 0 when the
+    module doesn't expose the transformer geometry."""
+    mla = getattr(module, "mla", None)
+    if mla is not None:
+        return int(mla.latent_width)
     heads = getattr(module, "num_heads", None)
     embed = getattr(module, "embed_dim", None)
     if not heads or not embed:
@@ -439,7 +481,9 @@ def _kv_width(module) -> int:
 
 def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
     """HBM bytes ONE physical page occupies across every layer's K and V
-    arenas — the unit of the arena byte budget. int8 mode adds the page's
+    arenas (a latent model's one arena a layer: ``page_tokens`` x the
+    latent's width, once) — the unit of the arena byte budget. int8 mode
+    adds the page's
     per-head f32 scale rows (k_scale/v_scale, [kv_pages, H]) so the
     capacity derivation charges quantization's real overhead. 0 when the
     module doesn't expose the transformer geometry."""
@@ -455,7 +499,8 @@ def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
         return int(depth) * 2 * (int(page_tokens) * width * 1
                                  + int(kv_heads) * 4)
     itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
-    return int(depth) * 2 * int(page_tokens) * width * int(itemsize)
+    return (int(depth) * _kv_copies(module) * int(page_tokens) * width
+            * int(itemsize))
 
 
 def service_interval(dispatched: float, done: float,
@@ -584,6 +629,9 @@ class BatchingDecoder:
     # whether this engine carries a recurrent model's per-row state through
     # admission, chunked prefill and the decode step (the paged engine does)
     _recurrent_ok = False
+    # whether this engine's arena can hold latent pages (one vector a token,
+    # models/mla.py): the paged engine's can, a slot cache is per-head K/V
+    _latent_ok = False
 
     def __init__(self, module, variables, *, slots: int = DEFAULT_SLOTS,
                  chunk_steps: int = 8, bucket_min: int = 16,
@@ -606,6 +654,16 @@ class BatchingDecoder:
         self._recurrent = has_recurrent_state(module)
         if self._recurrent and not self._recurrent_ok:
             raise RecurrentStateUnsupported("the slot engine")
+        from ..models.generation import expert_layers, has_latent_cache
+
+        self._latent = has_latent_cache(module)
+        if self._latent and not self._latent_ok:
+            raise LatentCacheUnsupported("the slot engine")
+        # routed-expert layers in the stack: a decode step hands back how
+        # many experts its live rows chose beside its tokens (_step_impl)
+        self._moe_layers = expert_layers(module)
+        self._moe_top_k = (module.experts.num_experts_per_tok
+                           if self._moe_layers else 0)
         self.module = module
         self.max_len = int(cap)
         self.slots = int(slots)
@@ -845,11 +903,12 @@ class BatchingDecoder:
     def _apply_step(self, variables, cache, tok, pos, pages=None, live=None):
         variables = self._dense_vars(variables)
         kw = {} if pages is None else {"pages": pages}
-        if self._recurrent:
+        if self._recurrent or self._moe_layers:
             # a row that is not live (retired, or mid-chunked-prefill) must
             # keep its recurrent state: a step is one position, and a
             # sequence length of 0 leaves state and convolution tail alone
-            # (its K/V write goes to the trash page as before)
+            # (its K/V write goes to the trash page as before); and an
+            # expert layer gives a row that is not live to no expert
             kw["seq_lens"] = live.astype(jnp.int32)
         logits, vs = self.module.apply(
             {**variables, "cache": cache}, tok[:, None], decode=True,
@@ -878,11 +937,16 @@ class BatchingDecoder:
         was live that step, -1 otherwise. Packing matters: every fetched
         array pays a host round trip, so the chunk's results
         must come back in a single fetch (token ids are non-negative, so -1
-        is unambiguous — PAD_ID 0 is a legal vocab id)."""
+        is unambiguous — PAD_ID 0 is a legal vocab id). A model with expert
+        layers adds one column, [T, S + 1]: the experts its live rows chose
+        that step, summed over the layers (each layer leaves its count in
+        the cache, models/experts.py), so the count comes back in the
+        tokens' own fetch."""
 
         def one(s, _):
             logits, cache = self._apply_step(variables, s.cache, s.tok, s.pos,
                                              pages=pages, live=s.live)
+            touched = _leaves_named(cache, "experts_touched")
             use, nxt_keys = _split_rows(s.keys)
             nxt = _sample_rows(logits, use, s.temp, s.topk, active=s.live)
             was_live = s.live
@@ -897,6 +961,9 @@ class BatchingDecoder:
             pos = jnp.where(live, s.pos + 1, s.pos)
             s2 = _Slab(cache, feed, pos, live, rem, nxt_keys, s.temp, s.topk,
                        s.eos)
+            if touched:
+                out = jnp.concatenate(
+                    [out, sum(touched).astype(out.dtype)[None]])
             return s2, out
 
         slab, packed = jax.lax.scan(
@@ -1608,7 +1675,8 @@ class BatchingDecoder:
         tracer on the call is an ``engine.dispatch`` span — ``kind`` is the
         record's (admit, step, spec, pchunk), ``steps`` the decode steps in
         the program, ``width`` its page-table width, ``state_rows`` the rows
-        whose recurrent state it writes (0 for a model without one) — under a profiler
+        whose recurrent state it writes (0 for a model without one)
+        (``moe_layers``: its routed-expert layers, 0 likewise) — under a profiler
         annotation of the same name, and an admitting program (``group``
         set) closes the ``engine.admit`` span that began where its rows
         were taken from the queue."""
@@ -1623,7 +1691,8 @@ class BatchingDecoder:
                 began = self._admit_from or tracer.at(t0)
                 tracer.add_span("engine.admit", began, tracer.at(t0) - began,
                                 rows=len(group), requests=requests,
-                                state_rows=state_rows)
+                                state_rows=state_rows,
+                                moe_layers=self._moe_layers)
             annotation = jax.profiler.TraceAnnotation("engine.dispatch",
                                                       seq=seq)
         # ONE call site, traced or not: a program's source locations are
@@ -1637,7 +1706,7 @@ class BatchingDecoder:
             self._engine_span(
                 "engine.dispatch", tracer.at(t0), t1 - t0, requests, seq=seq,
                 program=kind, steps=steps, width=width, cold=cold,
-                state_rows=state_rows,
+                state_rows=state_rows, moe_layers=self._moe_layers,
                 rows_live=sum(r is not None for r in self._slot_rows),
                 depth=self._depth, ahead=len(self._inflight))
             # a later group of the same wave is prepared from here on
@@ -1828,6 +1897,14 @@ class BatchingDecoder:
                     self._complete_row(slot, row)
             return tokens
         _, packed, snapshot, kv_bytes, cold, coloc = rec
+        if self._moe_layers:
+            # the block's last column is each step's count of experts its
+            # live rows chose (_step_impl); the assignments follow from the
+            # rows that emitted
+            packed, touched = packed[:, :-1], packed[:, -1]
+            self.stats.moe_steps(
+                int((packed >= 0).sum()) * self._moe_top_k
+                * self._moe_layers, int(touched.sum()))
         # decode-step histogram feed: the chunk's service time over its
         # steps is the per-step decode latency, and kv_bytes over it the
         # achieved KV bandwidth. Cold first executions quarantine to the
@@ -2107,6 +2184,7 @@ class PagedBatchingDecoder(BatchingDecoder):
     """
 
     _recurrent_ok = True
+    _latent_ok = True
 
     def __init__(self, module, variables, *, page_tokens: Optional[int] = None,
                  pages: Optional[int] = None,
@@ -2124,7 +2202,8 @@ class PagedBatchingDecoder(BatchingDecoder):
             raise ValueError(
                 "paged serving does not run on a mesh yet; use the dense "
                 "BatchingDecoder for sharded serving")
-        from ..models.generation import (has_recurrent_state,
+        from ..models.generation import (expert_layers, has_latent_cache,
+                                         has_recurrent_state,
                                          supports_paged_decode)
 
         if not supports_paged_decode(module):
@@ -2136,6 +2215,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         if recurrent and spec not in ("", "off", None):
             raise RecurrentStateUnsupported(
                 f"speculative decoding (spec={spec!r})")
+        if expert_layers(module) and spec == "self":
+            raise ExpertLayersUnsupported(
+                "early-exit self-drafting (spec='self')")
         cap = getattr(module, "max_len", None)
         if cap is None:
             raise GenerationInputError(
@@ -2171,6 +2253,8 @@ class PagedBatchingDecoder(BatchingDecoder):
                                else cfg.kv_quant)
         if not hasattr(module, "kv_quant"):
             kvq = "off"
+        if kvq == "int8" and has_latent_cache(module):
+            raise LatentCacheUnsupported("int8 page storage (kv_quant=int8)")
         self.kv_quant = kvq
         if kvq == "int8":
             bytes_off = _kv_page_bytes(module, pt, "off")
@@ -2376,6 +2460,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         self._param_bytes = sum(
             int(l.size) * np.dtype(l.dtype).itemsize
             for l in jax.tree.leaves(self._variables) if hasattr(l, "dtype"))
+        self._expert_param_bytes = sum(
+            int(l.size) * np.dtype(l.dtype).itemsize for l in _leaves_named(
+                self._variables, "w_gate", "w_up", "w_down"))
         # recurrent state beside the pages, counted off the slab once it is
         # built (the engine thread's first turn)
         self._recurrent_layers = self._recurrent_bytes = 0
@@ -2402,10 +2489,7 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     def _init_slab(self) -> _Slab:
         slab = super()._init_slab()
-        state = [l for path, l in
-                 jax.tree_util.tree_leaves_with_path(slab.cache)
-                 if getattr(path[-1], "key", None) in ("ssm_state",
-                                                       "conv_tail")]
+        state = _leaves_named(slab.cache, "ssm_state", "conv_tail")
         self._recurrent_layers = len(state) // 2
         self._recurrent_bytes = sum(int(l.nbytes) for l in state)
         if self.spec == "draft":
@@ -3100,6 +3184,8 @@ class PagedBatchingDecoder(BatchingDecoder):
             or (snap.eos >= 0 and snap.out[-1] == snap.eos))
         if snap.out and not done and self._recurrent:
             raise RecurrentStateUnsupported("restoring a mid-stream snapshot")
+        if snap.out and not done and self._latent:
+            raise LatentCacheUnsupported("restoring a mid-stream snapshot")
         if snap.out and not done:
             # mid-stream state only restores into a byte-compatible arena
             if int(snap.page_tokens) != self.page_tokens:
@@ -3272,9 +3358,11 @@ class PagedBatchingDecoder(BatchingDecoder):
         if self.spec == "draft":
             self.stats.snapshot_fail()
             return None
-        if self._recurrent:
+        if self._recurrent or self._latent:
+            refusal = (RecurrentStateUnsupported if self._recurrent
+                       else LatentCacheUnsupported)
             log.warning("%s: %s (request %s)", self.name,
-                        RecurrentStateUnsupported("a mid-stream snapshot"),
+                        refusal("a mid-stream snapshot"),
                         row.entry.request_id)
             self.stats.snapshot_fail()
             return None
@@ -3535,6 +3623,14 @@ class PagedBatchingDecoder(BatchingDecoder):
         snap["recurrent_state_bytes"] = float(self._recurrent_bytes)
         snap["prefix_cache_off_recurrent"] = (
             1.0 if self._prefix_off_recurrent else 0.0)
+        # a latent arena: values one token holds in one layer, once (0 for
+        # a model that pages K and V heads); routed-expert layers and the
+        # bytes of their stacked expert weights (a decode step reads the
+        # share of them its rows chose: moe_experts_touched)
+        snap["kv_latent_width"] = float(
+            self.module.mla.latent_width if self._latent else 0)
+        snap["moe_layers"] = float(self._moe_layers)
+        snap["expert_param_bytes"] = float(self._expert_param_bytes)
         if self._spec_ctl is not None:
             # current adaptive speculation depth (0 = retreated to plain
             # decode) + the controller's EWMA acceptance estimate

@@ -87,6 +87,12 @@ class DecoderStats:
         self.idle_slot_steps = 0      # no resident row (free capacity)
         self.prefill_tokens = 0       # real prompt tokens prefilled
         self.prefill_pad_tokens = 0   # bucket + row padding tokens computed
+        # routed-expert layers, decode steps only: token-to-expert
+        # assignments made (live rows x top_k x expert layers a step) and
+        # distinct experts they chose (summed over layers), whose weights a
+        # step has to read
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
         self.goodput_tokens = 0       # tokens delivered to a live waiter
         self.wasted_tokens = 0        # tokens routed to an aborted request
         # shared-prefix reuse (paged engine, serving/kvpool.py): admissions
@@ -286,6 +292,13 @@ class DecoderStats:
         with self._lock:
             self.prefill_tokens += int(real)
             self.prefill_pad_tokens += int(padding)
+
+    def moe_steps(self, assignments: int, touched: int) -> None:
+        """Expert-layer accounting for one processed decode chunk, from the
+        step program's own outputs (no extra fetch)."""
+        with self._lock:
+            self.moe_assignments += int(assignments)
+            self.moe_experts_touched += int(touched)
 
     def fetch_started(self) -> None:
         with self._lock:
@@ -556,6 +569,8 @@ class DecoderStats:
                 "idle_slot_steps": float(self.idle_slot_steps),
                 "prefill_tokens": float(self.prefill_tokens),
                 "prefill_pad_tokens": float(self.prefill_pad_tokens),
+                "moe_assignments": float(self.moe_assignments),
+                "moe_experts_touched": float(self.moe_experts_touched),
                 "goodput_tokens": float(self.goodput_tokens),
                 "wasted_tokens": float(self.wasted_tokens),
                 "prefix_hits": float(self.prefix_hits),

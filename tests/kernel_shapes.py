@@ -17,7 +17,9 @@ def _sds(shape, dtype):
 
 def kernel_cases():
     from kubeml_tpu.ops.flash_attention import flash_attention
+    from kubeml_tpu.ops.grouped_matmul import grouped_matmul
     from kubeml_tpu.ops.int8_matmul import int8_matmul
+    from kubeml_tpu.ops.mla_attention import mla_attn
     from kubeml_tpu.ops.paged_attention import paged_attention
     from kubeml_tpu.ops.ssm import ssm_update
 
@@ -61,6 +63,29 @@ def kernel_cases():
          _sds((rows, 32, 128), jnp.float32), _sds((rows, 32), jnp.float32),
          _sds((32,), jnp.float32), _sds((rows, 2, 256), jnp.float32),
          _sds((rows, 2, 256), jnp.float32)))
+    # GLM-4.7-Flash's published shapes: the latent page walk of a decode
+    # step (20 heads against pages of 16 x (512 + 64), 32 rows, both table
+    # widths the cell reaches) and the experts' grouped products (64 experts
+    # of 2048 x 1536; a step's 128 assignments, a prefill's 8192)
+    for width in (128, 256):
+        cases[f"mla_attn-glm-4.7-flash-P{width}"] = (
+            lambda q, a, t, p: mla_attn(q, a, t, p, value_dim=512,
+                                        scale=1 / 16, interpret=False),
+            (_sds((rows, 20, 576), jnp.bfloat16),
+             _sds((8193, PT, 576), jnp.bfloat16),
+             _sds((rows, width), jnp.int32), _sds((rows,), jnp.int32)))
+    for m in (128, 8192):
+        cases[f"moe_experts-gated-m{m}"] = (
+            lambda x, w, u, g: grouped_matmul(x, w, g, u, kernel=True,
+                                              interpret=False),
+            (_sds((m, 2048), jnp.bfloat16),
+             _sds((64, 2048, 1536), jnp.bfloat16),
+             _sds((64, 2048, 1536), jnp.bfloat16), _sds((64,), jnp.int32)))
+        cases[f"moe_experts-down-m{m}"] = (
+            lambda x, w, g: grouped_matmul(x, w, g, kernel=True,
+                                           interpret=False),
+            (_sds((m, 1536), jnp.bfloat16),
+             _sds((64, 1536, 2048), jnp.bfloat16), _sds((64,), jnp.int32)))
     # the MLP up-projection and the lm_head (vocab 50257: not a tile multiple)
     for K, N in ((768, 3072), (768, 50257)):
         cases[f"int8_matmul-{K}x{N}"] = (

@@ -296,6 +296,13 @@ UNPANELED = {
     "kubeml_serving_slots_busy": "occupancy ratio panel charts this",
     "kubeml_serving_slots_total": "static capacity gauge",
     "kubeml_serving_weight_bytes": "static per-model constant",
+    "kubeml_serving_kv_latent_width": "static per-model constant",
+    "kubeml_serving_moe_layers": "static per-model constant",
+    "kubeml_serving_expert_param_bytes": "static per-model constant",
+    # expert models only; the benchmark reads their ratio per decode step
+    "kubeml_serving_moe_assignments_total": "model-specific; ad-hoc only",
+    "kubeml_serving_moe_experts_touched_total":
+        "model-specific; ad-hoc only",
 }
 
 

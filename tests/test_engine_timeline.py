@@ -113,7 +113,8 @@ def test_traced_request_leaves_a_program_timeline(served, tracer, build):
                for q in seqs) == 6
     # the admission's host work ends where its jitted call begins
     (admit,) = [s for s in spans if s.name == "engine.admit"]
-    assert admit.attrs == {"rows": 1, "requests": req_id, "state_rows": 0}
+    assert admit.attrs == {"rows": 1, "requests": req_id, "state_rows": 0,
+                           "moe_layers": 0}
     assert admit.start + admit.duration == pytest.approx(
         first["engine.dispatch"].start, abs=1e-6)
     # the request-level tree is what it was, on the same clock
