@@ -257,7 +257,7 @@ def phase_kernels(sz: Sizes) -> dict:
     from kubeml_tpu.ops.attention import dot_product_attention
     from kubeml_tpu.ops.flash_attention import flash_attention
     from kubeml_tpu.ops.int8_matmul import int8_dot, int8_matmul
-    from kubeml_tpu.ops.paged_attention import paged_attention
+    from kubeml_tpu.ops.paged_attention import pack_kv_rows, paged_attention
 
     rng = np.random.default_rng(0)
     H, D, pt, N = sz.heads, sz.embed // sz.heads, sz.page_tokens, sz.arena_pages
@@ -305,15 +305,14 @@ def phase_kernels(sz: Sizes) -> dict:
         # L == 128: page-aligned suffix prefill after a prefix hit
         128: [0, 16, 32, 128, 256, 512, 640, sz.context - 128],
     }
-    head_major = lambda a: jnp.swapaxes(jnp.asarray(a), 1, 2)
     for L, positions in cases.items():
         pos = jnp.asarray(positions, jnp.int32)
         qf = rng.normal(size=(B, L, H, D)).astype(np.float32)
         for name, dt in (("bf16", jnp.bfloat16), ("f32", jnp.float32)):
             q = jnp.asarray(qf, dt)
             k_tok, v_tok = jnp.asarray(kf, dt), jnp.asarray(vf, dt)
-            got = paged_attention(q, head_major(k_tok), head_major(v_tok),
-                                  pages, pos, interpret=False)
+            got = paged_attention(q, pack_kv_rows(k_tok, v_tok), pages, pos,
+                                  interpret=False)
             want = gather_oracle(q.astype(jnp.float32),
                                  k_tok.astype(jnp.float32),
                                  v_tok.astype(jnp.float32), pages, pos)
@@ -323,8 +322,9 @@ def phase_kernels(sz: Sizes) -> dict:
         # same bytes through the same q * s / 127 reconstruction
         q = jnp.asarray(qf, jnp.bfloat16)
         got = paged_attention(
-            q, head_major(kq), head_major(vq), pages, pos, interpret=False,
-            k_scale=jnp.asarray(amax_k), v_scale=jnp.asarray(amax_v))
+            q, pack_kv_rows(jnp.asarray(kq), jnp.asarray(vq)), pages, pos,
+            interpret=False, k_scale=jnp.asarray(amax_k),
+            v_scale=jnp.asarray(amax_v))
         want = gather_oracle(
             q.astype(jnp.float32),
             jnp.asarray(kq, jnp.float32)

@@ -90,8 +90,9 @@ def init_cache(module, variables, batch: int) -> dict:
 
 
 def init_paged_cache(module, variables, batch: int, table_pages: int) -> dict:
-    """A zeroed PAGED KV-cache pytree: per-layer physical page arenas
-    ``[kv_pages, H, page_tokens, D]`` (the module carries ``kv_pages`` /
+    """A zeroed PAGED KV-cache pytree: per-layer physical page arenas of
+    token rows ``[kv_pages, page_tokens, W]`` (K‖V; a latent model's
+    ``latent_pages``; the module carries ``kv_pages`` /
     ``page_tokens`` — the serving layer clones them in) addressed through
     per-row page tables. Shapes come from ``jax.eval_shape`` over a
     one-token paged decode apply, so no device work happens; like

@@ -105,12 +105,12 @@ class CausalSelfAttention(nn.Module):
     rope_theta: float = 10000.0
     # PAGED KV cache (kubeml_tpu.serving.kvpool): when a block table is
     # passed at call time the cache collection holds one shared physical
-    # arena ``[kv_pages, H, page_tokens, D]`` instead of per-row
-    # ``[B, max_len, ...]`` stripes (head-major, so one head's page is a
-    # whole trailing ``(page_tokens, D)`` tile — the only page block the
-    # Pallas TPU lowering accepts, ops/paged_attention.py); rows address it through per-row page
+    # arena of token rows ``[kv_pages, page_tokens, W]`` (K of every K/V
+    # head, then V: ops/paged_attention.kv_row_width) instead of per-row
+    # ``[B, max_len, ...]`` stripes; rows address it through per-row page
     # tables, so rows of different lengths share one step program without
     # padding every row to max_len. 0/0 (default) = dense cache only.
+    # The paged branch below says why the arena is laid out so.
     # This page-granular layout is also what makes a live request's decode
     # state PORTABLE: serving/kvsnap.py gathers a row's written pages out
     # of the arena into a KMS1 frame and scatters them back into any
@@ -188,9 +188,9 @@ class CausalSelfAttention(nn.Module):
                                  "parallelism; use an sp=1 mesh for serving")
             if pages is not None:
                 # PAGED decode (serving.kvpool): the cache is one shared
-                # physical arena [kv_pages, H, pt, D]; each row addresses
-                # its own logical window through ``pages`` [B, P] (logical
-                # page j of row b lives at physical page pages[b, j]).
+                # physical arena of token rows [kv_pages, pt, W]; each row
+                # addresses its own logical window through ``pages`` [B, P]
+                # (logical page j of row b lives at physical page pages[b, j]).
                 # ``positions`` [B] is the logical position of each row's
                 # FIRST token this call — L == 1 per-token steps and L > 1
                 # suffix prefill (shared-prefix reuse: the cached prefix is
@@ -216,14 +216,33 @@ class CausalSelfAttention(nn.Module):
                     raise ValueError("paged decode needs per-row positions")
                 pt, npg = self.page_tokens, self.kv_pages
                 tw = pages.shape[1]  # table width (logical pages per row)
-                from ..ops.paged_attention import resolve_kv_quant
+                from ..ops.paged_attention import (kv_row_width,
+                                                   pack_kv_rows,
+                                                   resolve_kv_quant,
+                                                   unpack_kv_rows)
 
                 kvq = resolve_kv_quant(self.kv_quant)
                 store_dtype = jnp.int8 if kvq == "int8" else k.dtype
-                ck = self.variable("cache", "k_pages", jnp.zeros,
-                                   (npg, Hkv, pt, D), store_dtype)
-                cv = self.variable("cache", "v_pages", jnp.zeros,
-                                   (npg, Hkv, pt, D), store_dtype)
+                # ONE arena a layer, a token a row: K of the Hkv heads, then
+                # V, in W lanes — a whole number of 128-lane rows (2,560 at
+                # GPT-2 large, 3,200 at XL, 1,024 at Falcon-H1; a narrower
+                # model's row ends in zero lanes). The write is a scatter
+                # over (page, offset) whose window is the whole minor
+                # dimension, the kernel's page block (1, pt, W) has the
+                # array's own trailing dimensions, and XLA keeps the
+                # default layout for both. The layout before this one, K
+                # and V each [kv_pages, Hkv, pt, D] written by
+                # ``.at[phys, :, off].set``, had its two index dimensions
+                # straddle the heads: XLA laid each arena out token-major
+                # for the scatter, row-major again for the Mosaic call and
+                # a third time for the next use — six pool-sized copies a
+                # layer in a GPT-2 decode step and in a one-row admit, four
+                # under Falcon-H1's heads of 128 (compiled ahead of time
+                # for a v5e; on the chip 26-31 ms of a 48-62 ms step).
+                # tests/test_arena_copies.py holds the count at zero.
+                W = kv_row_width(Hkv, D)
+                ckv = self.variable("cache", "kv_rows", jnp.zeros,
+                                    (npg, pt, W), store_dtype)
                 if kvq == "int8":
                     # per-page-per-head running absmax: a page's int8 value
                     # q reconstructs as q * scale / 127. Scales live in the
@@ -267,34 +286,36 @@ class CausalSelfAttention(nn.Module):
                     # scatter this call's K/V at the final scale. Trash
                     # page 0 takes redirected writes exactly as before —
                     # its scale grows with the garbage, and nothing reads
-                    # it meaningfully.
-                    def _quant_write(arena, scales, x):
-                        xf = x.astype(jnp.float32)
-                        amax = jnp.abs(xf).max(axis=-1)          # [B, L, H]
-                        new_s = scales.at[phys].max(amax)        # [npg, H]
-                        old_at = scales[phys]                    # [B, L, H]
-                        new_at = new_s[phys]                     # [B, L, H]
-                        ratio = jnp.where(new_at > 0.0,
-                                          old_at / jnp.maximum(new_at, 1e-30),
-                                          1.0)
-                        old_q = arena[phys].astype(jnp.float32)  # [B,L,H,pt,D]
-                        req = jnp.clip(
-                            jnp.round(old_q * ratio[..., None, None]),
-                            -127, 127).astype(jnp.int8)
-                        arena = arena.at[phys].set(req)
-                        qv = jnp.clip(
-                            jnp.round(xf * 127.0
-                                      / jnp.maximum(new_at, 1e-30)[..., None]),
-                            -127, 127).astype(jnp.int8)
-                        return arena.at[phys, :, off].set(qv), new_s
-
-                    ck.value, ks.value = _quant_write(ck.value, ks.value, k)
-                    cv.value, vs.value = _quant_write(cv.value, vs.value, v)
+                    # it meaningfully. K and V ride one pass: a row's 2*Hkv
+                    # head slices against the K scales beside the V scales.
+                    xf = jnp.concatenate([k, v], axis=2).astype(jnp.float32)
+                    scales = jnp.concatenate([ks.value, vs.value], axis=1)
+                    amax = jnp.abs(xf).max(axis=-1)          # [B, L, 2Hkv]
+                    new_s = scales.at[phys].max(amax)        # [npg, 2Hkv]
+                    old_at = scales[phys]                    # [B, L, 2Hkv]
+                    new_at = new_s[phys]                     # [B, L, 2Hkv]
+                    ratio = jnp.where(new_at > 0.0,
+                                      old_at / jnp.maximum(new_at, 1e-30),
+                                      1.0)
+                    # [B, L, pt, 2Hkv, D]: the touched pages, head by head
+                    old_q = jnp.concatenate(unpack_kv_rows(
+                        ckv.value[phys].astype(jnp.float32), Hkv, D), axis=3)
+                    req = jnp.clip(
+                        jnp.round(old_q * ratio[:, :, None, :, None]),
+                        -127, 127).astype(jnp.int8)
+                    arena = ckv.value.at[phys].set(
+                        pack_kv_rows(req[..., :Hkv, :], req[..., Hkv:, :]))
+                    qv = jnp.clip(
+                        jnp.round(xf * 127.0
+                                  / jnp.maximum(new_at, 1e-30)[..., None]),
+                        -127, 127).astype(jnp.int8)
+                    ckv.value = arena.at[phys, off].set(
+                        pack_kv_rows(qv[:, :, :Hkv], qv[:, :, Hkv:]))
+                    ks.value, vs.value = new_s[:, :Hkv], new_s[:, Hkv:]
                 else:
-                    # (page, :, offset) — the two index arrays straddle
-                    # the head slice, so the update is [B, L, H, D] like k
-                    ck.value = ck.value.at[phys, :, off].set(k)
-                    cv.value = cv.value.at[phys, :, off].set(v)
+                    # (page, offset): the window is a token's whole row
+                    ckv.value = ckv.value.at[phys, off].set(
+                        pack_kv_rows(k, v))
                 from ..ops.paged_attention import resolve_paged_attn
 
                 if resolve_paged_attn(self.paged_attn) == "pallas":
@@ -307,28 +328,27 @@ class CausalSelfAttention(nn.Module):
                     from ..ops.paged_attention import paged_attention
 
                     if kvq == "int8":
-                        out = paged_attention(q, ck.value, cv.value, pages,
-                                              positions, k_scale=ks.value,
+                        out = paged_attention(q, ckv.value, pages, positions,
+                                              kv_heads=Hkv, k_scale=ks.value,
                                               v_scale=vs.value)
                     else:
-                        out = paged_attention(q, ck.value, cv.value, pages,
-                                              positions)
+                        out = paged_attention(q, ckv.value, pages, positions,
+                                              kv_heads=Hkv)
                 else:
-                    kg = ck.value[pages]  # [B, tw, H, pt, D]
-                    vg = cv.value[pages]
+                    # [B, tw, pt, Hkv, D]: rows come out token-major as is
+                    kg, vg = unpack_kv_rows(ckv.value[pages], Hkv, D)
                     if kvq == "int8":
                         # gather-path dequant: the parity oracle for the
                         # quantized STORAGE format itself (same q*s/127
                         # reconstruction as the kernel's VMEM dequant)
                         kg = (kg.astype(jnp.float32)
-                              * (ks.value[pages] / 127.0)[..., None, None]
+                              * (ks.value[pages] / 127.0)[:, :, None, :, None]
                               ).astype(q.dtype)
                         vg = (vg.astype(jnp.float32)
-                              * (vs.value[pages] / 127.0)[..., None, None]
+                              * (vs.value[pages] / 127.0)[:, :, None, :, None]
                               ).astype(q.dtype)
-                    # head-major pages back to token-major rows
-                    kg = kg.transpose(0, 1, 3, 2, 4).reshape(B, tw * pt, Hkv, D)
-                    vg = vg.transpose(0, 1, 3, 2, 4).reshape(B, tw * pt, Hkv, D)
+                    kg = kg.reshape(B, tw * pt, Hkv, D)
+                    vg = vg.reshape(B, tw * pt, Hkv, D)
                     k_pos = jnp.arange(tw * pt)[None, None, None, :]
                     # [B, 1, L, tw*pt]
                     mask = k_pos <= pos_full[:, None, :, None]

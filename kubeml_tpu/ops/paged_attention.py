@@ -2,27 +2,31 @@
 page table, no contiguous K/V copy, KV traffic that scales with occupancy.
 
 The paged serving engine (serving/kvpool.py + the paged decode branch in
-models/gpt.py) stores K/V in one shared physical arena
-``[kv_pages, Hkv, page_tokens, D]`` (``Hkv`` K/V heads: the query heads, or
-fewer under grouped-query attention) addressed through per-row page tables.
-The original decode read was gather-then-attend: every step, every layer,
-each row's WHOLE table is gathered into a contiguous ``[B, tw*pt, H, D]``
-HBM block and plain attention runs over it — so a row 64 tokens into a
-1024-token reservation reads (and materializes a copy of) 1024 tokens of K
-and V per layer per step, because admission reserves the worst case. This
-kernel is the vLLM PagedAttention / Flash-Decoding answer (Kwon et al.,
-SOSP 2023): stream the row's pages through VMEM with the online-softmax
-recurrence, so no contiguous copy ever exists and reads stop at the row's
-live depth.
+models/gpt.py) stores a layer's K and V in one shared physical arena of
+token rows, ``[kv_pages, page_tokens, W]``, addressed through per-row page
+tables. The original decode read was gather-then-attend: every step, every
+layer, each row's WHOLE table is gathered into a contiguous
+``[B, tw*pt, H, D]`` HBM block and plain attention runs over it — so a row
+64 tokens into a 1024-token reservation reads (and materializes a copy of)
+1024 tokens of K and V per layer per step, because admission reserves the
+worst case. This kernel is the vLLM PagedAttention / Flash-Decoding answer
+(Kwon et al., SOSP 2023): stream the row's pages through VMEM with the
+online-softmax recurrence, so no contiguous copy ever exists and reads stop
+at the row's live depth.
 
-Arena layout — HEAD-MAJOR pages. The Pallas TPU lowering takes a block
-only if its last two dims are (8, 128)-aligned or equal the array's, and
-at the serving defaults (16-token pages, head dim 64) no page-sized window
-of a token-major ``[N, pt, H, D]`` arena is either. With ``[N, H, pt, D]``
-a whole page ``(1, H, pt, D)`` is one block whose trailing ``(pt, D)``
-dims ARE the array's, each head's ``[pt, D]`` slice is a plain leading-dim
-index in the kernel, and the page is one contiguous DMA. Mosaic pads the
-sub-tile ``(16, 64)`` window itself, in bf16, f32 and int8 alike.
+Arena layout — TOKEN ROWS of K‖V. A token's row holds the K of all ``Hkv``
+K/V heads (the query heads, or fewer under grouped-query attention), then
+the V of all of them: ``W = 2 * Hkv * D`` lanes, rounded up to a whole
+number of 128-lane rows (:func:`kv_row_width`; exact at GPT-2 large 2,560,
+XL 3,200 and Falcon-H1 1,024, zero lanes at the tail of a toy model's row).
+The Pallas TPU lowering takes a block only if its last two dims are
+(8, 128)-aligned or equal the array's: a page ``(1, pt, W)`` has the
+array's own trailing dims, is one contiguous DMA, and a head's K or V is a
+static lane slice of it inside the kernel (at offset 64 too, for heads of
+64). The rule refuses a page-sized window of ``[N, pt, H, D]`` — which is
+why the arena used to be two head-major arrays ``[N, H, pt, D]`` — and not
+of ``[N, pt, H*D]``; models/gpt.py says, where the arena is declared, what
+XLA did to the head-major arrays around their write.
 
 Grid layout — the kv axis WALKS THE PAGE TABLE: grid ``(B, Lt, P)`` (rows,
 query tiles, logical pages) with the page index innermost (sequential on
@@ -62,6 +66,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -121,6 +126,34 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def kv_row_width(kv_heads: int, head_dim: int) -> int:
+    """Lanes of one token's arena row: K of every K/V head, then V of every
+    K/V head, rounded up to a whole number of 128-lane rows."""
+    return _round_up(2 * kv_heads * head_dim, _LANES)
+
+
+def pack_kv_rows(k, v):
+    """``[..., Hkv, D]`` K and V of the same tokens as arena rows
+    ``[..., W]``: K‖V, zeros in whatever lanes :func:`kv_row_width` adds."""
+    *lead, kv_heads, head_dim = k.shape
+    flat = kv_heads * head_dim
+    rows = jnp.concatenate([k.reshape(*lead, flat), v.reshape(*lead, flat)],
+                           axis=-1)
+    pad = kv_row_width(kv_heads, head_dim) - 2 * flat
+    if pad:
+        rows = jnp.pad(rows, [(0, 0)] * len(lead) + [(0, pad)])
+    return rows
+
+
+def unpack_kv_rows(rows, kv_heads: int, head_dim: int):
+    """Arena rows ``[..., W]`` back to K and V ``[..., Hkv, D]`` (numpy or
+    jax arrays alike: slices and reshapes only)."""
+    flat = kv_heads * head_dim
+    shape = rows.shape[:-1] + (kv_heads, head_dim)
+    return (rows[..., :flat].reshape(shape),
+            rows[..., flat:2 * flat].reshape(shape))
+
+
 # queries per program: L <= _Q_TILE runs as one tile (decode steps, verify
 # windows, short suffixes); longer prefills walk the table once per tile so
 # the acc/m/l scratch stays a fixed few hundred KiB whatever the prompt
@@ -137,17 +170,40 @@ def _tile_live(pos_b, live_b, j, tq: int, pt: int):
         jnp.minimum(live_b, (pos_b + (j + 1) * tq + pt - 1) // pt), 1)
 
 
-def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
+def _slab_width(head_dim: int) -> int:
+    """Lanes the kernel reads for one head: the head's own where they are
+    whole 128-lane rows (or no divisor of one), else one 128-lane row."""
+    return _LANES if head_dim < _LANES and _LANES % head_dim == 0 else head_dim
+
+
+def _slab(start: int, head_dim: int):
+    """``(first lane of the slab, the head's offset inside it)`` for a head
+    whose ``head_dim`` lanes start at ``start`` of an arena row."""
+    within = start % _slab_width(head_dim)
+    return start - within, within
+
+
+def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, kv_ref, *rest,
                page_tokens: int, n_pages: int, scale: float,
-               quantized: bool):
+               kv_heads: int, head_dim: int, quantized: bool):
     """One (batch row, query tile, logical page) program covering ALL
     heads. The page axis is the innermost (sequential) grid dimension;
     acc/m/l carry across it in VMEM scratch, and the output is written at
     the final page step. Heads are a static loop of plain 2-d
-    ``[tq, D] x [pt, D]`` contractions over the head-major page block
-    ``[H, pt, D]`` — one contiguous page DMA per step serves every head.
+    ``[tq, Dp] x [pt, Dp]`` contractions over the page block ``[pt, W]`` of
+    token rows — one contiguous page DMA per step serves K and V of every
+    head, and a head's K or V is a static lane slice of it. A head narrower
+    than a 128-lane row (GPT-2's 64) is read as the ALIGNED 128 lanes it
+    lies in (``Dp`` = 128, :func:`_slab`): a slice at lane offset 64 costs a
+    lane rotation a head, K and V, which made this walk 1.4-1.5 x slower
+    on the chip than over head-major pages (PR 33, the kernel alone at
+    gpt2-large's shapes, 8 rows 300-500 deep: 0.68 against 0.49 ms a layer;
+    aligned slabs 0.47). The query arrives with zeros in the slab's other
+    lanes, so the neighbour's K adds exact zeros to a score; the
+    accumulator and the output keep all ``Dp`` lanes, the neighbour's half
+    of them garbage that the wrapper drops.
 
-    When ``quantized`` the K/V blocks arrive int8 and the page's per-head
+    When ``quantized`` the page block arrives int8 and the page's per-head
     absmax scales ride two extra ``[H, pt]`` inputs (each head's scalar
     repeated along the page's tokens, so it multiplies a ``[tq, pt]``
     score tile as an ordinary row broadcast); dequant happens here in
@@ -161,10 +217,11 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
     b = pl.program_id(0)
     j = pl.program_id(1)
     i = pl.program_id(2)
-    n_heads, tq = q_ref.shape[1], q_ref.shape[2]
+    n_heads, tq, dp = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
     # grouped-query attention: the page block holds the K/V heads only and
     # query head h reads K/V head h // share (share 1: a head each)
-    share = n_heads // k_ref.shape[1]
+    share = n_heads // kv_heads
+    v0 = kv_heads * head_dim  # lane where the row's V half starts
     pt = page_tokens
 
     @pl.when(i == 0)
@@ -188,10 +245,14 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
         k_pos = i * pt + jax.lax.broadcasted_iota(jnp.int32, (tq, pt), 1)
         visible = k_pos <= q_pos
         for h in range(n_heads):
-            q = q_ref[0, h]      # [tq, D] (storage dtype; f32 accumulate)
+            q = q_ref[0, h]      # [tq, Dp] (storage dtype; f32 accumulate)
             hk = h // share
-            k_pg = k_ref[0, hk]  # [pt, D] — one physical page, this head
-            v_pg = v_ref[0, hk]
+            # [pt, Dp] — one physical page, the slab of its rows' lanes
+            # this head's K (V) lies in
+            k0 = _slab(hk * head_dim, head_dim)[0]
+            u0 = _slab(v0 + hk * head_dim, head_dim)[0]
+            k_pg = kv_ref[0, :, k0:k0 + dp]
+            v_pg = kv_ref[0, :, u0:u0 + dp]
             if quantized:
                 k_pg = k_pg.astype(q.dtype)
             s = jax.lax.dot_general(
@@ -232,36 +293,41 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, k_ref, v_ref, *rest,
 
 def paged_attention(
     q: jnp.ndarray,         # [B, L, H, D] this call's queries
-    k_pages: jnp.ndarray,   # [N, Hkv, pt, D] physical K arena (post-write)
-    v_pages: jnp.ndarray,   # [N, Hkv, pt, D] physical V arena (post-write)
+    kv_rows: jnp.ndarray,   # [N, pt, W] physical K‖V arena (post-write)
     pages: jnp.ndarray,     # [B, P] int32 per-row page table
     positions: jnp.ndarray,  # [B] int32 logical position of q[:, 0]
     interpret: Optional[bool] = None,
+    kv_heads: Optional[int] = None,  # Hkv; None = one per query head
     k_scale: Optional[jnp.ndarray] = None,  # [N, Hkv] f32 per-page absmax (int8)
     v_scale: Optional[jnp.ndarray] = None,  # [N, Hkv] f32 per-page absmax (int8)
 ) -> jnp.ndarray:
     """Paged decode attention; returns ``[B, L, H, D]``.
 
     Numerically equivalent (at f32-accumulation tolerance) to gathering
-    ``k_pages[pages]`` into a contiguous ``[B, P*pt, H, D]`` block and
-    attending under the positional causal mask — without the gather: the
-    kernel walks each row's table page by page. Callers must have already
-    scattered this call's K/V into the arenas (the paged decode branch in
-    models/gpt.py writes first, then attends).
+    ``kv_rows[pages]`` into contiguous ``[B, P*pt, Hkv, D]`` blocks of K
+    and of V and attending under the positional causal mask — without the
+    gather: the kernel walks each row's table page by page. Callers must
+    have already scattered this call's K/V into the arena (the paged decode
+    branch in models/gpt.py writes first, then attends).
 
-    With ``k_scale``/``v_scale`` the arenas are int8 (KUBEML_KV_QUANT=int8)
+    With ``k_scale``/``v_scale`` the arena is int8 (KUBEML_KV_QUANT=int8)
     and each page's per-head absmax rides the same clamped page walk as
-    its K/V block; dequant happens in the kernel's VMEM blocks around the
-    QK^T/PV matmuls — the arenas are never materialized wide.
+    its rows; dequant happens in the kernel's VMEM blocks around the
+    QK^T/PV matmuls — the arena is never materialized wide.
 
     The arena may hold fewer heads than ``q`` (grouped-query attention):
-    with ``Hkv`` K/V heads, query head ``h`` reads K/V head
-    ``h // (H / Hkv)``, and a page's block is the K/V heads' alone."""
+    with ``kv_heads`` K/V heads, query head ``h`` reads K/V head
+    ``h // (H / kv_heads)``, and a row is the K/V heads' alone
+    (:func:`pack_kv_rows` makes one, :func:`kv_row_width` sizes it)."""
     B, L, H, D = q.shape
-    Hkv = int(k_pages.shape[1])
+    Hkv = int(kv_heads or H)
     if H % Hkv:
         raise ValueError(f"{H} query heads over {Hkv} K/V heads")
-    pt = int(k_pages.shape[2])
+    pt, W = int(kv_rows.shape[1]), int(kv_rows.shape[2])
+    if W != kv_row_width(Hkv, D):
+        raise ValueError(
+            f"arena rows of {W} lanes do not hold K and V of {Hkv} heads of "
+            f"{D} (that row is {kv_row_width(Hkv, D)} lanes)")
     P = int(pages.shape[1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -273,6 +339,20 @@ def paged_attention(
     lqp = _round_up(L, tq)
     qt = jnp.moveaxis(q, 2, 1)
     qt = jnp.pad(qt, ((0, 0), (0, 0), (0, lqp - L), (0, 0)))
+    # a head narrower than a 128-lane row meets the aligned slab of the
+    # arena's rows it lies in (_pa_kernel): its query sits at the head's
+    # offset in that slab with zeros beside it, and its output is read
+    # back from the offset of the head's V (not K's, where the V half of a
+    # row starts mid-slab: GPT-2 XL's lane 1,600)
+    Dp = _slab_width(D)
+    share = H // Hkv
+    if Dp != D:
+        # which D-wide piece of its slab a head's K (V) is, [1, H, 1, 1]
+        k_at, v_at = (np.asarray([_slab((first + h // share) * D, D)[1] // D
+                                  for h in range(H)]).reshape(1, H, 1, 1)
+                      for first in (0, Hkv))
+        qt = jnp.concatenate([jnp.where(k_at == i, qt, 0)
+                              for i in range(Dp // D)], axis=-1)
     pages = pages.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
     # pages the row actually occupies after this call's writes: the stream
@@ -298,18 +378,17 @@ def paged_attention(
 
     def kv_map(b, j, i, pages_ref, pos_ref, live_ref):
         # logical->physical through the prefetched table
-        return (pages_ref[b, _logical(b, j, i, pos_ref, live_ref)], 0, 0, 0)
+        return (pages_ref[b, _logical(b, j, i, pos_ref, live_ref)], 0, 0)
 
     def scale_map(b, j, i, pages_ref, pos_ref, live_ref):
         # scales are pre-gathered per row (below): indexed by LOGICAL page
         return (b, _logical(b, j, i, pos_ref, live_ref), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, H, tq, D), q_map),
-        pl.BlockSpec((1, Hkv, pt, D), kv_map),
-        pl.BlockSpec((1, Hkv, pt, D), kv_map),
+        pl.BlockSpec((1, H, tq, Dp), q_map),
+        pl.BlockSpec((1, pt, W), kv_map),
     ]
-    operands = [qt, k_pages, v_pages]
+    operands = [qt, kv_rows]
     if quantized:
         # a [N, H] arena cannot be blocked one page at a time (a (1, H)
         # block's second-minor dim is neither 8-aligned nor the array's),
@@ -330,18 +409,24 @@ def paged_attention(
         num_scalar_prefetch=3,  # pages, positions, live
         grid=(B, lqp // tq, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, tq, D), q_map),
+        out_specs=pl.BlockSpec((1, H, tq, Dp), q_map),
         scratch_shapes=[
-            pltpu.VMEM((H, tq, D), jnp.float32),       # acc
+            pltpu.VMEM((H, tq, Dp), jnp.float32),      # acc
             pltpu.VMEM((H, tq, _LANES), jnp.float32),  # m (row max)
             pltpu.VMEM((H, tq, _LANES), jnp.float32),  # l (row sum)
         ],
     )
     out = pl.pallas_call(
         functools.partial(_pa_kernel, page_tokens=pt, n_pages=P, scale=scale,
-                          quantized=quantized),
+                          kv_heads=Hkv, head_dim=D, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, lqp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, lqp, Dp), q.dtype),
         interpret=interpret,
     )(pages, positions, live, *operands)
-    return jnp.moveaxis(out[:, :, :L], 1, 2)
+    out = out[:, :, :L]
+    if Dp != D:
+        pieces = [out[..., i * D:(i + 1) * D] for i in range(Dp // D)]
+        out = functools.reduce(
+            lambda kept, i: jnp.where(v_at == i, pieces[i], kept),
+            range(1, Dp // D), pieces[0])
+    return jnp.moveaxis(out, 1, 2)

@@ -44,7 +44,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -470,37 +470,44 @@ def _kv_width(module) -> int:
     mla = getattr(module, "mla", None)
     if mla is not None:
         return int(mla.latent_width)
+    kv_heads, head_dim = _kv_head_shape(module)
+    return kv_heads * head_dim
+
+
+def _kv_head_shape(module) -> Tuple[int, int]:
+    """``(K/V heads, head size)`` of one layer's attention; ``(0, 0)`` when
+    the module doesn't expose the transformer geometry."""
     heads = getattr(module, "num_heads", None)
     embed = getattr(module, "embed_dim", None)
     if not heads or not embed:
-        return 0
-    kv_heads = getattr(module, "num_kv_heads", 0) or heads
-    return int(kv_heads) * int(getattr(module, "head_dim", 0)
-                               or int(embed) // int(heads))
+        return 0, 0
+    return (int(getattr(module, "num_kv_heads", 0) or heads),
+            int(getattr(module, "head_dim", 0) or int(embed) // int(heads)))
 
 
 def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
-    """HBM bytes ONE physical page occupies across every layer's K and V
-    arenas (a latent model's one arena a layer: ``page_tokens`` x the
-    latent's width, once) — the unit of the arena byte budget. int8 mode
-    adds the page's
-    per-head f32 scale rows (k_scale/v_scale, [kv_pages, H]) so the
+    """HBM bytes ONE physical page occupies across every layer's arena —
+    the unit of the arena byte budget: ``page_tokens`` rows of K‖V as the
+    arena stores them (``ops/paged_attention.kv_row_width``: zero lanes
+    past a narrow model's K and V count, the published models have none),
+    or of the latent's width under latent attention. int8 mode adds the
+    page's per-head f32 scale rows (k_scale/v_scale, [kv_pages, H]) so the
     capacity derivation charges quantization's real overhead. 0 when the
     module doesn't expose the transformer geometry."""
     import jax.numpy as jnp
 
+    from ..ops.paged_attention import kv_row_width
+
     depth = getattr(module, "depth", None)
-    width = _kv_width(module)
-    if not depth or not width:
+    kv_heads, head_dim = _kv_head_shape(module)
+    row = (_kv_width(module) if getattr(module, "mla", None) is not None
+           else kv_row_width(kv_heads, head_dim))
+    if not depth or not row:
         return 0
     if kv_quant == "int8":
-        kv_heads = (getattr(module, "num_kv_heads", 0)
-                    or getattr(module, "num_heads"))
-        return int(depth) * 2 * (int(page_tokens) * width * 1
-                                 + int(kv_heads) * 4)
+        return int(depth) * (int(page_tokens) * row + 2 * kv_heads * 4)
     itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
-    return (int(depth) * _kv_copies(module) * int(page_tokens) * width
-            * int(itemsize))
+    return int(depth) * int(page_tokens) * row * int(itemsize)
 
 
 def service_interval(dispatched: float, done: float,
@@ -3374,7 +3381,8 @@ class PagedBatchingDecoder(BatchingDecoder):
             if row.lease is None or len(row.lease.pages) < npg:
                 raise kvsnap.SnapshotError("row holds no page lease")
             layers = (kvsnap.gather_pages(self._slab.cache,
-                                          list(row.lease.pages[:npg]))
+                                          list(row.lease.pages[:npg]),
+                                          *_kv_head_shape(self.module))
                       if npg else [])
             snap = kvsnap.RequestSnapshot(
                 model=self.name, request_id=row.entry.request_id,

@@ -2,7 +2,9 @@
 
 The paged engine made a live request's serving state fully explicit — a
 page table (serving/kvpool.py lease) plus page contents in the arena
-(models/gpt.py ``k_pages``/``v_pages``) plus a handful of host scalars
+(models/gpt.py ``kv_rows``: token rows of K‖V, so a page's K and V are
+lane slices of its rows and a frame's token-major tensors come out of the
+arena as they lie) plus a handful of host scalars
 (prompt, emitted tokens, sampler key-split chain position). This module
 serializes that state into one versioned binary frame so a generation can
 be *moved*: across an engine fault (snapshot-before-reinit, replay after
@@ -144,32 +146,37 @@ def replay_keys(root: Sequence[int], emissions: int) -> np.ndarray:
 
 def paged_cache_layers(cache: dict) -> List[Tuple[str, dict]]:
     """The arena's attention blocks in layer order:
-    ``[("block_0", {"k_pages", "v_pages", "k_scale"?, "v_scale"?}), ...]``.
+    ``[("block_0", {"kv_rows", "k_scale"?, "v_scale"?}), ...]``.
     Raises :class:`SnapshotError` for a non-paged cache."""
     blocks = []
     for name in sorted((n for n in cache if n.startswith("block_")),
                        key=lambda n: int(n.split("_", 1)[1])):
         attn = cache[name].get("attn") if isinstance(cache[name], dict) else None
-        if not isinstance(attn, dict) or "k_pages" not in attn:
+        if not isinstance(attn, dict) or "kv_rows" not in attn:
             raise SnapshotError(f"cache {name!r} is not a paged attention "
-                                "arena (no k_pages)")
+                                "arena (no kv_rows)")
         blocks.append((name, attn))
     if not blocks:
         raise SnapshotError("cache holds no block_* attention arenas")
     return blocks
 
 
-def gather_pages(cache: dict, pages: Sequence[int]) -> List[LayerSnapshot]:
+def gather_pages(cache: dict, pages: Sequence[int], kv_heads: int,
+                 head_dim: int) -> List[LayerSnapshot]:
     """Read ``pages`` (physical page ids) out of every layer's arena onto
     the host. The indexed read serializes after every dispatched program
     that wrote the arena (value dependency), so the bytes are the true
-    state through the last consumed emission."""
+    state through the last consumed emission. ``kv_heads`` x ``head_dim``
+    is the model's K (or V) of one token: an arena row's width alone does
+    not say where its zero lanes start."""
+    from ..ops.paged_attention import unpack_kv_rows
+
     idx = np.asarray(list(pages), dtype=np.int32)
     out: List[LayerSnapshot] = []
     for name, attn in paged_cache_layers(cache):
-        # the arena is head-major [N, H, pt, D]; the frame is token-major
-        k = np.asarray(attn["k_pages"][idx]).swapaxes(1, 2)
-        v = np.asarray(attn["v_pages"][idx]).swapaxes(1, 2)
+        # [n, pt, W] rows -> the frame's [n, pt, Hkv, D] K and V
+        k, v = unpack_kv_rows(np.asarray(attn["kv_rows"][idx]), kv_heads,
+                              head_dim)
         ks = vs = None
         if "k_scale" in attn:
             ks = np.asarray(attn["k_scale"][idx], dtype=np.float32)
@@ -183,6 +190,8 @@ def scatter_pages(cache: dict, pages: Sequence[int],
     """Write snapshot pages into fresh physical ``pages`` of ``cache``;
     returns the updated cache tree (functional ``.at[].set`` — the caller
     swaps it into the slab)."""
+    from ..ops.paged_attention import pack_kv_rows
+
     idx = np.asarray(list(pages), dtype=np.int32)
     blocks = paged_cache_layers(cache)
     if len(blocks) != len(layers):
@@ -191,10 +200,14 @@ def scatter_pages(cache: dict, pages: Sequence[int],
     new = {k: (dict(v) if isinstance(v, dict) else v) for k, v in cache.items()}
     for (name, attn), layer in zip(blocks, layers):
         a = dict(attn)
-        a["k_pages"] = attn["k_pages"].at[idx].set(
-            layer.k.swapaxes(1, 2).astype(attn["k_pages"].dtype))
-        a["v_pages"] = attn["v_pages"].at[idx].set(
-            layer.v.swapaxes(1, 2).astype(attn["v_pages"].dtype))
+        rows = pack_kv_rows(layer.k, layer.v)
+        if rows.shape[1:] != attn["kv_rows"].shape[1:]:
+            raise SnapshotError(
+                f"snapshot pages of {layer.k.shape[1:]} make rows of "
+                f"{rows.shape[1:]}, the arena's are "
+                f"{attn['kv_rows'].shape[1:]}")
+        a["kv_rows"] = attn["kv_rows"].at[idx].set(
+            rows.astype(attn["kv_rows"].dtype))
         if "k_scale" in attn:
             if layer.k_scale is None or layer.v_scale is None:
                 raise SnapshotError(

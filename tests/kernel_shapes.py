@@ -20,7 +20,7 @@ def kernel_cases():
     from kubeml_tpu.ops.grouped_matmul import grouped_matmul
     from kubeml_tpu.ops.int8_matmul import int8_matmul
     from kubeml_tpu.ops.mla_attention import mla_attn
-    from kubeml_tpu.ops.paged_attention import paged_attention
+    from kubeml_tpu.ops.paged_attention import kv_row_width, paged_attention
     from kubeml_tpu.ops.ssm import ssm_update
 
     cases = {}
@@ -34,27 +34,38 @@ def kernel_cases():
                                   ("f32", jnp.float32, jnp.float32),
                                   ("int8", jnp.bfloat16, jnp.int8)):
             q = _sds((B, L, H, D), q_dt)
-            arena = _sds((PAGES, H, PT, D), kv_dt)
+            # token rows of K‖V: 12 heads of 64 twice over, 1,536 lanes
+            arena = _sds((PAGES, PT, kv_row_width(H, D)), kv_dt)
             if name == "int8":
-                fn = lambda q, k, v, t, p, ks, vs: paged_attention(
-                    q, k, v, t, p, interpret=False, k_scale=ks, v_scale=vs)
-                args = (q, arena, arena, table, pos, scales, scales)
+                fn = lambda q, kv, t, p, ks, vs: paged_attention(
+                    q, kv, t, p, interpret=False, k_scale=ks, v_scale=vs)
+                args = (q, arena, table, pos, scales, scales)
             else:
-                fn = lambda q, k, v, t, p: paged_attention(
-                    q, k, v, t, p, interpret=False)
-                args = (q, arena, arena, table, pos)
+                fn = lambda q, kv, t, p: paged_attention(
+                    q, kv, t, p, interpret=False)
+                args = (q, arena, table, pos)
             cases[f"paged_attention-{name}-L{L}"] = (fn, args)
+    # GPT-2 XL's odd head count, 25 of 64 on 4 rows (rows of 3,200 lanes: a
+    # head's V starts at lane 1,600 + 64 h), a decode step and one tile of
+    # a one-row admit; gpt2-large's 20 of 64 on 8 rows the same
+    for tag, heads, slab, pool in (("xl", 25, 4, 257), ("large", 20, 8, 513)):
+        for L, nrows in ((1, slab), (128, 1)):
+            cases[f"paged_attention-gpt2-{tag}-bf16-L{L}"] = (
+                lambda q, kv, t, p: paged_attention(q, kv, t, p,
+                                                    interpret=False),
+                (_sds((nrows, L, heads, D), jnp.bfloat16),
+                 _sds((pool, PT, kv_row_width(heads, D)), jnp.bfloat16),
+                 _sds((nrows, TABLE), jnp.int32), _sds((nrows,), jnp.int32)))
     # Falcon-H1-34B's published shapes: 20 query heads on 4 K/V heads of
     # 128 over a 32-row slab (a decode step and one prefill tile), and the
     # mixer's state update, 32 heads of [256, 128] float32 in 2 groups
     rows, hq, hkv, d = 32, 20, 4, 128
     for L, width in ((1, 32), (128, 8)):
         cases[f"paged_attention-gqa-bf16-L{L}"] = (
-            lambda q, k, v, t, p: paged_attention(q, k, v, t, p,
-                                                  interpret=False),
+            lambda q, kv, t, p: paged_attention(q, kv, t, p, kv_heads=4,
+                                                interpret=False),
             (_sds((rows, L, hq, d), jnp.bfloat16),
-             _sds((2049, hkv, PT, d), jnp.bfloat16),
-             _sds((2049, hkv, PT, d), jnp.bfloat16),
+             _sds((2049, PT, kv_row_width(hkv, d)), jnp.bfloat16),
              _sds((rows, width), jnp.int32), _sds((rows,), jnp.int32)))
     cases["ssm_update-falcon-h1-34b"] = (
         lambda s, x, dt, a, b, c: ssm_update(s, x, dt, a, b, c,

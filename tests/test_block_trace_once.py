@@ -199,7 +199,7 @@ def paged_prefill_and_step(m, vs, recurrent, exit_layer=None):
     paths = [jax.tree_util.keystr(p) for p, _ in
              jax.tree_util.tree_leaves_with_path(cache)]
     for i in range(m.depth):
-        assert f"['block_{i}']['attn']['k_pages']" in paths
+        assert f"['block_{i}']['attn']['kv_rows']" in paths
         if recurrent:
             assert f"['block_{i}']['mixer']['ssm_state']" in paths
             assert f"['block_{i}']['mixer']['conv_tail']" in paths
@@ -240,7 +240,7 @@ def test_exit_layer_leaves_the_later_layers_alone():
         for a, b in zip(jax.tree.leaves(after[name]),
                         jax.tree.leaves(before[name])):
             assert np.array_equal(a, b)
-    assert float(jnp.abs(after["block_1"]["attn"]["k_pages"]).max()) > 0
+    assert float(jnp.abs(after["block_1"]["attn"]["kv_rows"]).max()) > 0
 
 
 def test_dense_cache_prefill_and_step():

@@ -26,7 +26,8 @@ from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
 from kubeml_tpu.models.generation import (generate, has_recurrent_state,  # noqa: E402
                                           init_paged_cache)
 from kubeml_tpu.ops import ssm  # noqa: E402
-from kubeml_tpu.ops.paged_attention import paged_attention  # noqa: E402
+from kubeml_tpu.ops.paged_attention import (pack_kv_rows,  # noqa: E402
+                                            paged_attention)
 from kubeml_tpu.serving.batcher import (BatchingDecoder,  # noqa: E402
                                         PagedBatchingDecoder,
                                         RecurrentStateUnsupported,
@@ -199,29 +200,28 @@ def test_ssm_update_kernel_matches_jnp(H, G, P, N):
 @pytest.mark.parametrize("H,Hkv,D,L", [(4, 4, 16, 1), (4, 2, 16, 1),
                                        (20, 4, 128, 1), (4, 1, 32, 9)])
 def test_gqa_page_walk_kernel(H, Hkv, D, L):
-    """The kernel over an arena of Hkv heads against (a) the gather oracle
-    and (b) the same kernel over the arena with each K/V head repeated for
-    its query heads: bit-identical, since head h does the same arithmetic
-    on the same numbers. With Hkv == H the kernel is the one it was."""
+    """The kernel over rows of Hkv heads against (a) the gather oracle and
+    (b) the same kernel over rows with each K/V head repeated for its query
+    heads: bit-identical, since head h does the same arithmetic on the same
+    numbers. With Hkv == H the kernel is the one it was."""
     B, pt, P, N = 3, 8, 4, 13
     k = jax.random.split(jax.random.PRNGKey(H * 7 + Hkv), 4)
     q = jax.random.normal(k[0], (B, L, H, D))
-    ka = jax.random.normal(k[1], (N, Hkv, pt, D))
-    va = jax.random.normal(k[2], (N, Hkv, pt, D))
+    ka = jax.random.normal(k[1], (N, pt, Hkv, D))
+    va = jax.random.normal(k[2], (N, pt, Hkv, D))
     pages = jax.random.permutation(k[3], jnp.arange(1, N))[:B * P].reshape(
         B, P).astype(jnp.int32)
     pos = jnp.asarray([0, 11, 22], jnp.int32)
-    out = paged_attention(q, ka, va, pages, pos, interpret=True)
+    out = paged_attention(q, pack_kv_rows(ka, va), pages, pos,
+                          kv_heads=Hkv, interpret=True)
     share = H // Hkv
-    wide = paged_attention(q, jnp.repeat(ka, share, axis=1),
-                           jnp.repeat(va, share, axis=1), pages, pos,
-                           interpret=True)
+    wide = paged_attention(q, pack_kv_rows(jnp.repeat(ka, share, axis=2),
+                                           jnp.repeat(va, share, axis=2)),
+                           pages, pos, interpret=True)
     assert bool((out == wide).all())
     # gather oracle
-    kg = jnp.repeat(ka[pages].transpose(0, 1, 3, 2, 4).reshape(
-        B, P * pt, Hkv, D), share, axis=2)
-    vg = jnp.repeat(va[pages].transpose(0, 1, 3, 2, 4).reshape(
-        B, P * pt, Hkv, D), share, axis=2)
+    kg = jnp.repeat(ka[pages].reshape(B, P * pt, Hkv, D), share, axis=2)
+    vg = jnp.repeat(va[pages].reshape(B, P * pt, Hkv, D), share, axis=2)
     qp = pos[:, None] + jnp.arange(L)
     mask = jnp.arange(P * pt)[None, None, :] <= qp[:, :, None]
     s = jnp.einsum("blhd,bshd->bhls", q, kg) / np.sqrt(D)

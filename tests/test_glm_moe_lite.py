@@ -276,7 +276,7 @@ def test_a_latent_page_is_576_values_a_token_once(model):
               if getattr(path[-1], "key", "") == "latent_pages"]
     assert [a.shape for a in arenas] == [(33, 8, 24)] * 3
     assert sum(a.nbytes for a in arenas) == 33 * _kv_page_bytes(m, 8)
-    assert not any(getattr(path[-1], "key", "") in ("k_pages", "v_pages")
+    assert not any(getattr(path[-1], "key", "") == "kv_rows"
                    for path, _ in jax.tree_util.tree_leaves_with_path(cache))
 
 

@@ -30,10 +30,13 @@ from kubeml_tpu.api.types import GenerateRequest
 from kubeml_tpu.models.generation import generate, init_paged_cache
 from kubeml_tpu.models.gpt import CausalTransformer
 from kubeml_tpu.ops.attention import dot_product_attention
-from kubeml_tpu.ops.paged_attention import (paged_attention,
+from kubeml_tpu.ops.paged_attention import (kv_row_width, pack_kv_rows,
+                                            paged_attention,
                                             resolve_kv_quant,
                                             resolve_paged_attn)
 from kubeml_tpu.serving.batcher import PagedBatchingDecoder, _Row
+
+from head_major_paged_attention import head_major_paged_attention
 
 VOCAB = 101
 
@@ -45,12 +48,14 @@ def tiny(pos="learned", max_len=64):
 
 def gather_reference(q, k_pages, v_pages, pages, positions):
     """The exact fallback read from models/gpt.py: gather the table into a
-    contiguous block, attend under the positional causal mask."""
+    contiguous block, attend under the positional causal mask (each K/V
+    head once per query head that shares it)."""
     B, L = q.shape[:2]
     P, pt = pages.shape[1], k_pages.shape[1]
     H, D = k_pages.shape[2], k_pages.shape[3]
-    kg = k_pages[pages].reshape(B, P * pt, H, D)
-    vg = v_pages[pages].reshape(B, P * pt, H, D)
+    share = q.shape[2] // H
+    kg = jnp.repeat(k_pages[pages].reshape(B, P * pt, H, D), share, axis=2)
+    vg = jnp.repeat(v_pages[pages].reshape(B, P * pt, H, D), share, axis=2)
     k_pos = jnp.arange(P * pt)[None, None, None, :]
     pos_full = positions[:, None] + jnp.arange(L)
     mask = k_pos <= pos_full[:, None, :, None]
@@ -58,11 +63,34 @@ def gather_reference(q, k_pages, v_pages, pages, positions):
 
 
 def paged(q, k_tok, v_tok, pages, positions, **kw):
-    """The op-level cases build their arenas token-major ``[N, pt, H, D]``
-    (a page reads as rows of tokens, like the gather reference above); the
-    device arena the kernel takes is head-major ``[N, H, pt, D]``."""
-    return paged_attention(q, jnp.swapaxes(k_tok, 1, 2),
-                           jnp.swapaxes(v_tok, 1, 2), pages, positions, **kw)
+    """The op-level cases build K and V token-major ``[N, pt, Hkv, D]`` (a
+    page reads as rows of tokens, like the gather reference above); the
+    device arena the kernel takes is their rows of K‖V, ``[N, pt, W]``."""
+    return paged_attention(q, pack_kv_rows(k_tok, v_tok), pages, positions,
+                           kv_heads=k_tok.shape[2], **kw)
+
+
+def assert_equals_head_major(out, q, k_tok, v_tok, pages, positions, **kw):
+    """``out`` against the same arrays laid out the old way, two arenas
+    ``[N, Hkv, pt, D]``, through the kernel as it was. Bit for bit where a
+    head is whole 128-lane rows: the same arithmetic on the same numbers.
+    A narrower head is contracted over the 128 lanes it lies in, zeros in
+    the query beside it: the same products, summed in another order by the
+    CPU's dot, so equal to a few units in the last place."""
+    old = head_major_paged_attention(q, jnp.swapaxes(k_tok, 1, 2),
+                                     jnp.swapaxes(v_tok, 1, 2), pages,
+                                     positions, **kw)
+    if q.shape[-1] % 128 == 0:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(old))
+    else:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(old),
+                                   atol=1e-6, rtol=1e-6)
+
+
+# (query heads, K/V heads, head size) beside the toy 2 / 2 / 16, whose row
+# is half zero lanes: GPT-2 XL's odd count (a head's V starts at lane
+# 1,600 + 64 h of a 3,200-lane row), and Falcon-H1's 20 on 4 of 128
+HEADS = {"toy": (2, 2, 16), "xl": (25, 25, 64), "falcon-h1": (20, 4, 128)}
 
 
 # --- op-level kernel parity (interpret mode) ---
@@ -80,23 +108,29 @@ def test_resolve_impl_values():
 
 
 @pytest.mark.kernel
-@pytest.mark.parametrize("L,positions", [
-    (1, [5, 0, 17]),        # per-token decode step at mixed depths
-    (4, [3, 0, 12]),        # spec verify window (k+1 = 4)
-    (8, [0, 8, 16]),        # suffix prefill, incl. page-aligned bases
+@pytest.mark.parametrize("heads,L,positions", [
+    ("toy", 1, [5, 0, 17]),        # per-token decode step at mixed depths
+    ("toy", 4, [3, 0, 12]),        # spec verify window (k+1 = 4)
+    ("toy", 8, [0, 8, 16]),        # suffix prefill, incl. page-aligned bases
+    ("xl", 1, [5, 0, 17]),
+    ("xl", 8, [0, 8, 16]),         # suffix prefill through the lane slices
+    ("falcon-h1", 1, [5, 0, 17]),
+    ("falcon-h1", 8, [3, 8, 16]),
 ])
-def test_kernel_logit_parity(L, positions):
+def test_kernel_logit_parity(heads, L, positions):
     rng = np.random.default_rng(0)
-    B, H, D, pt, P, N = 3, 2, 16, 4, 6, 20
-    k_pages = jnp.asarray(rng.normal(size=(N, pt, H, D)), jnp.float32)
-    v_pages = jnp.asarray(rng.normal(size=(N, pt, H, D)), jnp.float32)
+    H, Hkv, D = HEADS[heads]
+    B, pt, P, N = 3, 4, 6, 20
+    k_pages = jnp.asarray(rng.normal(size=(N, pt, Hkv, D)), jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=(N, pt, Hkv, D)), jnp.float32)
     pages = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
     pos = jnp.asarray(positions, jnp.int32)
     out = paged(q, k_pages, v_pages, pages, pos)
     ref = gather_reference(q, k_pages, v_pages, pages, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-6, rtol=2e-6)
+                               atol=2e-6 * D / 16, rtol=2e-6 * D / 16)
+    assert_equals_head_major(out, q, k_pages, v_pages, pages, pos)
 
 
 @pytest.mark.kernel
@@ -429,15 +463,17 @@ def test_resolve_kv_quant_values():
     (4, [3, 0, 12]),        # spec verify window (k+1 = 4)
     (8, [0, 8, 16]),        # suffix prefill, incl. page-aligned bases
 ])
-def test_kernel_int8_parity_and_bounded_divergence(L, positions):
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_kernel_int8_parity_and_bounded_divergence(heads, L, positions):
     """The int8 kernel path against two references: the DEQUANTIZED gather
     (same storage bytes, same q*s/127 reconstruction — must match at
     f32-accumulation tolerance, the storage-format parity oracle) and the
     unquantized f32 gather (divergence bounded by the int8 step size)."""
     rng = np.random.default_rng(10)
-    B, H, D, pt, P, N = 3, 2, 16, 4, 6, 20
-    kf = rng.normal(size=(N, pt, H, D)).astype(np.float32)
-    vf = rng.normal(size=(N, pt, H, D)).astype(np.float32)
+    H, Hkv, D = HEADS[heads]
+    B, pt, P, N = 3, 4, 6, 20
+    kf = rng.normal(size=(N, pt, Hkv, D)).astype(np.float32)
+    vf = rng.normal(size=(N, pt, Hkv, D)).astype(np.float32)
     kq, ks = quantize_pages(kf)
     vq, vs = quantize_pages(vf)
     pages = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
@@ -449,6 +485,8 @@ def test_kernel_int8_parity_and_bounded_divergence(L, positions):
                                pages, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(deq_ref),
                                atol=2e-5, rtol=2e-5)
+    assert_equals_head_major(out, q, kq, vq, pages, pos, k_scale=ks,
+                             v_scale=vs)
     f32_ref = gather_reference(q, jnp.asarray(kf), jnp.asarray(vf),
                                pages, pos)
     # bounded divergence: attention outputs are convex combinations of V
@@ -521,7 +559,8 @@ def test_module_int8_kernel_matches_gather_oracle():
         cache = init_paged_cache(mod, variables, 1, tp)
         if kvq == "int8":
             arena = cache["block_0"]["attn"]
-            assert arena["k_pages"].dtype == jnp.int8
+            assert arena["kv_rows"].dtype == jnp.int8
+            assert arena["kv_rows"].shape == (npages, pt, 128)
             assert arena["k_scale"].shape == (npages, 2)
         logits, vs = mod.apply(
             {**variables, "cache": cache}, prompt, decode=True,

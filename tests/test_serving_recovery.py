@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from kubeml_tpu.api.errors import (EngineFaultError, KubeMLError,
                                    OverloadedError)
@@ -150,6 +151,98 @@ def test_kms1_roundtrip(kv_quant, compress):
                                           np.asarray(b.k_scale))
             np.testing.assert_array_equal(np.asarray(a.v_scale),
                                           np.asarray(b.v_scale))
+
+
+# the frames the PARENT of PR 33 made (head-major arenas ``[N, Hkv, pt, D]``,
+# ``k_pages[idx].swapaxes(1, 2)``) of ``arena_content``'s pages 4, 1, 5:
+# (bytes, sha256), written down from a run of that commit's kvsnap.py
+PARENT_FRAMES = {
+    "none": (6667, "8f35ab79a81ad367d0ab2f156a21174696db40a5b2ea33a9efdc6956"
+                   "f7dedce4"),
+    "int8": (2147, "75cdb3876514ae5daa488a47a4fb1b00b4e0f852eeaec21f7fb7e452"
+                   "f72c33d3"),
+}
+
+
+def arena_content(kv_quant):
+    """Two layers of six pages of 4 tokens, 2 K/V heads of 16, token-major
+    (the recipe the parent's frames were made from: do not reorder)."""
+    rng = np.random.default_rng(33)
+    layers = []
+    for _ in range(2):
+        shape = (6, 4, 2, 16)
+        if kv_quant == "int8":
+            layers.append(dict(
+                k=rng.integers(-127, 128, shape).astype(np.int8),
+                v=rng.integers(-127, 128, shape).astype(np.int8),
+                ks=rng.random((6, 2)).astype(np.float32),
+                vs=rng.random((6, 2)).astype(np.float32)))
+        else:
+            layers.append(dict(k=rng.normal(size=shape).astype(np.float32),
+                               v=rng.normal(size=shape).astype(np.float32)))
+    return layers
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_kms1_frame_bytes_survive_the_row_arena(kv_quant):
+    """A frame gathered from the arena of token rows (K‖V, here 64 lanes of
+    K and V and 64 of zeros) is byte for byte the frame the parent gathered
+    from its head-major arenas, and the frame built from the token-major
+    arrays directly; it restores into a fresh arena's rows."""
+    import hashlib
+
+    from kubeml_tpu.ops.paged_attention import pack_kv_rows
+
+    content = arena_content(kv_quant)
+
+    def arena(fill):
+        cache = {}
+        for i, l in enumerate(content):
+            rows = pack_kv_rows(l["k"], l["v"])
+            attn = {"kv_rows": rows if fill else jnp.zeros_like(rows)}
+            if kv_quant == "int8":
+                attn["k_scale"] = jnp.asarray(l["ks"]) * fill
+                attn["v_scale"] = jnp.asarray(l["vs"]) * fill
+            cache[f"block_{i}"] = {"attn": attn}
+        return cache
+
+    def snap(layers):
+        return kvsnap.RequestSnapshot(
+            model="tinymodel", request_id="req-33", page_tokens=4,
+            kv_quant=kv_quant, spec="off", prompt=list(range(1, 10)),
+            out=[7, 8, 9], max_new=8, temp=0.0, topk=0, eos=-1, key=(1, 2),
+            layers=layers)
+
+    pages = [4, 1, 5]
+    cache = arena(1)
+    assert cache["block_0"]["attn"]["kv_rows"].shape == (6, 4, 128)
+    frame = kvsnap.encode_snapshot(snap(kvsnap.gather_pages(cache, pages,
+                                                            2, 16)))
+    assert (len(frame), hashlib.sha256(frame).hexdigest()) == \
+        PARENT_FRAMES[kv_quant]
+    direct = kvsnap.encode_snapshot(snap([
+        kvsnap.LayerSnapshot(
+            name=f"block_{i}", k=l["k"][pages], v=l["v"][pages],
+            k_scale=l["ks"][pages] if kv_quant == "int8" else None,
+            v_scale=l["vs"][pages] if kv_quant == "int8" else None)
+        for i, l in enumerate(content)]))
+    assert frame == direct
+    # restore: other physical pages of an empty arena hold the same rows
+    back = kvsnap.decode_snapshot(frame)
+    fresh = kvsnap.scatter_pages(arena(0), [2, 3, 0], back.layers)
+    for name, attn in cache.items():
+        got = fresh[name]["attn"]
+        for key, value in attn["attn"].items():
+            np.testing.assert_array_equal(
+                np.asarray(got[key])[[2, 3, 0]], np.asarray(value)[pages])
+        assert not np.asarray(got["kv_rows"])[[1, 4, 5]].any()
+    # a frame of other heads does not fit these rows
+    wide = [kvsnap.LayerSnapshot(name=l.name, k=np.repeat(l.k, 4, axis=2),
+                                 v=np.repeat(l.v, 4, axis=2),
+                                 k_scale=l.k_scale, v_scale=l.v_scale)
+            for l in back.layers]
+    with pytest.raises(kvsnap.SnapshotError):
+        kvsnap.scatter_pages(arena(0), [2, 3, 0], wide)
 
 
 def test_kms1_rejects_corrupt_frames():
