@@ -12,7 +12,8 @@ The control is the same reading with the reference itself computed in the
 configuration's ``lower_precision_control`` put in the program's place: at
 each position, the gap of the token the lower precision puts first. Limits
 sit between the two (``limits/<cell>.json`` gives the readings they were set
-from)."""
+from). A limits file names the readings its cell is held to; the mean gap
+(``logit_gap_mean``) is the one that decides where experts route."""
 
 from __future__ import annotations
 
@@ -78,15 +79,18 @@ def gaps(cell: spec.Cell, weights: dict, prompts: dict, sampled: list,
 
 
 def compare(readings: dict, limits: dict) -> tuple:
-    """Each number beside its limit. Returns (correct, lines)."""
-    ok, lines = True, []
+    """Each number the cell's limits file names, beside its limit. Returns
+    (correct, lines for standard error, {name: {"value", "limit"}} for the
+    result's line)."""
+    ok, lines, compared = True, [], {}
     for name, limit in limits.items():
         value = readings.get(name)
         good = value is not None and value <= limit
         ok = ok and good
         lines.append(f"check {name}: {value!r} (limit {limit!r}) "
                      f"{'ok' if good else 'NOT OK'}")
-    return ok, lines
+        compared[name] = {"value": value, "limit": limit}
+    return ok, lines, compared
 
 
 def serving_readings(served_gaps: list, win, sampled: list) -> dict:
@@ -96,6 +100,12 @@ def serving_readings(served_gaps: list, win, sampled: list) -> dict:
         # widest gap, over the sampled served tokens, between the
         # reference's best logit and its logit for the token served
         "logit_gap_max": max(served_gaps) if served_gaps else None,
+        # the same gap on average over those tokens. Where experts route,
+        # the widest gap is a flipped expert choice in the sound program
+        # and in the lower-precision control alike, and the mean separates
+        # them ten to one (limits/glm-4.7-flash.rag.json)
+        "logit_gap_mean": (sum(served_gaps) / len(served_gaps)
+                           if served_gaps else None),
         # exact: every sampled request got as many tokens as it asked for
         "short_answers": sum(len(r["tokens"]) != r["max_new"]
                              for r in sampled) if sampled else None,

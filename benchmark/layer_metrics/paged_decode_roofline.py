@@ -15,16 +15,23 @@ from ..costs import paged_attention
 from ._programs import step_executions
 
 
-def _live_depth(records: list, t: float) -> float:
-    """Tokens in the caches of the requests decoding at window time ``t``."""
-    total = 0.0
+def live(records: list, t: float) -> tuple:
+    """(rows, tokens in their caches) of the requests decoding at window
+    time ``t``."""
+    rows = depth = 0.0
     for x in records:
         if x["error"] or x["first"] is None or x["last"] <= x["first"]:
             continue
         if x["first"] <= t <= x["last"]:
             done = (t - x["first"]) / (x["last"] - x["first"])
-            total += x["prompt_tokens"] + done * len(x["tokens"])
-    return total
+            rows += 1.0
+            depth += x["prompt_tokens"] + done * len(x["tokens"])
+    return rows, depth
+
+
+def _live_depth(records: list, t: float) -> float:
+    """Tokens in the caches of the requests decoding at window time ``t``."""
+    return live(records, t)[1]
 
 
 def read(r):

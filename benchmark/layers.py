@@ -50,9 +50,6 @@ def breakdown(win, trace: reduce.Trace) -> dict:
     if plane is None:
         return {"device_ops": [], "idle_gaps": []}
     rows = trace.rows(plane, reduce.OPS_LINE)
-    t0 = min(r[1] for r in rows)
-    t1 = max(r[1] + r[2] for r in rows)
-    gaps = reduce.idle_gaps(rows, (t0, t1))
     return {"device_ops": reduce.top(rows, 10),
-            "idle_gaps": reduce.name_gaps(gaps, win.spans,
+            "idle_gaps": reduce.name_gaps(reduce.idle_gaps(rows), win.spans,
                                           trace.wall_zero)[:10]}

@@ -7,7 +7,13 @@ The plan names the controller, the model, the requests in sending order, and
 the wall-clock time at which the window opens. Every request goes through the
 controller's streaming ``/generate`` as a user's would; times are taken here,
 at the client: a request is timed from when it was *due*, and how late it was
-sent is reported beside it."""
+sent is reported beside it.
+
+The clock is the wall clock as it stood when this process started, advanced
+by the monotonic clock: a step of the machine's wall clock inside the window
+(a fresh virtual machine's gets corrected now and then) must not read as a
+second of waiting. What the wall clock moved against it, and every send more
+than 50 ms late, go to standard error."""
 
 from __future__ import annotations
 
@@ -19,8 +25,19 @@ import time
 from urllib.parse import urlparse
 
 
+def anchored_clock():
+    """``now()``: the wall clock at this call, advanced monotonically; and
+    ``moved()``: how far the wall clock has since moved against it, s."""
+    wall0, mono0 = time.time(), time.monotonic()
+
+    def now() -> float:
+        return wall0 + (time.monotonic() - mono0)
+
+    return now, lambda: time.time() - now()
+
+
 def _one(url, model_id: str, req: dict, t_open: float, due: float,
-         timeout: float, seconds: float) -> dict:
+         timeout: float, seconds: float, clock) -> dict:
     """Send one request and read its stream to the end. Times are seconds
     from the window's opening; ``in_window`` counts the tokens that arrived
     before it closed."""
@@ -33,7 +50,7 @@ def _one(url, model_id: str, req: dict, t_open: float, due: float,
            "last": None, "tokens": [], "in_window": 0, "error": None}
     conn = http.client.HTTPConnection(url.hostname, url.port, timeout=timeout)
     try:
-        rec["sent"] = time.time() - t_open
+        rec["sent"] = clock() - t_open
         conn.request("POST", "/generate", body=body,
                      headers={"Content-Type": "application/json",
                               "Connection": "close"})
@@ -50,7 +67,7 @@ def _one(url, model_id: str, req: dict, t_open: float, due: float,
             if not line:
                 continue
             item = json.loads(line)
-            now = time.time() - t_open
+            now = clock() - t_open
             if "error" in item:
                 rec["error"] = str(item["error"])[:300]
                 return rec
@@ -82,24 +99,32 @@ def run(plan: dict) -> list:
     timeout = float(plan["request_timeout"])
     reqs = plan["requests"]
     records, lock = [], threading.Lock()
+    clock, moved = anchored_clock()
 
     def send(req, due):
-        rec = _one(url, plan["model_id"], req, t_open, due, timeout, seconds)
+        rec = _one(url, plan["model_id"], req, t_open, due, timeout, seconds,
+                   clock)
         with lock:
             records.append(rec)
 
     threads = []
-    delay = t_open - time.time()
+    delay = t_open - clock()
     if delay > 0:
         time.sleep(delay)
     if plan["kind"] == "open_loop":
         for req in reqs:
-            delay = t_open + req["due_s"] - time.time()
+            delay = t_open + req["due_s"] - clock()
             if delay > 0:
                 time.sleep(delay)
+            woke = clock() - t_open
             t = threading.Thread(target=send, args=(req, req["due_s"]))
             t.start()
             threads.append(t)
+            if woke - req["due_s"] > 0.05:
+                print(f"[loadgen] request {req['id']} due at "
+                      f"{req['due_s']:.3f} s: woke {woke:.3f}, thread "
+                      f"started {clock() - t_open:.3f}", file=sys.stderr,
+                      flush=True)
     else:
         nxt = iter(reqs)
 
@@ -107,7 +132,7 @@ def run(plan: dict) -> list:
             while True:
                 with lock:
                     req = next(nxt, None)
-                now = time.time() - t_open
+                now = clock() - t_open
                 if req is None or now >= seconds:
                     return
                 send(req, now)
@@ -118,7 +143,11 @@ def run(plan: dict) -> list:
             t.start()
     deadline = t_open + seconds + float(plan["drain_seconds"])
     for t in threads:
-        t.join(max(0.0, deadline - time.time()) + timeout)
+        t.join(max(0.0, deadline - clock()) + timeout)
+    if abs(moved()) > 0.005:
+        print(f"[loadgen] the wall clock moved {1000.0 * moved():.1f} ms "
+              f"against the monotonic clock since the generator started",
+              file=sys.stderr, flush=True)
     with lock:
         return sorted(records, key=lambda r: r["id"])
 

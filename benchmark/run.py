@@ -8,7 +8,9 @@ window measures for ``--seconds``; then the program is freed and the plain
 reference decides ``correct``. The last line of standard output is one JSON
 object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
 end-to-end metrics with ``--trace 0``, its per-layer metrics with
-``--trace 1``) and ``device`` (plus ``breakdown`` in a traced run).
+``--trace 1``), ``device`` (plus ``breakdown`` in a traced run) and last
+``check``, each number compared beside its limit, which are also the last
+lines of standard error.
 
 It runs on the machine it is started on and refuses anything but a TPU whose
 kind is in ``peaks.json``: no number of a CPU run is ever printed under a
@@ -84,6 +86,11 @@ def _run(args, cell, device, peaks, driver, units, system) -> int:
     failed = sum(bool(r["error"]) for r in records)
     for r in [r for r in records if r["error"]][:5]:
         serving.log(f"request {r['id']} failed: {r['error']}")
+    late = max(((r["sent"] - r["due"], r["id"]) for r in records
+                if r["sent"] is not None), default=None)
+    if late is not None:
+        serving.log(f"the generator's latest send: {1000.0 * late[0]:.1f} ms "
+                    f"after it was due (request {late[1]})")
 
     # correctness, after the program's state is freed
     t0 = time.time()
@@ -93,11 +100,11 @@ def _run(args, cell, device, peaks, driver, units, system) -> int:
     got = check.gaps(cell, weights, prompts, sampled)
     del weights
     readings = check.serving_readings(got["served"], win, sampled)
-    correct, lines = check.compare(readings, check.limits_for(cell.name))
-    for line in lines:
-        print(line)
-    print(f"check: {len(got['served'])} served tokens of {len(sampled)} "
-          f"requests against the reference in {time.time() - t0:.1f} s")
+    correct, lines, compared = check.compare(readings,
+                                             check.limits_for(cell.name))
+    serving.log(f"check: {len(got['served'])} served tokens of "
+                f"{len(sampled)} requests against the reference in "
+                f"{time.time() - t0:.1f} s")
 
     device["memory_peak_bytes"] = win.memory_peak_bytes
     result = {"correct": bool(correct), "attempted": attempted,
@@ -119,7 +126,12 @@ def _run(args, cell, device, peaks, driver, units, system) -> int:
     result["metrics"] = {k: {"value": v, "unit": units[k]}
                          for k, v in values.items()}
     result["device"] = device
+    # each number compared beside its limit: last in the result's line, and
+    # the last lines of standard error
+    result["check"] = compared
     print(json.dumps(result), flush=True)
+    for line in lines:
+        print(line, file=sys.stderr, flush=True)
     return 0
 
 

@@ -46,6 +46,89 @@ def test_union_gaps_and_names_on_rows():
     assert reduce.family("%attn.394 custom-call") == "%attn custom-call"
 
 
+def _name_gaps_by_scan(gaps, spans, wall_zero,
+                       default="host, no span open"):
+    """``reduce.name_gaps`` as it stood until PR 36: every span scanned for
+    every gap. The reference the sweep is held to."""
+    named = {}
+    for start, dur in gaps:
+        name = default
+        if wall_zero is not None:
+            mid = wall_zero + start + 0.5 * dur
+            cover = [s for s in spans
+                     if s["start"] <= mid <= s["start"] + s["duration"]]
+            if cover:
+                name = min(cover, key=lambda s: s["duration"])["name"]
+        named[name] = named.get(name, 0.0) + dur
+    return [[k, v] for k, v in sorted(named.items(), key=lambda kv: -kv[1])]
+
+
+def _gaps_and_nested_spans(seed, n_gaps, n_spans, seconds=4.0):
+    """Seeded gaps of a ``seconds`` trace, longest first as ``idle_gaps``
+    gives them, and spans nested three deep over a window that starts before
+    the trace and ends after it, with stretches no span covers, spans of
+    equal duration on one start, and spans that touch end to start."""
+    rng = np.random.default_rng(seed)
+    edges = np.sort(rng.uniform(0.0, seconds, 2 * n_gaps))
+    gaps = sorted(((float(a), float(b - a)) for a, b
+                   in zip(edges[::2], edges[1::2])), key=lambda g: -g[1])
+    wall_zero = 1000.0
+    spans, t = [], wall_zero - 2.0
+    names = ["engine.wait_work", "engine.dispatch", "engine.process",
+             "engine.wait_result", "serving.request"]
+    while len(spans) < n_spans:
+        dur = float(rng.choice([0.25, 0.5, 1.0])) * 16.0 * seconds / n_spans
+        spans.append({"name": names[len(spans) % 5], "start": t,
+                      "duration": dur})
+        # children: two of equal duration on one start, then one that
+        # touches the second's end
+        spans.append({"name": "child.a", "start": t + 0.1 * dur,
+                      "duration": 0.3 * dur})
+        spans.append({"name": "child.b", "start": t + 0.1 * dur,
+                      "duration": 0.3 * dur})
+        spans.append({"name": "grandchild", "start": t + 0.2 * dur,
+                      "duration": 0.1 * dur})
+        # every third outer span is followed by a stretch with no span open
+        t += dur * (1.5 if len(spans) % 3 == 0 else 1.0)
+    order = rng.permutation(len(spans))    # the tracer's list is not sorted
+    return gaps, [spans[i] for i in order], wall_zero
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 2 ** 31 + 5])
+def test_the_sweep_names_gaps_as_the_scan_did(seed):
+    gaps, spans, zero = _gaps_and_nested_spans(seed, 400, 300)
+    want = _name_gaps_by_scan(gaps, spans, zero)
+    got = reduce.name_gaps(gaps, spans, zero)
+    assert got == want            # names, order and sums, to the last bit
+    # child.a and child.b tie; the list's order (permuted) picks between them
+    assert {"host, no span open", "grandchild", "child.a", "child.b"} <= {
+        n for n, _ in got}
+    # a trace without the wall-clock tie, no spans, no gaps
+    assert reduce.name_gaps(gaps, spans, None) == _name_gaps_by_scan(
+        gaps, spans, None) == [["host, no span open",
+                                pytest.approx(sum(g[1] for g in gaps))]]
+    assert reduce.name_gaps(gaps, [], zero) == _name_gaps_by_scan(
+        gaps, [], zero)
+    assert reduce.name_gaps([], spans, zero) == []
+    # a gap whose middle is a span's very end or start is inside it
+    edge = [{"name": "a", "start": zero + 1.0, "duration": 1.0}]
+    for g in ([(0.5, 1.0)], [(1.5, 1.0)], [(1.5, 1.0 + 1e-9)]):
+        assert reduce.name_gaps(g, edge, zero) == _name_gaps_by_scan(
+            g, edge, zero)
+
+
+def test_naming_a_traced_windows_gaps_takes_seconds_not_minutes():
+    """200,000 gaps against 16,000 spans: a turns window (PR 31), where the
+    scan took 210 s on the chip's host."""
+    import time
+
+    gaps, spans, zero = _gaps_and_nested_spans(7, 200_000, 16_000)
+    t0 = time.perf_counter()
+    named = reduce.name_gaps(gaps, spans, zero)
+    assert time.perf_counter() - t0 < 5.0
+    assert sum(v for _, v in named) == pytest.approx(sum(g[1] for g in gaps))
+
+
 def test_reduction_of_the_recorded_trace():
     """A quarter second of a gpt2-large.chat window on the v5e (PR 23),
     reduced to rows: the numbers below were read off it by hand."""
@@ -103,6 +186,35 @@ def test_traffic_is_the_seeds_and_every_seed_offers_the_same_work(mix_name):
         traffic.warmup_requests(mix, big, 50257)
 
 
+def test_every_cells_mix_says_where_its_load_comes_from():
+    """An open loop's rate is a number somebody swept for: its file says how
+    (``rate_note``); a closed loop's load is its ``clients``."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for w in bench["workloads"]:
+        mix = json.loads((spec.HERE / "traffic" / f"{w['traffic']}.json")
+                         .read_text())
+        if mix["kind"] == "open_loop":
+            assert mix["rate_per_s"] > 0 and len(mix["rate_note"]) > 40, w
+            # the cell's line names the rate it runs at
+            assert f"{mix['rate_per_s']:g}/s" in w["why"], w
+        else:
+            assert mix["clients"] > 0 and "rate_per_s" not in mix, w
+
+
+def test_perf_md_gives_the_bounds_the_benchmark_has():
+    """PERF.md section 2's table, one row an end-to-end metric: the bound in
+    its fourth column is ``BENCHMARK.json``'s."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    text = (ROOT / "PERF.md").read_text()
+    section = text[text.index("\n## 2."):text.index("\n## 3.")]
+    rows = [[c.strip() for c in line.strip().strip("|").split("|")]
+            for line in section.splitlines() if line.startswith("| `")]
+    assert {r[0].strip("`"): float(r[3]) for r in rows} == {
+        m["name"]: m["bound"] for m in bench["end_to_end"]}
+    # and the window's length
+    assert f"--seconds {bench['run_seconds']} " in section
+
+
 # -- no chip, no result -----------------------------------------------------------
 
 def test_a_cpu_run_exits_non_zero_naming_the_platform():
@@ -129,21 +241,29 @@ def _run_tiny(capsys, workload="tiny.open", seed=2 ** 31 + 7):
 
     rc = run.main(["--workload", workload, "--seed", str(seed),
                    "--seconds", "2", "--trace", "0"], require_tpu=False)
-    out = capsys.readouterr().out.strip().splitlines()
-    return rc, json.loads(out[-1]), out
+    io = capsys.readouterr()
+    return (rc, json.loads(io.out.strip().splitlines()[-1]),
+            io.err.strip().splitlines())
 
 
 def test_a_sound_run_is_correct_and_prints_each_number_beside_its_limit(
         tiny, capsys):
-    rc, result, out = _run_tiny(capsys)
+    rc, result, err = _run_tiny(capsys)
     assert rc == 0 and result["correct"] is True
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "check"]
     assert result["failed"] == 0 and result["attempted"] == 8
     assert set(result["metrics"]) == {"ttft_p50_ms", "tpot_mean_ms",
                                       "output_tokens_per_s", "setup_s"}
-    assert any(l.startswith("check logit_gap_max:") and "limit" in l
-               for l in out)
+    # each number compared beside its limit: the last lines of standard
+    # error, and the last key of the result's line
+    limits = check.limits_for("tiny.open")
+    assert [l.split(":")[0] for l in err[-len(limits):]] == [
+        f"check {name}" for name in limits]
+    assert all("limit" in l and l.endswith(" ok") for l in err[-len(limits):])
+    assert {k: v["limit"] for k, v in result["check"].items()} == limits
+    assert 0.0 <= result["check"]["logit_gap_max"]["value"] <= \
+        limits["logit_gap_max"]
 
 
 def test_the_lower_precision_control_is_not_correct(tiny):
@@ -187,6 +307,8 @@ def test_a_run_whose_served_tokens_are_altered_is_not_correct(
             yield item
 
     monkeypatch.setattr(batcher.PagedBatchingDecoder, "stream", altered)
-    rc, result, out = _run_tiny(capsys)
+    rc, result, err = _run_tiny(capsys)
     assert rc == 0 and result["correct"] is False
-    assert any("logit_gap_max" in l and "NOT OK" in l for l in out)
+    assert any("logit_gap_max" in l and "NOT OK" in l for l in err)
+    gap = result["check"]["logit_gap_max"]
+    assert gap["value"] > gap["limit"]

@@ -104,9 +104,26 @@ def test_every_metric_of_the_benchmark_has_its_reader_and_its_cells():
     for m in bench["per_layer"]:
         assert callable(spec.plugin("layer_metrics", m["name"]).read)
     by = {m["name"]: m for m in bench["per_layer"]}
-    chat, docs = "gpt2-large.chat", "gpt2-xl.docs"
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    # the cells judged by a latency and those judged by their capacity, as
+    # the benchmark's own end-to-end entries list them
+    latency = e2e["ttft_p50_ms"]["workloads"]
+    capacity = e2e["output_tokens_per_s"]["workloads"]
+    assert latency == e2e["tpot_mean_ms"]["workloads"]
+    assert latency and capacity and not set(latency) & set(capacity)
+    assert set(latency) | set(capacity) == {
+        w["name"] for w in bench["workloads"]}
     for name in BY_HAND:
-        assert by[name]["workloads"] == [chat]
+        assert by[name]["workloads"] == latency
+        assert by[name]["moves"] in ("ttft_p50_ms", "tpot_mean_ms")
     for name in CAPACITY:
-        assert by[name]["workloads"] == [docs]
+        assert by[name]["workloads"] == capacity
         assert by[name]["moves"] == "output_tokens_per_s"
+    # every cell reports a metric of a whole step's share of the peak that
+    # moves the end-to-end metric its kernels' rooflines move
+    for m in bench["per_layer"]:
+        if m["name"].endswith("_roofline"):
+            mfu = [x for x in bench["per_layer"] if "mfu" in x["name"]
+                   and x["moves"] == m["moves"]
+                   and set(m["workloads"]) <= set(x["workloads"])]
+            assert mfu, m["name"]

@@ -196,10 +196,11 @@ def test_a_whole_toy_run_is_correct_and_holds_bfloat16(monkeypatch, capsys):
     rc = run.main(["--workload", "tiny-falcon.turns", "--seed",
                    str(2 ** 31 + 27), "--seconds", "2", "--trace", "0"],
                   require_tpu=False)
-    out = capsys.readouterr().out.strip().splitlines()
-    result = json.loads(out[-1])
+    io = capsys.readouterr()
+    result = json.loads(io.out.strip().splitlines()[-1])
     assert rc == 0 and result["correct"] is True and result["failed"] == 0
-    assert any(l.startswith("check logit_gap_max:") for l in out)
+    assert any(l.startswith("check logit_gap_max:")
+               for l in io.err.splitlines())
     assert seen["recurrent_layers"] == 2.0
     # 2 bytes a parameter: the checkpoint's float32 files were cast
     params = 211 * 64 * 2 + 64 + 2 * (

@@ -72,6 +72,10 @@ def collect(workload: str, seed: int, seconds: float) -> dict:
                            for n in cell.end_to_end if n != "setup_s"},
             "per_layer": layers.read_all(cell, win, trace, peaks),
             "breakdown": layers.breakdown(win, trace),
+            # what the breakdown's idle_gaps were named from: every idle
+            # stretch between the first and the last device operation
+            "idle_gaps": reduce.idle_gaps(
+                trace.rows(plane, reduce.OPS_LINE)),
             "records": [{k: v for k, v in r.items() if k != "tokens"}
                         | {"n_tokens": len(r["tokens"])}
                         for r in win.records],
