@@ -152,6 +152,13 @@ def expert_layers(module) -> int:
     return max(0, int(module.depth) - int(getattr(module, "dense_layers", 0)))
 
 
+def residual_sublayers(module) -> int:
+    """Sub-layers of ``module`` whose residual path mixes several streams
+    (``hc_mult`` > 0, ops/hyper_connection.py: two a layer); 0 for the single
+    stream every other model has."""
+    return 2 * int(module.depth) if getattr(module, "hc_mult", 0) else 0
+
+
 def has_recurrent_state(module) -> bool:
     """Whether ``module`` keeps per-row recurrent state in its cache beside
     the attention's K/V (a Mamba-2 mixer, ``ssm`` set): the serving layer

@@ -19,9 +19,13 @@ or grouped-query attention at a head size of its own, or multi-head latent
 attention over a latent cache (models/mla.py); learned or rotary positions;
 optionally a Mamba-2 mixer in parallel with attention (models/mamba2.py) and
 a muP checkpoint's multipliers. The stack may be a pattern: ``dense_layers``
-leading SwiGLU layers before the expert layers. GPT-2 is the defaults;
-Falcon-H1 is rmsnorm + swiglu + GQA + rope + ssm + mup; GLM-4.7-Flash is
-rmsnorm + rope + mla + experts behind one dense layer.
+leading SwiGLU layers before the expert layers. The residual path is a kind
+too: one stream and ``x = x + y``, or ``hc_mult`` streams that every sub-layer
+reads, writes and mixes through maps made from the token's own streams
+(ops/hyper_connection.py). GPT-2 is the defaults; Falcon-H1 is rmsnorm +
+swiglu + GQA + rope + ssm + mup; GLM-4.7-Flash is rmsnorm + rope + mla +
+experts behind one dense layer; Xing4.0 is that with YaRN rotary on four
+hyper-connected streams.
 """
 
 from __future__ import annotations
@@ -38,6 +42,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
+from ..ops.hyper_connection import HCConfig, hc_post, hc_pre
 from ..parallel.ring import ring_attention
 from .experts import ExpertMLP, ExpertsConfig
 from .layers import QuantizableDense
@@ -482,11 +487,38 @@ class GPTBlock(nn.Module):
     state_rows: int = 0
     mla: Optional[MLAConfig] = None
     experts: Optional[ExpertsConfig] = None
+    hc: Optional[HCConfig] = None
+
+    def _hc_maps(self, name: str, width: int):
+        """A sub-layer's ``phi``, ``alpha`` and ``bias`` (ops/
+        hyper_connection.py). A fresh model starts near the single stream:
+        ``pre`` 1/n, ``post`` 1, ``M`` close to the identity."""
+        n, c = self.hc.mult, self.hc.maps
+        bias = lambda *_: jnp.concatenate([
+            jnp.full((n,), -jnp.log(n - 1.0)), jnp.zeros((n,)),
+            (4.0 * jnp.eye(n) - 2.0).reshape(n * n)])
+        return {
+            "phi": self.param(f"{name}_phi", _part((None, None))(
+                nn.initializers.lecun_normal()), (n * width, c)),
+            "alpha": self.param(f"{name}_alpha",
+                                nn.initializers.constant(0.01), (3,)),
+            "bias": self.param(f"{name}_bias", bias, (c,))}
 
     @nn.compact
     def __call__(self, x, valid, train: bool = False, decode: bool = False,
                  positions=None, pages=None, seq_lens=None, rows=None):
         mup = self.mup or MuP()
+        hc = self.hc
+        if hc is not None:
+            if self.ssm is not None:
+                raise ValueError("hyper-connections around a parallel mixer "
+                                 "are not defined")
+            # x is the n streams, flat [B, L, n E]; the Pallas kernels serve
+            # decode applies on a TPU (they have no backward)
+            E = x.shape[-1] // hc.mult
+            kernel = None if decode else False
+            xs, (x, post, mix) = x, hc_pre(x, self._hc_maps("hc1", E), hc,
+                                           kernel=kernel)
         u = _norm(self.norm, "ln1", self.ln_eps)(x).astype(self.dtype)
         if self.mla is not None:
             attn = MLAttention(self.num_heads, self.mla, dtype=self.dtype,
@@ -507,7 +539,12 @@ class GPTBlock(nn.Module):
                  positions=positions, pages=pages, seq_lens=seq_lens)
         y = _scaled(y, mup.attention_out)
         y = nn.Dropout(self.dropout, deterministic=not train)(y)
-        x = x + y
+        if hc is None:
+            x = x + y
+        else:
+            xs = hc_post(xs, y, post, mix, kernel=kernel)
+            x, post, mix = hc_pre(xs, self._hc_maps("hc2", E), hc,
+                                  kernel=kernel)
         if self.ssm is not None:
             # the mixer reads the SAME normed input as attention and both
             # land on the residual together (Falcon-H1's parallel block)
@@ -555,6 +592,8 @@ class GPTBlock(nn.Module):
             raise ValueError(f"unknown mlp {self.mlp!r} (valid: 'gelu', "
                              f"'swiglu', 'experts')")
         y = nn.Dropout(self.dropout, deterministic=not train)(y)
+        if hc is not None:
+            return hc_post(xs, y, post, mix, kernel=kernel)
         return x + y
 
 
@@ -680,6 +719,18 @@ class CausalTransformer(nn.Module):
     mla: Optional[MLAConfig] = None
     experts: Optional[ExpertsConfig] = None
     dense_layers: int = 0
+    # --- the residual path. ``hc_mult`` 0: one stream, ``x = x + y``.
+    # ``hc_mult`` n > 0: manifold-constrained hyper-connections over n
+    # streams (ops/hyper_connection.py; the other three are the published
+    # ``hc_sinkhorn_iters``, ``hc_eps``, ``mhc_h_res_clamp_max``). The
+    # embedding fans out into n copies, the stream between layers is
+    # [B, L, n E], and the last layer's streams are summed before ``ln_f``.
+    # Every program takes the stream's shape from here; nothing in the
+    # serving layer knows its width. ---
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: float = 30.0
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, decode: bool = False,
@@ -776,7 +827,14 @@ class CausalTransformer(nn.Module):
             norm=self.norm, mlp=self.mlp, mlp_dim=self.mlp_dim,
             num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
             ssm=self.ssm, mup=self.mup, state_rows=self.state_rows,
-            mla=self.mla, experts=self.experts)
+            mla=self.mla, experts=self.experts,
+            hc=HCConfig(self.hc_mult, self.hc_sinkhorn_iters, self.hc_eps,
+                        self.hc_clamp, self.ln_eps) if self.hc_mult else None)
+        if self.hc_mult:
+            if self.moe_every > 0:
+                raise ValueError("hyper-connections do not cover "
+                                 "moe_every's block (parallel/moe.py)")
+            x = jnp.tile(x, (1, 1, self.hc_mult))
         if (self.mlp == "experts") != (self.experts is not None):
             raise ValueError("mlp='experts' and an ExpertsConfig go together")
         if self.mla is not None and not use_rope:
@@ -831,6 +889,10 @@ class CausalTransformer(nn.Module):
                 x = block_cls(self.num_heads, self.mlp_ratio, self.dropout,
                               name=name, **fields)(x, valid, train, decode,
                                                    **at)
+        if self.hc_mult:
+            # the read-out: the streams' sum, in float32 as the norm is
+            x = x.astype(jnp.float32).reshape(
+                B, L, self.hc_mult, self.embed_dim).sum(axis=2)
         x = _norm(self.norm, "ln_f", self.ln_eps)(x).astype(self.dtype)
         if return_hidden:
             # final hidden states [B, L, E] for a chunked lm_head+loss

@@ -25,13 +25,16 @@ axis, no V arena). Two forms of the one function read it:
   carries the result out. The cache is never expanded in a step.
 
 Norms are float32; rotary pairs are rotate-half over the ``dr`` rope dims.
+With ``rope_scaling`` (YaRN, ops/rotary.py) the pair frequencies are the
+blended ones and the softmax scale is ``softmax_mscale ** 2 / sqrt(dn + dr)``,
+in both forms alike.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
@@ -39,7 +42,7 @@ import jax.numpy as jnp
 from ..ops.attention import dot_product_attention
 from ..ops.mla_attention import mla_attn, mla_attn_gather
 from ..ops.paged_attention import resolve_paged_attn
-from ..ops.rotary import apply_rope
+from ..ops.rotary import YarnScaling, apply_rope
 from .layers import QuantizableDense
 
 
@@ -54,11 +57,18 @@ class MLAConfig:
     qk_rope_head_dim: int    # dr: the shared rope key a token caches
     v_head_dim: int          # dv
     norm_eps: float = 1e-5
+    rope_scaling: Optional[YarnScaling] = None   # None: plain rotary
 
     @property
     def latent_width(self) -> int:
         """Values one token holds in one layer's arena."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        m = self.rope_scaling.softmax_mscale if self.rope_scaling else 1.0
+        return m * m / math.sqrt(self.qk_nope_head_dim
+                                 + self.qk_rope_head_dim)
 
 
 def _part(names):
@@ -98,7 +108,9 @@ class MLAttention(nn.Module):
                 in_axis=0, out_axis=(1, 2))), (dc, H, dn + dv))
         w_ukv = jnp.asarray(w_ukv, self.dtype)
         out_proj = dense(E, ("tp", None), "proj")
-        scale = 1.0 / math.sqrt(dn + dr)    # dot_product_attention's own
+        yarn = c.rope_scaling
+        # dot_product_attention's own 1 / sqrt(dn + dr) unless YaRN scales it
+        own = {} if yarn is None else {"scale": c.softmax_scale}
 
         def expanded(q, lat, **masking):
             """q [B, L, H, dn + dr] (rope part rotated) against the latents
@@ -112,13 +124,14 @@ class MLAttention(nn.Module):
             # the kernel wants one head size for q, k and v
             impl = None if dv == dn + dr else "xla"
             return dot_product_attention(q, k, kv[..., dn:], impl=impl,
-                                         **masking)
+                                         **own, **masking)
 
         def rotated(q, kr, pos):
             q = jnp.concatenate(
-                [q[..., :dn], apply_rope(q[..., dn:], pos, self.rope_theta)],
+                [q[..., :dn],
+                 apply_rope(q[..., dn:], pos, self.rope_theta, yarn)],
                 axis=-1)
-            return q, apply_rope(kr, pos, self.rope_theta)[:, :, 0]
+            return q, apply_rope(kr, pos, self.rope_theta, yarn)[:, :, 0]
 
         if not decode:
             q, kr = rotated(q, kr, jnp.arange(L))
@@ -161,6 +174,6 @@ class MLAttention(nn.Module):
         walk = (mla_attn if resolve_paged_attn(self.paged_attn) == "pallas"
                 else mla_attn_gather)
         oa = walk(ql, arena.value, pages, positions, value_dim=dc,
-                  scale=scale)
+                  scale=c.softmax_scale)
         out = jnp.einsum("bhc,chd->bhd", oa, w_ukv[..., dn:])
         return out_proj(out.reshape(B, 1, H * dv))

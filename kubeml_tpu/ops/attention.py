@@ -59,12 +59,19 @@ def dot_product_attention(
     causal: bool = False,
     kv_valid: Optional[jnp.ndarray] = None,  # [B, Lk] True = real token
     impl: Optional[str] = None,  # None=auto | "xla" | "pallas"
+    scale: Optional[float] = None,  # None = 1/sqrt(D); else XLA path only
 ) -> jnp.ndarray:
     """Scaled dot-product attention; returns [B, Lq, H, D].
 
     Masking comes either as a dense ``mask`` (XLA path only) or structurally
     as ``causal`` / ``kv_valid`` (eligible for the Pallas flash kernel).
+    A softmax ``scale`` of the caller's own (YaRN's, models/mla.py) is
+    applied to the float32 scores, on the XLA path.
     """
+    if scale is not None:
+        if impl == "pallas":
+            raise ValueError("pallas impl scales by 1/sqrt(D) only")
+        impl = "xla"
     if impl is None:
         impl = (
             "pallas"
@@ -91,8 +98,11 @@ def dot_product_attention(
             extra = extra & kv_valid[:, None, None, :].astype(bool)
         mask = extra if mask is None else mask & extra
     depth = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(depth).astype(q.dtype)
-    scores = scores.astype(jnp.float32)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    if scale is None:
+        scores = (scores / jnp.sqrt(depth).astype(q.dtype)).astype(jnp.float32)
+    else:
+        scores = scores.astype(jnp.float32) * scale
     if mask is not None:
         scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     weights = jnp.exp(scores - scores.max(axis=-1, keepdims=True))

@@ -287,6 +287,16 @@ SERVING_COUNTERS = {
         "moe_experts_touched", "Distinct experts chosen by a decode step's "
                                "live rows, summed over expert layers and "
                                "steps: the expert weights the steps read"),
+    "kubeml_serving_hc_positions_total": (
+        "hc_positions", "Positions x sub-layers whose residual streams the "
+                        "hyper-connection maps mixed, bucket padding and "
+                        "dead rows included (0 for a single stream)"),
+    "kubeml_serving_hc_positions_admit_total": (
+        "hc_positions_admit", "The admission programs' part of "
+                              "kubeml_serving_hc_positions_total"),
+    "kubeml_serving_hc_positions_step_total": (
+        "hc_positions_step", "The decode steps' part of "
+                             "kubeml_serving_hc_positions_total"),
 }
 # XLA compile counter, labeled {model, program} — rendered from the
 # snapshot's per-program compile-count dict rather than the scalar tables
@@ -467,6 +477,9 @@ SERVING_GAUGES = {
     "kubeml_serving_moe_layers": (
         "moe_layers", "Layers of the served model whose feed-forward is "
                       "routed experts (0: none)"),
+    "kubeml_serving_residual_streams": (
+        "residual_streams", "Streams of the served model's residual path: 1, "
+                            "or hc_mult under hyper-connections"),
     "kubeml_serving_expert_param_bytes": (
         "expert_param_bytes", "Bytes of the routed experts' stacked weights "
                               "resident for this model; a decode step reads "

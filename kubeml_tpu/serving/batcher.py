@@ -681,6 +681,9 @@ class BatchingDecoder:
         from .stats import DecoderStats
 
         self.stats = DecoderStats(slots)
+        from ..models.generation import residual_sublayers
+
+        self.stats.hc_sublayers = residual_sublayers(module)
         # request-id mint: unique across decoder rebuilds of the same model
         # (the per-boot nonce), monotonic within one decoder — the handle
         # `kubeml trace <request-id>` looks serving span trees up by
@@ -3625,6 +3628,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         # _decode_block; engines share them): about one per compiled
         # program, not one per layer of each
         snap["block_traces"] = float(block_traces())
+        # streams of the model's residual path (1; hyper-connections: n)
+        snap["residual_streams"] = float(
+            getattr(self.module, "hc_mult", 0) or 1)
         # recurrent state beside the pages: layers that keep one, its bytes
         # over all program rows, and whether it switched prefix sharing off
         snap["recurrent_layers"] = float(self._recurrent_layers)

@@ -93,6 +93,14 @@ class DecoderStats:
         # step has to read
         self.moe_assignments = 0
         self.moe_experts_touched = 0
+        # a residual path of several streams (hyper-connections): positions
+        # x sub-layers its maps were made and its streams mixed for, bucket
+        # padding and dead rows included (the device does them), by the
+        # kind of program; ``hc_sublayers`` is the model's count of such
+        # sub-layers (set by the engine; 0 for a single stream)
+        self.hc_sublayers = 0
+        self.hc_positions_admit = 0
+        self.hc_positions_step = 0
         self.goodput_tokens = 0       # tokens delivered to a live waiter
         self.wasted_tokens = 0        # tokens routed to an aborted request
         # shared-prefix reuse (paged engine, serving/kvpool.py): admissions
@@ -243,6 +251,7 @@ class DecoderStats:
         with self._lock:
             self.device_steps += int(steps)
             self.slot_steps += total
+            self.hc_positions_step += total * self.hc_sublayers
             self.live_slot_steps += int(live)
             self.dead_slot_steps += int(dead)
             self.idle_slot_steps += int(idle)
@@ -292,6 +301,8 @@ class DecoderStats:
         with self._lock:
             self.prefill_tokens += int(real)
             self.prefill_pad_tokens += int(padding)
+            self.hc_positions_admit += (
+                int(real) + int(padding)) * self.hc_sublayers
 
     def moe_steps(self, assignments: int, touched: int) -> None:
         """Expert-layer accounting for one processed decode chunk, from the
@@ -571,6 +582,10 @@ class DecoderStats:
                 "prefill_pad_tokens": float(self.prefill_pad_tokens),
                 "moe_assignments": float(self.moe_assignments),
                 "moe_experts_touched": float(self.moe_experts_touched),
+                "hc_positions": float(self.hc_positions_admit
+                                      + self.hc_positions_step),
+                "hc_positions_admit": float(self.hc_positions_admit),
+                "hc_positions_step": float(self.hc_positions_step),
                 "goodput_tokens": float(self.goodput_tokens),
                 "wasted_tokens": float(self.wasted_tokens),
                 "prefix_hits": float(self.prefix_hits),

@@ -17,6 +17,7 @@ def _sds(shape, dtype):
 
 def kernel_cases():
     from kubeml_tpu.ops.flash_attention import flash_attention
+    from kubeml_tpu.ops import hyper_connection as hc
     from kubeml_tpu.ops.grouped_matmul import grouped_matmul
     from kubeml_tpu.ops.int8_matmul import int8_matmul
     from kubeml_tpu.ops.mla_attention import mla_attn
@@ -97,6 +98,42 @@ def kernel_cases():
                                            interpret=False),
             (_sds((m, 1536), jnp.bfloat16),
              _sds((64, 1536, 2048), jnp.bfloat16), _sds((64,), jnp.int32)))
+    # Xing4.0-29B-A4B's published shapes: the latent walk at 32 heads of 128
+    # + 64 (YaRN's softmax scale), the experts at 3584 x 1024, and the
+    # residual path's two kernels over four streams of 3584 in bfloat16, a
+    # 2,048-position admit and a 32-row step
+    cases["mla_attn-xing4.0-P128"] = (
+        lambda q, a, t, p: mla_attn(q, a, t, p, value_dim=512,
+                                    scale=0.14467962580, interpret=False),
+        (_sds((rows, 32, 576), jnp.bfloat16),
+         _sds((8193, PT, 576), jnp.bfloat16),
+         _sds((rows, 128), jnp.int32), _sds((rows,), jnp.int32)))
+    for m in (128, 8192):
+        cases[f"moe_experts-xing4.0-gated-m{m}"] = (
+            lambda x, w, u, g: grouped_matmul(x, w, g, u, kernel=True,
+                                              interpret=False),
+            (_sds((m, 3584), jnp.bfloat16),
+             _sds((64, 3584, 1024), jnp.bfloat16),
+             _sds((64, 3584, 1024), jnp.bfloat16), _sds((64,), jnp.int32)))
+        cases[f"moe_experts-xing4.0-down-m{m}"] = (
+            lambda x, w, g: grouped_matmul(x, w, g, kernel=True,
+                                           interpret=False),
+            (_sds((m, 1024), jnp.bfloat16),
+             _sds((64, 1024, 3584), jnp.bfloat16), _sds((64,), jnp.int32)))
+    streams = hc.HCConfig(mult=4)
+    for positions in (2048, 32):
+        cases[f"hc_pre-xing4.0-T{positions}"] = (
+            lambda x, phi, alpha, bias: hc._pre_call(
+                x, {"phi": phi, "alpha": alpha, "bias": bias}, streams,
+                False),
+            (_sds((positions, 4 * 3584), jnp.bfloat16),
+             _sds((4 * 3584, 24), jnp.bfloat16), _sds((3,), jnp.bfloat16),
+             _sds((24,), jnp.bfloat16)))
+        cases[f"hc_post-xing4.0-T{positions}"] = (
+            lambda x, y, co: hc._post_call(x, y, co, 4, False),
+            (_sds((positions, 4 * 3584), jnp.bfloat16),
+             _sds((positions, 3584), jnp.bfloat16),
+             _sds((positions, 20), jnp.float32)))
     # the MLP up-projection and the lm_head (vocab 50257: not a tile multiple)
     for K, N in ((768, 3072), (768, 50257)):
         cases[f"int8_matmul-{K}x{N}"] = (

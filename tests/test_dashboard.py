@@ -303,6 +303,11 @@ UNPANELED = {
     "kubeml_serving_moe_assignments_total": "model-specific; ad-hoc only",
     "kubeml_serving_moe_experts_touched_total":
         "model-specific; ad-hoc only",
+    # hyper-connected models only; the benchmark reads the admit part
+    "kubeml_serving_residual_streams": "static per-model constant",
+    "kubeml_serving_hc_positions_total": "model-specific; ad-hoc only",
+    "kubeml_serving_hc_positions_admit_total": "model-specific; ad-hoc only",
+    "kubeml_serving_hc_positions_step_total": "model-specific; ad-hoc only",
 }
 
 
