@@ -1,5 +1,5 @@
-"""Pallas TPU paged-attention decode kernel: attend straight through the
-page table, no contiguous K/V copy, KV traffic that scales with occupancy.
+"""Pallas TPU paged-attention kernels: attend straight through the page
+table, no contiguous K/V copy, KV traffic that scales with occupancy.
 
 The paged serving engine (serving/kvpool.py + the paged decode branch in
 models/gpt.py) stores a layer's K and V in one shared physical arena of
@@ -28,34 +28,54 @@ why the arena used to be two head-major arrays ``[N, H, pt, D]`` — and not
 of ``[N, pt, H*D]``; models/gpt.py says, where the arena is declared, what
 XLA did to the head-major arrays around their write.
 
-Grid layout — the kv axis WALKS THE PAGE TABLE: grid ``(B, Lt, P)`` (rows,
-query tiles, logical pages) with the page index innermost (sequential on
-TPU); every program covers all heads. The page table, per-row positions
-and per-row live-page counts ride ``PrefetchScalarGridSpec`` scalar
-prefetch, so the K/V BlockSpec index maps translate the LOGICAL page index
-``i`` into the row's PHYSICAL arena page before the block is fetched — the
-"gather" happens per VMEM block inside the kernel's DMA stream, never as a
-materialized HBM tensor. The online-softmax carry (acc/m/l) lives in VMEM
-scratch across the page axis exactly like ops/flash_attention.py, and the
-output block is revisited (constant index map along the page axis) so it is
-written once at the final step.
+TWO BODIES, one rule. Both walk a row's page table through
+``PrefetchScalarGridSpec`` scalar prefetch (the table, per-row positions and
+per-row live-page counts), so the K/V BlockSpec index maps translate a
+LOGICAL page index into the row's PHYSICAL arena page before the block is
+fetched — the "gather" happens per VMEM block inside the kernel's DMA
+stream, never as a materialized HBM tensor — and both carry the
+online-softmax state (acc/m/l) in VMEM scratch across the page axis exactly
+like ops/flash_attention.py, the output block revisited (constant index map
+along that axis) and written once at the final step. The choice is made on
+static shapes the call observes in its input, never on a knob:
+
+* ``L == 1`` over an arena in the compute type — every decode step — takes
+  the DECODE BODY (:func:`_decode_kernel`): grid ``(B, P / C)``, ``C`` =
+  ``gcd(P, 16)`` pages (256 tokens at 16 a page) a program, the arena handed
+  to the call ``C`` times so that Pallas pipelines the page copies. The heads
+  are the rows of ONE product: the step's queries are set out against an
+  arena row's K lanes (head ``h`` on sublane ``h``, its values where its K/V
+  head's K lies, zeros elsewhere), so one ``dot_general`` over the lanes
+  gives every head's scores for the block, one more every head's values,
+  and under grouped-query attention one K/V read serves the whole group.
+  On the chip (PR 38) 11-17 x the tile body's speed at the three
+  configurations' decode shapes, 62-73% of the K/V read's roofline at
+  GPT-2's rows and 24-30% at Falcon-H1's narrower one.
+* everything else — ``L > 1`` (one-row admits, suffix prefill after a prefix
+  hit, chunked prefill, speculative verify windows) and int8 pages with
+  their scales at any ``L`` — takes the TILE BODY (:func:`_pa_kernel`): grid
+  ``(B, Lt, P)`` (rows, query tiles of 128, logical pages) with the page
+  index innermost (sequential on TPU), a static loop over the heads inside a
+  program, each a ``[tq, Dp] x [pt, Dp]`` contraction over one page.
 
 Per-row depth clamp — grid steps past a row's last live page repeat the
-previous physical index (the index map clamps at ``live[b] - 1``, the same
+previous physical index (the index maps clamp at ``live[b] - 1``, the same
 trick the flash kernels use at the causal diagonal), so Pallas elides their
-HBM->VMEM copies, and ``pl.when(i < live)`` skips their compute: HBM
-reads and FLOPs scale with the row's ACTUAL ``positions + L``, not the
-reserved table width. Dead rows the host already retired point at the
-pool's trash page 0; their output is garbage the engine discards anyway
-(exactly the gather path's contract).
+HBM->VMEM copies, and ``pl.when`` skips their compute: HBM reads and FLOPs
+scale with the row's ACTUAL ``positions + L``, not the reserved table
+width; the grid step itself remains (``serving/stats.py walk_chunks_live``
+over ``walk_chunks_grid`` says how much of a decode step's grid is real).
+Dead rows the host already retired point at the pool's trash page 0; their
+output is garbage the engine discards anyway (exactly the gather path's
+contract), and the decode body reads one page for them whatever their
+frozen cursor says.
 
-One kernel serves all three paged callers: L == 1 decode steps, L == k+1
-speculative verify windows, and L > 1 page-aligned suffix prefill after a
-prefix hit — the mask is purely positional (``k_pos <= positions[b] + l``),
+The mask is purely positional in both (``k_pos <= positions[b] + l``),
 identical to the gather path's, so every logical position at or before the
 query is attended and later positions (incl. everything past the live
-clamp) are not. ``interpret=True`` (automatic off-TPU) runs the same kernel
-on CPU for the parity suite (tests/test_paged_attention.py).
+clamp) are not; a masked probability is exactly 0, so a poisoned trash page
+or an unwritten slot cannot leak. ``interpret=True`` (automatic off-TPU) runs
+the same kernels on CPU for the parity suite (tests/test_paged_attention.py).
 """
 
 from __future__ import annotations
@@ -291,6 +311,201 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, kv_ref, *rest,
                            ).astype(o_ref.dtype)
 
 
+# pages a decode program streams, chosen on the chip at the three
+# configurations' decode shapes (PR 38, CHANGES.md: 16 beat 8 by 0-20% and 4
+# lost 15-35%); a table narrower than this, or no multiple of it, walks
+# gcd(P, _CHUNK) pages a program
+_CHUNK = 16
+
+
+def decode_chunk_pages(table_width: int) -> int:
+    """Pages one program of the decode body streams from a table
+    ``table_width`` pages wide (the engine counts its grid by this too)."""
+    return math.gcd(table_width, _CHUNK)
+
+
+def _slab_pieces(n_heads: int, kv_heads: int, head_dim: int):
+    """For each query head, which ``head_dim``-wide piece of its slab its
+    K/V head's K is, and which its V is (two ``[n_heads]`` arrays; all
+    zeros where a head is whole slabs)."""
+    share = n_heads // kv_heads
+    return tuple(
+        np.asarray([_slab((first + h // share) * head_dim, head_dim)[1]
+                    // head_dim for h in range(n_heads)])
+        for first in (0, kv_heads))
+
+
+def _slab_rows(n_heads: int, kv_heads: int, head_dim: int, first: int):
+    """``[(lane, lo, hi)]``: the slabs of an arena row that hold the K
+    (``first`` 0) or the V (``first`` = ``kv_heads``) of some head, each
+    with the query heads ``[lo, hi)`` whose K/V head lies in it."""
+    share = n_heads // kv_heads
+    rows: dict = {}
+    for h in range(n_heads):
+        lane = _slab((first + h // share) * head_dim, head_dim)[0]
+        lo, hi = rows.get(lane, (h, h))
+        rows[lane] = (min(lo, h), max(hi, h + 1))
+    return [(lane, lo, hi) for lane, (lo, hi) in sorted(rows.items())]
+
+
+def _into_slabs(q, at, head_dim: int):
+    """Queries ``[..., head_dim]`` at their piece ``at`` (broadcast against
+    ``q``) of a slab, zeros in the slab's other lanes; whole-slab heads pass
+    as they are."""
+    pieces = _slab_width(head_dim) // head_dim
+    if pieces == 1:
+        return q
+    return jnp.concatenate([jnp.where(at == i, q, 0) for i in range(pieces)],
+                           axis=-1)
+
+
+def _out_of_slabs(out, at, head_dim: int):
+    """The piece ``at`` of each head's slab of outputs ``[..., Dp]``."""
+    n = _slab_width(head_dim) // head_dim
+    pieces = [out[..., i * head_dim:(i + 1) * head_dim] for i in range(n)]
+    return functools.reduce(
+        lambda kept, i: jnp.where(at == i, pieces[i], kept),
+        range(1, n), pieces[0])
+
+
+def _decode_kernel(pages_ref, pos_ref, live_ref, q_ref, *rest, chunk: int,
+                   page_tokens: int, n_chunks: int, k_slabs, v_slabs,
+                   k_lanes: int, v_first: int, scale: float):
+    """One (batch row, chunk of ``chunk`` logical pages) program of a decode
+    step: ALL heads as the rows of one product. At the row's first chunk the
+    step's queries (``q_ref``, ``[Hp, Dp]``: head ``h`` on sublane ``h``, its
+    values at its place in a slab as the tile body's wrapper lays them) are
+    set out against an arena row's K lanes, ``qbd`` ``[Hp, k_lanes]``: row
+    ``h`` holds head ``h``'s values in the slab where its K/V head's K lies
+    and zeros elsewhere, so ``qbd . kv^T`` over the lanes is every head's
+    scores at once, the other heads' lanes adding exact zeros (as a slab's
+    neighbour does in the tile body), and under grouped-query attention one
+    K/V read serves the whole group. The probabilities then multiply the
+    rows' lanes from ``v_first`` on, and head ``h``'s output is the slab of
+    row ``h`` where its K/V head's V lies, picked at the last chunk. The
+    arena arrives ``chunk`` times, one page each, so Pallas pipelines the
+    page copies; m/l/acc carry across the chunk axis in scratch like the
+    tile body's."""
+    kv_refs = rest[:chunk]
+    o_ref, qbd_ref, acc_ref, m_ref, l_ref = rest[chunk:]
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    span = chunk * page_tokens
+    dp = q_ref.shape[2]
+    head = jax.lax.broadcasted_iota(jnp.int32, q_ref.shape[1:], 0)
+
+    @pl.when(i == 0)
+    def _init():
+        # the K slabs tile [0, k_lanes), so every lane of qbd is written;
+        # the select runs in float32, whose mask has the iota's layout
+        q = q_ref[0].astype(jnp.float32)
+        for lane, lo, hi in k_slabs:
+            qbd_ref[:, lane:lane + dp] = jnp.where(
+                (head >= lo) & (head < hi), q, 0.0).astype(qbd_ref.dtype)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    # chunks past the row's live depth: copies elided by the clamped index
+    # maps, compute skipped here
+    @pl.when(i * chunk < live_ref[b])
+    def _chunk():
+        k = jnp.concatenate([r[0, :, :k_lanes] for r in kv_refs], axis=0)
+        v = jnp.concatenate([r[0, :, v_first:] for r in kv_refs], axis=0)
+        s = jax.lax.dot_general(qbd_ref[...], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        # the tile body's positional mask at L == 1: the query sits at
+        # positions[b] and sees every key at or before it; a page fetched
+        # again past the live depth lies past it too
+        k_pos = i * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        visible = k_pos <= pos_ref[b]
+        s = jnp.where(visible, s, _NEG)
+        m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)  # masked: exactly 0
+        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(i == n_chunks - 1)
+    def _finalize():
+        out = jnp.zeros(q_ref.shape[1:], jnp.float32)
+        for lane, lo, hi in v_slabs:
+            at = lane - v_first
+            out = jnp.where((head >= lo) & (head < hi),
+                            acc_ref[:, at:at + dp], out)
+        o_ref[0] = (out / jnp.maximum(l_ref[:, 0:1], 1e-9)
+                    ).astype(o_ref.dtype)
+
+
+def _decode_step(q, kv_rows, pages, positions, kv_heads: int, scale: float,
+                 interpret: bool):
+    """``paged_attention`` at ``L == 1`` over an arena in the compute type:
+    grid ``(B, P / chunk)``, :func:`_decode_kernel` a program."""
+    B, _, H, D = q.shape
+    pt, W = int(kv_rows.shape[1]), int(kv_rows.shape[2])
+    P = int(pages.shape[1])
+    Dp = _slab_width(D)
+    k_slabs = _slab_rows(H, kv_heads, D, 0)
+    v_slabs = _slab_rows(H, kv_heads, D, kv_heads)
+    # the lanes the two products take: K's from the row's start to the end
+    # of its last slab, V's from its first slab to the row's end (GPT-2
+    # XL's V starts mid-slab at lane 1,600, so both take lanes [1536, 1664)
+    # and the queries' zeros there, like those at other heads, add nothing)
+    k_lanes = k_slabs[-1][0] + Dp
+    v_first = v_slabs[0][0]
+    # heads on the sublanes, up to the storage type's tile (8 rows of
+    # float32, 16 of bfloat16: 32 for GPT-2's 20 and 25): the padding rows
+    # are zero queries whose output is dropped; a head narrower than its
+    # slab sits at its piece of it, zeros beside it, as in the tile body
+    Hp = _round_up(H, 32 // q.dtype.itemsize)
+    k_at, v_at = (at[None, :, None] for at in _slab_pieces(H, kv_heads, D))
+    qs = jnp.pad(_into_slabs(q[:, 0], k_at, D),
+                 ((0, 0), (0, Hp - H), (0, 0)))
+    chunk = decode_chunk_pages(P)
+    n_chunks = P // chunk
+    # pages the row occupies, this step's write included (the tile body's
+    # clamp at L == 1). A row whose table starts at the trash page holds
+    # nothing: the host retired it (or is still prefilling it) and zeroed
+    # its table, while its cursor stays frozen wherever it ended, so its
+    # depth is one page of trash and not the frozen cursor's
+    live = jnp.where(pages[:, 0] == 0, 1,
+                     jnp.clip((positions + pt) // pt, 1, P))
+
+    def q_map(b, i, pages_ref, pos_ref, live_ref):
+        return (b, 0, 0)
+
+    def page_map(c):
+        def index(b, i, pages_ref, pos_ref, live_ref):
+            # past the live depth: the last live page again, so no copy
+            return (pages_ref[b, jnp.minimum(i * chunk + c,
+                                             live_ref[b] - 1)], 0, 0)
+        return index
+
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, chunk=chunk, page_tokens=pt,
+                          n_chunks=n_chunks, k_slabs=k_slabs,
+                          v_slabs=v_slabs, k_lanes=k_lanes, v_first=v_first,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # pages, positions, live
+            grid=(B, n_chunks),
+            in_specs=[pl.BlockSpec((1, Hp, Dp), q_map)]
+            + [pl.BlockSpec((1, pt, W), page_map(c)) for c in range(chunk)],
+            out_specs=pl.BlockSpec((1, Hp, Dp), q_map),
+            scratch_shapes=[pltpu.VMEM((Hp, k_lanes), q.dtype),       # qbd
+                            pltpu.VMEM((Hp, W - v_first), jnp.float32),
+                            pltpu.VMEM((Hp, _LANES), jnp.float32),    # m
+                            pltpu.VMEM((Hp, _LANES), jnp.float32)]),  # l
+        out_shape=jax.ShapeDtypeStruct((B, Hp, Dp), q.dtype),
+        interpret=interpret,
+    )(pages, positions, live, qs, *([kv_rows] * chunk))
+    return _out_of_slabs(out[:, :H], v_at, D)[:, None]
+
+
 def paged_attention(
     q: jnp.ndarray,         # [B, L, H, D] this call's queries
     kv_rows: jnp.ndarray,   # [N, pt, W] physical K‖V arena (post-write)
@@ -306,9 +521,13 @@ def paged_attention(
     Numerically equivalent (at f32-accumulation tolerance) to gathering
     ``kv_rows[pages]`` into contiguous ``[B, P*pt, Hkv, D]`` blocks of K
     and of V and attending under the positional causal mask — without the
-    gather: the kernel walks each row's table page by page. Callers must
-    have already scattered this call's K/V into the arena (the paged decode
-    branch in models/gpt.py writes first, then attends).
+    gather: the kernel walks each row's table, a decode step (``L == 1``,
+    the arena in the compute type) 16 pages a program with all heads the
+    rows of one product, anything else a page a program and a head at a
+    time (the module docstring's two bodies; the choice is read off the
+    shapes). Callers must have already scattered this call's K/V into the
+    arena (the paged decode branch in models/gpt.py writes first, then
+    attends).
 
     With ``k_scale``/``v_scale`` the arena is int8 (KUBEML_KV_QUANT=int8)
     and each page's per-head absmax rides the same clamped page walk as
@@ -331,6 +550,15 @@ def paged_attention(
     P = int(pages.shape[1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    quantized = k_scale is not None
+    if quantized and v_scale is None:
+        raise ValueError("k_scale and v_scale must be passed together")
+    pages = pages.astype(jnp.int32)
+    positions = positions.astype(jnp.int32)
+    scale = 1.0 / math.sqrt(D)
+    if L == 1 and not quantized:
+        return _decode_step(q, kv_rows, pages, positions, Hkv, scale,
+                            interpret)
     # queries move to [B, H, Lp, D] so a block's trailing dims are a clean
     # (tq, D) tile per head; L pads up to the storage dtype's sublane
     # minimum (8 rows of f32, 16 of bf16 — padded rows are sliced off; L
@@ -345,26 +573,15 @@ def paged_attention(
     # back from the offset of the head's V (not K's, where the V half of a
     # row starts mid-slab: GPT-2 XL's lane 1,600)
     Dp = _slab_width(D)
-    share = H // Hkv
-    if Dp != D:
-        # which D-wide piece of its slab a head's K (V) is, [1, H, 1, 1]
-        k_at, v_at = (np.asarray([_slab((first + h // share) * D, D)[1] // D
-                                  for h in range(H)]).reshape(1, H, 1, 1)
-                      for first in (0, Hkv))
-        qt = jnp.concatenate([jnp.where(k_at == i, qt, 0)
-                              for i in range(Dp // D)], axis=-1)
-    pages = pages.astype(jnp.int32)
-    positions = positions.astype(jnp.int32)
+    # which D-wide piece of its slab a head's K (V) is, [1, H, 1, 1]
+    k_at, v_at = (at.reshape(1, H, 1, 1) for at in _slab_pieces(H, Hkv, D))
+    qt = _into_slabs(qt, k_at, D)
     # pages the row actually occupies after this call's writes: the stream
     # clamp. At least one page (a fresh row still reads its own first
     # write); at most the table width (bucket-padding rows whose nominal
     # positions run past the table just re-read their last page — their
     # output is discarded, matching the gather path's clip).
     live = jnp.clip((positions + L + pt - 1) // pt, 1, P)
-    scale = 1.0 / math.sqrt(D)
-    quantized = k_scale is not None
-    if quantized and v_scale is None:
-        raise ValueError("k_scale and v_scale must be passed together")
 
     def q_map(b, j, i, pages_ref, pos_ref, live_ref):
         return (b, 0, j, 0)
@@ -423,10 +640,4 @@ def paged_attention(
         out_shape=jax.ShapeDtypeStruct((B, H, lqp, Dp), q.dtype),
         interpret=interpret,
     )(pages, positions, live, *operands)
-    out = out[:, :, :L]
-    if Dp != D:
-        pieces = [out[..., i * D:(i + 1) * D] for i in range(Dp // D)]
-        out = functools.reduce(
-            lambda kept, i: jnp.where(v_at == i, pieces[i], kept),
-            range(1, Dp // D), pieces[0])
-    return jnp.moveaxis(out, 1, 2)
+    return jnp.moveaxis(_out_of_slabs(out[:, :, :L], v_at, D), 1, 2)

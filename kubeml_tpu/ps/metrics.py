@@ -297,6 +297,15 @@ SERVING_COUNTERS = {
     "kubeml_serving_hc_positions_step_total": (
         "hc_positions_step", "The decode steps' part of "
                              "kubeml_serving_hc_positions_total"),
+    "kubeml_serving_walk_chunks_grid_total": (
+        "walk_chunks_grid", "Programs of the K/V page walk's decode body the "
+                            "decode steps ran, all attention layers: program "
+                            "rows x table width / pages a program (absent "
+                            "where steps do not take that body)"),
+    "kubeml_serving_walk_chunks_live_total": (
+        "walk_chunks_live", "Those of kubeml_serving_walk_chunks_grid_total "
+                            "inside a live row's depth: the ones that fetch "
+                            "pages and multiply, the rest are empty"),
 }
 # XLA compile counter, labeled {model, program} — rendered from the
 # snapshot's per-program compile-count dict rather than the scalar tables

@@ -2380,6 +2380,11 @@ class PagedBatchingDecoder(BatchingDecoder):
             clone_kw["state_rows"] = slots
         module = module.clone(**clone_kw)
         super().__init__(module, variables, mesh=None, **kw)
+        # a decode step's K/V page walk takes the kernel's decode body (one
+        # query a row, the arena in the compute type): its grid is counted
+        # at each chunk dispatch (_walk_chunks)
+        self.stats.walks_kv_chunks = (
+            impl == "pallas" and kvq == "off" and not self._latent)
         # drafter KV-read constant for the spec accounting: the early-exit
         # self-drafter reads only its truncated stack's layers; a separate
         # draft model reads its own geometry
@@ -3077,6 +3082,25 @@ class PagedBatchingDecoder(BatchingDecoder):
             total += min(-(-(row.pos_cap + adv) // pt), w) * pt
         return total
 
+    def _walk_chunks(self, w: int, size: int) -> tuple:
+        """``(live, grid)`` programs of the page walk's decode body in one
+        chunk of ``size`` steps over a ``w``-page table, all attention
+        layers: the kernel's grid is every program row by ``w / C`` chunks
+        of ``C`` pages, and a resident row's chunks up to its depth are the
+        ones with pages to read (step ``s``'s query sits ``s`` positions
+        past ``pos_cap``, as in :meth:`_chunk_kv_tokens`)."""
+        from ..ops.paged_attention import decode_chunk_pages
+
+        pages = decode_chunk_pages(w)
+        span = pages * self.page_tokens
+        live = sum(min(-(-(row.pos_cap + s) // span), w // pages)
+                   for row in self._slot_rows
+                   if row is not None and row.lease is not None
+                   and not row.prefilling
+                   for s in range(1, size + 1))
+        layers = int(self.module.depth)
+        return live * layers, size * self.slots * (w // pages) * layers
+
     def _dispatch_chunk_paged(self, size: int) -> tuple:
         # the table ships CLAMPED to the batch's live width (see
         # _live_table_width) and as a COPY: jnp.asarray of a numpy array
@@ -3098,6 +3122,8 @@ class PagedBatchingDecoder(BatchingDecoder):
         # one span per step: step s's query sits s positions past pos_cap
         kv_bytes = sum(self._chunk_kv_tokens(w, s)
                        for s in range(1, size + 1)) * self._kv_token_bytes
+        if self.stats.walks_kv_chunks:
+            self.stats.walk_chunks(*self._walk_chunks(w, size))
         self._bump_pos_caps(size)
         for row in self._slot_rows:
             if (row is not None and not row.done and not row.canceled

@@ -101,6 +101,16 @@ class DecoderStats:
         self.hc_sublayers = 0
         self.hc_positions_admit = 0
         self.hc_positions_step = 0
+        # the K/V page walk's decode body (ops/paged_attention.py): grid
+        # programs the decode steps ran, all attention layers (program rows
+        # x table width / pages a program), and those inside a live row's
+        # depth, which fetch pages and multiply; the rest are empty. Set and
+        # fed by the paged engine where its steps take that body
+        # (``walks_kv_chunks``); an engine that walks latents, a quantized
+        # arena or gathers reports neither
+        self.walks_kv_chunks = False
+        self.walk_chunks_live = 0
+        self.walk_chunks_grid = 0
         self.goodput_tokens = 0       # tokens delivered to a live waiter
         self.wasted_tokens = 0        # tokens routed to an aborted request
         # shared-prefix reuse (paged engine, serving/kvpool.py): admissions
@@ -310,6 +320,13 @@ class DecoderStats:
         with self._lock:
             self.moe_assignments += int(assignments)
             self.moe_experts_touched += int(touched)
+
+    def walk_chunks(self, live: int, grid: int) -> None:
+        """One dispatched decode chunk's page-walk programs, all steps and
+        attention layers: ``live`` of ``grid`` had pages to read."""
+        with self._lock:
+            self.walk_chunks_live += int(live)
+            self.walk_chunks_grid += int(grid)
 
     def fetch_started(self) -> None:
         with self._lock:
@@ -614,6 +631,9 @@ class DecoderStats:
                 and compiles_per_min > self.compile_storm_per_min)
             if self.compiles:
                 out["compiles"] = dict(self.compiles)
+            if self.walks_kv_chunks:
+                out["walk_chunks_live"] = float(self.walk_chunks_live)
+                out["walk_chunks_grid"] = float(self.walk_chunks_grid)
             # speculative-decoding series only exist once a spec step ran:
             # dense decoders / spec-off engines keep a clean exposition
             # (absence reads as "not speculating", like the paged gauges)

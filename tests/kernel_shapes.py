@@ -48,7 +48,8 @@ def kernel_cases():
             cases[f"paged_attention-{name}-L{L}"] = (fn, args)
     # GPT-2 XL's odd head count, 25 of 64 on 4 rows (rows of 3,200 lanes: a
     # head's V starts at lane 1,600 + 64 h), a decode step and one tile of
-    # a one-row admit; gpt2-large's 20 of 64 on 8 rows the same
+    # a one-row admit; gpt2-large's 20 of 64 on 8 rows the same. The decode
+    # step (L 1) takes the kernel's decode body, 16 pages a program
     for tag, heads, slab, pool in (("xl", 25, 4, 257), ("large", 20, 8, 513)):
         for L, nrows in ((1, slab), (128, 1)):
             cases[f"paged_attention-gpt2-{tag}-bf16-L{L}"] = (
@@ -61,8 +62,10 @@ def kernel_cases():
     # 128 over a 32-row slab (a decode step and one prefill tile), and the
     # mixer's state update, 32 heads of [256, 128] float32 in 2 groups
     rows, hq, hkv, d = 32, 20, 4, 128
-    for L, width in ((1, 32), (128, 8)):
-        cases[f"paged_attention-gqa-bf16-L{L}"] = (
+    # (the step at the cell's widest and narrowest table: 16 and 8 pages a
+    # program of the decode body)
+    for L, width, tag in ((1, 32, ""), (1, 8, "-P8"), (128, 8, "")):
+        cases[f"paged_attention-gqa-bf16-L{L}{tag}"] = (
             lambda q, kv, t, p: paged_attention(q, kv, t, p, kv_heads=4,
                                                 interpret=False),
             (_sds((rows, L, hq, d), jnp.bfloat16),

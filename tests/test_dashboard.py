@@ -308,6 +308,9 @@ UNPANELED = {
     "kubeml_serving_hc_positions_total": "model-specific; ad-hoc only",
     "kubeml_serving_hc_positions_admit_total": "model-specific; ad-hoc only",
     "kubeml_serving_hc_positions_step_total": "model-specific; ad-hoc only",
+    # the K/V page walk's decode body only; the benchmark reads their ratio
+    "kubeml_serving_walk_chunks_grid_total": "kernel-specific; ad-hoc only",
+    "kubeml_serving_walk_chunks_live_total": "kernel-specific; ad-hoc only",
 }
 
 

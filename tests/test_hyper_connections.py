@@ -578,14 +578,19 @@ def program_digest(module, tree):
 
 
 # made by this function on the parent commit (078c30b, PR 36), where the
-# block had one residual path: jax 0.9.0 on the CPU
+# block had one residual path: jax 0.9.0 on the CPU. The two K/V families'
+# "step" was made again on PR 38's tree, which moved it on purpose: a decode
+# step's page walk takes the kernel's decode body (ops/paged_attention.py;
+# 902 equations before for gpt2, 1295 for falcon). Their "admit" and both of
+# glm's are still PR 36's: the admission programs and the latent path are
+# the parent's, equation for equation
 PARENT_PROGRAMS = {
     "glm": {"admit": (843, "e8f497ae78ac0e01"),
             "step": (1023, "9b54162a64849441")},
     "gpt2": {"admit": (900, "f6f86aa87098da37"),
-             "step": (902, "72f28cdec1f89f67")},
+             "step": (690, "66df1c13cb8cce27")},
     "falcon": {"admit": (1297, "36129b785067064e"),
-               "step": (1295, "ca0c282c60281400")},
+               "step": (1083, "0667e1250f4b39dc")},
 }
 
 

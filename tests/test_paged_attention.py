@@ -30,7 +30,8 @@ from kubeml_tpu.api.types import GenerateRequest
 from kubeml_tpu.models.generation import generate, init_paged_cache
 from kubeml_tpu.models.gpt import CausalTransformer
 from kubeml_tpu.ops.attention import dot_product_attention
-from kubeml_tpu.ops.paged_attention import (kv_row_width, pack_kv_rows,
+from kubeml_tpu.ops.paged_attention import (decode_chunk_pages,
+                                            kv_row_width, pack_kv_rows,
                                             paged_attention,
                                             resolve_kv_quant,
                                             resolve_paged_attn)
@@ -73,14 +74,17 @@ def paged(q, k_tok, v_tok, pages, positions, **kw):
 def assert_equals_head_major(out, q, k_tok, v_tok, pages, positions, **kw):
     """``out`` against the same arrays laid out the old way, two arenas
     ``[N, Hkv, pt, D]``, through the kernel as it was. Bit for bit where a
-    head is whole 128-lane rows: the same arithmetic on the same numbers.
-    A narrower head is contracted over the 128 lanes it lies in, zeros in
-    the query beside it: the same products, summed in another order by the
-    CPU's dot, so equal to a few units in the last place."""
+    head is whole 128-lane rows and the call takes the tile body: the same
+    arithmetic on the same numbers. A narrower head is contracted over the
+    128 lanes it lies in, zeros in the query beside it, and a decode step
+    (one query, the arena in the compute type) over a whole row's K lanes,
+    16 pages a block: the same products, summed in another order, so equal
+    to a few units in the last place."""
     old = head_major_paged_attention(q, jnp.swapaxes(k_tok, 1, 2),
                                      jnp.swapaxes(v_tok, 1, 2), pages,
                                      positions, **kw)
-    if q.shape[-1] % 128 == 0:
+    decode_body = q.shape[1] == 1 and "k_scale" not in kw
+    if q.shape[-1] % 128 == 0 and not decode_body:
         np.testing.assert_array_equal(np.asarray(out), np.asarray(old))
     else:
         np.testing.assert_allclose(np.asarray(out), np.asarray(old),
@@ -91,6 +95,9 @@ def assert_equals_head_major(out, q, k_tok, v_tok, pages, positions, **kw):
 # is half zero lanes: GPT-2 XL's odd count (a head's V starts at lane
 # 1,600 + 64 h of a 3,200-lane row), and Falcon-H1's 20 on 4 of 128
 HEADS = {"toy": (2, 2, 16), "xl": (25, 25, 64), "falcon-h1": (20, 4, 128)}
+# the decode body's three published shapes: gpt2-large's 20 of 64 beside them
+DECODE_HEADS = {"large": (20, 20, 64), "xl": HEADS["xl"],
+                "falcon-h1": HEADS["falcon-h1"]}
 
 
 # --- op-level kernel parity (interpret mode) ---
@@ -133,6 +140,98 @@ def test_kernel_logit_parity(heads, L, positions):
     assert_equals_head_major(out, q, k_pages, v_pages, pages, pos)
 
 
+def walk_grid(fn, *args):
+    """The grid of the one ``pallas_call`` a traced call holds."""
+    calls = [e for e in jax.make_jaxpr(fn)(*args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1     # one unnamed call a layer a step
+    return tuple(calls[0].params["grid_mapping"].grid)
+
+
+# page_tokens 4 and a table of 32 pages: a decode program streams 16 pages,
+# 64 tokens, so a row's depth crosses a program's edge at 63 / 64 / 65
+DECODE_CASES = {
+    # (table width, positions, rows whose table is all trash)
+    "edges": (32, [0, 62, 63, 64, 127], ()),   # depth 1, C pt - 1, C pt,
+                                               # C pt + 1, the full table
+    "narrow": (4, [0, 5, 15], ()),             # P = 4: gcd(4, 16) pages
+    "retired": (32, [70, 0, 3, 90], (1, 3)),   # the host zeroed rows 1, 3;
+                                               # row 3's cursor froze deep
+}
+
+
+@pytest.mark.kernel
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", sorted(DECODE_HEADS))
+def test_decode_body_parity(heads, dtype, case):
+    """A decode step (``L == 1``, the arena in the compute type) takes the
+    body of its own: all heads the rows of one product over 16 pages a
+    program. Against the gather oracle and against the parent's kernel over
+    head-major pages, at the three published head shapes, both storage
+    types, depths on either side of a program's edge, a table narrower than
+    a program's pages, and rows the host retired beside live ones."""
+    rng = np.random.default_rng(5)
+    H, Hkv, D = DECODE_HEADS[heads]
+    P, positions, retired = DECODE_CASES[case]
+    B, pt = len(positions), 4
+    N = B * P + 1
+    dt = jnp.dtype(dtype)
+    k_pages = jnp.asarray(rng.normal(size=(N, pt, Hkv, D)), dt)
+    v_pages = jnp.asarray(rng.normal(size=(N, pt, Hkv, D)), dt)
+    table = 1 + rng.permutation(N - 1)[:B * P].reshape(B, P)
+    table[list(retired)] = 0
+    pages = jnp.asarray(table, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, D)), dt)
+    pos = jnp.asarray(positions, jnp.int32)
+    chunk = decode_chunk_pages(P)
+    assert chunk == min(P, 16)
+    assert walk_grid(lambda *a: paged(*a), q, k_pages, v_pages, pages,
+                     pos) == (B, P // chunk)
+    out = paged(q, k_pages, v_pages, pages, pos)
+    assert out.shape == q.shape and out.dtype == dt
+    # a retired row reads one page of trash, whatever its frozen cursor: its
+    # output is garbage the engine drops, and it must be a finite one
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    live = [b for b in range(B) if b not in retired or positions[b] < pt]
+    got = np.asarray(out, np.float32)[live]
+    ref = gather_reference(q, k_pages, v_pages, pages, pos)
+    old = head_major_paged_attention(q, jnp.swapaxes(k_pages, 1, 2),
+                                     jnp.swapaxes(v_pages, 1, 2), pages, pos)
+    tol = 0.05 if dtype == "bfloat16" else 2e-6 * D / 16
+    np.testing.assert_allclose(got, np.asarray(ref, np.float32)[live],
+                               atol=tol, rtol=tol)
+    np.testing.assert_allclose(got, np.asarray(old, np.float32)[live],
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.kernel
+@pytest.mark.parametrize("case,L,quantized,grid", [
+    ("decode step", 1, False, (3, 2)),          # (rows, P / 16 pages)
+    ("verify window", 5, False, (3, 1, 32)),    # (rows, query tiles, P)
+    ("int8 decode step", 1, True, (3, 1, 32)),
+    ("int8 suffix", 8, True, (3, 1, 32)),
+])
+def test_which_body_a_call_takes(case, L, quantized, grid):
+    """The rule is in the call's shapes: one query a row over an arena in
+    the compute type walks 16 pages a program; more queries, or int8 pages
+    with their scales, keep the tile body, a page a program."""
+    rng = np.random.default_rng(6)
+    B, H, D, pt, P, N = 3, 2, 16, 4, 32, 40
+    kf = rng.normal(size=(N, pt, H, D)).astype(np.float32)
+    pages = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
+    pos = jnp.asarray([5, 0, 100], jnp.int32)
+    if quantized:
+        kq, ks = quantize_pages(kf)
+        fn = lambda q, kv, s: paged(q, kv, kv, pages, pos, k_scale=s,
+                                    v_scale=s)
+        assert walk_grid(fn, q, kq, ks) == grid
+    else:
+        fn = lambda q, kv: paged(q, kv, kv, pages, pos)
+        assert walk_grid(fn, q, jnp.asarray(kf)) == grid
+
+
 @pytest.mark.kernel
 def test_kernel_query_tiles_long_prefill():
     """A prefill longer than one query tile (128) walks the table once
@@ -170,21 +269,32 @@ def test_kernel_bf16_storage_dtype():
 
 
 @pytest.mark.kernel
-def test_kernel_poisoned_trash_page_cannot_leak():
+@pytest.mark.parametrize("P,positions,L", [
+    (4, [5, 9], 1),       # a table narrower than a decode program's pages
+    (32, [5, 70], 1),     # two programs a row, the second dead for row 0
+    (32, [63, 64], 1),    # a depth on either side of a program's edge
+    (4, [5, 8], 4),       # the tile body (a verify window)
+])
+def test_kernel_poisoned_trash_page_cannot_leak(P, positions, L):
     """Every arena position a live row did NOT legitimately write — the
     reserved trash page 0, unallocated pages, and the slots past each
     row's cursor inside its own last page — is poisoned with huge values;
     the output must be bit-identical to the clean-arena run. This is the
     paged pool's whole safety story (stale writes are trash-redirected):
-    the read side must never reach what the write side quarantined."""
+    the read side must never reach what the write side quarantined. The
+    decode body multiplies a block of 16 pages at once, live and masked
+    slots in one product: a masked probability is exactly 0 there too."""
     rng = np.random.default_rng(2)
-    B, H, D, pt, P, N = 2, 2, 8, 4, 4, 10
-    positions = np.array([5, 9])  # rows attend positions 0..5 / 0..9
-    L = 1
+    B, H, D, pt = 2, 2, 8, 4
+    positions = np.array(positions)  # row b attends 0..positions[b] + L - 1
+    N = 2 * P + 4
     pages = np.zeros((B, P), np.int32)
     # row tables: live pages allocated, the rest left at 0 (trash)
-    pages[0, :2] = [3, 4]
-    pages[1, :3] = [5, 6, 7]
+    nxt = 3
+    for b in range(B):
+        n_live = -(-(positions[b] + L) // pt)
+        pages[b, :n_live] = np.arange(nxt, nxt + n_live)
+        nxt += n_live
     clean = np.zeros((N, pt, H, D), np.float32)
     written = set()
     for b in range(B):
