@@ -90,52 +90,19 @@ def emit(**row) -> None:
 
 
 # --------------------------------------------------------------------------
-# compile accounting (jax.monitoring): seconds in the backend compiler (a
-# persistent-cache hit lands here as its read time), cache hits and writes
+# compile accounting: the program's own compile clock (utils/tracing.py), its
+# sum over all threads (the phases compile on the cluster's) read at each
+# change of phase: seconds in the backend compiler (a persistent-cache hit
+# lands here as its read time), programs, cache hits and writes
 # --------------------------------------------------------------------------
 
-class CompileMeter:
-    def __init__(self):
-        import jax
-
-        self.phase = "startup"
-        self.rows: dict = {}
-        self._lock = threading.Lock()
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _row(self) -> dict:
-        return self.rows.setdefault(self.phase, {
-            "compile_seconds": 0.0, "programs": 0, "cache_hits": 0,
-            "cache_writes": 0})
-
-    def _duration(self, event: str, secs: float, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                row = self._row()
-                row["compile_seconds"] += secs
-                row["programs"] += 1
-
-    def _event(self, event: str, **_):
-        key = {"/jax/compilation_cache/cache_hits": "cache_hits",
-               # jax counts a "miss" where it WRITES an entry
-               "/jax/compilation_cache/cache_misses": "cache_writes"}.get(event)
-        if key:
-            with self._lock:
-                self._row()[key] += 1
-
-    def totals(self) -> dict:
-        out = {"compile_seconds": 0.0, "programs": 0, "cache_hits": 0,
-               "cache_writes": 0}
-        for row in self.rows.values():
-            for k in out:
-                out[k] += row[k]
-        out["compile_seconds"] = round(out["compile_seconds"], 2)
-        return out
-
-    def by_phase(self) -> dict:
-        return {p: {**r, "compile_seconds": round(r["compile_seconds"], 2)}
-                for p, r in self.rows.items()}
+def compile_row(before: dict, after: dict) -> dict:
+    return {
+        "compile_seconds": round(after["backend_s"] - before["backend_s"], 2),
+        "programs": after["programs"] - before["programs"],
+        "cache_hits": after["cache_hits"] - before["cache_hits"],
+        # jax counts a "miss" where it WRITES an entry
+        "cache_writes": after["cache_misses"] - before["cache_misses"]}
 
 
 # --------------------------------------------------------------------------
@@ -859,31 +826,43 @@ def run_pass(name: str, sz: Sizes = Sizes()) -> int:
 
     from kubeml_tpu.api.config import enable_compilation_cache
 
+    from kubeml_tpu.utils.tracing import compile_clock
+
     cache_dir = enable_compilation_cache()
-    meter = CompileMeter()
+    clock = compile_clock()
+    marks = [("startup", clock.totals())]   # (phase, the clock at its start)
+
+    def enter(phase: str) -> None:
+        marks.append((phase, clock.totals()))
+
     t0 = time.time()
-    meter.phase = "device"
+    enter("device")
     device = phase_device(cache_dir)
-    meter.phase = "kernels"
+    enter("kernels")
     phase_kernels(sz)
     data_root = tempfile.mkdtemp(prefix="kubeml-smoke-")
     cfg, cluster, client = start_cluster(data_root)
     try:
-        meter.phase = "train"
+        enter("train")
         phase_train(sz, cluster, client, device["count"])
-        meter.phase = "serve"
+        enter("serve")
         phase_serve(sz, cfg, cluster, client)
-        meter.phase = "spmd"
+        enter("spmd")
         phase_spmd(sz, cluster, client, device["count"])
     finally:
-        meter.phase = "shutdown"
+        enter("shutdown")
         cluster.stop()
         shutil.rmtree(data_root, ignore_errors=True)
+    enter("done")
+    rows = {phase: compile_row(a, b)
+            for (phase, a), (_, b) in zip(marks, marks[1:])}
     emit(phase="pass", name=name, ok=True,
          device={"platform": device["platform"],
                  "kind": device["device_kind"], "count": device["count"]},
          wall_seconds=round(time.time() - t0, 1),
-         compile=meter.totals(), compile_by_phase=meter.by_phase())
+         compile=compile_row(marks[0][1], marks[-1][1]),
+         # a phase in which nothing compiled has no row
+         compile_by_phase={p: r for p, r in rows.items() if any(r.values())})
     return 0
 
 

@@ -54,6 +54,7 @@ from ..api.errors import KubeMLError
 from ..models.generation import GenerationInputError, init_cache
 from ..models.gpt import PAD_ID, block_traces
 from ..utils import tracing
+from .stats import COMPILE_PHASES
 
 log = logging.getLogger("kubeml.serving")
 
@@ -876,6 +877,8 @@ class BatchingDecoder:
         # engine.admit span began (0 = tracing was off then)
         self._admit_from = 0.0
         self._tracer = tracing.get_tracer()
+        self._compile_clock = tracing.compile_clock()
+        self._startup_logged = False
         # programs are built lazily on the engine thread (first submit);
         # the slab is donated through every link of the dispatch chain
         # (on every backend: the CPU suite runs the chip's buffer lifetimes)
@@ -1073,6 +1076,25 @@ class BatchingDecoder:
         # no host or single device ever holds the whole KV cache
         return jax.jit(self._init_slab_impl,
                        out_shardings=self._slab_sharding)()
+
+    def _build_slab(self) -> _Slab:
+        """``_init_slab`` on the engine thread, timed: an abstract trace of
+        the model for the cache's shapes, then the programs that zero it
+        (one jit on a mesh, small eager ones without). Its wall is the
+        stats' ``slab`` start-up phase (again after a fault's rebuild) and
+        an ``engine.init_slab`` span with the compile clock's bracket; the
+        phases stay out of the engine programs' sums."""
+        before, t0 = self._compile_clock.read(), time.monotonic()
+        slab = jax.block_until_ready(self._init_slab())
+        seconds = time.monotonic() - t0
+        self.stats.startup("slab", seconds)
+        if self._tracer.enabled:
+            self._tracer.add_span(
+                "engine.init_slab", self._tracer.at(t0), seconds,
+                slots=self.slots, bytes=sum(
+                    int(l.nbytes) for l in jax.tree.leaves(slab.cache)),
+                **self._compile_clock.since(before))
+        return slab
 
     def _slab_shardings(self):
         """NamedSharding pytree for the slab: 4-d ``k``/``v`` cache leaves
@@ -1403,6 +1425,14 @@ class BatchingDecoder:
                                 "a decode slot", 504),
                     self.stats.deadline_expired, outcome="expired")
 
+    # what the ``ps.serving.decoder`` span says of the K/V memory: physical
+    # pages (the slot engine has none) and its bytes by the module's geometry
+    arena_pages = 0
+
+    @property
+    def arena_bytes(self) -> int:
+        return self.slots * self.max_len * self._kv_token_bytes
+
     def telemetry(self) -> dict:
         """One snapshot of the decoder's serving metrics: the stats counters
         plus the live queue-depth and slot-occupancy gauges (engine state —
@@ -1462,7 +1492,7 @@ class BatchingDecoder:
         rows step harmlessly (device-side live flags gate emission), so
         lateness costs idle slot-steps, not correctness."""
         try:
-            self._slab = self._init_slab()
+            self._slab = self._build_slab()
         except Exception as e:  # init/compile failure fails all waiters
             log.exception("%s: slab init failed", self.name)
             with self._cond:
@@ -1553,7 +1583,7 @@ class BatchingDecoder:
                     self._admits_inflight = 0
                 try:
                     self._reset_engine_state()
-                    self._slab = self._init_slab()
+                    self._slab = self._build_slab()
                 except Exception:
                     with self._cond:
                         self._closed = True
@@ -1689,10 +1719,17 @@ class BatchingDecoder:
         (``moe_layers``: its routed-expert layers, 0 likewise) — under a profiler
         annotation of the same name, and an admitting program (``group``
         set) closes the ``engine.admit`` span that began where its rows
-        were taken from the queue."""
+        were taken from the queue. A first call's span also carries
+        ``sig`` (the program and its shape signature) and the compile
+        clock's bracket of the call: ``trace_s``, ``lower_s``,
+        ``backend_s``, ``cache_hits``, ``cache_misses``; the same five add
+        into the stats whether the tracer is on or not."""
         cold = self.stats.compile_begin(program, sig)
         tracer, seq, requests = self._tracer, self._next_seq, None
         traced, annotation = tracer.enabled, _NO_ANNOTATION
+        # a first call's tracing, lowering and compile (or cache read) all
+        # happen inside it, on this thread: the compile clock's bracket
+        before = self._compile_clock.read() if cold else None
         t0 = time.monotonic()
         if traced:
             if group is not None:
@@ -1712,17 +1749,21 @@ class BatchingDecoder:
         with annotation:
             out = fn(*args)
         t1 = time.monotonic()
+        phases = self._compile_clock.since(before) if cold else None
         if traced:
+            first = ({"sig": f"{program}{sig}",
+                      **{k: phases[k] for k in COMPILE_PHASES}}
+                     if cold else {})
             self._engine_span(
                 "engine.dispatch", tracer.at(t0), t1 - t0, requests, seq=seq,
                 program=kind, steps=steps, width=width, cold=cold,
                 state_rows=state_rows, moe_layers=self._moe_layers,
                 rows_live=sum(r is not None for r in self._slot_rows),
-                depth=self._depth, ahead=len(self._inflight))
+                depth=self._depth, ahead=len(self._inflight), **first)
             # a later group of the same wave is prepared from here on
             self._admit_from = tracer.at(t1)
         if cold:
-            self.stats.compiled(program, t1 - t0)
+            self.stats.compiled(program, t1 - t0, phases)
         elif self._turn_from:
             self._host_s.add(t1 - self._turn_from)
         self._turn_from = 0.0
@@ -2072,6 +2113,13 @@ class BatchingDecoder:
             # compile wall — it lands in cold_start, not the TTFT series
             self.stats.first_token(entry.first_token_at - entry.submitted_at,
                                    cold=cold)
+            if not self._startup_logged:
+                # the operator's reading of a replica's start, once
+                self._startup_logged = True
+                log.info("%s: first token %.2f s after its request; "
+                         "start-up in s: %s", self.name,
+                         entry.first_token_at - entry.submitted_at,
+                         self.stats.startup_report())
         if row.first_emit_at == 0.0:
             row.first_emit_at = now
         else:
@@ -2285,6 +2333,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 kw.get("name", "decoder"))
             use_trie = False
         self._pool = KVPool(npages, pt, prefix_cache=use_trie)
+        self.arena_pages = npages
         # --- paged-attention read path (KUBEML_PAGED_ATTN=auto|pallas|
         # gather, ops/paged_attention.py): resolved HERE and cloned onto
         # the module, so the impl is part of the module identity every jit
@@ -3630,6 +3679,11 @@ class PagedBatchingDecoder(BatchingDecoder):
         req.evt.set()
         return process_seq
 
+    @property
+    def arena_bytes(self) -> int:
+        return self.arena_pages * _kv_page_bytes(
+            self.module, self.page_tokens, self.kv_quant)
+
     def telemetry(self) -> dict:
         snap = super().telemetry()
         snap.update(self._pool.telemetry())
@@ -3686,7 +3740,7 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     def _loop(self) -> None:
         try:
-            self._slab = self._init_slab()
+            self._slab = self._build_slab()
         except Exception as e:
             log.exception("%s: paged slab init failed", self.name)
             with self._cond:
@@ -3818,7 +3872,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                     self._prefill_turn = True
                 try:
                     self._reset_engine_state()
-                    self._slab = self._init_slab()
+                    self._slab = self._build_slab()
                 except Exception:
                     # rebuild failed: the engine is permanently down — the
                     # salvaged rows live nowhere _fail_all can see, so

@@ -57,6 +57,15 @@ RATE_RING = 4096
 # compile-storm detection window: compiles/minute is judged over this span
 COMPILE_WINDOW_S = 60.0
 
+# a replica's start before its first program, and jax's phases of a first
+# call (with the persistent cache's two counts) as telemetry() names them
+STARTUP_PHASES = ("restore", "hold", "decoder", "slab")
+COMPILE_PHASES = {"trace_s": "compile_trace_seconds",
+                  "lower_s": "compile_lower_seconds",
+                  "backend_s": "compile_backend_seconds",
+                  "cache_hits": "compile_cache_hits",
+                  "cache_misses": "compile_cache_misses"}
+
 logger = logging.getLogger(__name__)
 
 
@@ -174,6 +183,15 @@ class DecoderStats:
         # above it flips the storm gauge and logs a throttled warning)
         self._compiled: set = set()
         self.compiles: Dict[str, int] = {}
+        # where a replica's start went, in seconds: the parameter server's
+        # load path (checkpoint files to leaves, leaves held in the served
+        # type, the decoder's construction) and the slab's own program,
+        # then the first call of each engine program by jax's phases
+        # (utils.tracing.CompileClock) beside the walls the ``compile``
+        # histogram sums, and the persistent cache's hits and writes. They
+        # grow at a first call and nowhere else
+        self.startup_seconds = dict.fromkeys(STARTUP_PHASES, 0.0)
+        self.compile_phases = dict.fromkeys(COMPILE_PHASES, 0.0)
         self.compile_storm_per_min = 0.0
         self._storm_logged_at = 0.0
         self._lat: deque = deque(maxlen=LATENCY_RING)        # (total_s,)
@@ -449,15 +467,38 @@ class DecoderStats:
             self._compiled.add(key)
             return True
 
-    def compiled(self, program: str, seconds: float) -> None:
-        """One first-call program wall (trace + XLA compile + execute):
-        bumps the per-program compile counter, the compile-wall histogram,
+    def startup(self, phase: str, seconds: float) -> None:
+        """Seconds of one of :data:`STARTUP_PHASES`, timed where it ran."""
+        with self._lock:
+            self.startup_seconds[phase] += max(0.0, float(seconds))
+
+    def startup_report(self) -> str:
+        """Where the start went, for the line a decoder logs at its first
+        token: every phase in seconds, the cache's hits and writes."""
+        with self._lock:
+            parts = [f"{k} {v:.2f}" for k, v in self.startup_seconds.items()]
+            c = self.compile_phases
+            return (f"{', '.join(parts)}; {len(self._compiled)} programs "
+                    f"{self._hist_compile.sum:.2f} (trace {c['trace_s']:.2f}"
+                    f", lower {c['lower_s']:.2f}, backend "
+                    f"{c['backend_s']:.2f}; cache {c['cache_hits']:.0f} hits"
+                    f", {c['cache_misses']:.0f} writes)")
+
+    def compiled(self, program: str, seconds: float,
+                 phases: Optional[Dict[str, float]] = None) -> None:
+        """One first-call program wall: tracing, lowering and XLA's compile
+        or the persistent cache's read. Not the execution: the dispatch that
+        follows is asynchronous. ``phases`` is the compile clock's bracket
+        of the same call (:data:`COMPILE_PHASES`). Bumps the per-program
+        compile counter, the compile-wall histogram,
         and the storm-rate series; logs a throttled warning when the
         60s compile rate exceeds the configured compiles/min knob."""
         now = time.monotonic()
         with self._lock:
             self.compiles[program] = self.compiles.get(program, 0) + 1
             self._hist_compile.observe(max(0.0, float(seconds)))
+            for key in COMPILE_PHASES:
+                self.compile_phases[key] += (phases or {}).get(key, 0.0)
             total = sum(self.compiles.values())
             self._compile_series.observe(float(total), t=now)
             per_min = self._compile_series.rate(
@@ -622,7 +663,12 @@ class DecoderStats:
                 "prefill_chunks": float(self.prefill_chunks),
                 "prefill_chunk_tokens": float(self.prefill_chunk_tokens),
                 "compiled_programs": float(len(self._compiled)),
+                "compile_wall_seconds": float(self._hist_compile.sum),
             }
+            for phase, seconds in self.startup_seconds.items():
+                out[f"startup_{phase}_seconds"] = seconds
+            for key, name in COMPILE_PHASES.items():
+                out[name] = float(self.compile_phases[key])
             compiles_per_min = self._compile_series.rate(
                 COMPILE_WINDOW_S, now=now) * 60.0
             out["compiles_per_minute"] = compiles_per_min

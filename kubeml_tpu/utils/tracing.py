@@ -527,6 +527,140 @@ def get_tracer() -> Tracer:
     return _global
 
 
+# --- the compile clock (jax.monitoring) ---
+
+# the events jax reports around the three phases of a first call, each with
+# its start and its end
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+# the persistent cache's events; jax counts a "miss" where it WRITES an entry
+_COMPILE_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_ZERO_PHASES = dict.fromkeys(_COMPILE_PHASES.values(), 0.0)
+_ZERO_COUNTS = {"programs": 0, **dict.fromkeys(_COMPILE_COUNTS.values(), 0)}
+# a thread keeps the ends of its phase intervals to find those a later, outer
+# interval contains, for as long as an outer one can still be open: no trace
+# or compile lasts an hour. (A cap by count fails: one program's trace holds
+# thousands of small jits side by side, and a forgotten one is counted again
+# under its caller.) An older end is forgotten, its seconds stay counted
+_COMPILE_HORIZON_S = 3600.0
+
+
+class _ThreadCompiles:
+    """One thread's compile seconds and counts so far."""
+
+    __slots__ = ("seconds", "counts", "marks", "floor")
+
+    def __init__(self):
+        self.seconds = dict(_ZERO_PHASES)
+        self.counts = dict(_ZERO_COUNTS)
+        # (end of an interval, the seconds as they stood at that end)
+        self.marks: deque = deque()
+        self.floor = dict(_ZERO_PHASES)   # the seconds before marks[0]
+
+
+class CompileClock:
+    """Where jax says compiling went: seconds tracing, lowering and in the
+    backend compiler (a persistent-cache hit lands there as its read time),
+    programs compiled, cache hits and writes, from ``jax.monitoring``.
+
+    The events fire on the thread that compiles, so the clock accumulates
+    per thread and a caller brackets a region with :meth:`read` before and
+    :meth:`since` after: what another thread compiled meanwhile is not in
+    it. :meth:`totals` is the sum over all threads.
+
+    jax reports a jit traced inside another's trace, and a helper traced
+    while a program is lowered, as events of their own inside their
+    caller's interval. A thread's seconds are the union of its intervals
+    (jax gives each event's start and end, and an outer one after those it
+    holds): an interval that contains earlier ones replaces them, under its
+    own phase. So the three phases of a bracket never add up to more than
+    the bracket's wall."""
+
+    def __init__(self):
+        self._tls = threading.local()
+        self._lock = threading.Lock()
+        self._total = {**_ZERO_PHASES, **_ZERO_COUNTS}
+
+    def _mine(self) -> _ThreadCompiles:
+        st = getattr(self._tls, "st", None)
+        if st is None:
+            st = self._tls.st = _ThreadCompiles()
+        return st
+
+    # --- the two listeners (compiling threads) ---
+
+    def span(self, event: str, start: float, end: float, **_: Any) -> None:
+        phase = _COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        st = self._mine()
+        while st.marks and st.marks[-1][0] > start:
+            st.marks.pop()
+        now = dict(st.marks[-1][1] if st.marks else st.floor)
+        now[phase] += end - start
+        programs = int(phase == "backend_s")
+        with self._lock:
+            for k, v in now.items():
+                self._total[k] += v - st.seconds[k]
+            self._total["programs"] += programs
+        st.counts["programs"] += programs
+        st.seconds = now
+        st.marks.append((end, now))
+        while st.marks[0][0] < end - _COMPILE_HORIZON_S:
+            st.floor = st.marks.popleft()[1]
+
+    def event(self, event: str, **_: Any) -> None:
+        key = _COMPILE_COUNTS.get(event)
+        if key is None:
+            return
+        self._mine().counts[key] += 1
+        with self._lock:
+            self._total[key] += 1
+
+    # --- reading ---
+
+    def read(self) -> Dict[str, float]:
+        """What this thread has compiled so far."""
+        st = self._mine()
+        return {**st.seconds, **st.counts}
+
+    def since(self, before: Dict[str, float]) -> Dict[str, float]:
+        """What this thread compiled since ``before`` (a :meth:`read`)."""
+        return {k: v - before[k] for k, v in self.read().items()}
+
+    def totals(self) -> Dict[str, float]:
+        """What every thread of the process has compiled so far."""
+        with self._lock:
+            return dict(self._total)
+
+
+_compile_clock: Optional[CompileClock] = None
+_compile_clock_lock = threading.Lock()
+
+
+def compile_clock() -> CompileClock:
+    """The process's one compile clock; the first call hangs its two
+    listeners on ``jax.monitoring``, for the life of the process: it
+    registers once, never per decoder. A listener that is never called
+    between compiles costs the hot path nothing."""
+    global _compile_clock
+    with _compile_clock_lock:
+        if _compile_clock is None:
+            import jax
+
+            clock = CompileClock()
+            jax.monitoring.register_event_time_span_listener(clock.span)
+            jax.monitoring.register_event_listener(clock.event)
+            _compile_clock = clock
+    return _compile_clock
+
+
 # --- device (XLA) profiling ---
 
 

@@ -73,3 +73,38 @@ def test_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     assert json.loads(lines[-1]) == {"ok": True, "device": device}
     summary = json.loads(lines[-2])
     assert summary["phase"] == "cache" and lines[-2].endswith('"claim": null}')
+
+
+def test_a_phases_compile_row_is_the_programs_clock_between_two_readings():
+    """``run_pass`` keeps no meter of its own: a phase's row is the program's
+    compile clock (``utils/tracing.py``), its sum over all threads, read where
+    the phase begins and where it ends, under the keys the summary had."""
+    import threading
+
+    from kubeml_tpu.utils import tracing
+
+    sys.path.insert(0, str(REPO))
+    try:
+        import chip_smoke
+    finally:
+        sys.path.pop(0)
+
+    assert not hasattr(chip_smoke, "CompileMeter")
+    clock = tracing.CompileClock()   # fed by hand
+    backend = "/jax/core/compile/backend_compile_duration"
+    clock.span(backend, 1.0, 1.25)   # before the phase: not in its row
+    before = clock.totals()
+
+    def cluster_thread():
+        clock.span(backend, 2.0, 3.5)
+        clock.event("/jax/compilation_cache/cache_misses")
+        clock.span("/jax/core/compile/jaxpr_trace_duration", 3.5, 4.0)
+
+    t = threading.Thread(target=cluster_thread)
+    t.start()
+    t.join(10.0)
+    clock.span(backend, 2.5, 2.625)  # meanwhile, on this thread
+    clock.event("/jax/compilation_cache/cache_hits")
+    assert chip_smoke.compile_row(before, clock.totals()) == {
+        "compile_seconds": 1.62, "programs": 2, "cache_hits": 1,
+        "cache_writes": 1}

@@ -2,8 +2,12 @@
 dispatched program leaves engine.dispatch / engine.fetch / engine.process
 spans that share its sequence number, on the clock the request-level
 serving.* spans use; with the tracer off nothing is recorded; and a
-program's service time is taken where it completes, not over the pipe."""
+program's service time is taken where it completes, not over the pipe.
+And where a replica's start goes (ISSUE 39): a program's first call carries
+the compile clock's bracket of it, the slab's program likewise, and the
+decoder's telemetry holds the seconds whether the tracer is on or not."""
 
+import threading
 import time
 
 import numpy as np
@@ -221,3 +225,181 @@ def test_one_clock(tracer):
     a, b = tracer.spans()
     assert t0 <= a.start <= b.start <= tracer.at(mono) <= t1
     assert abs(t1 - time.time()) < 5.0   # a wall clock, not a bare counter
+
+
+# --- where a replica's start goes (ISSUE 39) ---
+
+PHASES = ("trace_s", "lower_s", "backend_s", "cache_hits", "cache_misses")
+START_KEYS = ("startup_restore_seconds", "startup_hold_seconds",
+              "startup_decoder_seconds", "startup_slab_seconds",
+              "compile_trace_seconds", "compile_lower_seconds",
+              "compile_backend_seconds", "compile_wall_seconds",
+              "compile_cache_hits", "compile_cache_misses")
+TRACE, LOWER, BACKEND = tracing._COMPILE_PHASES
+HIT, MISS = tracing._COMPILE_COUNTS
+
+
+def _compiled_seconds(stats):
+    return (stats["compile_trace_seconds"] + stats["compile_lower_seconds"]
+            + stats["compile_backend_seconds"])
+
+
+@pytest.mark.parametrize("build", ENGINES)
+def test_first_calls_carry_their_compile_phases(served, tracer, build):
+    tracer.enabled = True
+    dec = build(*served)
+    try:
+        _stream_one(dec)
+        _settle(tracer)
+        stats = dec.telemetry()
+    finally:
+        dec.close()
+    dispatches = tracer.spans("engine.dispatch")
+    cold = [s for s in dispatches if s.attrs["cold"]]
+    warm = [s for s in dispatches if not s.attrs["cold"]]
+    # the admit program and the one-step program, each compiled once; the
+    # answer's other steps run what is compiled
+    assert len(cold) == stats["compiled_programs"] == 2 and len(warm) >= 4
+    assert {s.attrs["program"] for s in cold} == {"admit", "step"}
+    for s in cold:
+        assert s.attrs["sig"].startswith(("prefill(", "step(", "admit("))
+        assert all(s.attrs[k] >= 0 for k in PHASES)
+        spent = s.attrs["trace_s"] + s.attrs["lower_s"] + s.attrs["backend_s"]
+        assert 0.0 < spent <= s.duration
+    for s in warm:
+        assert "sig" not in s.attrs and not set(PHASES) & set(s.attrs)
+    # the same seconds reached the stats, beside the walls they lie inside
+    assert set(START_KEYS) <= set(stats)
+    assert _compiled_seconds(stats) == pytest.approx(sum(
+        s.attrs["trace_s"] + s.attrs["lower_s"] + s.attrs["backend_s"]
+        for s in cold))
+    assert stats["compile_wall_seconds"] == pytest.approx(
+        sum(s.duration for s in cold), abs=1e-3)
+    assert stats["compile_wall_seconds"] >= _compiled_seconds(stats) > 0.0
+    # the slab's own program: a span with its bracket, seconds in the stats,
+    # and nothing of it among the engine programs' sums
+    (slab,) = tracer.spans("engine.init_slab")
+    assert slab.attrs["slots"] == 2 and slab.attrs["bytes"] > 0
+    assert set(PHASES) <= set(slab.attrs)
+    assert stats["startup_slab_seconds"] == pytest.approx(slab.duration)
+    assert slab.start + slab.duration <= min(s.start for s in cold) + 1e-6
+    # built by hand, not by the parameter server: no load path to time
+    assert stats["startup_restore_seconds"] == 0.0
+    assert stats["startup_hold_seconds"] == 0.0
+
+
+def test_the_start_is_in_the_telemetry_with_the_tracer_off(served, tracer):
+    tracer.enabled = False
+    dec = PagedBatchingDecoder(*served, slots=2, chunk_steps=1,
+                               page_tokens=4)
+    try:
+        _stream_one(dec)
+        first = dec.telemetry()
+        _stream_one(dec)
+        second = dec.telemetry()
+    finally:
+        dec.close()
+    assert tracer.spans() == []
+    assert set(START_KEYS) <= set(first)
+    assert first["compile_wall_seconds"] >= _compiled_seconds(first) > 0.0
+    assert first["startup_slab_seconds"] > 0.0
+    # they grow at a first call and nowhere else
+    assert first["compiled_programs"] == second["compiled_programs"]
+    assert all(first[k] == second[k] for k in START_KEYS)
+
+
+def _compile_something(tag: float):
+    # a new function object each call: never in jax's in-memory caches
+    return jax.block_until_ready(
+        jax.jit(lambda x: x * tag + 1.0)(np.ones((3,), np.float32)))
+
+
+def test_bracket_leaves_out_another_threads_compile():
+    clock = tracing.compile_clock()
+    assert clock is tracing.compile_clock()   # one a process
+    before, total = clock.read(), clock.totals()
+    other = threading.Thread(target=_compile_something, args=(2.0,))
+    other.start()
+    other.join(60.0)
+    assert not other.is_alive()
+    assert clock.since(before) == dict.fromkeys(before, 0)
+    grown = clock.totals()
+    assert grown["programs"] >= total["programs"] + 1
+    assert grown["backend_s"] > total["backend_s"]
+    _compile_something(3.0)
+    mine = clock.since(before)
+    assert mine["programs"] >= 1
+    assert min(mine["trace_s"], mine["lower_s"], mine["backend_s"]) > 0.0
+
+
+def test_cache_counters_follow_the_listener():
+    """A CPU box may have no persistent cache: the two events by hand,
+    through jax.monitoring, as jax's compiler records them."""
+    clock = tracing.compile_clock()
+    before, total = clock.read(), clock.totals()
+    seen = {}
+
+    def elsewhere():
+        at = clock.read()
+        jax.monitoring.record_event(HIT)
+        seen.update(clock.since(at))
+
+    for event in (HIT, HIT, MISS, "/jax/some/other_event"):
+        jax.monitoring.record_event(event)
+    other = threading.Thread(target=elsewhere)
+    other.start()
+    other.join(60.0)
+    mine = clock.since(before)
+    assert (mine["cache_hits"], mine["cache_misses"]) == (2, 1)
+    assert (seen["cache_hits"], seen["cache_misses"]) == (1, 0)
+    grown = clock.totals()
+    assert grown["cache_hits"] - total["cache_hits"] == 3
+    assert grown["cache_misses"] - total["cache_misses"] == 1
+
+
+def test_a_phase_is_the_union_of_its_intervals():
+    """jax reports a jit traced inside another's trace as an event of its
+    own inside its caller's interval: an interval that contains earlier
+    ones replaces them, so the phases never add up to more than the wall."""
+    clock = tracing.CompileClock()   # fed by hand, on no listener
+    clock.span(TRACE, 10.1, 10.2)          # an inner jit's trace
+    clock.span(TRACE, 10.3, 10.4)          # another
+    clock.span(TRACE, 10.0, 10.5)          # their caller's: holds both
+    assert clock.read()["trace_s"] == pytest.approx(0.5)
+    clock.span(TRACE, 10.6, 10.7)          # a helper traced while lowering
+    clock.span(LOWER, 10.5, 11.0)          # the lowering that holds it
+    clock.span(BACKEND, 11.0, 13.0)        # begins where the lowering ends
+    clock.span("/jax/core/compile/something_else", 0.0, 99.0)
+    got = clock.read()
+    assert got["trace_s"] == pytest.approx(0.5)
+    assert got["lower_s"] == pytest.approx(0.5)
+    assert got["backend_s"] == pytest.approx(2.0) and got["programs"] == 1
+    assert clock.totals() == got           # one thread fed it
+    # a later program on the same thread adds to each phase
+    clock.span(TRACE, 20.0, 21.0)
+    clock.span(LOWER, 21.0, 21.25)
+    clock.span(BACKEND, 21.25, 21.5)
+    assert clock.since(got) == pytest.approx({
+        "trace_s": 1.0, "lower_s": 0.25, "backend_s": 0.25, "programs": 1,
+        "cache_hits": 0, "cache_misses": 0})
+
+
+def test_a_trace_of_thousands_of_small_jits_is_still_its_own_length():
+    """One program's trace holds thousands of jits side by side (3,200 in
+    the toy hyper-connected model, more at a published depth): each is kept
+    until its caller's interval arrives, however many there are, and only
+    what ended an hour before is forgotten."""
+    clock = tracing.CompileClock()
+    n = 5000
+    for i in range(n):
+        clock.span(TRACE, 100.0 + i * 0.002, 100.001 + i * 0.002)
+    assert clock.read()["trace_s"] == pytest.approx(n * 0.001)
+    clock.span(TRACE, 99.0, 111.0)         # the program's own trace
+    assert clock.read()["trace_s"] == pytest.approx(12.0)
+    # a day later: the marks behind the horizon go, their seconds stay
+    clock.span(LOWER, 86500.0, 86501.0)
+    clock.span(BACKEND, 86501.0, 86503.0)
+    assert clock.read() == pytest.approx({
+        "trace_s": 12.0, "lower_s": 1.0, "backend_s": 2.0, "programs": 1,
+        "cache_hits": 0, "cache_misses": 0})
+    assert len(clock._mine().marks) == 2

@@ -570,17 +570,7 @@ def test_chunk_occupancy_capacity_generalization():
 # --- PS integration: engine selection + payload field ---
 
 
-@pytest.mark.paged
-def test_ps_serves_finished_checkpoint_through_paged_engine(tmp_path):
-    """The PS picks the paged engine for capable models
-    (KUBEML_SERVING_PAGED default) and the /generate payload carries
-    prefix_cached_tokens; with the knob off it builds the dense engine."""
-    from kubeml_tpu.api.config import Config
-    from kubeml_tpu.functions.registry import FunctionRegistry
-    from kubeml_tpu.ps.parameter_server import ParameterServer
-    from kubeml_tpu.storage.checkpoint import FINAL_TAG, CheckpointStore
-
-    fn_src = """
+PAGED_FN = """
 import optax
 from kubeml_tpu.runtime.model import KubeModel
 from kubeml_tpu.data.dataset import KubeDataset
@@ -599,8 +589,17 @@ class Model(KubeModel):
     def configure_optimizers(self):
         return optax.adamw(self.lr)
 """
+
+
+def _finished_job(tmp_path, **config):
+    """A finished job "pagedjob" of the function "pagedfn" under
+    ``tmp_path``: (config, registry)."""
+    from kubeml_tpu.api.config import Config
+    from kubeml_tpu.functions.registry import FunctionRegistry
+    from kubeml_tpu.storage.checkpoint import FINAL_TAG, CheckpointStore
+
     cfg = Config(data_root=tmp_path, serving_slots=2, serving_chunk_steps=4,
-                 serving_page_tokens=4)
+                 serving_page_tokens=4, **config)
     cfg.ensure_dirs()
     module = CausalTransformer(vocab_size=64, max_len=32, embed_dim=32,
                                depth=2, num_heads=4)
@@ -609,10 +608,23 @@ class Model(KubeModel):
 
     variables = jax.tree.map(np.asarray, nn.meta.unbox(variables))
     reg = FunctionRegistry(config=cfg)
-    reg.create("pagedfn", fn_src)
+    reg.create("pagedfn", PAGED_FN)
     CheckpointStore(config=cfg).save(
         "pagedjob", variables, epoch=1, tag=FINAL_TAG,
         meta={"request": {"function_name": "pagedfn"}})
+    return cfg, reg
+
+
+@pytest.mark.paged
+def test_ps_serves_finished_checkpoint_through_paged_engine(tmp_path):
+    """The PS picks the paged engine for capable models
+    (KUBEML_SERVING_PAGED default) and the /generate payload carries
+    prefix_cached_tokens; with the knob off it builds the dense engine."""
+    from kubeml_tpu.api.config import Config
+    from kubeml_tpu.functions.registry import FunctionRegistry
+    from kubeml_tpu.ps.parameter_server import ParameterServer
+
+    cfg, reg = _finished_job(tmp_path)
     ps = ParameterServer(registry=reg, config=cfg)
     out = ps.generate("pagedjob", GenerateRequest(
         prompts=[[1, 2, 3, 4, 5, 6, 7, 8]], max_new_tokens=4))
@@ -634,3 +646,60 @@ class Model(KubeModel):
     dec2 = ps2._decoders["pagedjob"][0]
     assert isinstance(dec2, BatchingDecoder)
     assert not isinstance(dec2, PagedBatchingDecoder)
+
+
+@pytest.mark.paged
+def test_a_fresh_replicas_first_request_is_one_tree(tmp_path):
+    """Where a replica's start goes (ISSUE 39): the first /generate on a
+    finished job loads it under the request's server span (restore, hold,
+    decoder, then the request itself, one trace) and the decoder's stats
+    take the seconds; a second request loads nothing."""
+    from kubeml_tpu.ps.parameter_server import ParameterServer
+    from kubeml_tpu.utils import tracing
+
+    cfg, reg = _finished_job(tmp_path, serving_param_dtype="bfloat16")
+    ps = ParameterServer(registry=reg, config=cfg)
+    tracer = tracing.get_tracer()
+    was_on = tracer.enabled
+    tracer.clear()
+    tracer.enabled = True
+    load = ("ps.serving.restore", "ps.serving.hold", "ps.serving.decoder")
+    try:
+        trees = []
+        for _ in range(2):
+            # utils.httpd records this span around the route's handler
+            with tracer.span("ps POST /generate/pagedjob", service="ps") as s:
+                out = ps.generate("pagedjob", GenerateRequest(
+                    prompts=[[1, 2, 3, 4, 5, 6, 7, 8]], max_new_tokens=4))
+            under = [c for c in tracer.spans() if c.parent_id == s.span_id]
+            assert {c.trace_id for c in under} == {s.trace_id}
+            (req,) = [c for c in under if c.name == "serving.request"]
+            assert req.attrs["job"] == out["request_id"]
+            trees.append((s, [c for c in under if c.name in load]))
+        stats = ps._decoders["pagedjob"][0].telemetry()
+    finally:
+        tracer.enabled = was_on
+        tracer.clear()
+        for dec, _ in ps._decoders.values():
+            dec.close()
+    (first, loaded), (_, again) = trees
+    assert [c.name for c in loaded] == list(load) and again == []
+    restore, hold, decoder = loaded
+    assert restore.start + restore.duration <= hold.start <= decoder.start
+    assert decoder.start + decoder.duration <= first.start + first.duration
+    assert restore.attrs["job"] == "pagedjob"
+    assert restore.attrs["kind"] == "flat" and restore.attrs["leaves"] > 0
+    # stored wide, held narrow: the hold's casts compiled on this thread
+    assert (hold.attrs["from"], hold.attrs["to"]) == ("float32", "bfloat16")
+    assert restore.attrs["bytes"] == 2 * hold.attrs["bytes"] > 0
+    assert hold.attrs["programs"] >= 1 and hold.attrs["backend_s"] > 0.0
+    assert decoder.attrs["slots"] == 2 and decoder.attrs["pages"] == 17
+    assert decoder.attrs["arena_bytes"] > 0
+    for span, key in zip(loaded, ("restore", "hold", "decoder")):
+        assert stats[f"startup_{key}_seconds"] == pytest.approx(
+            span.duration, abs=5e-3)
+    assert stats["startup_slab_seconds"] > 0.0
+    # the engine programs' sums hold nothing of the hold's casts
+    assert stats["compile_wall_seconds"] >= (
+        stats["compile_trace_seconds"] + stats["compile_lower_seconds"]
+        + stats["compile_backend_seconds"]) > 0.0
