@@ -28,35 +28,43 @@ why the arena used to be two head-major arrays ``[N, H, pt, D]`` — and not
 of ``[N, pt, H*D]``; models/gpt.py says, where the arena is declared, what
 XLA did to the head-major arrays around their write.
 
-TWO BODIES, one rule. Both walk a row's page table through
+TWO BODIES, one walk. Both walk a row's page table through
 ``PrefetchScalarGridSpec`` scalar prefetch (the table, per-row positions and
 per-row live-page counts), so the K/V BlockSpec index maps translate a
 LOGICAL page index into the row's PHYSICAL arena page before the block is
 fetched — the "gather" happens per VMEM block inside the kernel's DMA
-stream, never as a materialized HBM tensor — and both carry the
-online-softmax state (acc/m/l) in VMEM scratch across the page axis exactly
-like ops/flash_attention.py, the output block revisited (constant index map
-along that axis) and written once at the final step. The choice is made on
-static shapes the call observes in its input, never on a knob:
+stream, never as a materialized HBM tensor. Both stream a CHUNK of ``C`` =
+``gcd(P, 16)`` pages a program (256 tokens at 16 a page; :func:`walk_chunk_pages`),
+the arena handed to the call ``C`` times with one page's index map each so
+that Pallas pipelines the page copies, and both carry the online-softmax
+state (acc/m/l) in VMEM scratch across the chunk axis exactly like
+ops/flash_attention.py — touched once a chunk, not once a page — the output
+block revisited (constant index map along that axis) and written once at
+the final step. The choice is made on static shapes the call observes in
+its input, never on a knob:
 
 * ``L == 1`` over an arena in the compute type — every decode step — takes
-  the DECODE BODY (:func:`_decode_kernel`): grid ``(B, P / C)``, ``C`` =
-  ``gcd(P, 16)`` pages (256 tokens at 16 a page) a program, the arena handed
-  to the call ``C`` times so that Pallas pipelines the page copies. The heads
+  the DECODE BODY (:func:`_decode_kernel`): grid ``(B, P / C)``. The heads
   are the rows of ONE product: the step's queries are set out against an
   arena row's K lanes (head ``h`` on sublane ``h``, its values where its K/V
   head's K lies, zeros elsewhere), so one ``dot_general`` over the lanes
   gives every head's scores for the block, one more every head's values,
   and under grouped-query attention one K/V read serves the whole group.
-  On the chip (PR 38) 11-17 x the tile body's speed at the three
+  On the chip (PR 38) 11-17 x the page-a-program form's speed at the three
   configurations' decode shapes, 62-73% of the K/V read's roofline at
   GPT-2's rows and 24-30% at Falcon-H1's narrower one.
 * everything else — ``L > 1`` (one-row admits, suffix prefill after a prefix
   hit, chunked prefill, speculative verify windows) and int8 pages with
-  their scales at any ``L`` — takes the TILE BODY (:func:`_pa_kernel`): grid
-  ``(B, Lt, P)`` (rows, query tiles of 128, logical pages) with the page
-  index innermost (sequential on TPU), a static loop over the heads inside a
-  program, each a ``[tq, Dp] x [pt, Dp]`` contraction over one page.
+  their scales at any ``L`` — takes the TILE BODY (:func:`_tile_kernel`):
+  grid ``(B, Lt, P / C)`` (rows, query tiles of 256, chunks) with the chunk
+  index innermost (sequential on TPU), a static loop over the heads inside
+  a program, each a ``[tq, Dp] x [C pt, Dp]`` contraction over the chunk's
+  rows laid end to end (256 query rows already fill the MXU, so the decode
+  body's layout buys nothing here), and the compare-and-select mask only in
+  the chunks that meet the causal diagonal or the row's depth. On the chip
+  (PR 40) 11-16 x the page-a-program form at the cells' admit shapes: one
+  row of 1,024 positions in 116 us a layer at 20 heads of 64 where it took
+  1,666.
 
 Per-row depth clamp — grid steps past a row's last live page repeat the
 previous physical index (the index maps clamp at ``live[b] - 1``, the same
@@ -64,7 +72,10 @@ trick the flash kernels use at the causal diagonal), so Pallas elides their
 HBM->VMEM copies, and ``pl.when`` skips their compute: HBM reads and FLOPs
 scale with the row's ACTUAL ``positions + L``, not the reserved table
 width; the grid step itself remains (``serving/stats.py walk_chunks_live``
-over ``walk_chunks_grid`` says how much of a decode step's grid is real).
+over ``walk_chunks_grid`` says how much of a decode step's grid is real,
+``tile_chunks_live`` over ``tile_chunks_grid`` how much of an admit's). A
+page past the depth is clamped by itself, inside a chunk too: a chunk that
+straddles the depth fetches its live pages alone.
 Dead rows the host already retired point at the pool's trash page 0; their
 output is garbage the engine discards anyway (exactly the gather path's
 contract), and the decode body reads one page for them whatever their
@@ -174,20 +185,63 @@ def unpack_kv_rows(rows, kv_heads: int, head_dim: int):
             rows[..., flat:2 * flat].reshape(shape))
 
 
-# queries per program: L <= _Q_TILE runs as one tile (decode steps, verify
-# windows, short suffixes); longer prefills walk the table once per tile so
-# the acc/m/l scratch stays a fixed few hundred KiB whatever the prompt
-# bucket (a 1024-token prefill as ONE tile would need ~18 MiB of scratch)
-_Q_TILE = 128
+# queries per program of the tile body: L <= _Q_TILE runs as one tile (verify
+# windows, short suffixes, Falcon-H1's 128-position admit); longer prefills
+# walk the table once per tile so the acc/m/l scratch stays a fixed few MiB
+# whatever the prompt bucket (10 MiB at 25 heads; a 1,024-token prefill as
+# ONE tile would need 31 MiB at 20). Chosen on the chip at the cells' admit
+# shapes (PR 40, CHANGES.md): 256 beat 128 by 20-24% at 1,024 and at 512
+# positions (a K slab is loaded into the MXU once for twice the rows, and
+# half as many tiles fetch the table again), for 3 s more of Mosaic compile
+# a prefill program on a start that finds no compile cache
+_Q_TILE = 256
+
+# pages a program of either body streams, chosen on the chip (PR 38 at the
+# three configurations' decode shapes: 16 beat 8 by 0-20% and 4 lost 15-35%;
+# PR 40 at their admit shapes, CHANGES.md); a table narrower than this, or no
+# multiple of it, walks gcd(P, _CHUNK) pages a program
+_CHUNK = 16
 
 
-def _tile_live(pos_b, live_b, j, tq: int, pt: int):
+def walk_chunk_pages(table_width: int) -> int:
+    """Pages one program of the walk streams from a table ``table_width``
+    pages wide, in either body (the engine counts both grids by this too)."""
+    return math.gcd(table_width, _CHUNK)
+
+
+def _tile_rows(n_queries: int, itemsize: int) -> int:
+    """Query rows of one tile-body program: ``n_queries`` up to the storage
+    type's sublane tile (8 rows of float32, 16 of bfloat16), at most
+    ``_Q_TILE``."""
+    return min(_round_up(n_queries, 32 // itemsize), _Q_TILE)
+
+
+def _tile_live(pos_b, live_b, j, tq: int, pt: int, least=jax.lax.min,
+               most=jax.lax.max):
     """Pages query tile ``j`` of a row can see: the row's live depth,
     further clamped at the tile's own last query position (causality — an
     early tile of a long prefill never streams the pages later tiles
-    write). At least one page, like ``live`` itself."""
-    return jnp.maximum(
-        jnp.minimum(live_b, (pos_b + (j + 1) * tq + pt - 1) // pt), 1)
+    write). At least one page, like ``live`` itself. On traced scalars, or
+    on host integers with Python's ``min`` and ``max``."""
+    return most(least(live_b, (pos_b + (j + 1) * tq + pt - 1) // pt), 1)
+
+
+def tile_chunks(position: int, n_queries: int, table_width: int,
+                page_tokens: int, itemsize: int) -> tuple:
+    """``(live, grid)`` programs of the tile body for ONE row whose
+    ``n_queries`` queries (the bucket, padding and all) start at
+    ``position``, a layer: the grid is query tiles by ``table_width / C``
+    chunks, and a tile's chunks up to its causal depth (and the row's) are
+    the ones with pages to read. The host's twin of the kernel's own clamp,
+    for the engine's count (``serving/stats.py tile_chunks_live``)."""
+    tq = _tile_rows(n_queries, itemsize)
+    tiles = _round_up(n_queries, tq) // tq
+    chunk = walk_chunk_pages(table_width)
+    depth = min(max(-(-(position + n_queries) // page_tokens), 1),
+                table_width)
+    live = sum(-(-_tile_live(position, depth, j, tq, page_tokens, min, max)
+                 // chunk) for j in range(tiles))
+    return live, tiles * (table_width // chunk)
 
 
 def _slab_width(head_dim: int) -> int:
@@ -203,33 +257,94 @@ def _slab(start: int, head_dim: int):
     return start - within, within
 
 
-def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, kv_ref, *rest,
-               page_tokens: int, n_pages: int, scale: float,
-               kv_heads: int, head_dim: int, quantized: bool):
-    """One (batch row, query tile, logical page) program covering ALL
-    heads. The page axis is the innermost (sequential) grid dimension;
-    acc/m/l carry across it in VMEM scratch, and the output is written at
-    the final page step. Heads are a static loop of plain 2-d
-    ``[tq, Dp] x [pt, Dp]`` contractions over the page block ``[pt, W]`` of
-    token rows — one contiguous page DMA per step serves K and V of every
-    head, and a head's K or V is a static lane slice of it. A head narrower
-    than a 128-lane row (GPT-2's 64) is read as the ALIGNED 128 lanes it
-    lies in (``Dp`` = 128, :func:`_slab`): a slice at lane offset 64 costs a
-    lane rotation a head, K and V, which made this walk 1.4-1.5 x slower
-    on the chip than over head-major pages (PR 33, the kernel alone at
-    gpt2-large's shapes, 8 rows 300-500 deep: 0.68 against 0.49 ms a layer;
-    aligned slabs 0.47). The query arrives with zeros in the slab's other
-    lanes, so the neighbour's K adds exact zeros to a score; the
-    accumulator and the output keep all ``Dp`` lanes, the neighbour's half
-    of them garbage that the wrapper drops.
+def _lay_rows(kv_refs, lane: int, stop: int, dtype=None):
+    """Lanes ``[lane, stop)`` of a chunk's page blocks laid end to end,
+    ``[C pt, stop - lane]`` (cast page by page where ``dtype`` is given)."""
+    rows = [r[0, :, lane:stop] for r in kv_refs]
+    if dtype is not None:
+        rows = [x.astype(dtype) for x in rows]
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
 
-    When ``quantized`` the page block arrives int8 and the page's per-head
-    absmax scales ride two extra ``[H, pt]`` inputs (each head's scalar
-    repeated along the page's tokens, so it multiplies a ``[tq, pt]``
-    score tile as an ordinary row broadcast); dequant happens here in
-    VMEM, int8_matmul-style — contract the raw int8 values (the cast is
-    exact, |q| <= 127), fold ``s/127`` into the f32 scores (K) and the
-    f32 probabilities (V) instead of into a dense page."""
+
+@functools.partial(jax.jit, inline=True, static_argnames=("scale",))
+def _attend(q, k, v, acc, m, l, visible, k_scale, v_scale, *, scale: float):
+    """One head's online-softmax step over one chunk of the tile body:
+    queries ``[tq, Dp]`` against the chunk's slab of K and of V
+    ``[C pt, Dp]``, the head's accumulator ``[tq, Dp]`` and its carries
+    ``m``, ``l`` (``[tq, 128]``, a row's scalar on every lane) in and out.
+    ``visible`` is the chunk's positional mask, None where every key is
+    visible to every query; ``k_scale`` / ``v_scale`` are an int8 chunk's
+    ``[1, C pt]`` rows of page scales. A jitted function inlined where it is
+    called: a program's heads share its one trace, which is what keeps a
+    25-head kernel's tracing near the page-a-program form's (PR 40: the
+    body written out a head cost a start 3 s a prefill program on the
+    chip's host)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # [tq, C pt]
+    if k_scale is not None:
+        s = s * (k_scale / _KV_QMAX)
+    if visible is not None:
+        s = jnp.where(visible, s, _NEG)
+    m_prev, l_prev = m[:, 0:1], l[:, 0:1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    if visible is not None:
+        p = jnp.where(visible, p, 0.0)  # masked keys stay exactly 0
+    l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+    if v_scale is not None:
+        # contract p against the raw int8 pages; a page's scale folds into
+        # p first (one scalar per page — same sum)
+        p = p * (v_scale / _KV_QMAX)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return (acc * alpha + pv, jnp.broadcast_to(m_new, m.shape),
+            jnp.broadcast_to(l_new, l.shape))
+
+
+def _tile_kernel(pages_ref, pos_ref, live_ref, q_ref, *rest, chunk: int,
+                 page_tokens: int, n_chunks: int, scale: float,
+                 kv_heads: int, head_dim: int, quantized: bool):
+    """One (batch row, query tile, chunk of ``chunk`` logical pages) program
+    covering ALL heads. The chunk axis is the innermost (sequential) grid
+    dimension; acc/m/l carry across it in VMEM scratch, touched once a chunk,
+    and the output is written at the final chunk. The arena arrives ``chunk``
+    times, one page ``[pt, W]`` of token rows each (the decode body's fetch:
+    Pallas pipelines the page copies); their rows are laid end to end once,
+    ``[C pt, lanes]``, and heads are a static loop of plain 2-d contractions
+    over the chunk: a head's K (V) is a static lane slice of those rows,
+    ``[C pt, Dp]``, so ``s = q . K^T`` is ``[tq, C pt]`` — 256 keys a
+    product at 16 pages of 16 — with one max, one exponential, one sum and
+    one rescale of the accumulator a chunk (:func:`_attend`). The loop
+    stays unrolled: as a ``fori_loop`` over dynamic lane slices it compiles
+    in a fifth of the time and runs at half the speed (PR 40, on the chip:
+    one head's softmax no longer overlaps the next one's products). A
+    tile's query rows already fill the MXU, so the decode body's
+    all-heads-one-product layout buys nothing here.
+
+    A head narrower than a 128-lane row (GPT-2's 64) is read as the ALIGNED
+    128 lanes it lies in (``Dp`` = 128, :func:`_slab`): a slice at lane
+    offset 64 costs a lane rotation a head, K and V (PR 33: 1.4-1.5 x on
+    the chip). The query arrives with zeros in the slab's other lanes, so
+    the neighbour's K adds exact zeros to a score; the accumulator and the
+    output keep all ``Dp`` lanes, the neighbour's half of them garbage that
+    the wrapper drops.
+
+    The compare-and-select mask runs only where it can matter: a chunk that
+    ends at or before the tile's first query position, inside the row's
+    depth, is visible to every query of the tile (the flash kernels'
+    off-diagonal case) and takes the same inner function without it.
+
+    When ``quantized`` the page blocks arrive int8 and the chunk's per-head
+    absmax scales ride two extra ``[Hkv, C pt]`` inputs (each page's scalar
+    repeated along the page's tokens, so a head's row multiplies a
+    ``[tq, C pt]`` score tile as an ordinary row broadcast); dequant happens
+    here in VMEM, int8_matmul-style — contract the raw int8 values (the
+    cast is exact, |q| <= 127), fold ``s/127`` into the f32 scores (K) and
+    the f32 probabilities (V) instead of into a dense page."""
+    kv_refs, rest = rest[:chunk], rest[chunk:]
     if quantized:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -238,11 +353,15 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, kv_ref, *rest,
     j = pl.program_id(1)
     i = pl.program_id(2)
     n_heads, tq, dp = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    # grouped-query attention: the page block holds the K/V heads only and
+    # grouped-query attention: a page block holds the K/V heads only and
     # query head h reads K/V head h // share (share 1: a head each)
     share = n_heads // kv_heads
     v0 = kv_heads * head_dim  # lane where the row's V half starts
-    pt = page_tokens
+    k_lanes = _slab(v0 - head_dim, head_dim)[0] + dp  # end of the K slabs
+    v_first = _slab(v0, head_dim)[0]                  # first V slab
+    span = chunk * page_tokens
+    q_first = pos_ref[b] + j * tq  # position of the tile's first query
+    tile_live = _tile_live(pos_ref[b], live_ref[b], j, tq, page_tokens)
 
     @pl.when(i == 0)
     def _init():
@@ -250,78 +369,62 @@ def _pa_kernel(pages_ref, pos_ref, live_ref, q_ref, kv_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # pages at or past the tile's live depth contribute nothing: their
-    # copies were elided by the clamped index map, their compute is
-    # skipped here
-    @pl.when(i < _tile_live(pos_ref[b], live_ref[b], j, tq, pt))
-    def _step():
-        # purely positional mask, identical to the gather path: query l sits
-        # at logical position positions[b] + l and attends every key at or
-        # before it (prompts are dense, decode writes contiguous — every
-        # earlier position is real by construction). Padded query rows
-        # (l >= the caller's true L) produce garbage that is sliced off.
-        q_pos = (pos_ref[b] + j * tq
-                 + jax.lax.broadcasted_iota(jnp.int32, (tq, pt), 0))
-        k_pos = i * pt + jax.lax.broadcasted_iota(jnp.int32, (tq, pt), 1)
-        visible = k_pos <= q_pos
+    def _chunk(masked: bool):
+        visible = None
+        if masked:
+            # purely positional, identical to the gather path: query l sits
+            # at logical position positions[b] + l and attends every key at
+            # or before it (prompts are dense, decode writes contiguous —
+            # every earlier position is real by construction). A page
+            # fetched again past the tile's depth lies past every real
+            # query too. Padded query rows (l >= the caller's true L)
+            # produce garbage that is sliced off.
+            q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32,
+                                                       (tq, span), 0)
+            k_pos = i * span + jax.lax.broadcasted_iota(jnp.int32,
+                                                        (tq, span), 1)
+            visible = k_pos <= q_pos
+        # the chunk's rows end to end, [C pt, lanes], once for all heads:
+        # the K lanes (to the end of the last K slab) and the V lanes (from
+        # the first V slab; GPT-2 XL's V starts mid-slab, so both hold
+        # lanes [1536, 1664)). An int8 page's rows pack four to a sublane
+        # and do not lie end to end as they are: they are cast page by page
+        k_all = _lay_rows(kv_refs, 0, k_lanes,
+                          q_ref.dtype if quantized else None)
+        v_all = _lay_rows(kv_refs, v_first, kv_refs[0].shape[2],
+                          jnp.float32 if quantized else None)
         for h in range(n_heads):
-            q = q_ref[0, h]      # [tq, Dp] (storage dtype; f32 accumulate)
             hk = h // share
-            # [pt, Dp] — one physical page, the slab of its rows' lanes
-            # this head's K (V) lies in
+            # [C pt, Dp]: the slab of the rows' lanes this head's K (V)
+            # lies in
             k0 = _slab(hk * head_dim, head_dim)[0]
-            u0 = _slab(v0 + hk * head_dim, head_dim)[0]
-            k_pg = kv_ref[0, :, k0:k0 + dp]
-            v_pg = kv_ref[0, :, u0:u0 + dp]
-            if quantized:
-                k_pg = k_pg.astype(q.dtype)
-            s = jax.lax.dot_general(
-                q, k_pg, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [tq, pt]
-            if quantized:
-                s = s * (ks_ref[0, 0, hk:hk + 1, :] / _KV_QMAX)
-            s = jnp.where(visible, s, _NEG)
-            m_prev = m_ref[h, :, 0:1]
-            l_prev = l_ref[h, :, 0:1]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            p = jnp.where(visible, p, 0.0)  # masked keys stay exactly 0
-            l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-            if quantized:
-                # contract p against the raw int8 page; the page scale
-                # folds into p first (one scalar per page — same sum)
-                pv = jax.lax.dot_general(
-                    p * (vs_ref[0, 0, hk:hk + 1, :] / _KV_QMAX),
-                    v_pg.astype(jnp.float32), (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            else:
-                pv = jax.lax.dot_general(
-                    p.astype(v_pg.dtype), v_pg, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            acc_ref[h] = acc_ref[h] * alpha + pv
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            u0 = _slab(v0 + hk * head_dim, head_dim)[0] - v_first
+            acc_ref[h], m_ref[h], l_ref[h] = _attend(
+                q_ref[0, h],     # [tq, Dp] (storage dtype; f32 accumulate)
+                jax.lax.slice_in_dim(k_all, k0, k0 + dp, axis=1),
+                jax.lax.slice_in_dim(v_all, u0, u0 + dp, axis=1),
+                acc_ref[h], m_ref[h], l_ref[h], visible,
+                ks_ref[0, 0, hk:hk + 1, :] if quantized else None,
+                vs_ref[0, 0, hk:hk + 1, :] if quantized else None,
+                scale=scale)
 
-    @pl.when(i == n_pages - 1)
+    # chunks at or past the tile's live depth contribute nothing: their
+    # copies were elided by the clamped index maps, their compute is skipped
+    # here
+    live_chunk = i * chunk < tile_live
+    clear = jnp.logical_and((i + 1) * span - 1 <= q_first,
+                            (i + 1) * chunk <= tile_live)
+    pl.when(jnp.logical_and(live_chunk, clear))(
+        functools.partial(_chunk, False))
+    pl.when(jnp.logical_and(live_chunk, jnp.logical_not(clear)))(
+        functools.partial(_chunk, True))
+
+    @pl.when(i == n_chunks - 1)
     def _finalize():
         for h in range(n_heads):
             l = l_ref[h, :, 0:1]
             o_ref[0, h] = (acc_ref[h] / jnp.maximum(l, 1e-9)
                            ).astype(o_ref.dtype)
-
-
-# pages a decode program streams, chosen on the chip at the three
-# configurations' decode shapes (PR 38, CHANGES.md: 16 beat 8 by 0-20% and 4
-# lost 15-35%); a table narrower than this, or no multiple of it, walks
-# gcd(P, _CHUNK) pages a program
-_CHUNK = 16
-
-
-def decode_chunk_pages(table_width: int) -> int:
-    """Pages one program of the decode body streams from a table
-    ``table_width`` pages wide (the engine counts its grid by this too)."""
-    return math.gcd(table_width, _CHUNK)
 
 
 def _slab_pieces(n_heads: int, kv_heads: int, head_dim: int):
@@ -410,8 +513,8 @@ def _decode_kernel(pages_ref, pos_ref, live_ref, q_ref, *rest, chunk: int,
     # maps, compute skipped here
     @pl.when(i * chunk < live_ref[b])
     def _chunk():
-        k = jnp.concatenate([r[0, :, :k_lanes] for r in kv_refs], axis=0)
-        v = jnp.concatenate([r[0, :, v_first:] for r in kv_refs], axis=0)
+        k = _lay_rows(kv_refs, 0, k_lanes)
+        v = _lay_rows(kv_refs, v_first, kv_refs[0].shape[2])
         s = jax.lax.dot_general(qbd_ref[...], k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         # the tile body's positional mask at L == 1: the query sits at
@@ -465,7 +568,7 @@ def _decode_step(q, kv_rows, pages, positions, kv_heads: int, scale: float,
     k_at, v_at = (at[None, :, None] for at in _slab_pieces(H, kv_heads, D))
     qs = jnp.pad(_into_slabs(q[:, 0], k_at, D),
                  ((0, 0), (0, Hp - H), (0, 0)))
-    chunk = decode_chunk_pages(P)
+    chunk = walk_chunk_pages(P)
     n_chunks = P // chunk
     # pages the row occupies, this step's write included (the tile body's
     # clamp at L == 1). A row whose table starts at the trash page holds
@@ -521,10 +624,10 @@ def paged_attention(
     Numerically equivalent (at f32-accumulation tolerance) to gathering
     ``kv_rows[pages]`` into contiguous ``[B, P*pt, Hkv, D]`` blocks of K
     and of V and attending under the positional causal mask — without the
-    gather: the kernel walks each row's table, a decode step (``L == 1``,
-    the arena in the compute type) 16 pages a program with all heads the
-    rows of one product, anything else a page a program and a head at a
-    time (the module docstring's two bodies; the choice is read off the
+    gather: the kernel walks each row's table 16 pages a program, a decode
+    step (``L == 1``, the arena in the compute type) with all heads the
+    rows of one product, anything else under tiles of 256 queries a head at
+    a time (the module docstring's two bodies; the choice is read off the
     shapes). Callers must have already scattered this call's K/V into the
     arena (the paged decode branch in models/gpt.py writes first, then
     attends).
@@ -561,14 +664,13 @@ def paged_attention(
                             interpret)
     # queries move to [B, H, Lp, D] so a block's trailing dims are a clean
     # (tq, D) tile per head; L pads up to the storage dtype's sublane
-    # minimum (8 rows of f32, 16 of bf16 — padded rows are sliced off; L
-    # is 1 on the decode step path) and, past one tile, to whole tiles
-    tq = min(_round_up(L, 32 // q.dtype.itemsize), _Q_TILE)
+    # minimum (padded rows are sliced off) and, past one tile, to whole tiles
+    tq = _tile_rows(L, q.dtype.itemsize)
     lqp = _round_up(L, tq)
     qt = jnp.moveaxis(q, 2, 1)
     qt = jnp.pad(qt, ((0, 0), (0, 0), (0, lqp - L), (0, 0)))
     # a head narrower than a 128-lane row meets the aligned slab of the
-    # arena's rows it lies in (_pa_kernel): its query sits at the head's
+    # arena's rows it lies in (_tile_kernel): its query sits at the head's
     # offset in that slab with zeros beside it, and its output is read
     # back from the offset of the head's V (not K's, where the V half of a
     # row starts mid-slab: GPT-2 XL's lane 1,600)
@@ -576,6 +678,8 @@ def paged_attention(
     # which D-wide piece of its slab a head's K (V) is, [1, H, 1, 1]
     k_at, v_at = (at.reshape(1, H, 1, 1) for at in _slab_pieces(H, Hkv, D))
     qt = _into_slabs(qt, k_at, D)
+    chunk = walk_chunk_pages(P)
+    n_chunks = P // chunk
     # pages the row actually occupies after this call's writes: the stream
     # clamp. At least one page (a fresh row still reads its own first
     # write); at most the table width (bucket-padding rows whose nominal
@@ -586,45 +690,65 @@ def paged_attention(
     def q_map(b, j, i, pages_ref, pos_ref, live_ref):
         return (b, 0, j, 0)
 
-    def _logical(b, j, i, pos_ref, live_ref):
-        # steps past the tile's live depth repeat the previous page so
-        # Pallas elides their copies (the flash kernels' causal-diagonal
-        # trick, applied to per-row occupancy)
-        return jnp.minimum(
-            i, _tile_live(pos_ref[b], live_ref[b], j, tq, pt) - 1)
-
-    def kv_map(b, j, i, pages_ref, pos_ref, live_ref):
-        # logical->physical through the prefetched table
-        return (pages_ref[b, _logical(b, j, i, pos_ref, live_ref)], 0, 0)
+    def page_map(c):
+        def index(b, j, i, pages_ref, pos_ref, live_ref):
+            # logical->physical through the prefetched table; a page past
+            # the tile's live depth is the last live page again, so Pallas
+            # elides its copy (the flash kernels' causal-diagonal trick,
+            # applied to per-row occupancy, page by page inside a chunk)
+            last = _tile_live(pos_ref[b], live_ref[b], j, tq, pt) - 1
+            return (pages_ref[b, jax.lax.min(i * chunk + c, last)], 0, 0)
+        return index
 
     def scale_map(b, j, i, pages_ref, pos_ref, live_ref):
-        # scales are pre-gathered per row (below): indexed by LOGICAL page
-        return (b, _logical(b, j, i, pos_ref, live_ref), 0, 0)
+        # scales are pre-gathered per row (below): indexed by LOGICAL
+        # chunk, a dead chunk the last live one again
+        last = _tile_live(pos_ref[b], live_ref[b], j, tq, pt) - 1
+        return (b, jax.lax.min(i, last // chunk), 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, H, tq, Dp), q_map),
-        pl.BlockSpec((1, pt, W), kv_map),
-    ]
-    operands = [qt, kv_rows]
+    in_specs = [pl.BlockSpec((1, H, tq, Dp), q_map)] + [
+        pl.BlockSpec((1, pt, W), page_map(c)) for c in range(chunk)]
+    operands = [qt] + [kv_rows] * chunk
+    span = chunk * pt
     if quantized:
         # a [N, H] arena cannot be blocked one page at a time (a (1, H)
         # block's second-minor dim is neither 8-aligned nor the array's),
         # and a per-head scalar in VMEM would need a lane->sublane
-        # relayout to meet its [tq, pt] score tile. So the row's scales
+        # relayout to meet its [tq, C pt] score tile. So the row's scales
         # are gathered through its table here (B*P*H floats — noise next
-        # to the pages) and repeated along the page's tokens: the block
-        # (1, 1, H, pt) is legal (trailing dims == the array's) and row h
+        # to the pages; zero past the row's depth, whose keys are masked
+        # anyway), repeated along the page's tokens and laid a chunk's
+        # pages end to end as the kernel lays their rows: the block
+        # (1, 1, H, C pt) is legal (trailing dims == the array's) and row h
         # of it broadcasts over a score tile as is.
-        def rows(s):
-            return jnp.broadcast_to(
-                s.astype(jnp.float32)[pages][..., None], (B, P, Hkv, pt))
+        owned = jnp.arange(P)[None, :, None] < live[:, None, None]
 
-        in_specs += [pl.BlockSpec((1, 1, Hkv, pt), scale_map),
-                     pl.BlockSpec((1, 1, Hkv, pt), scale_map)]
+        def rows(s):
+            s = jnp.where(owned, s.astype(jnp.float32)[pages], 0.0)
+            s = jnp.repeat(s.reshape(B, n_chunks, chunk, Hkv), pt, axis=2)
+            return jnp.swapaxes(s, 2, 3)            # [B, P / C, Hkv, C pt]
+
+        in_specs += [pl.BlockSpec((1, 1, Hkv, span), scale_map)] * 2
         operands += [rows(k_scale), rows(v_scale)]
+    # what a program holds in VMEM, from the shapes at hand: the query and
+    # output blocks and the chunk's pages (and scales) double-buffered,
+    # acc/m/l, the chunk's rows laid end to end (int8 rows cast: K in the
+    # compute type, V in float32), and a head's scores, probabilities and
+    # product in flight. The compiler's default (16 MiB) where that is
+    # less: GPT-2 XL's 25 heads under 256 queries hold 22 MiB
+    blocks = 2 * (2 * H * tq * Dp * q.dtype.itemsize
+                  + span * W * kv_rows.dtype.itemsize
+                  + (2 * Hkv * span * 4 if quantized else 0))
+    scratch = H * tq * (Dp + 2 * _LANES) * 4
+    laid = span * W * (q.dtype.itemsize + 4 if quantized
+                       else kv_rows.dtype.itemsize)
+    in_flight = 4 * tq * (2 * span + Dp) * 4
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=max(
+            16 << 20, (blocks + scratch + laid + in_flight) * 5 // 4))}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # pages, positions, live
-        grid=(B, lqp // tq, P),
+        grid=(B, lqp // tq, n_chunks),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, H, tq, Dp), q_map),
         scratch_shapes=[
@@ -634,10 +758,11 @@ def paged_attention(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_pa_kernel, page_tokens=pt, n_pages=P, scale=scale,
-                          kv_heads=Hkv, head_dim=D, quantized=quantized),
+        functools.partial(_tile_kernel, chunk=chunk, page_tokens=pt,
+                          n_chunks=n_chunks, scale=scale, kv_heads=Hkv,
+                          head_dim=D, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, lqp, Dp), q.dtype),
-        interpret=interpret,
+        interpret=interpret, **params,
     )(pages, positions, live, *operands)
     return jnp.moveaxis(_out_of_slabs(out[:, :, :L], v_at, D), 1, 2)

@@ -306,6 +306,16 @@ SERVING_COUNTERS = {
         "walk_chunks_live", "Those of kubeml_serving_walk_chunks_grid_total "
                             "inside a live row's depth: the ones that fetch "
                             "pages and multiply, the rest are empty"),
+    "kubeml_serving_tile_chunks_grid_total": (
+        "tile_chunks_grid", "Programs of the K/V page walk's tile body the "
+                            "prefill and admission programs ran, all "
+                            "attention layers: query tiles x table width / "
+                            "pages a program (absent where "
+                            "kubeml_serving_walk_chunks_grid_total is)"),
+    "kubeml_serving_tile_chunks_live_total": (
+        "tile_chunks_live", "Those of kubeml_serving_tile_chunks_grid_total "
+                            "under their tile's causal depth and the row's: "
+                            "the ones that fetch pages and multiply"),
 }
 # XLA compile counter, labeled {model, program} — rendered from the
 # snapshot's per-program compile-count dict rather than the scalar tables

@@ -2431,7 +2431,8 @@ class PagedBatchingDecoder(BatchingDecoder):
         super().__init__(module, variables, mesh=None, **kw)
         # a decode step's K/V page walk takes the kernel's decode body (one
         # query a row, the arena in the compute type): its grid is counted
-        # at each chunk dispatch (_walk_chunks)
+        # at each chunk dispatch (_walk_chunks), the tile body's at each
+        # prefill dispatch (_run_prefill)
         self.stats.walks_kv_chunks = (
             impl == "pallas" and kvq == "off" and not self._latent)
         # drafter KV-read constant for the spec accounting: the early-exit
@@ -2934,6 +2935,16 @@ class PagedBatchingDecoder(BatchingDecoder):
         # prefix-cached tokens are the measured FLOP saving, padding is
         # what the bucket adds to the row's own tokens
         self.stats.admit_tokens(take, bucket - take)
+        if self.stats.walks_kv_chunks:
+            # the page walk's tile body: the bucket's queries from the
+            # row's cursor, every attention layer
+            from ..ops.paged_attention import tile_chunks
+
+            itemsize = jnp.dtype(
+                getattr(self.module, "dtype", jnp.float32)).itemsize
+            live, grid = tile_chunks(pre, bucket, wa, pt, itemsize)
+            layers = int(self.module.depth)
+            self.stats.tile_chunks(live * layers, grid * layers)
         # KV model for the prefill forward(s): gather reads the row's
         # clamped table, the kernel stops at the depth the row has reached;
         # a draft backend prefills the drafter's arena too
@@ -3138,9 +3149,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         of ``C`` pages, and a resident row's chunks up to its depth are the
         ones with pages to read (step ``s``'s query sits ``s`` positions
         past ``pos_cap``, as in :meth:`_chunk_kv_tokens`)."""
-        from ..ops.paged_attention import decode_chunk_pages
+        from ..ops.paged_attention import walk_chunk_pages
 
-        pages = decode_chunk_pages(w)
+        pages = walk_chunk_pages(w)
         span = pages * self.page_tokens
         live = sum(min(-(-(row.pos_cap + s) // span), w // pages)
                    for row in self._slot_rows

@@ -116,10 +116,15 @@ class DecoderStats:
         # depth, which fetch pages and multiply; the rest are empty. Set and
         # fed by the paged engine where its steps take that body
         # (``walks_kv_chunks``); an engine that walks latents, a quantized
-        # arena or gathers reports neither
+        # arena or gathers reports neither. ``tile_chunks_*`` are the same
+        # two counts for the tile body's programs in the prefill and
+        # admission programs (query tiles x table width / pages a program):
+        # live are those under the tile's causal depth and the row's
         self.walks_kv_chunks = False
         self.walk_chunks_live = 0
         self.walk_chunks_grid = 0
+        self.tile_chunks_live = 0
+        self.tile_chunks_grid = 0
         self.goodput_tokens = 0       # tokens delivered to a live waiter
         self.wasted_tokens = 0        # tokens routed to an aborted request
         # shared-prefix reuse (paged engine, serving/kvpool.py): admissions
@@ -345,6 +350,13 @@ class DecoderStats:
         with self._lock:
             self.walk_chunks_live += int(live)
             self.walk_chunks_grid += int(grid)
+
+    def tile_chunks(self, live: int, grid: int) -> None:
+        """One dispatched prefill's page-walk programs, all query tiles and
+        attention layers: ``live`` of ``grid`` had pages to read."""
+        with self._lock:
+            self.tile_chunks_live += int(live)
+            self.tile_chunks_grid += int(grid)
 
     def fetch_started(self) -> None:
         with self._lock:
@@ -680,6 +692,8 @@ class DecoderStats:
             if self.walks_kv_chunks:
                 out["walk_chunks_live"] = float(self.walk_chunks_live)
                 out["walk_chunks_grid"] = float(self.walk_chunks_grid)
+                out["tile_chunks_live"] = float(self.tile_chunks_live)
+                out["tile_chunks_grid"] = float(self.tile_chunks_grid)
             # speculative-decoding series only exist once a spec step ran:
             # dense decoders / spec-off engines keep a clean exposition
             # (absence reads as "not speculating", like the paged gauges)

@@ -58,6 +58,21 @@ def kernel_cases():
                 (_sds((nrows, L, heads, D), jnp.bfloat16),
                  _sds((pool, PT, kv_row_width(heads, D)), jnp.bfloat16),
                  _sds((nrows, TABLE), jnp.int32), _sds((nrows,), jnp.int32)))
+    # the tile body at the cells' admit shapes (PR 40's sweep on the chip):
+    # one row of 1,024 positions under 64 pages, 8 query tiles by 4 chunks
+    # of 16 pages, at 20 and at 25 heads; gpt2-large.chat's 1 x 512 under 32
+    # pages; and the 512 positions after a prefix hit 512 deep, whose
+    # prefix chunks take the unmasked branch. (Falcon-H1's 1 x 128 under 8
+    # pages is the L128 case below.)
+    for tag, heads, pool, L, width in (
+            ("large", 20, 513, 1024, 64), ("xl", 25, 257, 1024, 64),
+            ("large", 20, 513, 512, 32), ("large-suffix", 20, 513, 512, 64)):
+        cases[f"paged_attention-gpt2-{tag}-bf16-admit-L{L}-P{width}"] = (
+            lambda q, kv, t, p: paged_attention(q, kv, t, p,
+                                                interpret=False),
+            (_sds((1, L, heads, D), jnp.bfloat16),
+             _sds((pool, PT, kv_row_width(heads, D)), jnp.bfloat16),
+             _sds((1, width), jnp.int32), _sds((1,), jnp.int32)))
     # Falcon-H1-34B's published shapes: 20 query heads on 4 K/V heads of
     # 128 over a 32-row slab (a decode step and one prefill tile), and the
     # mixer's state update, 32 heads of [256, 128] float32 in 2 groups

@@ -311,6 +311,9 @@ UNPANELED = {
     # the K/V page walk's decode body only; the benchmark reads their ratio
     "kubeml_serving_walk_chunks_grid_total": "kernel-specific; ad-hoc only",
     "kubeml_serving_walk_chunks_live_total": "kernel-specific; ad-hoc only",
+    # the same walk's tile body, in the prefill and admission programs
+    "kubeml_serving_tile_chunks_grid_total": "kernel-specific; ad-hoc only",
+    "kubeml_serving_tile_chunks_live_total": "kernel-specific; ad-hoc only",
 }
 
 

@@ -451,6 +451,7 @@ def test_engine_serves_the_reference_tokens(model):
     assert tel["kv_latent_width"] == 24.0 and tel["moe_layers"] == 2.0
     # the latent walk is another kernel: no grid of K/V chunks to count
     assert "walk_chunks_live" not in tel and "walk_chunks_grid" not in tel
+    assert "tile_chunks_live" not in tel and "tile_chunks_grid" not in tel
     # 2 layers x 8 experts x 3 matrices of 64 x 32 float32
     assert tel["expert_param_bytes"] == 2 * 8 * 3 * 64 * 32 * 4
     # a step's live rows make 2 choices in each of 2 layers; the first

@@ -581,15 +581,18 @@ def program_digest(module, tree):
 # block had one residual path: jax 0.9.0 on the CPU. The two K/V families'
 # "step" was made again on PR 38's tree, which moved it on purpose: a decode
 # step's page walk takes the kernel's decode body (ops/paged_attention.py;
-# 902 equations before for gpt2, 1295 for falcon). Their "admit" and both of
-# glm's are still PR 36's: the admission programs and the latent path are
-# the parent's, equation for equation
+# 902 equations before for gpt2, 1295 for falcon), and their "admit" on PR
+# 40's, whose admits walk a chunk of pages a program in the tile body (900
+# equations before for gpt2, 1297 for falcon: the head loop is there twice,
+# masked and clear). Both of glm's are still PR 36's and every "step" PR
+# 38's: the latent path and the decode programs are the parent's, equation
+# for equation
 PARENT_PROGRAMS = {
     "glm": {"admit": (843, "e8f497ae78ac0e01"),
             "step": (1023, "9b54162a64849441")},
-    "gpt2": {"admit": (900, "f6f86aa87098da37"),
+    "gpt2": {"admit": (1248, "e5faa9da9aaadbb1"),
              "step": (690, "66df1c13cb8cce27")},
-    "falcon": {"admit": (1297, "36129b785067064e"),
+    "falcon": {"admit": (1645, "100bfbb328f0ac69"),
                "step": (1083, "0667e1250f4b39dc")},
 }
 

@@ -20,6 +20,8 @@ Correctness bars:
   self-drafting, and int8 weights.
 """
 
+import math
+
 import numpy as np
 import pytest
 
@@ -30,7 +32,7 @@ from kubeml_tpu.api.types import GenerateRequest
 from kubeml_tpu.models.generation import generate, init_paged_cache
 from kubeml_tpu.models.gpt import CausalTransformer
 from kubeml_tpu.ops.attention import dot_product_attention
-from kubeml_tpu.ops.paged_attention import (decode_chunk_pages,
+from kubeml_tpu.ops.paged_attention import (walk_chunk_pages,
                                             kv_row_width, pack_kv_rows,
                                             paged_attention,
                                             resolve_kv_quant,
@@ -73,22 +75,17 @@ def paged(q, k_tok, v_tok, pages, positions, **kw):
 
 def assert_equals_head_major(out, q, k_tok, v_tok, pages, positions, **kw):
     """``out`` against the same arrays laid out the old way, two arenas
-    ``[N, Hkv, pt, D]``, through the kernel as it was. Bit for bit where a
-    head is whole 128-lane rows and the call takes the tile body: the same
-    arithmetic on the same numbers. A narrower head is contracted over the
-    128 lanes it lies in, zeros in the query beside it, and a decode step
-    (one query, the arena in the compute type) over a whole row's K lanes,
-    16 pages a block: the same products, summed in another order, so equal
-    to a few units in the last place."""
+    ``[N, Hkv, pt, D]``, through the kernel as it was: a page a program and
+    a head at a time. Both bodies now take a chunk of pages a product (the
+    decode body all heads at once over a whole row's K lanes, the tile body
+    a head's slab of 128 lanes, zeros in the query beside a narrower head):
+    the same products, one maximum and one sum a chunk where there was one a
+    page, so equal to a few units in the last place and not bit for bit."""
     old = head_major_paged_attention(q, jnp.swapaxes(k_tok, 1, 2),
                                      jnp.swapaxes(v_tok, 1, 2), pages,
                                      positions, **kw)
-    decode_body = q.shape[1] == 1 and "k_scale" not in kw
-    if q.shape[-1] % 128 == 0 and not decode_body:
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(old))
-    else:
-        np.testing.assert_allclose(np.asarray(out), np.asarray(old),
-                                   atol=1e-6, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(old),
+                               atol=1e-6, rtol=1e-6)
 
 
 # (query heads, K/V heads, head size) beside the toy 2 / 2 / 16, whose row
@@ -184,7 +181,7 @@ def test_decode_body_parity(heads, dtype, case):
     pages = jnp.asarray(table, jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), dt)
     pos = jnp.asarray(positions, jnp.int32)
-    chunk = decode_chunk_pages(P)
+    chunk = walk_chunk_pages(P)
     assert chunk == min(P, 16)
     assert walk_grid(lambda *a: paged(*a), q, k_pages, v_pages, pages,
                      pos) == (B, P // chunk)
@@ -205,17 +202,121 @@ def test_decode_body_parity(heads, dtype, case):
                                atol=tol, rtol=tol)
 
 
+# the tile body streams gcd(P, 16) pages a program: a chunk's span in
+# positions is that times page_tokens, and a query tile is 256 rows
+TILE_CASES = {
+    # (table width, page tokens, queries, first positions)
+    # a verify window whose depth (position + 5) is 5, C pt - 1, C pt and
+    # C pt + 1 at 16 pages of 4: 64 positions a program
+    "edges": (64, 4, 5, [0, 58, 59, 60]),
+    # half a tile, at a page's edge and from the middle of a page, and one
+    # query more
+    "128 queries": (64, 4, 128, [0, 2, 64]),
+    "129 queries": (64, 4, 129, [0, 30]),
+    # exactly one tile; one query past it: a second tile, 255 rows padding
+    "one tile": (64, 8, 256, [0, 2, 64]),
+    "tile and one": (64, 8, 257, [0, 30]),
+    # two tiles (384 queries) over four programs of 128 positions; from
+    # position 100 (a prefix hit, mid-page) the second tile sees whole
+    # chunks with no mask
+    "two tiles": (64, 8, 384, [0, 100]),
+    # three tiles over four programs of 256 positions, the published page
+    "three tiles": (64, 16, 600, [0, 300]),
+    # a table narrower than 16 pages: one program a tile
+    "narrow": (8, 4, 5, [0, 10, 27]),
+    # 12 pages walk gcd(12, 16) = 4 a program; depths 15, 16, 17 and 45
+    "gcd 4": (12, 4, 5, [0, 10, 11, 12, 40]),
+    "gcd 4, two tiles": (12, 32, 300, [0, 70]),
+    # an odd width: a chunk of one page is the same code
+    "odd": (7, 4, 5, [0, 3, 20]),
+}
+# the published shapes cost the interpreter 10-15 s a call (a program holds
+# every head twice, masked and clear), so they take the cases that cross a
+# chunk's edge, several tiles and a short chunk; every case runs under three
+# small shapes of the same structure: four heads of 64 (two a slab, V on a
+# slab's edge), three (V starting mid-slab, as GPT-2 XL's 25) and four
+# query heads on two K/V heads of 128
+TILE_HEADS = {**DECODE_HEADS, "even": (4, 4, 64), "odd": (3, 3, 64),
+              "grouped": (4, 2, 128), "wide": (2, 2, 192)}
+SMALL = ("even", "odd", "grouped")
+TILE_MATRIX = (
+    [(case, "float32", heads) for case in sorted(TILE_CASES)
+     for heads in SMALL]
+    + [(case, dtype, heads) for dtype in ("bfloat16", "int8")
+       for case in ("edges", "two tiles", "gcd 4") for heads in SMALL]
+    + [("edges", "float32", heads) for heads in sorted(DECODE_HEADS)]
+    + [("two tiles", "bfloat16", "xl"), ("gcd 4", "int8", "falcon-h1"),
+       # a head of 192 lanes is its own slab, cut off the 128-lane rows
+       ("edges", "float32", "wide")])
+
+
+@pytest.mark.kernel
+@pytest.mark.parametrize("case,dtype,heads", TILE_MATRIX)
+def test_tile_body_parity(case, dtype, heads):
+    """More than one query a row (an admit, a suffix after a prefix hit, a
+    verify window), or int8 pages, takes the tile body: a head at a time
+    over a chunk of pages a program, the mask only where a chunk meets the
+    diagonal or the row's depth. Against the gather oracle (over the
+    dequantized arena for int8) at the three published head shapes, and
+    against the parent's page-a-program kernel over head-major pages where
+    the call is short enough for the interpreter to run that too."""
+    rng = np.random.default_rng(8)
+    H, Hkv, D = TILE_HEADS[heads]
+    P, pt, L, positions = TILE_CASES[case]
+    B = len(positions)
+    N = B * P + 1
+    quantized = dtype == "int8"
+    dt = jnp.dtype("float32" if quantized else dtype)
+    kf = rng.normal(size=(N, pt, Hkv, D)).astype(np.float32)
+    vf = rng.normal(size=(N, pt, Hkv, D)).astype(np.float32)
+    pages = jnp.asarray(1 + rng.permutation(N - 1)[:B * P].reshape(B, P),
+                        jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, L, H, D)), dt)
+    pos = jnp.asarray(positions, jnp.int32)
+    if quantized:
+        (k_pages, ks), (v_pages, vs) = quantize_pages(kf), quantize_pages(vf)
+        kw = {"k_scale": ks, "v_scale": vs}
+        k_ref = jnp.asarray(dequantize_pages(k_pages, ks))
+        v_ref = jnp.asarray(dequantize_pages(v_pages, vs))
+    else:
+        k_pages = k_ref = jnp.asarray(kf, dt)
+        v_pages = v_ref = jnp.asarray(vf, dt)
+        kw = {}
+    chunk = walk_chunk_pages(P)
+    assert chunk == math.gcd(P, 16)
+    tiles = -(-L // 256)
+    assert walk_grid(lambda q, k, v: paged(q, k, v, pages, pos, **kw),
+                     q, k_pages, v_pages) == (B, tiles, P // chunk)
+    out = paged(q, k_pages, v_pages, pages, pos, **kw)
+    assert out.shape == q.shape and out.dtype == dt
+    ref = gather_reference(q, k_ref, v_ref, pages, pos)
+    tol = {"bfloat16": 0.05, "int8": 2e-5 * D / 16}.get(dtype,
+                                                         2e-6 * D / 16)
+    got = np.asarray(out, np.float32)
+    np.testing.assert_allclose(got, np.asarray(ref, np.float32), atol=tol,
+                               rtol=tol)
+    if L <= 5:
+        old = head_major_paged_attention(
+            q, jnp.swapaxes(k_pages, 1, 2), jnp.swapaxes(v_pages, 1, 2),
+            pages, pos, **kw)
+        np.testing.assert_allclose(got, np.asarray(old, np.float32),
+                                   atol=tol, rtol=tol)
+
+
 @pytest.mark.kernel
 @pytest.mark.parametrize("case,L,quantized,grid", [
     ("decode step", 1, False, (3, 2)),          # (rows, P / 16 pages)
-    ("verify window", 5, False, (3, 1, 32)),    # (rows, query tiles, P)
-    ("int8 decode step", 1, True, (3, 1, 32)),
-    ("int8 suffix", 8, True, (3, 1, 32)),
+    # (rows, query tiles, P / 16 pages)
+    ("verify window", 5, False, (3, 1, 2)),
+    ("prefill of two tiles", 300, False, (3, 2, 2)),
+    ("int8 decode step", 1, True, (3, 1, 2)),
+    ("int8 suffix", 8, True, (3, 1, 2)),
 ])
 def test_which_body_a_call_takes(case, L, quantized, grid):
     """The rule is in the call's shapes: one query a row over an arena in
-    the compute type walks 16 pages a program; more queries, or int8 pages
-    with their scales, keep the tile body, a page a program."""
+    the compute type takes the decode body, all heads one product; more
+    queries, or int8 pages with their scales, take the tile body, a head at
+    a time under tiles of 256 queries. Both walk 16 pages a program."""
     rng = np.random.default_rng(6)
     B, H, D, pt, P, N = 3, 2, 16, 4, 32, 40
     kf = rng.normal(size=(N, pt, H, D)).astype(np.float32)
@@ -274,6 +375,10 @@ def test_kernel_bf16_storage_dtype():
     (32, [5, 70], 1),     # two programs a row, the second dead for row 0
     (32, [63, 64], 1),    # a depth on either side of a program's edge
     (4, [5, 8], 4),       # the tile body (a verify window)
+    (32, [5, 70], 4),     # its second program dead for row 0
+    (32, [59, 60], 5),    # depths 64 and 65 on either side of its edge
+    (12, [3, 14], 4),     # four pages a program
+    (96, [0, 37], 264),   # two query tiles: the first's later pages elided
 ])
 def test_kernel_poisoned_trash_page_cannot_leak(P, positions, L):
     """Every arena position a live row did NOT legitimately write — the
@@ -281,9 +386,9 @@ def test_kernel_poisoned_trash_page_cannot_leak(P, positions, L):
     row's cursor inside its own last page — is poisoned with huge values;
     the output must be bit-identical to the clean-arena run. This is the
     paged pool's whole safety story (stale writes are trash-redirected):
-    the read side must never reach what the write side quarantined. The
-    decode body multiplies a block of 16 pages at once, live and masked
-    slots in one product: a masked probability is exactly 0 there too."""
+    the read side must never reach what the write side quarantined. Both
+    bodies multiply a block of 16 pages at once, live and masked slots in
+    one product: a masked probability is exactly 0 there too."""
     rng = np.random.default_rng(2)
     B, H, D, pt = 2, 2, 8, 4
     positions = np.array(positions)  # row b attends 0..positions[b] + L - 1
@@ -607,14 +712,15 @@ def test_kernel_int8_parity_and_bounded_divergence(heads, L, positions):
 
 
 @pytest.mark.kernel
-def test_kernel_int8_poisoned_arena_cannot_leak():
+@pytest.mark.parametrize("P", [4, 32])   # one short chunk; two of 16 pages
+def test_kernel_int8_poisoned_arena_cannot_leak(P):
     """The poisoned-arena contract holds for quantized storage too: every
     position a live row did not write — trash page 0, unallocated pages,
     slots past each row's cursor — is poisoned with full-scale int8
     values, and unallocated pages' (and trash's) SCALES are poisoned huge.
     The output must be bit-identical to the clean-arena run."""
     rng = np.random.default_rng(11)
-    B, H, D, pt, P, N = 2, 2, 8, 4, 4, 10
+    B, H, D, pt, N = 2, 2, 8, 4, 10
     positions = np.array([5, 9])
     L = 1
     pages = np.zeros((B, P), np.int32)

@@ -5,8 +5,12 @@ paged engine at each chunk dispatch) and the benchmark's reader of them
 
 The decode body of ``ops/paged_attention.py`` runs every program row by the
 table width's chunks of 16 pages, whatever a row holds; the counts say which
-of those programs had pages to read. Host arithmetic only: no kernel runs in
-the unit cases, and the one engine run is the tiny model in interpret mode."""
+of those programs had pages to read. The tile body's twins (PR 40:
+``tile_chunks_live``, ``tile_chunks_grid``, read by
+``layer_metrics/tile_live_chunk_share_capacity.py``) count a prefill's query
+tiles by the same chunks: those under a tile's causal depth are live. Host
+arithmetic only: no kernel runs in the unit cases, and the engine runs are
+the tiny model in interpret mode."""
 
 import sys
 from pathlib import Path
@@ -21,11 +25,13 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
 from benchmark.layer_metrics import (  # noqa: E402
-    walk_live_chunk_share, walk_live_chunk_share_capacity)
+    tile_live_chunk_share_capacity, walk_live_chunk_share,
+    walk_live_chunk_share_capacity)
 from benchmark.layers import Reading  # noqa: E402
 from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
 from kubeml_tpu.models.gpt import CausalTransformer  # noqa: E402
-from kubeml_tpu.ops.paged_attention import decode_chunk_pages  # noqa: E402
+from kubeml_tpu.ops.paged_attention import (tile_chunks,  # noqa: E402
+                                            walk_chunk_pages)
 from kubeml_tpu.ps.metrics import SERVING_COUNTERS  # noqa: E402
 from kubeml_tpu.serving.batcher import PagedBatchingDecoder, _Row  # noqa: E402
 
@@ -68,6 +74,49 @@ def test_the_reader_on_a_made_up_snapshot(reader, c0, c1, want):
     assert got is None or 0.0 <= got <= 100.0
 
 
+@pytest.mark.parametrize("c0,c1,want", [
+    # the window's growth: 10 of 16 programs an admit and layer, 10 admits
+    ({"tile_chunks_live": 360.0, "tile_chunks_grid": 576.0},
+     {"tile_chunks_live": 3960.0, "tile_chunks_grid": 6336.0}, 62.5),
+    # one tile, one chunk (a prompt bucket of 128 under a table of 8 pages)
+    ({"tile_chunks_live": 0.0, "tile_chunks_grid": 0.0},
+     {"tile_chunks_live": 6.0, "tile_chunks_grid": 6.0}, 100.0),
+    # the parent commit, or a family whose admits do not walk K/V pages
+    ({"walk_chunks_live": 1.0, "walk_chunks_grid": 2.0},
+     {"walk_chunks_live": 5.0, "walk_chunks_grid": 9.0}, None),
+    ({"tile_chunks_grid": 5.0}, {"tile_chunks_grid": 9.0}, None),
+    # no admit in the window
+    ({"tile_chunks_live": 3.0, "tile_chunks_grid": 8.0},
+     {"tile_chunks_live": 3.0, "tile_chunks_grid": 8.0}, None),
+])
+def test_the_tile_reader_on_a_made_up_snapshot(c0, c1, want):
+    got = tile_live_chunk_share_capacity.read(reading(c0, c1))
+    assert got == want
+    assert got is None or 0.0 <= got <= 100.0
+
+
+# (start, queries, table width, page tokens, itemsize) -> (live, grid)
+@pytest.mark.parametrize("call,want", [
+    # the docs cells' admit: 4 tiles of 256 by 4 chunks of 16 pages of 16;
+    # under the diagonal lie 1, 2, 3, 4
+    ((0, 1024, 64, 16, 2), (10, 16)),
+    # chat's 1 x 512: 2 tiles by 2 chunks
+    ((0, 512, 32, 16, 2), (3, 4)),
+    # Falcon-H1's 1 x 128 under 8 pages: one program, whole
+    ((0, 128, 8, 16, 2), (1, 1)),
+    # after a prefix hit 512 deep, the same 512 queries under 64 pages: the
+    # prefix's two chunks are live for every tile
+    ((512, 512, 64, 16, 2), (3 + 4, 8)),
+    # a table of 12 pages walks gcd(12, 16) = 4 a program, of 7 one
+    ((0, 128, 12, 16, 2), (2, 3)),
+    ((0, 100, 7, 16, 4), (7, 7)),
+    # a bucket whose positions run past the table: the depth stops at it
+    ((96, 64, 8, 16, 2), (1, 1)),
+])
+def test_the_tile_bodys_grid_by_host_arithmetic(call, want):
+    assert tile_chunks(*call) == want
+
+
 # --- the engine's two counts ------------------------------------------------
 
 
@@ -107,7 +156,7 @@ def test_two_live_rows_and_a_retired_one(engine, steps):
     dec._slot_rows[0], dec._slot_rows[2] = deep, shallow   # slot 1: retired
     try:
         w = dec._live_table_width(steps)
-        assert w == 64 and decode_chunk_pages(w) == 16
+        assert w == 64 and walk_chunk_pages(w) == 16
         live, grid = dec._walk_chunks(w, steps)
         layers = 2
         assert grid == steps * 3 * 4 * layers
@@ -142,7 +191,12 @@ def test_the_snapshot_carries_the_counts_where_steps_take_the_body(
         dec.close()
     assert ("walk_chunks_live" in snap) == counted
     assert ("walk_chunks_grid" in snap) == counted
+    assert ("tile_chunks_live" in snap) == counted
+    assert ("tile_chunks_grid" in snap) == counted
     if counted:
+        # the admit: a bucket of 8 queries (one tile) under a table of 8
+        # pages (one chunk), 2 layers
+        assert snap["tile_chunks_grid"] == snap["tile_chunks_live"] == 2
         # 5 steps after the prefill's token, 2 layers, 2 program rows, one
         # program a row (a table of 8 pages): one row live
         assert snap["walk_chunks_grid"] == 5 * 2 * 2
@@ -157,5 +211,38 @@ def test_an_engine_that_never_said_so_reports_neither():
 
     snap = DecoderStats(slots=2).snapshot()
     assert "walk_chunks_live" not in snap and "walk_chunks_grid" not in snap
+    assert "tile_chunks_live" not in snap and "tile_chunks_grid" not in snap
     keys = {key for key, _ in SERVING_COUNTERS.values()}
-    assert {"walk_chunks_live", "walk_chunks_grid"} <= keys
+    assert {"walk_chunks_live", "walk_chunks_grid", "tile_chunks_live",
+            "tile_chunks_grid"} <= keys
+
+
+def test_an_admit_at_position_0_and_one_after_a_prefix_hit():
+    """The engine's tile counts on the tiny model, page_tokens 4 and a
+    float32 arena: a 300-token prompt admits as a bucket of 512 queries (two
+    tiles of 256) under a table of 128 pages (8 chunks of 16 pages, 64
+    positions each): the first tile sees 4 chunks, the second all 8. The
+    same prompt again shares its 296 whole-page tokens: the suffix's bucket
+    is one tile of 8 queries at position 296, under the same table, and
+    sees the prefix's chunks, 5 of 8."""
+    m = tiny(max_len=1024)
+    variables = m.init(jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
+    dec = PagedBatchingDecoder(m, variables, slots=2, chunk_steps=4,
+                               page_tokens=4, paged_attn="pallas")
+    prompt = [[1 + i % 100 for i in range(300)]]
+    layers = 2
+    try:
+        dec.wait(dec.submit(GenerateRequest(prompts=prompt,
+                                            max_new_tokens=2)), timeout=600)
+        first = dec.telemetry()
+        assert first["tile_chunks_grid"] == 2 * 8 * layers
+        assert first["tile_chunks_live"] == (4 + 8) * layers
+        out = dec.wait(dec.submit(GenerateRequest(prompts=prompt,
+                                                  max_new_tokens=2)),
+                       timeout=600)
+        assert out["prefix_cached_tokens"] == 296
+        second = dec.telemetry()
+    finally:
+        dec.close()
+    assert second["tile_chunks_grid"] - first["tile_chunks_grid"] == 8 * layers
+    assert second["tile_chunks_live"] - first["tile_chunks_live"] == 5 * layers
