@@ -144,12 +144,24 @@ def has_latent_cache(module) -> bool:
 
 def expert_layers(module) -> int:
     """Layers of ``module`` whose feed-forward is routed experts
-    (``mlp="experts"``: all but the ``dense_layers`` leading ones); 0 for
+    (``mlp="experts"``: all but the ``dense_layers`` leading ones;
+    ``mlp="shortcut"``: one in every double layer); 0 for
     every other model, the training-side ``moe_every`` interleaving among
     them (it has no paged path at all)."""
-    if getattr(module, "mlp", None) != "experts":
+    if getattr(module, "mlp", None) not in ("experts", "shortcut"):
         return 0
     return max(0, int(module.depth) - int(getattr(module, "dense_layers", 0)))
+
+
+def cache_sublayers(module) -> int:
+    """Sub-layers of ``module`` that hold a paged cache: its depth times
+    what its layers' class says a layer holds (one; two where a layer is a
+    double layer of two attentions, models/gpt.py ShortcutBlock). What
+    sizes, reads and counts caches asks this, not ``depth``. 0 when the
+    module doesn't expose its depth."""
+    depth = int(getattr(module, "depth", 0) or 0)
+    return depth * getattr(getattr(module, "layer_cls", None),
+                           "cache_sublayers", 1)
 
 
 def residual_sublayers(module) -> int:

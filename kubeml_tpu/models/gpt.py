@@ -22,7 +22,10 @@ a muP checkpoint's multipliers. The stack may be a pattern: ``dense_layers``
 leading SwiGLU layers before the expert layers. The residual path is a kind
 too: one stream and ``x = x + y``, or ``hc_mult`` streams that every sub-layer
 reads, writes and mixes through maps made from the token's own streams
-(ops/hyper_connection.py). GPT-2 is the defaults; Falcon-H1 is rmsnorm +
+(ops/hyper_connection.py). And the layer itself may be LongCat-Flash's double
+layer (``mlp="shortcut"``, :class:`ShortcutBlock`): two attentions, two dense
+SwiGLUs, and an expert layer on a shortcut around the second pair. GPT-2 is
+the defaults; Falcon-H1 is rmsnorm +
 swiglu + GQA + rope + ssm + mup; GLM-4.7-Flash is rmsnorm + rope + mla +
 experts behind one dense layer; Xing4.0 is that with YaRN rotary on four
 hyper-connected streams.
@@ -30,10 +33,11 @@ hyper-connected streams.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import threading
 from dataclasses import dataclass
-from typing import Any, Optional, Tuple
+from typing import Any, Callable, ClassVar, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -488,6 +492,9 @@ class GPTBlock(nn.Module):
     mla: Optional[MLAConfig] = None
     experts: Optional[ExpertsConfig] = None
     hc: Optional[HCConfig] = None
+    # paged caches a layer of this class holds (models/generation.py
+    # cache_sublayers): one attention, one cache
+    cache_sublayers: ClassVar[int] = 1
 
     def _hc_maps(self, name: str, width: int):
         """A sub-layer's ``phi``, ``alpha`` and ``bias`` (ops/
@@ -506,13 +513,19 @@ class GPTBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, valid, train: bool = False, decode: bool = False,
-                 positions=None, pages=None, seq_lens=None, rows=None):
+                 positions=None, pages=None, seq_lens=None, rows=None,
+                 branch: Optional[Callable] = None):
+        """``branch(u, real)``, where given, is called with the
+        feed-forward's own normed input and the mask of the tokens that are
+        real, before the feed-forward: a side branch of the caller's
+        (:class:`ShortcutBlock`'s experts) that reads what the feed-forward
+        reads. What it makes is the caller's to keep."""
         mup = self.mup or MuP()
         hc = self.hc
         if hc is not None:
-            if self.ssm is not None:
+            if self.ssm is not None or branch is not None:
                 raise ValueError("hyper-connections around a parallel mixer "
-                                 "are not defined")
+                                 "or a side branch are not defined")
             # x is the n streams, flat [B, L, n E]; the Pallas kernels serve
             # decode applies on a TPU (they have no backward)
             E = x.shape[-1] // hc.mult
@@ -555,6 +568,13 @@ class GPTBlock(nn.Module):
             x = x + _scaled(m, mup.ssm_out)
         y = _norm(self.norm, "ln2", self.ln_eps)(x).astype(self.dtype)
         E = x.shape[-1]
+        # the tokens given to experts: a decode apply's real positions (a
+        # dead row, a bucket's padding are not), else the non-pad ids
+        real = lambda: (
+            jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
+            if decode and seq_lens is not None else valid.astype(jnp.bool_))
+        if branch is not None:
+            branch(y, real())
         if self.mlp == "gelu":
             y = QuantizableDense(
                 self.mlp_dim or E * self.mlp_ratio, name="mlp_in",
@@ -581,13 +601,8 @@ class GPTBlock(nn.Module):
                 kernel_init=_part(("tp", None))(
                     nn.initializers.lecun_normal()))(y), mup.mlp[1])
         elif self.mlp == "experts":
-            # the tokens given to experts: a decode apply's real positions
-            # (a dead row, a bucket's padding are not), else the non-pad ids
-            real = (jnp.arange(x.shape[1])[None, :] < seq_lens[:, None]
-                    if decode and seq_lens is not None
-                    else valid.astype(jnp.bool_))
             y = ExpertMLP(self.experts, dtype=self.dtype, name="experts")(
-                y, real, decode=decode)
+                y, real(), decode=decode)
         else:
             raise ValueError(f"unknown mlp {self.mlp!r} (valid: 'gelu', "
                              f"'swiglu', 'experts')")
@@ -595,6 +610,50 @@ class GPTBlock(nn.Module):
         if hc is not None:
             return hc_post(xs, y, post, mix, kernel=kernel)
         return x + y
+
+
+class ShortcutBlock(GPTBlock):
+    """LongCat-Flash's double layer (shortcut-connected experts,
+    arXiv:2509.01322), ``mlp="shortcut"``:
+
+        h = h + MLA_0(norm(h));  u = norm(h);  s = Experts(u)
+        h = h + SwiGLU_0(u)
+        h = h + MLA_1(norm(h));  h = h + SwiGLU_1(norm(h))
+        h = h + s
+
+    ``sub_0`` and ``sub_1`` are ordinary SwiGLU blocks; the experts are a
+    side branch of ``sub_0`` that reads its feed-forward's normed input
+    (``branch``; their weights lie under ``sub_0/experts``), and nothing
+    between there and the end reads ``s``, which is what lets a deployment's
+    expert exchange run behind ``SwiGLU_0`` and ``MLA_1`` (here, on one
+    chip, it only leaves XLA free to order the branch). It is a ``GPTBlock``
+    by its fields: the stack makes a layer of either class from the same
+    arguments, and both sub-blocks take every one of them but ``mlp`` and
+    ``experts``. Two attentions a layer, two caches; each is named ``attn``
+    under its sub-layer, so a device trace shows all of a program's page
+    walks under one name."""
+
+    cache_sublayers: ClassVar[int] = 2
+
+    @nn.compact
+    def __call__(self, x, valid, train: bool = False, decode: bool = False,
+                 positions=None, pages=None, seq_lens=None, rows=None):
+        if self.mlp != "shortcut" or self.experts is None:
+            raise ValueError("a ShortcutBlock is mlp='shortcut' with an "
+                             "ExpertsConfig")
+        sub = functools.partial(GPTBlock, mlp="swiglu", **{
+            f.name: getattr(self, f.name) for f in dataclasses.fields(self)
+            if f.name not in ("parent", "name", "mlp", "experts")})
+        at = dict(positions=positions, pages=pages, seq_lens=seq_lens,
+                  rows=rows)
+        s = []
+        x = sub(name="sub_0")(
+            x, valid, train, decode, **at,
+            branch=lambda u, real: s.append(ExpertMLP(
+                self.experts, dtype=self.dtype, name="experts")(
+                    u, real, decode=decode)))
+        x = sub(name="sub_1")(x, valid, train, decode, **at)
+        return x + s[0]
 
 
 _block_traces = 0   # times _decode_block's body has run: once per trace
@@ -707,7 +766,10 @@ class CausalTransformer(nn.Module):
     # token, ``mla.latent_width`` values, and it has no dense decode cache).
     # ``mlp="experts"`` with ``experts``: routed experts and a shared one
     # (models/experts.py), after ``dense_layers`` leading layers whose MLP
-    # is a SwiGLU of ``mlp_dim``: the one layer pattern a stack can have. ---
+    # is a SwiGLU of ``mlp_dim``: the one layer pattern a stack can have.
+    # ``mlp="shortcut"`` with ``experts``: every layer a :class:`ShortcutBlock`
+    # (two attentions, two SwiGLUs of ``mlp_dim``, the experts on a shortcut),
+    # so ``depth`` counts double layers and a layer holds two caches. ---
     norm: str = "layernorm"
     mlp: str = "gelu"
     mlp_dim: int = 0
@@ -731,6 +793,11 @@ class CausalTransformer(nn.Module):
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: float = 30.0
+
+    @property
+    def layer_cls(self):
+        """The class of every layer of the stack."""
+        return ShortcutBlock if self.mlp == "shortcut" else GPTBlock
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, decode: bool = False,
@@ -813,7 +880,7 @@ class CausalTransformer(nn.Module):
                 raise ValueError(
                     f"exit_layer must be in [1, depth={self.depth}], got "
                     f"{exit_layer}")
-            if self.moe_every > 0 or self.mlp == "experts":
+            if self.moe_every > 0 or self.mlp in ("experts", "shortcut"):
                 raise ValueError("early-exit drafting does not cover "
                                  "expert models")
         run_depth = self.depth if exit_layer is None else int(exit_layer)
@@ -835,8 +902,12 @@ class CausalTransformer(nn.Module):
                 raise ValueError("hyper-connections do not cover "
                                  "moe_every's block (parallel/moe.py)")
             x = jnp.tile(x, (1, 1, self.hc_mult))
-        if (self.mlp == "experts") != (self.experts is not None):
-            raise ValueError("mlp='experts' and an ExpertsConfig go together")
+        if (self.mlp in ("experts", "shortcut")) != (self.experts is not None):
+            raise ValueError("mlp='experts' (or 'shortcut') and an "
+                             "ExpertsConfig go together")
+        if self.mlp == "shortcut" and (self.dense_layers or self.moe_every):
+            raise ValueError("a stack of shortcut layers has no other kind "
+                             "of layer (dense_layers, moe_every)")
         if self.mla is not None and not use_rope:
             raise ValueError("latent attention takes rotary positions "
                              "(pos='rope')")
@@ -846,9 +917,10 @@ class CausalTransformer(nn.Module):
         # a decode apply sends every layer of a kind through the one trace
         # of _decode_block: the kind's block, detached from the module and
         # so equal for all its layers, is the static argument
-        detached = ({kind: GPTBlock(self.num_heads, self.mlp_ratio,
-                                    self.dropout, parent=None,
-                                    **{**fields, "mlp": kind})
+        layer_cls = self.layer_cls
+        detached = ({kind: layer_cls(self.num_heads, self.mlp_ratio,
+                                     self.dropout, parent=None,
+                                     **{**fields, "mlp": kind})
                      for kind in {kind_of(i) for i in range(run_depth)}}
                     if decode and not self.is_initializing() else None)
         for i in range(run_depth):
@@ -881,8 +953,8 @@ class CausalTransformer(nn.Module):
                 # `decode` arg 4; decode has no backward, so only training
                 # takes the remat wrapper, and its call stays positional
                 block_cls = (
-                    GPTBlock if decode or not self.remat
-                    else nn.remat(GPTBlock, static_argnums=(3, 4))
+                    layer_cls if decode or not self.remat
+                    else nn.remat(layer_cls, static_argnums=(3, 4))
                 )
                 at = dict(positions=positions, pages=pages,
                           seq_lens=seq_lens, rows=rows) if decode else {}

@@ -24,6 +24,13 @@ axis, no V arena). Two forms of the one function read it:
   .py: the ``mla_attn`` kernel, or its ``gather`` oracle), and ``W_uv_h``
   carries the result out. The cache is never expanded in a step.
 
+With ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` (LongCat-Flash) the two
+normed latents are multiplied by ``sqrt(E / q_lora_rank)`` and ``sqrt(E /
+kv_lora_rank)``, so that the up-projections see an input as wide as the
+stream's (``r`` is not scaled). Both are applied in float32 where the norm
+ends, before the one rounding to the compute type: the arena stores the
+scaled ``c``, and both forms read it as it is.
+
 Norms are float32; rotary pairs are rotate-half over the ``dr`` rope dims.
 With ``rope_scaling`` (YaRN, ops/rotary.py) the pair frequencies are the
 blended ones and the softmax scale is ``softmax_mscale ** 2 / sqrt(dn + dr)``,
@@ -58,6 +65,8 @@ class MLAConfig:
     v_head_dim: int          # dv
     norm_eps: float = 1e-5
     rope_scaling: Optional[YarnScaling] = None   # None: plain rotary
+    mla_scale_q_lora: bool = False    # cq times sqrt(E / q_lora_rank)
+    mla_scale_kv_lora: bool = False   # c times sqrt(E / kv_lora_rank)
 
     @property
     def latent_width(self) -> int:
@@ -97,10 +106,15 @@ class MLAttention(nn.Module):
         norm = lambda name: nn.RMSNorm(name=name, dtype=jnp.float32,
                                        epsilon=c.norm_eps)
         cq = norm("q_norm")(dense(c.q_lora_rank, (None, None), "q_down")(x))
+        if c.mla_scale_q_lora:
+            cq = cq * math.sqrt(E / c.q_lora_rank)
         q = dense(H * (dn + dr), (None, "tp"), "q_up")(
             cq.astype(self.dtype)).reshape(B, L, H, dn + dr)
         ckr = dense(dc + dr, (None, None), "kv_down")(x)
-        ckv = norm("kv_norm")(ckr[..., :dc]).astype(self.dtype)
+        ckv = norm("kv_norm")(ckr[..., :dc])
+        if c.mla_scale_kv_lora:
+            ckv = ckv * math.sqrt(E / dc)
+        ckv = ckv.astype(self.dtype)
         kr = ckr[..., None, dc:]                          # [B, L, 1, dr]
         # [dc, H, dn + dv]: head h's W_uk (dc -> dn) and W_uv (dc -> dv)
         w_ukv = self.param(

@@ -281,8 +281,18 @@ SERVING_COUNTERS = {
         "pool_audit_failures", "Pool audits that found a broken invariant "
                                "and triggered fault recovery"),
     "kubeml_serving_moe_assignments_total": (
-        "moe_assignments", "Token-to-expert assignments made in decode steps "
-                           "(live rows x experts per token x expert layers)"),
+        "moe_assignments", "Token-to-expert assignments given to experts "
+                           "held here in decode steps (live rows x experts "
+                           "per token x expert layers where every choice is "
+                           "one)"),
+    "kubeml_serving_moe_assignments_zero_total": (
+        "moe_assignments_zero", "Decode-step assignments to identity "
+                                "(zero-compute) experts: the token itself "
+                                "times the gate, no weights read"),
+    "kubeml_serving_moe_assignments_absent_total": (
+        "moe_assignments_absent", "Decode-step assignments to experts held "
+                                  "on other chips (an expert-parallel "
+                                  "share): they add nothing here"),
     "kubeml_serving_moe_experts_touched_total": (
         "moe_experts_touched", "Distinct experts chosen by a decode step's "
                                "live rows, summed over expert layers and "
@@ -496,6 +506,14 @@ SERVING_GAUGES = {
     "kubeml_serving_moe_layers": (
         "moe_layers", "Layers of the served model whose feed-forward is "
                       "routed experts (0: none)"),
+    "kubeml_serving_moe_experts_held": (
+        "moe_experts_held", "Routed experts of a layer whose weights are "
+                            "held here: all of them, or this chip's share "
+                            "(0: no expert layers)"),
+    "kubeml_serving_cache_sublayers": (
+        "cache_sublayers", "Sub-layers of the served model that hold a paged "
+                           "cache: its depth, or twice that where a layer "
+                           "has two attentions"),
     "kubeml_serving_residual_streams": (
         "residual_streams", "Streams of the served model's residual path: 1, "
                             "or hc_mult under hyper-connections"),

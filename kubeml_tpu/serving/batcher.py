@@ -51,7 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..api.errors import KubeMLError
-from ..models.generation import GenerationInputError, init_cache
+from ..models.generation import (GenerationInputError, cache_sublayers,
+                                 init_cache)
 from ..models.gpt import PAD_ID, block_traces
 from ..utils import tracing
 from .stats import COMPILE_PHASES
@@ -113,6 +114,11 @@ class ExpertLayersUnsupported(KubeMLError):
         super().__init__(
             f"{what} is not supported for a model with routed-expert "
             f"layers", 409)
+
+
+# what an expert layer leaves in the cache a decode apply, in the order of
+# the step block's last columns (models/experts.py)
+_MOE_COUNTS = ("experts_touched", "assignments_held", "assignments_zero")
 
 
 def _leaves_named(tree, *names) -> list:
@@ -438,7 +444,7 @@ def _kv_token_bytes(module, layers: Optional[int] = None) -> int:
     doesn't expose the transformer geometry (accounting is skipped)."""
     import jax.numpy as jnp
 
-    depth = layers if layers is not None else getattr(module, "depth", None)
+    depth = layers if layers is not None else cache_sublayers(module)
     width = _kv_width(module)
     if not depth or not width:
         return 0
@@ -499,7 +505,7 @@ def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
 
     from ..ops.paged_attention import kv_row_width
 
-    depth = getattr(module, "depth", None)
+    depth = cache_sublayers(module)
     kv_heads, head_dim = _kv_head_shape(module)
     row = (_kv_width(module) if getattr(module, "mla", None) is not None
            else kv_row_width(kv_heads, head_dim))
@@ -951,15 +957,17 @@ class BatchingDecoder:
         array pays a host round trip, so the chunk's results
         must come back in a single fetch (token ids are non-negative, so -1
         is unambiguous — PAD_ID 0 is a legal vocab id). A model with expert
-        layers adds one column, [T, S + 1]: the experts its live rows chose
-        that step, summed over the layers (each layer leaves its count in
-        the cache, models/experts.py), so the count comes back in the
-        tokens' own fetch."""
+        layers adds three columns, [T, S + 3], summed over the layers
+        (each layer leaves its counts in the cache, models/experts.py): the
+        experts held here that its live rows chose that step, their
+        assignments that entered the grouped product, and those to identity
+        experts; so the counts come back in the tokens' own fetch."""
 
         def one(s, _):
             logits, cache = self._apply_step(variables, s.cache, s.tok, s.pos,
                                              pages=pages, live=s.live)
-            touched = _leaves_named(cache, "experts_touched")
+            counts = [sum(leaves) for leaves in (
+                _leaves_named(cache, name) for name in _MOE_COUNTS) if leaves]
             use, nxt_keys = _split_rows(s.keys)
             nxt = _sample_rows(logits, use, s.temp, s.topk, active=s.live)
             was_live = s.live
@@ -974,9 +982,9 @@ class BatchingDecoder:
             pos = jnp.where(live, s.pos + 1, s.pos)
             s2 = _Slab(cache, feed, pos, live, rem, nxt_keys, s.temp, s.topk,
                        s.eos)
-            if touched:
+            if counts:
                 out = jnp.concatenate(
-                    [out, sum(touched).astype(out.dtype)[None]])
+                    [out, jnp.stack(counts).astype(out.dtype)])
             return s2, out
 
         slab, packed = jax.lax.scan(
@@ -1949,13 +1957,16 @@ class BatchingDecoder:
             return tokens
         _, packed, snapshot, kv_bytes, cold, coloc = rec
         if self._moe_layers:
-            # the block's last column is each step's count of experts its
-            # live rows chose (_step_impl); the assignments follow from the
-            # rows that emitted
-            packed, touched = packed[:, :-1], packed[:, -1]
-            self.stats.moe_steps(
-                int((packed >= 0).sum()) * self._moe_top_k
-                * self._moe_layers, int(touched.sum()))
+            # the block's last columns are each step's counts (_step_impl):
+            # the experts its live rows chose, their assignments to held and
+            # to identity experts; all assignments follow from the rows that
+            # emitted, and the rest lie on other chips
+            cols = len(_MOE_COUNTS)
+            packed, counts = packed[:, :-cols], packed[:, -cols:].sum(axis=0)
+            touched, held, zero = (int(n) for n in counts)
+            made = (int((packed >= 0).sum()) * self._moe_top_k
+                    * self._moe_layers)
+            self.stats.moe_steps(held, touched, zero, made - held - zero)
         # decode-step histogram feed: the chunk's service time over its
         # steps is the per-step decode latency, and kv_bytes over it the
         # achieved KV bandwidth. Cold first executions quarantine to the
@@ -2943,7 +2954,7 @@ class PagedBatchingDecoder(BatchingDecoder):
             itemsize = jnp.dtype(
                 getattr(self.module, "dtype", jnp.float32)).itemsize
             live, grid = tile_chunks(pre, bucket, wa, pt, itemsize)
-            layers = int(self.module.depth)
+            layers = cache_sublayers(self.module)
             self.stats.tile_chunks(live * layers, grid * layers)
         # KV model for the prefill forward(s): gather reads the row's
         # clamped table, the kernel stops at the depth the row has reached;
@@ -3158,7 +3169,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                    if row is not None and row.lease is not None
                    and not row.prefilling
                    for s in range(1, size + 1))
-        layers = int(self.module.depth)
+        layers = cache_sublayers(self.module)
         return live * layers, size * self.slots * (w // pages) * layers
 
     def _dispatch_chunk_paged(self, size: int) -> tuple:
@@ -3299,7 +3310,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                     "mid-stream restore is unsupported under spec='draft' "
                     "(the drafter's separate arena is not captured); "
                     "resubmit the prompt", 409)
-            depth = getattr(self.module, "depth", None)
+            depth = cache_sublayers(self.module) or None
             if depth is not None and len(snap.layers) != int(depth):
                 raise KubeMLError(
                     f"snapshot has {len(snap.layers)} layers, model has "
@@ -3719,6 +3730,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         # _decode_block; engines share them): about one per compiled
         # program, not one per layer of each
         snap["block_traces"] = float(block_traces())
+        # sub-layers that hold a paged cache (the depth; twice that where
+        # a layer is a double layer of two attentions)
+        snap["cache_sublayers"] = float(cache_sublayers(self.module))
         # streams of the model's residual path (1; hyper-connections: n)
         snap["residual_streams"] = float(
             getattr(self.module, "hc_mult", 0) or 1)
@@ -3735,6 +3749,10 @@ class PagedBatchingDecoder(BatchingDecoder):
         snap["kv_latent_width"] = float(
             self.module.mla.latent_width if self._latent else 0)
         snap["moe_layers"] = float(self._moe_layers)
+        # experts of a layer whose weights are here (all, or this chip's
+        # share of them)
+        snap["moe_experts_held"] = float(
+            self.module.experts.held_range[1] if self._moe_layers else 0)
         snap["expert_param_bytes"] = float(self._expert_param_bytes)
         if self._spec_ctl is not None:
             # current adaptive speculation depth (0 = retreated to plain

@@ -97,11 +97,18 @@ class DecoderStats:
         self.prefill_tokens = 0       # real prompt tokens prefilled
         self.prefill_pad_tokens = 0   # bucket + row padding tokens computed
         # routed-expert layers, decode steps only: token-to-expert
-        # assignments made (live rows x top_k x expert layers a step) and
-        # distinct experts they chose (summed over layers), whose weights a
-        # step has to read
+        # assignments given to experts held here (live rows x top_k x
+        # expert layers a step where every choice is one: what enters the
+        # grouped product) and distinct experts they chose (summed over
+        # layers), whose weights a step has to read; and, where a layer
+        # holds a share of its experts or has identity experts, the
+        # assignments to identity (zero-compute) experts and to experts that
+        # lie on other chips, which add nothing here. The three add up to
+        # live rows x top_k x expert layers a step
         self.moe_assignments = 0
         self.moe_experts_touched = 0
+        self.moe_assignments_zero = 0
+        self.moe_assignments_absent = 0
         # a residual path of several streams (hyper-connections): positions
         # x sub-layers its maps were made and its streams mixed for, bucket
         # padding and dead rows included (the device does them), by the
@@ -337,12 +344,17 @@ class DecoderStats:
             self.hc_positions_admit += (
                 int(real) + int(padding)) * self.hc_sublayers
 
-    def moe_steps(self, assignments: int, touched: int) -> None:
+    def moe_steps(self, assignments: int, touched: int, zero: int,
+                  absent: int) -> None:
         """Expert-layer accounting for one processed decode chunk, from the
-        step program's own outputs (no extra fetch)."""
+        step program's own outputs (no extra fetch): ``assignments`` to
+        ``touched`` experts held here, ``zero`` to identity experts,
+        ``absent`` to experts held elsewhere."""
         with self._lock:
             self.moe_assignments += int(assignments)
             self.moe_experts_touched += int(touched)
+            self.moe_assignments_zero += int(zero)
+            self.moe_assignments_absent += int(absent)
 
     def walk_chunks(self, live: int, grid: int) -> None:
         """One dispatched decode chunk's page-walk programs, all steps and
@@ -652,6 +664,8 @@ class DecoderStats:
                 "prefill_pad_tokens": float(self.prefill_pad_tokens),
                 "moe_assignments": float(self.moe_assignments),
                 "moe_experts_touched": float(self.moe_experts_touched),
+                "moe_assignments_zero": float(self.moe_assignments_zero),
+                "moe_assignments_absent": float(self.moe_assignments_absent),
                 "hc_positions": float(self.hc_positions_admit
                                       + self.hc_positions_step),
                 "hc_positions_admit": float(self.hc_positions_admit),

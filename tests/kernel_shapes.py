@@ -138,6 +138,31 @@ def kernel_cases():
                                            interpret=False),
             (_sds((m, 1024), jnp.bfloat16),
              _sds((64, 1024, 3584), jnp.bfloat16), _sds((64,), jnp.int32)))
+    # LongCat-Flash's published shapes (ISSUE 41): the latent walk at 64
+    # heads of 128 + 64 on the 512 + 64 latent, 64 rows, the three table
+    # widths the cell reaches; the grouped products over one chip's share,
+    # 16 groups of 6144 x 2048, at a step's 64 x 12 and a 512-position
+    # admit's 512 x 12 assignment rows, of which a group holds 0, 1 or 2 and
+    # most belong to none (group sizes are the kernel's data, not its shape)
+    for width in (32, 64, 128):
+        cases[f"mla_attn-longcat-flash-P{width}"] = (
+            lambda q, a, t, p: mla_attn(q, a, t, p, value_dim=512,
+                                        scale=192 ** -0.5, interpret=False),
+            (_sds((64, 64, 576), jnp.bfloat16),
+             _sds((8193, PT, 576), jnp.bfloat16),
+             _sds((64, width), jnp.int32), _sds((64,), jnp.int32)))
+    for m in (768, 6144):
+        cases[f"moe_experts-longcat-flash-gated-m{m}"] = (
+            lambda x, w, u, g: grouped_matmul(x, w, g, u, kernel=True,
+                                              interpret=False),
+            (_sds((m, 6144), jnp.bfloat16),
+             _sds((16, 6144, 2048), jnp.bfloat16),
+             _sds((16, 6144, 2048), jnp.bfloat16), _sds((16,), jnp.int32)))
+        cases[f"moe_experts-longcat-flash-down-m{m}"] = (
+            lambda x, w, g: grouped_matmul(x, w, g, kernel=True,
+                                           interpret=False),
+            (_sds((m, 2048), jnp.bfloat16),
+             _sds((16, 2048, 6144), jnp.bfloat16), _sds((16,), jnp.int32)))
     streams = hc.HCConfig(mult=4)
     for positions in (2048, 32):
         cases[f"hc_pre-xing4.0-T{positions}"] = (

@@ -303,6 +303,13 @@ UNPANELED = {
     "kubeml_serving_moe_assignments_total": "model-specific; ad-hoc only",
     "kubeml_serving_moe_experts_touched_total":
         "model-specific; ad-hoc only",
+    # a chip's share of a layer's experts, identity experts (LongCat-Flash)
+    "kubeml_serving_moe_assignments_zero_total":
+        "model-specific; ad-hoc only",
+    "kubeml_serving_moe_assignments_absent_total":
+        "model-specific; ad-hoc only",
+    "kubeml_serving_moe_experts_held": "static per-model constant",
+    "kubeml_serving_cache_sublayers": "static per-model constant",
     # hyper-connected models only; the benchmark reads the admit part
     "kubeml_serving_residual_streams": "static per-model constant",
     "kubeml_serving_hc_positions_total": "model-specific; ad-hoc only",

@@ -584,12 +584,14 @@ def program_digest(module, tree):
 # 902 equations before for gpt2, 1295 for falcon), and their "admit" on PR
 # 40's, whose admits walk a chunk of pages a program in the tile body (900
 # equations before for gpt2, 1297 for falcon: the head loop is there twice,
-# masked and clear). Both of glm's are still PR 36's and every "step" PR
-# 38's: the latent path and the decode programs are the parent's, equation
-# for equation
+# masked and clear). Both of glm's were made again on PR 41's tree: every
+# expert layer masks its assignments by the range of the experts it holds
+# and leaves three counts in the cache, whatever it holds (models/experts
+# .py has one path; 843 and 1023 equations before); the latent path and everything else of the two are PR
+# 36's, equation for equation
 PARENT_PROGRAMS = {
-    "glm": {"admit": (843, "e8f497ae78ac0e01"),
-            "step": (1023, "9b54162a64849441")},
+    "glm": {"admit": (865, "38f8e7ec29e37aa1"),
+            "step": (1045, "90416aeef59b0cad")},
     "gpt2": {"admit": (1248, "e5faa9da9aaadbb1"),
              "step": (690, "66df1c13cb8cce27")},
     "falcon": {"admit": (1645, "100bfbb328f0ac69"),
