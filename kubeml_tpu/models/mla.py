@@ -10,8 +10,20 @@
 
 ``r`` is ONE rope key a token, shared by all heads, so the cache holds one
 vector ``[c | r]`` of ``dc + dr`` values a token and layer, once: the paged
-arena is ``latent_pages`` ``[kv_pages, page_tokens, dc + dr]`` (no head
-axis, no V arena). Two forms of the one function read it:
+arena is ``latent_pages`` ``[kv_pages, page_tokens, R]`` (no head axis, no V
+arena), a token's row ``[c | r | zeros]`` with ``R`` = ``dc + dr`` rounded up
+to whole 128-lane rows (``MLAConfig.row_width``: 640 for the published 512 +
+64, no lane added where ``dc + dr`` is a multiple of 128): the write below
+(an XLA scatter) and the kernel's read (a Mosaic call) meet only at whole
+lane rows, and XLA answered 4.5 of them with two copies of the pool a layer
+in every program (ops/mla_attention.py says what that cost). The write
+stores the pad lanes as zeros, the trash page's too, so they are zero for
+ever and a reader may multiply over them. Two figures follow: the STORED
+bytes, ``R`` a token (``serving/batcher.py _kv_page_bytes``: what the pool
+occupies), and the LIVE bytes, ``dc + dr`` (``latent_width``; ``_kv_width``,
+the KV-read counter and the benchmark's ``costs/mla_latent.py``: what
+attention needs).
+Two forms of the one function read it:
 
 * **expanded** — per-head K and V made from the latents. The whole-sequence
   forward (``decode=False``), and a paged call of more than one position (an
@@ -20,9 +32,10 @@ axis, no V arena). Two forms of the one function read it:
   dense under the positional mask.
 * **absorbed** — a decode step (one position a row): the query is carried
   into the latent space, ``qa_h = q_nope_h W_uk_h^T``, the page walk scores
-  ``[qa_h | q_rope_h]`` against ``[c | r]`` and sums ``c`` (ops/mla_attention
-  .py: the ``mla_attn`` kernel, or its ``gather`` oracle), and ``W_uv_h``
-  carries the result out. The cache is never expanded in a step.
+  ``[qa_h | q_rope_h]`` (padded with zeros to the row) against ``[c | r |
+  zeros]`` and sums ``c`` (ops/mla_attention.py: the ``mla_attn`` kernel, or
+  its ``gather`` oracle), and ``W_uv_h`` carries the result out. The cache
+  is never expanded in a step.
 
 With ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` (LongCat-Flash) the two
 normed latents are multiplied by ``sqrt(E / q_lora_rank)`` and ``sqrt(E /
@@ -47,7 +60,8 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
-from ..ops.mla_attention import mla_attn, mla_attn_gather
+from ..ops.mla_attention import (latent_row_width, mla_attn,
+                                 mla_attn_gather, pad_lanes)
 from ..ops.paged_attention import resolve_paged_attn
 from ..ops.rotary import YarnScaling, apply_rope
 from .layers import QuantizableDense
@@ -72,6 +86,11 @@ class MLAConfig:
     def latent_width(self) -> int:
         """Values one token holds in one layer's arena."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def row_width(self) -> int:
+        """Lanes the arena stores them in: whole 128-lane rows."""
+        return latent_row_width(self.latent_width)
 
     @property
     def softmax_scale(self) -> float:
@@ -164,10 +183,11 @@ class MLAttention(nn.Module):
             raise ValueError("paged decode needs per-row positions")
         pt, npg, tw = self.page_tokens, self.kv_pages, pages.shape[1]
         arena = self.variable("cache", "latent_pages", jnp.zeros,
-                              (npg, pt, dc + dr), self.dtype)
+                              (npg, pt, c.row_width), self.dtype)
         pos_full = positions[:, None] + jnp.arange(L)          # [B, L]
         q, kr = rotated(q, kr, pos_full)
-        lat = jnp.concatenate([ckv, kr], axis=-1)              # [B, L, .]
+        # the stored row [c | r | zeros]; the trash page's rows alike
+        lat = pad_lanes(jnp.concatenate([ckv, kr], axis=-1), c.row_width)
         # the write, as K's in CausalSelfAttention: invalid positions and
         # positions past the table go to the trash page (physical page 0)
         wvalid = (jnp.arange(L)[None, :] < seq_lens[:, None]
@@ -178,7 +198,7 @@ class MLAttention(nn.Module):
         phys = jnp.where(wvalid, phys, 0)
         arena.value = arena.value.at[phys, pos_full % pt].set(lat)
         if L > 1:
-            rows = arena.value[pages].reshape(B, tw * pt, dc + dr)
+            rows = arena.value[pages].reshape(B, tw * pt, c.row_width)
             seen = (jnp.arange(tw * pt)[None, None, None, :]
                     <= pos_full[:, None, :, None])
             out = expanded(q, rows, mask=seen)

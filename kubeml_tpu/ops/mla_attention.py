@@ -3,14 +3,28 @@ in its absorbed form), and its plain oracle.
 
 The arena holds, per token and layer, ONE vector ``[c | r]`` of ``W = dc +
 dr`` values (the normed compressed K/V ``c`` and the rotated shared rope key
-``r``), ``[kv_pages, page_tokens, W]``, shared by every head and by K and V.
-A decode step's query arrives already carried into that space
-(``models/mla.py``): per head ``[q_nope W_uk^T | q_rope]``, so
+``r``), shared by every head and by K and V, stored as a row ``[c | r |
+zeros]`` of ``R`` lanes, ``[kv_pages, page_tokens, R]``: ``W`` rounded up to
+a whole number of 128-lane rows (:func:`latent_row_width`; 576 -> 640 at the
+published ``dc`` 512 and ``dr`` 64, nothing added where ``W`` is a multiple
+of 128 already). The arena's layout is a contract between its write (an XLA
+scatter, ``models/mla.py``) and its read (the Mosaic call below), and the two
+meet only at whole lane rows: at 576 lanes, 4.5 rows, XLA relaid the whole
+pool out before the scatter and back after it, twice a layer in every
+program (a third of a decode step on the chip, PERF.md PR 43), as it did to
+the K/V arena before its rows were whole (``ops/paged_attention
+.kv_row_width``). The lanes past ``W`` are zero from the arena's
+``jnp.zeros`` on: every write stores them as zeros (:func:`pad_lanes`).
 
-    s[h, j] = q[h] . [c_j | r_j] * scale,   j <= position
+A decode step's query arrives already carried into the latent space
+(``models/mla.py``): per head ``[q_nope W_uk^T | q_rope]``, ``W`` wide, and
+is padded with zeros to ``R`` here, so
+
+    s[h, j] = q[h] . [c_j | r_j | 0] * scale,   j <= position
     o[h]    = softmax(s[h]) @ c              (dc wide; W_uv carries it out)
 
-and the cache is never expanded to per-head K and V. :func:`mla_attn` is the
+the products over the added lanes are exact zeros, and the cache is never
+expanded to per-head K and V. :func:`mla_attn` is the
 page walk as a Pallas TPU kernel: grid ``(rows, table width / C)``, each
 program streams ``C`` of the row's pages (the arena is handed to the kernel
 ``C`` times, each copy's index map one page of the chunk through the
@@ -34,17 +48,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import _LANES, _NEG   # one masking value, one carry width
+# one masking value, one carry width, one rounding
+from .paged_attention import _LANES, _NEG, _round_up
 
 _CHUNK = 8        # pages a program streams (128 positions at 16 a page)
 
 
+def latent_row_width(latent: int) -> int:
+    """Lanes of one token's arena row: its ``latent`` = ``dc + dr`` values,
+    rounded up to a whole number of 128-lane rows."""
+    return _round_up(latent, _LANES)
+
+
+def pad_lanes(x, width: int):
+    """``x [..., W]`` with zeros after it up to ``width`` lanes (``x`` itself
+    where it is that wide already)."""
+    pad = width - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
 def mla_attn_gather(q, arena, pages, positions, *, value_dim: int,
                     scale: float):
-    """q ``[B, H, W]``, arena ``[N, pt, W]``, pages ``[B, P]``,
-    positions ``[B]`` (the query's own, already written) -> ``[B, H, dc]``."""
+    """q ``[B, H, W]``, arena ``[N, pt, R]`` (``R >= W``, zeros past ``W``),
+    pages ``[B, P]``, positions ``[B]`` (the query's own, already written)
+    -> ``[B, H, dc]``."""
     B, P = pages.shape
     pt = arena.shape[1]
+    q = pad_lanes(q, arena.shape[-1])
     lat = arena[pages].reshape(B, P * pt, arena.shape[-1])
     s = jnp.einsum("bhc,bjc->bhj", q, lat,
                    preferred_element_type=jnp.float32) * scale
@@ -74,7 +104,7 @@ def _mla_kernel(pages_ref, pos_ref, live_ref, q_ref, *rest, chunk: int,
 
     @pl.when(i * chunk < live_ref[b])
     def _chunk():
-        q = q_ref[0]                                            # [H, W]
+        q = q_ref[0]                                            # [H, R]
         lat = jnp.concatenate([r[0] for r in lat_refs], axis=0)  # [span, .]
         s = jax.lax.dot_general(q, lat, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -103,7 +133,8 @@ def mla_attn(q, arena, pages, positions, *, value_dim: int, scale: float,
     """The page walk of :func:`mla_attn_gather`'s contract (same arguments,
     same result at float32-accumulation tolerance). The caller has already
     written this step's latent into the arena."""
-    B, H, W = q.shape
+    q = pad_lanes(q, arena.shape[-1])
+    B, H, R = q.shape
     pt = int(arena.shape[1])
     P = int(pages.shape[1])
     if interpret is None:
@@ -133,8 +164,8 @@ def mla_attn(q, arena, pages, positions, *, value_dim: int, scale: float,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, n_chunks),
-            in_specs=[pl.BlockSpec((1, H, W), q_map)]
-            + [pl.BlockSpec((1, pt, W), page_map(c)) for c in range(chunk)],
+            in_specs=[pl.BlockSpec((1, H, R), q_map)]
+            + [pl.BlockSpec((1, pt, R), page_map(c)) for c in range(chunk)],
             out_specs=pl.BlockSpec((1, H, value_dim), q_map),
             scratch_shapes=[pltpu.VMEM((H, value_dim), jnp.float32),
                             pltpu.VMEM((H, _LANES), jnp.float32),

@@ -503,6 +503,11 @@ SERVING_GAUGES = {
         "kv_latent_width", "Values one cached token holds in one layer of a "
                            "latent (MLA) arena, once for all heads and for K "
                            "and V; 0 for a model that pages K/V heads"),
+    "kubeml_serving_kv_latent_row_width": (
+        "kv_latent_row_width", "Lanes the latent arena stores one token's "
+                               "row in: kv_latent_width rounded up to whole "
+                               "128-lane rows, zeros past the values; 0 for "
+                               "a model that pages K/V heads"),
     "kubeml_serving_moe_layers": (
         "moe_layers", "Layers of the served model whose feed-forward is "
                       "routed experts (0: none)"),

@@ -497,7 +497,9 @@ def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
     the unit of the arena byte budget: ``page_tokens`` rows of K‖V as the
     arena stores them (``ops/paged_attention.kv_row_width``: zero lanes
     past a narrow model's K and V count, the published models have none),
-    or of the latent's width under latent attention. int8 mode adds the
+    or of the latent arena's stored rows (``MLAConfig.row_width``: 640
+    lanes hold the published 576; ``_kv_width`` is the live values, which
+    the read accounting counts). int8 mode adds the
     page's per-head f32 scale rows (k_scale/v_scale, [kv_pages, H]) so the
     capacity derivation charges quantization's real overhead. 0 when the
     module doesn't expose the transformer geometry."""
@@ -507,7 +509,8 @@ def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
 
     depth = cache_sublayers(module)
     kv_heads, head_dim = _kv_head_shape(module)
-    row = (_kv_width(module) if getattr(module, "mla", None) is not None
+    mla = getattr(module, "mla", None)
+    row = (int(mla.row_width) if mla is not None
            else kv_row_width(kv_heads, head_dim))
     if not depth or not row:
         return 0
@@ -3742,12 +3745,15 @@ class PagedBatchingDecoder(BatchingDecoder):
         snap["recurrent_state_bytes"] = float(self._recurrent_bytes)
         snap["prefix_cache_off_recurrent"] = (
             1.0 if self._prefix_off_recurrent else 0.0)
-        # a latent arena: values one token holds in one layer, once (0 for
-        # a model that pages K and V heads); routed-expert layers and the
-        # bytes of their stacked expert weights (a decode step reads the
-        # share of them its rows chose: moe_experts_touched)
+        # a latent arena: values one token holds in one layer, once, and
+        # the lanes its row is stored in (both 0 for a model that pages K
+        # and V heads); routed-expert layers and the bytes of their stacked
+        # expert weights (a decode step reads the share of them its rows
+        # chose: moe_experts_touched)
         snap["kv_latent_width"] = float(
             self.module.mla.latent_width if self._latent else 0)
+        snap["kv_latent_row_width"] = float(
+            self.module.mla.row_width if self._latent else 0)
         snap["moe_layers"] = float(self._moe_layers)
         # experts of a layer whose weights are here (all, or this chip's
         # share of them)

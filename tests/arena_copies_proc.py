@@ -1,18 +1,21 @@
 """Subprocess entry for tests/test_arena_copies.py: compile the paged forward
 pass of a decode step and of a one-row admit, ahead of time, with the real
 TPU compiler against a chipless topology description, at the attention
-shapes of the three configurations that use the K/V arena, and report every
-``copy``, ``copy-start`` or ``transpose`` in the compiled program whose
-result has the arena's element count or more. One kind is told apart and
-allowed in the admit alone, once a layer: a ``copy-start`` whose source and
-destination differ in memory space and in nothing else. XLA's memory-space
-assignment carries the arena into fast memory for a 1,024-row scatter and
-back (asynchronous, no relayout); a decode step's 4-32 rows do not move it.
+shapes of the three configurations that use the K/V arena and of the three
+that use the latent one, and report every ``copy``, ``copy-start`` or
+``transpose`` in the compiled program whose result has the arena's element
+count or more. One kind is told apart and allowed in the admit alone, once
+an arena: a ``copy-start`` whose source and destination differ in memory
+space and in nothing else. XLA's memory-space assignment carries the arena
+into fast memory for a 1,024-row scatter and back (asynchronous, no
+relayout); a decode step's 4-64 rows do not move it.
 
-Only attention's shapes are the published ones (heads, K/V heads, head size,
-rows, pages): two layers, a small vocabulary and a narrow MLP, so that no
-weight is as large as the arena and a relayout of a weight cannot be taken
-for one of the pool.
+Only attention's shapes are the published ones (heads, K/V heads, head size
+or the latent attention's ranks, rows, pages): two layers, a small
+vocabulary and a narrow MLP, so that no weight is as large as the arena and
+a relayout of a weight cannot be taken for one of the pool. LongCat-Flash's
+two layers are the two attentions of one double layer, with four small
+experts on its side branch.
 
 Prints ``OK <case>`` / ``COPIES <case>: <n> <first few>`` per case; exit 0
 when no case holds a forbidden operation, 1 when one does, 77 when this
@@ -32,11 +35,28 @@ SHAPES = {
     "gpt2-xl": (1600, 25, 0, 0, 4, 64, 1024),
     "falcon-h1-34b": (5120, 20, 4, 128, 32, 64, 128),
 }
+# name -> (embed, heads, (q_lora_rank, kv_lora_rank, nope, rope, value head
+#          dims), scaled latents and a double layer, rows, table pages,
+#          admit bucket): the same, of the configurations with a latent arena
+LATENT_SHAPES = {
+    "glm-4.7-flash": (2048, 20, (768, 512, 192, 64, 256), False,
+                      32, 256, 2048),
+    "xing4.0-29b-a4b": (3584, 32, (768, 512, 128, 64, 128), False,
+                        32, 256, 2048),
+    "longcat-flash-omni": (6144, 64, (1536, 512, 128, 64, 128), True,
+                           64, 128, 512),
+}
+CASES = {f"{name}-{case}" for name in (*SHAPES, *LATENT_SHAPES)
+         for case in ("step", "admit")}
 
 _SHAPE = r"\w+\[[0-9,]*\](?:\{[^}]*\})?"
 _OP = re.compile(rf" = \(?({_SHAPE})(?:, ({_SHAPE}))?.*? "
                  r"(copy|copy-start|transpose)\(")
 _SPACE = re.compile(r"S\(\d+\)")
+# a page walk (either arena's): the kernel call under the module's name,
+# as a device trace shows it; an expert layer's kernels have their own
+_WALK = re.compile(r'^\s*%attn[.\d]* = .*custom_call_target="tpu_custom_call"',
+                   re.M)
 
 
 def pool_sized(hlo: str, floor: int) -> list:
@@ -86,22 +106,47 @@ def main() -> int:
 
     import flax.linen as nn
 
+    from kubeml_tpu.models.experts import ExpertsConfig
     from kubeml_tpu.models.gpt import CausalTransformer
+    from kubeml_tpu.models.mla import MLAConfig
 
     def on_chip(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=chip), tree)
 
+    def stack(table, rows, **kw):
+        return CausalTransformer(
+            vocab_size=512, max_len=table * PT, mlp_dim=512,
+            dtype=jnp.bfloat16, page_tokens=PT, kv_pages=rows * table + 1,
+            paged_attn="pallas", **kw)
+
+    # (name, module, the arena's key, rows, table, bucket, page walks a
+    # step and an admit hold: a latent admit attends without the kernel)
+    models = [
+        (name, stack(table, rows, embed_dim=embed, depth=2, num_heads=heads,
+                     num_kv_heads=kv_heads, head_dim=head_dim,
+                     attn_bias=True, pos="rope" if kv_heads else "learned"),
+         "kv_rows", rows, table, bucket, {"step": 2, "admit": 2})
+        for name, (embed, heads, kv_heads, head_dim, rows, table,
+                   bucket) in SHAPES.items()]
+    for name, (embed, heads, (rq, dc, dn, dr, dv), double, rows, table,
+               bucket) in LATENT_SHAPES.items():
+        mla = MLAConfig(q_lora_rank=rq, kv_lora_rank=dc, qk_nope_head_dim=dn,
+                        qk_rope_head_dim=dr, v_head_dim=dv,
+                        mla_scale_q_lora=double, mla_scale_kv_lora=double)
+        kind = (dict(depth=1, mlp="shortcut", experts=ExpertsConfig(
+            n_routed_experts=4, num_experts_per_tok=2,
+            moe_intermediate_size=256, n_shared_experts=0))
+            if double else dict(depth=2, mlp="swiglu"))
+        models.append((name, stack(table, rows, embed_dim=embed,
+                                   num_heads=heads, norm="rmsnorm",
+                                   pos="rope", mla=mla, **kind),
+                       "latent_pages", rows, table, bucket,
+                       {"step": 2, "admit": 0}))
+
     failed = 0
     i32 = jnp.int32
-    for name, (embed, heads, kv_heads, head_dim, rows, table,
-               bucket) in SHAPES.items():
-        module = CausalTransformer(
-            vocab_size=512, max_len=table * PT, embed_dim=embed, depth=2,
-            num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
-            mlp_dim=512, dtype=jnp.bfloat16, attn_bias=True,
-            pos="rope" if kv_heads else "learned", page_tokens=PT,
-            kv_pages=rows * table + 1, paged_attn="pallas")
+    for name, module, key, rows, table, bucket, walks in models:
 
         def forward(variables, cache, ids, positions, pages, seq_lens):
             logits, upd = module.apply(
@@ -117,7 +162,10 @@ def main() -> int:
                     decode=True, positions=jnp.zeros((b,), i32),
                     pages=jnp.zeros((b, table), i32),
                     seq_lens=jnp.ones((b,), i32))))
-            arena = full["cache"]["block_0"]["attn"]["kv_rows"]
+            arenas = [leaf for path, leaf in
+                      jax.tree_util.tree_leaves_with_path(full["cache"])
+                      if path[-1].key == key]
+            assert len(arenas) == 2, (name, len(arenas))
             vec = jax.ShapeDtypeStruct((b,), i32)
             hlo = jax.jit(forward, donate_argnums=(1,)).lower(
                 on_chip({"params": full["params"]}), on_chip(full["cache"]),
@@ -125,13 +173,13 @@ def main() -> int:
                 on_chip(vec),
                 on_chip(jax.ShapeDtypeStruct((b, table), i32)),
                 on_chip(vec)).compile().as_text()
-            found = pool_sized(hlo, arena.size)
+            found = pool_sized(hlo, arenas[0].size)
             moves = [f for f in found if f[0] == "move"]
-            if case == "admit" and len(moves) <= module.depth:
+            if case == "admit" and len(moves) <= len(arenas):
                 found = [f for f in found if f[0] != "move"]
-            if hlo.count('custom_call_target="tpu_custom_call"') != 2:
-                found.append(("page-walk calls", str(hlo.count(
-                    'custom_call_target="tpu_custom_call"'))))
+            calls = len(_WALK.findall(hlo))
+            if calls != walks[case]:
+                found.append(("page-walk calls", str(calls)))
             if found:
                 failed += 1
                 print(f"COPIES {name}-{case}: {len(found)} {found[:6]}",

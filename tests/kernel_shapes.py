@@ -20,7 +20,7 @@ def kernel_cases():
     from kubeml_tpu.ops import hyper_connection as hc
     from kubeml_tpu.ops.grouped_matmul import grouped_matmul
     from kubeml_tpu.ops.int8_matmul import int8_matmul
-    from kubeml_tpu.ops.mla_attention import mla_attn
+    from kubeml_tpu.ops.mla_attention import latent_row_width, mla_attn
     from kubeml_tpu.ops.paged_attention import kv_row_width, paged_attention
     from kubeml_tpu.ops.ssm import ssm_update
 
@@ -102,7 +102,7 @@ def kernel_cases():
             lambda q, a, t, p: mla_attn(q, a, t, p, value_dim=512,
                                         scale=1 / 16, interpret=False),
             (_sds((rows, 20, 576), jnp.bfloat16),
-             _sds((8193, PT, 576), jnp.bfloat16),
+             _sds((8193, PT, latent_row_width(576)), jnp.bfloat16),
              _sds((rows, width), jnp.int32), _sds((rows,), jnp.int32)))
     for m in (128, 8192):
         cases[f"moe_experts-gated-m{m}"] = (
@@ -124,7 +124,7 @@ def kernel_cases():
         lambda q, a, t, p: mla_attn(q, a, t, p, value_dim=512,
                                     scale=0.14467962580, interpret=False),
         (_sds((rows, 32, 576), jnp.bfloat16),
-         _sds((8193, PT, 576), jnp.bfloat16),
+         _sds((8193, PT, latent_row_width(576)), jnp.bfloat16),
          _sds((rows, 128), jnp.int32), _sds((rows,), jnp.int32)))
     for m in (128, 8192):
         cases[f"moe_experts-xing4.0-gated-m{m}"] = (
@@ -149,7 +149,7 @@ def kernel_cases():
             lambda q, a, t, p: mla_attn(q, a, t, p, value_dim=512,
                                         scale=192 ** -0.5, interpret=False),
             (_sds((64, 64, 576), jnp.bfloat16),
-             _sds((8193, PT, 576), jnp.bfloat16),
+             _sds((8193, PT, latent_row_width(576)), jnp.bfloat16),
              _sds((64, width), jnp.int32), _sds((64,), jnp.int32)))
     for m in (768, 6144):
         cases[f"moe_experts-longcat-flash-gated-m{m}"] = (

@@ -1,14 +1,17 @@
-"""Does a paged program copy the KV pool? (ROADMAP S1)
+"""Does a paged program copy the KV pool, or the latent one? (ROADMAP S1)
 
 The arena's layout is a contract between its write (an XLA scatter) and its
 read (a Mosaic custom call), and XLA answers a layout neither likes with
 copies of the whole pool, every layer, every step: the head-major arenas
 cost six a layer, 26-31 ms of a 48-62 ms GPT-2 decode step on the chip, and
 an unrelated edit once doubled their count (PR 29). Interpret mode cannot
-see any of it. This compiles the paged forward pass for a described v5e, in
-a subprocess (it loads the TPU plugin, which the test session must not), at
-the three configurations' attention shapes, and holds the count of
-pool-sized copies at zero (tests/arena_copies_proc.py says what it counts).
+see any of it. The latent arena had the same disease at 576 lanes a token,
+4.5 lane rows: two copies of the pool a layer in every program, a third of a
+decode step, until its rows were whole ones too (PR 43). This compiles the
+paged forward pass for a described v5e, in a subprocess (it loads the TPU
+plugin, which the test session must not), once for all cases, at the six
+configurations' attention shapes, and holds the count of pool-sized copies
+at zero in each (tests/arena_copies_proc.py says what it counts).
 """
 
 import subprocess
@@ -17,23 +20,27 @@ from pathlib import Path
 
 import pytest
 
-from arena_copies_proc import SHAPES, pool_sized
+from arena_copies_proc import CASES, pool_sized
 
-CASES = {f"{name}-{case}" for name in SHAPES for case in ("step", "admit")}
+
+@pytest.fixture(scope="module")
+def compiled():
+    """The subprocess's report: case -> its line."""
+    proc = subprocess.run(
+        [sys.executable, str(Path(__file__).parent / "arena_copies_proc.py")],
+        capture_output=True, text=True, timeout=1200)
+    if proc.returncode == 77:
+        pytest.skip(proc.stdout.strip().splitlines()[-1])
+    lines = [l for l in proc.stdout.splitlines()
+             if l.startswith(("OK ", "COPIES "))]
+    assert proc.returncode in (0, 1) and lines, proc.stderr[-2000:]
+    return {l.split()[1].rstrip(":"): l for l in lines}
 
 
 @pytest.mark.kernel
-def test_paged_programs_hold_no_pool_sized_copy():
-    proc = subprocess.run(
-        [sys.executable, str(Path(__file__).parent / "arena_copies_proc.py")],
-        capture_output=True, text=True, timeout=900)
-    if proc.returncode == 77:
-        pytest.skip(proc.stdout.strip().splitlines()[-1])
-    lines = proc.stdout.splitlines()
-    found = [l for l in lines if l.startswith("COPIES ")]
-    assert proc.returncode == 0 and not found, (
-        "\n".join(found) or proc.stderr[-2000:])
-    assert {l[3:] for l in lines if l.startswith("OK ")} == CASES
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_paged_programs_hold_no_pool_sized_copy(compiled, case):
+    assert compiled.get(case) == f"OK {case}", compiled
 
 
 @pytest.mark.parametrize("line,want", [

@@ -297,6 +297,7 @@ UNPANELED = {
     "kubeml_serving_slots_total": "static capacity gauge",
     "kubeml_serving_weight_bytes": "static per-model constant",
     "kubeml_serving_kv_latent_width": "static per-model constant",
+    "kubeml_serving_kv_latent_row_width": "static per-model constant",
     "kubeml_serving_moe_layers": "static per-model constant",
     "kubeml_serving_expert_param_bytes": "static per-model constant",
     # expert models only; the benchmark reads their ratio per decode step

@@ -7,6 +7,7 @@ bias that is not zero; latent 16 + rope 8; 4 heads) in float32 on the CPU,
 built by the benchmark's own builder and held against the benchmark's plain
 reference (``benchmark/reference/glm_moe_lite.py``)."""
 
+import dataclasses
 import json
 import sys
 from pathlib import Path
@@ -18,6 +19,8 @@ import jax
 import jax.numpy as jnp
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+from latent_rows import pad_lanes_are_zero  # noqa: E402
 
 from benchmark.models import glm_moe_lite as builder  # noqa: E402
 from benchmark.reference import glm_moe_lite as reference  # noqa: E402
@@ -31,7 +34,9 @@ from kubeml_tpu.models.generation import (expert_layers, has_latent_cache,  # no
                                           supports_paged_decode)
 from kubeml_tpu.models.mla import MLAConfig  # noqa: E402
 from kubeml_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
-from kubeml_tpu.ops.mla_attention import mla_attn, mla_attn_gather  # noqa: E402
+from kubeml_tpu.ops.mla_attention import (latent_row_width,  # noqa: E402
+                                          mla_attn, mla_attn_gather,
+                                          pad_lanes)
 from kubeml_tpu.serving.batcher import (BatchingDecoder,  # noqa: E402
                                         ExpertLayersUnsupported,
                                         LatentCacheUnsupported,
@@ -227,19 +232,31 @@ def test_grouped_product_equals_a_loop_over_experts(sizes, gated):
                                atol=1e-4)   # (the empty case runs too)
 
 
+# a toy latent (24 values in a 128-lane row), the published one (576 in
+# 640) and one that fills its rows (640: nothing added)
+@pytest.mark.parametrize("dc,dr", [(16, 8), (512, 64), (512, 128)])
 @pytest.mark.parametrize("P,positions", [(16, [0, 17, 63]), (8, [31, 5, 8]),
                                          (3, [11, 0, 7])])
-def test_latent_page_walk_kernel_equals_gather(P, positions):
+def test_latent_page_walk_kernel_equals_gather(P, positions, dc, dr):
     rng = np.random.default_rng(P)
-    B, H, dc, dr, pt, N = 3, 4, 16, 8, 4, 40
-    q = jnp.asarray(rng.standard_normal((B, H, dc + dr)), jnp.float32)
-    arena = jnp.asarray(rng.standard_normal((N, pt, dc + dr)), jnp.float32)
+    B, H, pt, N = 3, 4, 4, 40
+    W, R = dc + dr, latent_row_width(dc + dr)
+    assert R % 128 == 0 and 0 <= R - W < 128
+    q = jnp.asarray(rng.standard_normal((B, H, W)), jnp.float32)
+    live = jnp.asarray(rng.standard_normal((N, pt, W)), jnp.float32)
+    arena = pad_lanes(live, R)                   # the rows as they are stored
+    assert arena.shape == (N, pt, R) and (arena is live) == (R == W)
     pages = jnp.asarray(rng.integers(1, N, (B, P)), jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
-    got = mla_attn(q, arena, pages, pos, value_dim=dc, scale=0.25)
-    want = mla_attn_gather(q, arena, pages, pos, value_dim=dc, scale=0.25)
+    how = dict(value_dim=dc, scale=W ** -0.5)
+    got = mla_attn(q, arena, pages, pos, **how)
+    want = mla_attn_gather(q, arena, pages, pos, **how)
     assert got.shape == (B, H, dc)
     assert float(jnp.abs(got - want).max()) < 1e-5
+    # the added lanes add exact zeros: the same call on an arena of the live
+    # values alone (the layout before PR 43) gives the same bits
+    assert np.array_equal(got, mla_attn(q, live, pages, pos, **how))
+    assert np.array_equal(want, mla_attn_gather(q, live, pages, pos, **how))
     # pages past a row's depth are never looked at: poison them
     depth = (np.asarray(positions) // pt) + 1
     poisoned = np.asarray(arena).copy()
@@ -247,8 +264,7 @@ def test_latent_page_walk_kernel_equals_gather(P, positions):
     for p in range(N):
         if p not in keep:
             poisoned[p] = np.nan
-    again = mla_attn(q, jnp.asarray(poisoned), pages, pos, value_dim=dc,
-                     scale=0.25)
+    again = mla_attn(q, jnp.asarray(poisoned), pages, pos, **how)
     assert float(jnp.abs(again - want).max()) < 1e-5
 
 
@@ -264,7 +280,11 @@ def test_a_latent_page_is_576_values_a_token_once(model):
     assert glm.mla.latent_width == 576 and glm.depth == 6
     # bfloat16: 1,152 B a token and layer, 6,912 B over the six layers
     assert _kv_token_bytes(glm) == 6 * 576 * 2 == 6912
-    assert _kv_page_bytes(glm, 16) == 16 * 6912
+    # stored in whole 128-lane rows: 640 lanes, 1,280 B a token and layer
+    assert glm.mla.row_width == 640
+    assert _kv_page_bytes(glm, 16) == 16 * 6 * 640 * 2
+    # a latent that fills its rows is stored as it is
+    assert dataclasses.replace(glm.mla, qk_rope_head_dim=128).row_width == 640
     # expanded K and V for the same token would be 20 x (256 + 256) x 2 B
     mha = glm.clone(mla=None, head_dim=256)
     assert _kv_token_bytes(mha) == 6 * 2 * 20 * 256 * 2 == 122880
@@ -274,7 +294,7 @@ def test_a_latent_page_is_576_values_a_token_once(model):
     cache = init_paged_cache(m, tree, 4, 8)
     arenas = [l for path, l in jax.tree_util.tree_leaves_with_path(cache)
               if getattr(path[-1], "key", "") == "latent_pages"]
-    assert [a.shape for a in arenas] == [(33, 8, 24)] * 3
+    assert [a.shape for a in arenas] == [(33, 8, 128)] * 3
     assert sum(a.nbytes for a in arenas) == 33 * _kv_page_bytes(m, 8)
     assert not any(getattr(path[-1], "key", "") == "kv_rows"
                    for path, _ in jax.tree_util.tree_leaves_with_path(cache))
@@ -334,6 +354,7 @@ def test_prefill_then_decode_logits_match_reference(model, impl, monkeypatch):
     seqs = [p[:n] for p, n in zip(prompts(3, 40, 40, seed=5), (5, 17, 30))]
     rows = [2, 0, 3]
     logits, cache = admit(m, tree, cache, rows, seqs, 32)
+    assert pad_lanes_are_zero(cache, 3)
     full = [list(s) for s in seqs]
     for i, s in enumerate(seqs):
         want = ref_logits(cfg, weights, s, np.arange(len(s)))
@@ -353,6 +374,7 @@ def test_prefill_then_decode_logits_match_reference(model, impl, monkeypatch):
             logits, upd = step_fn(cache, jnp.asarray(tok), jnp.asarray(pos),
                                   jnp.asarray(tbl), jnp.asarray(live))
         cache = upd["cache"]
+        assert pad_lanes_are_zero(cache, 3)        # the dead row's write too
         for r, f in zip(rows, full):
             want = ref_logits(cfg, weights, f, [len(f) - 1])
             assert float(jnp.abs(logits[r, 0] - want[0]).max()) < TOL
@@ -373,7 +395,9 @@ def test_absorbed_attention_equals_expanded(model):
     seq = prompts(1, 30, 30, seed=8)[0]
     empty = init_paged_cache(m, tree, SLOTS, TABLE)
     _, cache = admit(m, tree, empty, [1], [seq[:25]], 32)
-    window, _ = admit(m, tree, cache, [1], [seq[25:]], 8, base=[25])
+    # a suffix admitted over cached pages, as after a prefix hit
+    window, shared = admit(m, tree, cache, [1], [seq[25:]], 8, base=[25])
+    assert pad_lanes_are_zero(shared, 3)
     step_fn = jax.jit(lambda c, tok, pos, tbl: m.apply(
         {**tree, "cache": c}, tok[:, None], decode=True, positions=pos,
         pages=tbl, seq_lens=jnp.ones((1,), jnp.int32), mutable=["cache"]))
@@ -449,6 +473,7 @@ def test_engine_serves_the_reference_tokens(model):
         assert len(toks) == 9
         assert served_gap(cfg, weights, p, toks) < TOL
     assert tel["kv_latent_width"] == 24.0 and tel["moe_layers"] == 2.0
+    assert tel["kv_latent_row_width"] == 128.0   # the lanes they are stored in
     # the latent walk is another kernel: no grid of K/V chunks to count
     assert "walk_chunks_live" not in tel and "walk_chunks_grid" not in tel
     assert "tile_chunks_live" not in tel and "tile_chunks_grid" not in tel

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
+from latent_rows import pad_lanes_are_zero  # noqa: E402
+
 from benchmark.models import falcon_h1 as falcon_builder  # noqa: E402
 from benchmark.models import glm_moe_lite as glm_builder  # noqa: E402
 from benchmark.models import xing4 as builder  # noqa: E402
@@ -208,6 +210,8 @@ def test_prefill_then_decode_logits_match_reference(model, impl, monkeypatch):
     seqs = [p[:n] for p, n in zip(prompts(3, 40, 40, seed=5), (5, 17, 30))]
     rows = [2, 0, 7]
     logits, cache = admit(m, tree, cache, rows, seqs, 32)
+    depth = cfg["num_hidden_layers"]
+    assert pad_lanes_are_zero(cache, depth)
     full = [list(s) for s in seqs]
     for i, s in enumerate(seqs):
         want = ref_logits(cfg, weights, s, np.arange(len(s)))
@@ -227,6 +231,7 @@ def test_prefill_then_decode_logits_match_reference(model, impl, monkeypatch):
             logits, upd = step_fn(cache, jnp.asarray(tok), jnp.asarray(pos),
                                   jnp.asarray(tbl), jnp.asarray(live))
         cache = upd["cache"]
+        assert pad_lanes_are_zero(cache, depth)
         for r, f in zip(rows, full):
             want = ref_logits(cfg, weights, f, [len(f) - 1])
             assert float(jnp.abs(logits[r, 0] - want[0]).max()) < TOL
@@ -587,11 +592,15 @@ def program_digest(module, tree):
 # masked and clear). Both of glm's were made again on PR 41's tree: every
 # expert layer masks its assignments by the range of the experts it holds
 # and leaves three counts in the cache, whatever it holds (models/experts
-# .py has one path; 843 and 1023 equations before); the latent path and everything else of the two are PR
-# 36's, equation for equation
+# .py has one path; 843 and 1023 equations before), and again on PR 43's:
+# the latent arena's rows are whole 128-lane rows, so each of the three
+# layers pads the row it writes and a step the query it walks with (a
+# ``jnp.pad`` is three equations: 865 and 1045 before); everything else of
+# the two are PR 36's, equation for equation. PR 43 left the two K/V
+# families' programs as they were
 PARENT_PROGRAMS = {
-    "glm": {"admit": (865, "38f8e7ec29e37aa1"),
-            "step": (1045, "90416aeef59b0cad")},
+    "glm": {"admit": (874, "1c210d6a9a19ffc6"),
+            "step": (1063, "195bd73393f0dde1")},
     "gpt2": {"admit": (1248, "e5faa9da9aaadbb1"),
              "step": (690, "66df1c13cb8cce27")},
     "falcon": {"admit": (1645, "100bfbb328f0ac69"),

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
+from latent_rows import pad_lanes_are_zero  # noqa: E402
+
 from benchmark.models import longcat_flash as builder  # noqa: E402
 from benchmark.reference import longcat_flash as reference  # noqa: E402
 from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
@@ -214,12 +216,10 @@ def test_prefill_then_decode_logits_match_reference(model, impl, monkeypatch):
         force_kernels(monkeypatch)
     m = paged(module, impl)
     cache = init_paged_cache(m, tree, SLOTS, TABLE)
-    arenas = [k for k, _ in jax.tree_util.tree_leaves_with_path(cache)
-              if getattr(k[-1], "key", "") == "latent_pages"]
-    assert len(arenas) == 4                      # two a double layer
     seqs = [p[:n] for p, n in zip(prompts(3, 40, 40, seed=5), (5, 17, 30))]
     rows = [2, 0, 7]
     logits, cache = admit(m, tree, cache, rows, seqs, 32)
+    assert pad_lanes_are_zero(cache, 4)          # two arenas a double layer
     full = [list(s) for s in seqs]
     for i, s in enumerate(seqs):
         want = ref_logits(cfg, weights, s, np.arange(len(s)))
@@ -239,6 +239,7 @@ def test_prefill_then_decode_logits_match_reference(model, impl, monkeypatch):
             logits, upd = step_fn(cache, jnp.asarray(tok), jnp.asarray(pos),
                                   jnp.asarray(tbl), jnp.asarray(live))
         cache = upd["cache"]
+        assert pad_lanes_are_zero(cache, 4)
         for r, f in zip(rows, full):
             want = ref_logits(cfg, weights, f, [len(f) - 1])
             assert float(jnp.abs(logits[r, 0] - want[0]).max()) < TOL
@@ -294,6 +295,7 @@ def test_engine_serves_the_reference_tokens_and_counts_the_assignments(model):
         assert served_gap(cfg, weights, p, toks) < TOL
     assert tel["moe_layers"] == 2.0 and tel["moe_experts_held"] == 4.0
     assert tel["cache_sublayers"] == 4.0 and tel["kv_latent_width"] == 24.0
+    assert tel["kv_latent_row_width"] == 128.0
     # four latent arenas of 24 float32 values a token
     assert token_bytes == 4 * 24 * 4
     held, zero, absent = (tel[k] for k in (
