@@ -103,6 +103,11 @@ def init_paged_cache(module, variables, batch: int, table_pages: int) -> dict:
     dummy = jnp.zeros((batch, 1), jnp.int32)
     pos = jnp.zeros((batch,), jnp.int32)
     pages = jnp.zeros((batch, table_pages), jnp.int32)
+    ring = window_ring(module)
+    if ring:
+        # window layers' arenas (``window_pages`` pages) are addressed
+        # through a ring a row beside the full layers' table
+        pages = (pages, jnp.zeros((batch, ring), jnp.int32))
 
     def shape_fn(vs):
         return module.apply(vs, dummy, decode=True, positions=pos,
@@ -162,6 +167,50 @@ def cache_sublayers(module) -> int:
     depth = int(getattr(module, "depth", 0) or 0)
     return depth * getattr(getattr(module, "layer_cls", None),
                            "cache_sublayers", 1)
+
+
+def attention_kinds(module) -> list:
+    """``(K/V heads, K head size, V head size, window)`` of each sub-layer
+    of ``module`` that holds a paged K/V cache, in the stack's order
+    (``window`` 0: a full layer, whose row holds a page for every 
+    ``page_tokens`` positions; > 0: a window layer, whose row holds a ring,
+    models/gpt.py AttnKind). Empty when the module doesn't expose the
+    transformer geometry or pages latents."""
+    heads = getattr(module, "num_heads", None)
+    embed = getattr(module, "embed_dim", None)
+    if not heads or not embed or getattr(module, "mla", None) is not None:
+        return []
+    head_dim = int(getattr(module, "head_dim", 0) or int(embed) // int(heads))
+    v_dim = int(getattr(module, "v_head_dim", 0) or head_dim)
+    if getattr(module, "attn_kinds", ()):
+        kinds = [module.attn_kind(i) for i in range(int(module.depth))]
+        return [(int(a.num_kv_heads or heads), head_dim, v_dim, int(a.window))
+                for a in kinds]
+    kv_heads = int(getattr(module, "num_kv_heads", 0) or heads)
+    return [(kv_heads, head_dim, v_dim, 0)] * cache_sublayers(module)
+
+
+def window_layers(module) -> int:
+    """Layers of ``module`` whose attention sees a window of keys and whose
+    paged cache is a ring a row (``attn_kinds`` with ``window`` > 0): the
+    serving layer then keeps a second kind of lease and refuses what knows
+    one kind of page (prefix sharing, int8 pages, KMS1 frames, speculation,
+    the slot engine's dense cache)."""
+    return sum(1 for *_, window in attention_kinds(module) if window)
+
+
+def window_ring(module) -> int:
+    """Pages of a window layer's ring a row at the module's ``page_tokens``
+    (``ops/paged_attention.ring_pages`` of the widest window; every window
+    layer's ring is that wide, so one table serves them all); 0 without
+    window layers or before the serving layer cloned the page size in."""
+    windows = [w for *_, w in attention_kinds(module) if w]
+    pt = int(getattr(module, "page_tokens", 0) or 0)
+    if not windows or not pt:
+        return 0
+    from ..ops.paged_attention import ring_pages
+
+    return ring_pages(max(windows), pt)
 
 
 def residual_sublayers(module) -> int:

@@ -24,7 +24,11 @@ too: one stream and ``x = x + y``, or ``hc_mult`` streams that every sub-layer
 reads, writes and mixes through maps made from the token's own streams
 (ops/hyper_connection.py). And the layer itself may be LongCat-Flash's double
 layer (``mlp="shortcut"``, :class:`ShortcutBlock`): two attentions, two dense
-SwiGLUs, and an expert layer on a shortcut around the second pair. GPT-2 is
+SwiGLUs, and an expert layer on a shortcut around the second pair. And the ATTENTION may differ by layer (``attn_kinds``
+/ ``attn_pattern``, :class:`AttnKind`): full layers beside window layers,
+each kind with its own K/V head count, rotary base, window and sink, over K
+heads and V heads of different widths with rotary on a share of a head
+(MiMo-V2-Flash's ``hybrid_layer_pattern``). GPT-2 is
 the defaults; Falcon-H1 is rmsnorm +
 swiglu + GQA + rope + ssm + mup; GLM-4.7-Flash is rmsnorm + rope + mla +
 experts behind one dense layer; Xing4.0 is that with YaRN rotary on four
@@ -94,6 +98,24 @@ def _part(names):
     return lambda init: nn.with_partitioning(init, names)
 
 
+@dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer of a stack that mixes them, under the
+    names of the published ``config`` (MiMo-V2-Flash: ``num_key_value_heads``
+    and ``rope_theta`` for a full layer, ``swa_num_key_value_heads``,
+    ``swa_rope_theta``, ``sliding_window`` and
+    ``add_swa_attention_sink_bias`` for a window layer). ``window`` > 0: a
+    query sees that many keys, its own among them, and the layer's paged
+    cache is a RING of ``ops/paged_attention.ring_pages`` pages a row
+    (serving/kvpool.py); 0: every key before it. ``sink``: one learned logit
+    a head joins the softmax's denominator and takes no value."""
+
+    num_kv_heads: int = 0
+    rope_theta: float = 10000.0
+    window: int = 0
+    sink: bool = False
+
+
 class CausalSelfAttention(nn.Module):
     num_heads: int
     mesh: Optional[Mesh] = None
@@ -148,6 +170,94 @@ class CausalSelfAttention(nn.Module):
     num_kv_heads: int = 0
     head_dim: int = 0
     key_mult: float = 1.0   # a muP multiplier on the keys (key_multiplier)
+    # what MiMo-V2-Flash's ``config`` adds, under its names: V heads of a
+    # width of their own (``v_head_dim``; 0 = ``head_dim``), rotary on the
+    # first ``partial_rotary_factor`` of a head's lanes (ops/rotary.py
+    # rotary_width), the output times ``value_scale``
+    # (``attention_value_scale``), and the layer's kind (:class:`AttnKind`):
+    # ``window`` keys a query sees (0 = all), a learned ``sink`` a head
+    v_head_dim: int = 0
+    partial_rotary_factor: float = 1.0
+    value_scale: float = 1.0
+    window: int = 0
+    sink: bool = False
+
+    @nn.nowrap   # no scope of its own: the walk is ``%attn`` in every layer
+    def _window_paged(self, ckv, q, k, v, positions, pages, seq_lens, valid,
+                      sink):
+        """A window layer's paged decode: ``pages`` ``[B, ring]`` is the
+        row's RING (page of position ``p`` = slot ``(p // pt) mod ring``),
+        ``ckv`` the layer's arena of ``kv_pages`` pages of them. A step (``L == 1``) writes
+        its token into the ring and attends through it, at most ``window``
+        keys back. Anything longer is an admission of a row's whole prompt
+        (the serving layer refuses what would continue one: prefix hits,
+        chunked prefill, verify windows): it attends over the bucket's OWN
+        keys in a band, through the page walk's tile body over the bucket's
+        rows as they stand (no arena of the bucket's length exists in any
+        program), and keeps in the ring only the tail a step will read."""
+        from ..ops.paged_attention import (pack_kv_rows, paged_attention,
+                                           resolve_paged_attn, unpack_kv_rows)
+
+        B, L, H, D = q.shape
+        Hkv, Dv = k.shape[2], v.shape[3]
+        pt, ring = self.page_tokens, pages.shape[1]
+        if ring * pt < self.window + pt:
+            raise ValueError(f"a ring of {ring} pages of {pt} does not hold "
+                             f"a window of {self.window}")
+        kernel = resolve_paged_attn(self.paged_attn) == "pallas"
+        kinds = dict(kv_heads=Hkv, v_head_dim=Dv if Dv != D else 0,
+                     window=self.window, sink=sink,
+                     value_scale=self.value_scale)
+        shared = lambda t: (t if Hkv == H
+                            else jnp.repeat(t, H // Hkv, axis=2))
+        rows = pack_kv_rows(k, v)                              # [B, L, W]
+        n = (seq_lens if seq_lens is not None
+             else valid.astype(jnp.int32).sum(axis=1))         # real tokens
+        # the tail the ring keeps: the bucket's last ring-worth of positions
+        # (all of a step's one), those of them that are real and lie in the
+        # ring's newest turn; the others go to the trash page
+        T = min(L, ring * pt)
+        at = jnp.clip(n - T, 0, L - T)[:, None] + jnp.arange(T)  # [B, T]
+        pos = positions[:, None] + at
+        newest = (positions + jnp.maximum(n, 1) - 1) // pt
+        keep = (at < n[:, None]) & (pos // pt > newest[:, None] - ring)
+        phys = jnp.where(keep, jnp.take_along_axis(
+            pages, (pos // pt) % ring, axis=1), 0)
+        tail = rows if T == L else jnp.take_along_axis(
+            rows, at[:, :, None], axis=1)
+        ckv.value = ckv.value.at[phys, pos % pt].set(tail)
+        if L == 1:
+            if kernel:
+                return paged_attention(q, ckv.value, pages, positions,
+                                       **kinds)
+            kg, vg = unpack_kv_rows(ckv.value[pages], Hkv, D, Dv)
+            kg = kg.reshape(B, ring * pt, Hkv, D)
+            vg = vg.reshape(B, ring * pt, Hkv, Dv)
+            # slot s holds the newest logical page congruent to s at or
+            # before the query's own
+            col = jnp.arange(ring * pt)[None, :]
+            cur = (positions // pt)[:, None]
+            k_pos = (cur - (cur - col // pt) % ring) * pt + col % pt
+            here = positions[:, None]
+            mask = ((k_pos <= here) & (k_pos > here - self.window)
+                    & (k_pos >= 0))[:, None, None, :]
+        else:
+            if kernel:
+                # the bucket's own rows as a table of whole pages
+                Lp = -(-L // pt) * pt
+                own = jnp.pad(rows, ((0, 0), (0, Lp - L), (0, 0))).reshape(
+                    B * (Lp // pt), pt, rows.shape[-1])
+                table = jnp.arange(B * (Lp // pt), dtype=jnp.int32).reshape(
+                    B, Lp // pt)
+                return paged_attention(q, own, table,
+                                       jnp.zeros((B,), jnp.int32), **kinds)
+            kg, vg = k, v
+            i = jnp.arange(L)
+            mask = ((i[None, :] <= i[:, None])
+                    & (i[None, :] > i[:, None] - self.window))[None, None]
+        out = dot_product_attention(q, shared(kg), shared(vg), mask=mask,
+                                    sink=sink)
+        return _scaled(out, self.value_scale)
 
     @nn.compact
     def __call__(self, x, valid, decode: bool = False, positions=None,
@@ -159,7 +269,12 @@ class CausalSelfAttention(nn.Module):
         B, L, E = x.shape
         H = self.num_heads
         D = self.head_dim or E // H
+        Dv = self.v_head_dim or D
         Hkv = self.num_kv_heads or H
+        # what this layer's kind adds to the plain causal softmax over
+        # equal heads; none of it: the block every other model has
+        plain = not (self.window or self.sink or Dv != D
+                     or self.value_scale != 1.0)
         if H % Hkv:
             raise ValueError(f"num_heads {H} is not a multiple of "
                              f"num_kv_heads {Hkv}")
@@ -178,9 +293,18 @@ class CausalSelfAttention(nn.Module):
         )
         q = dense(H * D, (None, "tp"), "query")(x).reshape(B, L, H, D)
         k = dense(Hkv * D, (None, "tp"), "key")(x).reshape(B, L, Hkv, D)
-        v = dense(Hkv * D, (None, "tp"), "value")(x).reshape(B, L, Hkv, D)
+        v = dense(Hkv * Dv, (None, "tp"), "value")(x).reshape(B, L, Hkv, Dv)
         k = _scaled(k, self.key_mult)
         out_proj = dense(E, ("tp", None), "proj")
+        sink = (self.param("sink", nn.initializers.zeros, (H,))
+                if self.sink else None)
+        if self.rope:
+            from ..ops.rotary import apply_rope, rotary_width
+
+            rot = (0 if self.partial_rotary_factor == 1.0
+                   else rotary_width(D, self.partial_rotary_factor))
+            rope = lambda t, at: apply_rope(t, at, self.rope_theta,
+                                            rotary_dim=rot)
 
         if decode:
             # KV-cache decode (models.generation): write this call's K/V at
@@ -249,7 +373,7 @@ class CausalSelfAttention(nn.Module):
                 # under Falcon-H1's heads of 128 (compiled ahead of time
                 # for a v5e; on the chip 26-31 ms of a 48-62 ms step).
                 # tests/test_arena_copies.py holds the count at zero.
-                W = kv_row_width(Hkv, D)
+                W = kv_row_width(Hkv, D, Dv)
                 ckv = self.variable("cache", "kv_rows", jnp.zeros,
                                     (npg, pt, W), store_dtype)
                 if kvq == "int8":
@@ -264,10 +388,15 @@ class CausalSelfAttention(nn.Module):
                                        (npg, Hkv), jnp.float32)
                 pos_full = positions[:, None] + jnp.arange(L)  # [B, L]
                 if self.rope:
-                    from ..ops.rotary import apply_rope
-
-                    q = apply_rope(q, pos_full, self.rope_theta)
-                    k = apply_rope(k, pos_full, self.rope_theta)
+                    q, k = rope(q, pos_full), rope(k, pos_full)
+                if kvq == "int8" and not plain:
+                    raise ValueError("int8 pages do not cover a window, a "
+                                     "sink or V heads of their own width")
+                if self.window:
+                    # the arena above holds the rows' rings
+                    return out_proj(self._window_paged(
+                        ckv, q, k, v, positions, pages, seq_lens, valid,
+                        sink).reshape(B, L, H * Dv))
                 wvalid = (jnp.arange(L)[None, :] < seq_lens[:, None]
                           if seq_lens is not None
                           else valid.astype(jnp.bool_))
@@ -340,12 +469,17 @@ class CausalSelfAttention(nn.Module):
                         out = paged_attention(q, ckv.value, pages, positions,
                                               kv_heads=Hkv, k_scale=ks.value,
                                               v_scale=vs.value)
-                    else:
+                    elif plain:
                         out = paged_attention(q, ckv.value, pages, positions,
                                               kv_heads=Hkv)
+                    else:
+                        out = paged_attention(
+                            q, ckv.value, pages, positions, kv_heads=Hkv,
+                            v_head_dim=Dv if Dv != D else 0, sink=sink,
+                            value_scale=self.value_scale)
                 else:
                     # [B, tw, pt, Hkv, D]: rows come out token-major as is
-                    kg, vg = unpack_kv_rows(ckv.value[pages], Hkv, D)
+                    kg, vg = unpack_kv_rows(ckv.value[pages], Hkv, D, Dv)
                     if kvq == "int8":
                         # gather-path dequant: the parity oracle for the
                         # quantized STORAGE format itself (same q*s/127
@@ -357,13 +491,23 @@ class CausalSelfAttention(nn.Module):
                               * (vs.value[pages] / 127.0)[:, :, None, :, None]
                               ).astype(q.dtype)
                     kg = kg.reshape(B, tw * pt, Hkv, D)
-                    vg = vg.reshape(B, tw * pt, Hkv, D)
+                    vg = vg.reshape(B, tw * pt, Hkv, Dv)
                     k_pos = jnp.arange(tw * pt)[None, None, None, :]
                     # [B, 1, L, tw*pt]
                     mask = k_pos <= pos_full[:, None, :, None]
-                    out = dot_product_attention(q, shared(kg), shared(vg),
-                                                mask=mask)
-                return out_proj(out.reshape(B, L, H * D))
+                    if plain:
+                        out = dot_product_attention(q, shared(kg), shared(vg),
+                                                    mask=mask)
+                    else:
+                        out = _scaled(dot_product_attention(
+                            q, shared(kg), shared(vg), mask=mask, sink=sink),
+                            self.value_scale)
+                return out_proj(out.reshape(B, L, H * Dv))
+            if not plain:
+                raise ValueError(
+                    "a window, a sink, V heads of their own width and a "
+                    "value scale decode through the paged arena only (no "
+                    "dense cache holds them)")
             Lc = self.cache_len
             ck = self.variable("cache", "k", jnp.zeros, (B, Lc, Hkv, D),
                                k.dtype)
@@ -383,10 +527,8 @@ class CausalSelfAttention(nn.Module):
                                      "per step (L == 1); prefill uses the "
                                      "scalar-cursor path")
                 if self.rope:
-                    from ..ops.rotary import apply_rope
-
-                    q = apply_rope(q, positions[:, None], self.rope_theta)
-                    k = apply_rope(k, positions[:, None], self.rope_theta)
+                    q = rope(q, positions[:, None])
+                    k = rope(k, positions[:, None])
                 # per-row writes as a coordinate scatter at (row, position).
                 # Chip-measured: this beats a vmapped dynamic_update_slice
                 # (batched dynamic starts lower worse than the scatter —
@@ -406,13 +548,10 @@ class CausalSelfAttention(nn.Module):
                 return out_proj(out.reshape(B, L, H * D))
             i0 = cursor.value
             if self.rope:
-                from ..ops.rotary import apply_rope
-
                 # keys are cached ALREADY rotated by their absolute position,
                 # so cached entries never need re-rotation as the cursor moves
                 pos = i0 + jnp.arange(L)
-                q = apply_rope(q, pos, self.rope_theta)
-                k = apply_rope(k, pos, self.rope_theta)
+                q, k = rope(q, pos), rope(k, pos)
             ck.value = jax.lax.dynamic_update_slice(ck.value, k, (0, i0, 0, 0))
             cv.value = jax.lax.dynamic_update_slice(cv.value, v, (0, i0, 0, 0))
             cvalid.value = jax.lax.dynamic_update_slice(
@@ -428,13 +567,25 @@ class CausalSelfAttention(nn.Module):
             return out_proj(out.reshape(B, L, H * D))
 
         if self.rope:
-            from ..ops.rotary import apply_rope
-
             pos = jnp.arange(L)
-            q = apply_rope(q, pos, self.rope_theta)
-            k = apply_rope(k, pos, self.rope_theta)
+            q, k = rope(q, pos), rope(k, pos)
         k, v = shared(k), shared(v)
 
+        if not plain:
+            # the whole-sequence forward of such a layer: dense scores under
+            # the causal mask, the window's band and the sink
+            if self.mesh is not None and self.mesh.shape.get("sp", 1) > 1:
+                raise ValueError("sequence parallelism does not cover a "
+                                 "window, a sink or V heads of their own "
+                                 "width")
+            i = jnp.arange(L)
+            mask = (i[None, :] <= i[:, None])[None, None] & valid.astype(
+                jnp.bool_)[:, None, None, :]
+            if self.window:
+                mask = mask & (i[None, :] > i[:, None] - self.window)
+            out = _scaled(dot_product_attention(q, k, v, mask=mask, sink=sink),
+                          self.value_scale)
+            return out_proj(out.reshape(B, L, H * Dv))
         if self.mesh is not None and self.mesh.shape.get("sp", 1) > 1:
             if self.sp_impl == "ulysses":
                 from ..parallel.ulysses import ulysses_attention
@@ -461,7 +612,7 @@ class CausalSelfAttention(nn.Module):
             out = attn(q, k, v, valid)
         else:
             out = dot_product_attention(q, k, v, causal=True, kv_valid=valid)
-        return out_proj(out.reshape(B, L, H * D))
+        return out_proj(out.reshape(B, L, H * Dv))
 
 
 class GPTBlock(nn.Module):
@@ -492,6 +643,13 @@ class GPTBlock(nn.Module):
     mla: Optional[MLAConfig] = None
     experts: Optional[ExpertsConfig] = None
     hc: Optional[HCConfig] = None
+    # the attention's own additions (CausalSelfAttention documents them);
+    # ``window`` and ``sink`` are the layer's kind (AttnKind)
+    v_head_dim: int = 0
+    partial_rotary_factor: float = 1.0
+    value_scale: float = 1.0
+    window: int = 0
+    sink: bool = False
     # paged caches a layer of this class holds (models/generation.py
     # cache_sublayers): one attention, one cache
     cache_sublayers: ClassVar[int] = 1
@@ -534,6 +692,11 @@ class GPTBlock(nn.Module):
                                            kernel=kernel)
         u = _norm(self.norm, "ln1", self.ln_eps)(x).astype(self.dtype)
         if self.mla is not None:
+            if (self.window or self.sink or self.v_head_dim
+                    or self.partial_rotary_factor != 1.0
+                    or self.value_scale != 1.0):
+                raise ValueError("latent attention has no window, sink, V "
+                                 "width or rotary share of its own here")
             attn = MLAttention(self.num_heads, self.mla, dtype=self.dtype,
                                rope_theta=self.rope_theta,
                                page_tokens=self.page_tokens,
@@ -547,7 +710,11 @@ class GPTBlock(nn.Module):
                 rope_theta=self.rope_theta, page_tokens=self.page_tokens,
                 kv_pages=self.kv_pages, paged_attn=self.paged_attn,
                 kv_quant=self.kv_quant, num_kv_heads=self.num_kv_heads,
-                head_dim=self.head_dim, key_mult=mup.key, name="attn")
+                head_dim=self.head_dim, key_mult=mup.key, name="attn",
+                v_head_dim=self.v_head_dim,
+                partial_rotary_factor=self.partial_rotary_factor,
+                value_scale=self.value_scale, window=self.window,
+                sink=self.sink)
         y = attn(_scaled(u, mup.attention_in), valid, decode=decode,
                  positions=positions, pages=pages, seq_lens=seq_lens)
         y = _scaled(y, mup.attention_out)
@@ -793,11 +960,37 @@ class CausalTransformer(nn.Module):
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: float = 30.0
+    # --- attention that differs by layer. ``attn_kinds``: the kinds of
+    # attention layer the stack has (:class:`AttnKind`: K/V head count,
+    # rotary base, window, sink); ``attn_pattern[i]``: which of them layer
+    # ``i`` is (MiMo-V2-Flash's ``hybrid_layer_pattern``: 0 full, 1 window).
+    # Empty: every layer attends alike, by ``num_kv_heads`` and
+    # ``rope_theta`` above. A kind of LAYER is then its attention kind and
+    # its MLP kind together, one trace of ``_decode_block`` each. For every
+    # kind alike: ``v_head_dim`` (V heads' width; 0 = ``head_dim``),
+    # ``partial_rotary_factor`` (the share of a head that turns),
+    # ``value_scale`` (``attention_value_scale``). A window layer's paged
+    # cache is a ring of pages a row in an arena of ``window_pages`` pages
+    # (the serving layer clones that in beside ``kv_pages``), addressed
+    # through a table of its own: ``pages`` is then ``(full layers' table,
+    # window layers' rings)``. ---
+    attn_kinds: Tuple[AttnKind, ...] = ()
+    attn_pattern: Tuple[int, ...] = ()
+    v_head_dim: int = 0
+    partial_rotary_factor: float = 1.0
+    value_scale: float = 1.0
+    window_pages: int = 0
 
     @property
     def layer_cls(self):
         """The class of every layer of the stack."""
         return ShortcutBlock if self.mlp == "shortcut" else GPTBlock
+
+    def attn_kind(self, i: int) -> AttnKind:
+        """Layer ``i``'s kind of attention."""
+        if not self.attn_kinds:
+            return AttnKind(self.num_kv_heads, self.rope_theta)
+        return self.attn_kinds[self.attn_pattern[i]]
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, decode: bool = False,
@@ -895,8 +1088,23 @@ class CausalTransformer(nn.Module):
             num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
             ssm=self.ssm, mup=self.mup, state_rows=self.state_rows,
             mla=self.mla, experts=self.experts,
+            v_head_dim=self.v_head_dim,
+            partial_rotary_factor=self.partial_rotary_factor,
+            value_scale=self.value_scale,
             hc=HCConfig(self.hc_mult, self.hc_sinkhorn_iters, self.hc_eps,
                         self.hc_clamp, self.ln_eps) if self.hc_mult else None)
+        if self.attn_kinds:
+            if len(self.attn_pattern) < self.depth or not all(
+                    0 <= a < len(self.attn_kinds) for a in self.attn_pattern):
+                raise ValueError(
+                    f"attn_pattern names a kind of attn_kinds for each of "
+                    f"the {self.depth} layers")
+            if self.mla is not None or self.mlp == "shortcut":
+                raise ValueError("attention kinds by layer are K/V-head "
+                                 "attention's, one attention a layer")
+        # the full layers' page table and the window layers' rings
+        tables = (tuple(pages) if isinstance(pages, (tuple, list))
+                  else (pages, None))
         if self.hc_mult:
             if self.moe_every > 0:
                 raise ValueError("hyper-connections do not cover "
@@ -911,21 +1119,44 @@ class CausalTransformer(nn.Module):
         if self.mla is not None and not use_rope:
             raise ValueError("latent attention takes rotary positions "
                              "(pos='rope')")
-        # the stack's pattern: layer i's MLP kind
-        kind_of = lambda i: ("swiglu" if self.mlp == "experts"
-                             and i < self.dense_layers else self.mlp)
+        # the stack's pattern: layer i's MLP kind, and with attn_kinds its
+        # attention kind beside it
+        mlp_of = lambda i: ("swiglu" if self.mlp == "experts"
+                            and i < self.dense_layers else self.mlp)
+        kind_of = lambda i: (mlp_of(i) if not self.attn_kinds
+                             else (self.attn_pattern[i], mlp_of(i)))
+
+        shared_fields = fields
+
+        def fields_of(kind) -> dict:
+            if not self.attn_kinds:
+                return {**shared_fields, "mlp": kind}
+            a = self.attn_kinds[kind[0]]
+            return {**shared_fields, "mlp": kind[1], "num_kv_heads": a.num_kv_heads,
+                    "rope_theta": a.rope_theta, "window": a.window,
+                    "sink": a.sink, "kv_pages": (
+                        self.window_pages if a.window else self.kv_pages)}
+
+        def table_of(i):
+            if not self.attn_kind(i).window:
+                return tables[0]
+            if pages is not None and tables[1] is None:
+                raise ValueError("a window layer's paged decode needs its "
+                                 "rings: pages = (table, rings)")
+            return tables[1]
+
         # a decode apply sends every layer of a kind through the one trace
         # of _decode_block: the kind's block, detached from the module and
         # so equal for all its layers, is the static argument
         layer_cls = self.layer_cls
         detached = ({kind: layer_cls(self.num_heads, self.mlp_ratio,
                                      self.dropout, parent=None,
-                                     **{**fields, "mlp": kind})
+                                     **fields_of(kind))
                      for kind in {kind_of(i) for i in range(run_depth)}}
                     if decode and not self.is_initializing() else None)
         for i in range(run_depth):
             name = f"block_{i}"
-            fields["mlp"] = kind_of(i)
+            fields = fields_of(kind_of(i))
             if self.moe_every > 0 and (i + 1) % self.moe_every == 0:
                 from ..parallel.moe import MoEBlock
 
@@ -945,7 +1176,8 @@ class CausalTransformer(nn.Module):
                 if self.has_variable("cache", name):
                     vs["cache"] = self.get_variable("cache", name)
                 x, cache = _decode_block(detached[kind_of(i)], vs, x,
-                                         positions, pages, seq_lens, rows)
+                                         positions, table_of(i), seq_lens,
+                                         rows)
                 self.put_variable("cache", name, cache)
             else:
                 # static_argnums counts self as 0, so `train` (a trace-time
@@ -956,7 +1188,7 @@ class CausalTransformer(nn.Module):
                     layer_cls if decode or not self.remat
                     else nn.remat(layer_cls, static_argnums=(3, 4))
                 )
-                at = dict(positions=positions, pages=pages,
+                at = dict(positions=positions, pages=table_of(i),
                           seq_lens=seq_lens, rows=rows) if decode else {}
                 x = block_cls(self.num_heads, self.mlp_ratio, self.dropout,
                               name=name, **fields)(x, valid, train, decode,

@@ -60,17 +60,21 @@ def dot_product_attention(
     kv_valid: Optional[jnp.ndarray] = None,  # [B, Lk] True = real token
     impl: Optional[str] = None,  # None=auto | "xla" | "pallas"
     scale: Optional[float] = None,  # None = 1/sqrt(D); else XLA path only
+    sink: Optional[jnp.ndarray] = None,  # [H] a learned logit a head; XLA only
 ) -> jnp.ndarray:
-    """Scaled dot-product attention; returns [B, Lq, H, D].
+    """Scaled dot-product attention; returns [B, Lq, H, Dv] (V's heads may
+    be another width than K's on the XLA path).
 
     Masking comes either as a dense ``mask`` (XLA path only) or structurally
     as ``causal`` / ``kv_valid`` (eligible for the Pallas flash kernel).
     A softmax ``scale`` of the caller's own (YaRN's, models/mla.py) is
-    applied to the float32 scores, on the XLA path.
+    applied to the float32 scores, on the XLA path. ``sink`` is one more
+    logit a head in the softmax's denominator that takes no value (XLA path).
     """
-    if scale is not None:
+    if scale is not None or sink is not None:
         if impl == "pallas":
-            raise ValueError("pallas impl scales by 1/sqrt(D) only")
+            raise ValueError("pallas impl scales by 1/sqrt(D) only and "
+                             "knows no sink")
         impl = "xla"
     if impl is None:
         impl = (
@@ -105,8 +109,15 @@ def dot_product_attention(
         scores = scores.astype(jnp.float32) * scale
     if mask is not None:
         scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-    weights = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+    top = scores.max(axis=-1, keepdims=True)
+    if sink is not None:
+        logit = sink.astype(jnp.float32)[None, :, None, None]
+        top = jnp.maximum(top, logit)
+    weights = jnp.exp(scores - top)
     if mask is not None:
         weights = jnp.where(mask, weights, 0.0)
-    weights = weights / jnp.maximum(weights.sum(axis=-1, keepdims=True), 1e-9)
+    total = weights.sum(axis=-1, keepdims=True)
+    if sink is not None:
+        total = total + jnp.exp(logit - top)
+    weights = weights / jnp.maximum(total, 1e-9)
     return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(v.dtype), v)

@@ -96,11 +96,25 @@ def rope_angles(positions: jnp.ndarray, head_dim: int, theta: float = 10000.0,
     return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
+def rotary_width(head_dim: int, partial_rotary_factor: float = 1.0) -> int:
+    """Lanes of a head that turn under ``partial_rotary_factor`` (a
+    published ``config`` key): that share of ``head_dim``, rounded down to
+    an even number (0.334 of 192 is 64); the rest of the head passes."""
+    return int(head_dim * partial_rotary_factor) // 2 * 2
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0,
-               scaling: Optional[YarnScaling] = None) -> jnp.ndarray:
+               scaling: Optional[YarnScaling] = None,
+               rotary_dim: int = 0) -> jnp.ndarray:
     """Rotate ``x`` [B, L, H, D] by its positions [L] or [B, L]; returns the
     input dtype. Pairs are (x[..., :D/2], x[..., D/2:]) — the "rotate-half"
-    convention."""
+    convention. ``rotary_dim`` > 0: only the first ``rotary_dim`` lanes of a
+    head turn (rotate-half pairs within them, frequencies over
+    ``rotary_dim``), the others pass as they are."""
+    if rotary_dim and rotary_dim < x.shape[-1]:
+        return jnp.concatenate([
+            apply_rope(x[..., :rotary_dim], positions, theta, scaling),
+            x[..., rotary_dim:]], axis=-1)
     b, l, h, d = x.shape
     cos, sin = rope_angles(positions, d, theta, scaling)  # [..., L, D/2]
     if cos.ndim == 2:  # positions were [L]

@@ -326,6 +326,28 @@ SERVING_COUNTERS = {
         "tile_chunks_live", "Those of kubeml_serving_tile_chunks_grid_total "
                             "under their tile's causal depth and the row's: "
                             "the ones that fetch pages and multiply"),
+    "kubeml_serving_walk_chunks_grid_window_total": (
+        "walk_chunks_grid_window", "The window layers' part of "
+                                   "kubeml_serving_walk_chunks_grid_total: a "
+                                   "ring a row (absent without window "
+                                   "layers)"),
+    "kubeml_serving_walk_chunks_live_window_total": (
+        "walk_chunks_live_window", "The window layers' part of "
+                                   "kubeml_serving_walk_chunks_live_total"),
+    "kubeml_serving_tile_chunks_grid_window_total": (
+        "tile_chunks_grid_window", "The window layers' part of "
+                                   "kubeml_serving_tile_chunks_grid_total"),
+    "kubeml_serving_tile_chunks_live_window_total": (
+        "tile_chunks_live_window", "The window layers' part of "
+                                   "kubeml_serving_tile_chunks_live_total: "
+                                   "the chunks a tile's window meets"),
+    "kubeml_serving_window_pages_held_total": (
+        "window_pages_held", "Ring pages the live rows held, a decode step "
+                             "and window layer (the second kind of lease)"),
+    "kubeml_serving_window_pages_live_total": (
+        "window_pages_live", "Those of kubeml_serving_window_pages_held_total "
+                             "a step's query could read: the pages its "
+                             "window of keys lies in"),
 }
 # XLA compile counter, labeled {model, program} — rendered from the
 # snapshot's per-program compile-count dict rather than the scalar tables

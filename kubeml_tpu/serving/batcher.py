@@ -51,7 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..api.errors import KubeMLError
-from ..models.generation import (GenerationInputError, cache_sublayers,
+from ..models.generation import (GenerationInputError, attention_kinds,
+                                 cache_sublayers,
                                  init_cache)
 from ..models.gpt import PAD_ID, block_traces
 from ..utils import tracing
@@ -103,6 +104,25 @@ class LatentCacheUnsupported(KubeMLError):
             f"{what} is not supported for a model with a latent KV cache: "
             f"its pages hold one latent vector a token, not K and V heads",
             409)
+
+
+class WindowLayersUnsupported(KubeMLError):
+    """What the engine does not do for a model that mixes window layers with
+    full ones (models/gpt.py AttnKind): a window layer's row holds a RING of
+    pages that it overwrites as it advances (serving/kvpool.py), so nothing
+    that knows one kind of page covers it. The prefix trie shares pages that
+    are valid for every layer and a ring holds a prefix no longer; a
+    chunked prefill, a speculative verify window and a restored snapshot
+    would continue a window layer from keys the ring may have dropped or
+    that a frame does not carry; int8 page scales and KMS1 frames are laid
+    out for one arena a layer of one table; the slot engine keeps a dense
+    cache such a layer has not. Refused by name, never served wrong."""
+
+    def __init__(self, what: str):
+        super().__init__(
+            f"{what} is not supported for a model with window layers: a "
+            f"window layer's row holds a ring of pages, not a page for "
+            f"every position", 409)
 
 
 class ExpertLayersUnsupported(KubeMLError):
@@ -449,6 +469,12 @@ def _kv_token_bytes(module, layers: Optional[int] = None) -> int:
     if not depth or not width:
         return 0
     itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
+    if getattr(module, "attn_kinds", ()):
+        # attention that differs by layer: a cached token's K and V in the
+        # FULL layers, by each one's own heads and widths (a window layer
+        # reads its window, whatever the depth: _window_token_bytes)
+        return _kinds_sum(module, False, _live_values,
+                          layers=depth) * int(itemsize)
     # the accounting models STORAGE bytes: an int8-quantized arena
     # (KUBEML_KV_QUANT, the module carries the resolved mode as a clone
     # field) reads one byte per cached element — the halving/quartering
@@ -460,6 +486,30 @@ def _kv_token_bytes(module, layers: Optional[int] = None) -> int:
     if resolve_kv_quant(getattr(module, "kv_quant", "off")) == "int8":
         itemsize = 1
     return int(depth) * _kv_copies(module) * width * int(itemsize)
+
+
+def _kinds_sum(module, windowed: bool, width, layers=None) -> int:
+    """``width(K/V heads, K head size, V head size)`` added up over the
+    module's window layers (``windowed``) or its full ones, of its first
+    ``layers`` layers."""
+    return sum(width(hkv, dk, dv) for hkv, dk, dv, window
+               in attention_kinds(module)[:layers]
+               if bool(window) == windowed)
+
+
+def _live_values(kv_heads: int, k_dim: int, v_dim: int) -> int:
+    """Values a token's K and V hold in one layer."""
+    return kv_heads * (k_dim + v_dim)
+
+
+def _window_token_bytes(module) -> int:
+    """HBM bytes the WINDOW layers read per key a query sees, all of them:
+    K and V by each one's own heads and widths. A step reads at most
+    ``window`` keys a row in such a layer, whatever the row's depth."""
+    import jax.numpy as jnp
+
+    itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
+    return _kinds_sum(module, True, _live_values) * int(itemsize)
 
 
 def _kv_copies(module) -> int:
@@ -510,6 +560,13 @@ def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
     depth = cache_sublayers(module)
     kv_heads, head_dim = _kv_head_shape(module)
     mla = getattr(module, "mla", None)
+    if getattr(module, "attn_kinds", ()) and kv_quant != "int8":
+        # attention that differs by layer: the FULL layers' arenas, each
+        # row by its own heads and widths (the window layers' arenas are
+        # rings a row, sized by _window_page_bytes)
+        itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
+        return (_kinds_sum(module, False, kv_row_width) * int(page_tokens)
+                * int(itemsize))
     row = (int(mla.row_width) if mla is not None
            else kv_row_width(kv_heads, head_dim))
     if not depth or not row:
@@ -518,6 +575,18 @@ def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
         return int(depth) * (int(page_tokens) * row + 2 * kv_heads * 4)
     itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
     return int(depth) * int(page_tokens) * row * int(itemsize)
+
+
+def _window_page_bytes(module, page_tokens: int) -> int:
+    """HBM bytes ONE page of a ring occupies across the window layers'
+    arenas (``_kv_page_bytes`` is the full layers')."""
+    import jax.numpy as jnp
+
+    from ..ops.paged_attention import kv_row_width
+
+    itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
+    return (_kinds_sum(module, True, kv_row_width) * int(page_tokens)
+            * int(itemsize))
 
 
 def service_interval(dispatched: float, done: float,
@@ -649,6 +718,9 @@ class BatchingDecoder:
     # whether this engine's arena can hold latent pages (one vector a token,
     # models/mla.py): the paged engine's can, a slot cache is per-head K/V
     _latent_ok = False
+    # whether this engine leases a window layer's ring beside the full
+    # layers' pages (serving/kvpool.py): the paged engine does
+    _window_ok = False
 
     def __init__(self, module, variables, *, slots: int = DEFAULT_SLOTS,
                  chunk_steps: int = 8, bucket_min: int = 16,
@@ -676,6 +748,11 @@ class BatchingDecoder:
         self._latent = has_latent_cache(module)
         if self._latent and not self._latent_ok:
             raise LatentCacheUnsupported("the slot engine")
+        from ..models.generation import window_layers
+
+        self._window_layers = window_layers(module)
+        if self._window_layers and not self._window_ok:
+            raise WindowLayersUnsupported("the slot engine")
         # routed-expert layers in the stack: a decode step hands back how
         # many experts its live rows chose beside its tokens (_step_impl)
         self._moe_layers = expert_layers(module)
@@ -2253,10 +2330,23 @@ class PagedBatchingDecoder(BatchingDecoder):
     is off for such a model (shared pages have no state to go with them),
     and speculation and KMS1 snapshot / restore are refused by name
     (:class:`RecurrentStateUnsupported`).
+
+    **Two kinds of lease** — a model that mixes window layers with full
+    ones (``models.generation.window_layers``) has two arenas a kind: the
+    full layers' pages, addressed through ``_table`` as above, and the
+    window layers' RINGS, ``window / page_tokens + 2`` pages a row whatever
+    its depth (``_wtable`` ``[slots, ring]``; serving/kvpool.py says why a
+    ring). Every program takes both tables (``pages = (table, rings)``,
+    models/gpt.py), an admit writes only the tail of its bucket into the
+    ring, and the window arenas are ``slots`` rings whatever ``max_len``.
+    Refused by name for such a model (:class:`WindowLayersUnsupported`):
+    prefix sharing, chunked prefill, int8 pages, speculation, KMS1 snapshot
+    / restore, and the slot engine.
     """
 
     _recurrent_ok = True
     _latent_ok = True
+    _window_ok = True
 
     def __init__(self, module, variables, *, page_tokens: Optional[int] = None,
                  pages: Optional[int] = None,
@@ -2276,8 +2366,12 @@ class PagedBatchingDecoder(BatchingDecoder):
                 "BatchingDecoder for sharded serving")
         from ..models.generation import (expert_layers, has_latent_cache,
                                          has_recurrent_state,
-                                         supports_paged_decode)
+                                         supports_paged_decode, window_layers)
 
+        windowed = window_layers(module) > 0
+        if windowed and spec not in ("", "off", None):
+            raise WindowLayersUnsupported(
+                f"speculative decoding (spec={spec!r})")
         if not supports_paged_decode(module):
             raise GenerationInputError(
                 "module has no paged decode path (pages/seq_lens decode "
@@ -2327,6 +2421,8 @@ class PagedBatchingDecoder(BatchingDecoder):
             kvq = "off"
         if kvq == "int8" and has_latent_cache(module):
             raise LatentCacheUnsupported("int8 page storage (kv_quant=int8)")
+        if kvq == "int8" and windowed:
+            raise WindowLayersUnsupported("int8 page storage (kv_quant=int8)")
         self.kv_quant = kvq
         if kvq == "int8":
             bytes_off = _kv_page_bytes(module, pt, "off")
@@ -2346,7 +2442,22 @@ class PagedBatchingDecoder(BatchingDecoder):
                 "and the prefix trie holds pages only",
                 kw.get("name", "decoder"))
             use_trie = False
-        self._pool = KVPool(npages, pt, prefix_cache=use_trie)
+        if windowed and use_trie:
+            raise WindowLayersUnsupported(
+                "prefix sharing (serving_prefix_cache)")
+        # the second kind of lease: a ring a program row in the window
+        # layers' arenas, whatever max_len (0 / 0 without window layers)
+        from ..ops.paged_attention import ring_pages
+
+        # (every window layer's ring is as wide as the widest window's)
+        self._window = max((w for *_, w in attention_kinds(module)),
+                           default=0)
+        self.window_ring = ring_pages(self._window, pt) if windowed else 0
+        self.window_arena_pages = (slots * self.window_ring + 1
+                                   if windowed else 0)
+        self._pool = KVPool(npages, pt, prefix_cache=use_trie,
+                            window_pages=self.window_arena_pages,
+                            window_ring=self.window_ring)
         self.arena_pages = npages
         # --- paged-attention read path (KUBEML_PAGED_ATTN=auto|pallas|
         # gather, ops/paged_attention.py): resolved HERE and cloned onto
@@ -2441,8 +2552,12 @@ class PagedBatchingDecoder(BatchingDecoder):
         if recurrent:
             # one recurrent state per program row, beside the arena
             clone_kw["state_rows"] = slots
+        if windowed:
+            clone_kw["window_pages"] = self.window_arena_pages
         module = module.clone(**clone_kw)
         super().__init__(module, variables, mesh=None, **kw)
+        self.stats.window_layers = self._window_layers
+        self._window_token_bytes = _window_token_bytes(module)
         # a decode step's K/V page walk takes the kernel's decode body (one
         # query a row, the arena in the compute type): its grid is counted
         # at each chunk dispatch (_walk_chunks), the tile body's at each
@@ -2509,6 +2624,8 @@ class PagedBatchingDecoder(BatchingDecoder):
         # zeroed rows point at the trash page, so a retired/canceled row's
         # stale device writes can never reach a reallocated page
         self._table = np.zeros((self.slots, self.table_pages), np.int32)
+        # the window layers' rings, a row each (zeroed like _table's rows)
+        self._wtable = np.zeros((self.slots, self.window_ring), np.int32)
         # --- chunked prefill (KUBEML_PREFILL_CHUNK_TOKENS, ISSUE 19):
         # a cold prompt whose unshared suffix exceeds the cap advances one
         # page-aligned chunk per engine-loop iteration through the same
@@ -2517,6 +2634,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         self.prefill_chunk = _chunk_cap(
             int(prefill_chunk_tokens if prefill_chunk_tokens is not None
                 else cfg.prefill_chunk_tokens), pt)
+        if self._window_layers and self.prefill_chunk:
+            raise WindowLayersUnsupported(
+                "chunked prefill (prefill_chunk_tokens)")
         # rows mid-prefill: (slot, row) pairs holding program rows + leases
         # whose prompts still have undispatched chunks; the turn flag
         # alternates the last pipeline slot between a prefill chunk and a
@@ -2917,6 +3037,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         ptbl = np.zeros((wa,), np.int32)
         pgs = row.lease.pages[:wa]
         ptbl[:len(pgs)] = pgs
+        if self._window_layers:
+            # both tables: the full layers' pages and the row's ring
+            ptbl = (ptbl, np.asarray(row.lease.window, np.int32))
         if kind == "admit":
             max_new, temp, topk, eos, key = (row.max_new, row.temp, row.topk,
                                              row.eos, row.key)
@@ -2925,11 +3048,13 @@ class PagedBatchingDecoder(BatchingDecoder):
         # ptbl, suffix, base, slens, rowids, max_news, temps, topks, eoss,
         # keys: each gets its one row here
         i32 = np.int32
-        args = tuple(jnp.asarray(np.asarray(value, dtype)[None])
-                     for value, dtype in (
-                         (ptbl, i32), (suffix, i32), (pre, i32), (take, i32),
-                         (slot, i32), (max_new, i32), (temp, np.float32),
-                         (topk, i32), (eos, i32), (key, np.uint32)))
+        one = lambda value, dtype: jnp.asarray(np.asarray(value, dtype)[None])
+        args = (tuple(one(t, i32) for t in ptbl) if self._window_layers
+                else one(ptbl, i32),) + tuple(
+            one(value, dtype) for value, dtype in (
+                (suffix, i32), (pre, i32), (take, i32), (slot, i32),
+                (max_new, i32), (temp, np.float32), (topk, i32), (eos, i32),
+                (key, np.uint32)))
         span = dict(kind=kind, width=wa,
                     group=[(slot, row)] if kind == "admit" else None,
                     state_rows=1 if self._recurrent else 0)
@@ -2956,9 +3081,18 @@ class PagedBatchingDecoder(BatchingDecoder):
 
             itemsize = jnp.dtype(
                 getattr(self.module, "dtype", jnp.float32)).itemsize
-            live, grid = tile_chunks(pre, bucket, wa, pt, itemsize)
-            layers = cache_sublayers(self.module)
-            self.stats.tile_chunks(live * layers, grid * layers)
+            if self._window_layers:
+                # a window layer walks the bucket's own pages, and a layer
+                # of either kind a K/V head a program where the heads have
+                # a grid axis
+                live, grid, windowed = self._tile_chunks_by_kind(
+                    pre, bucket, wa, itemsize)
+                self.stats.tile_chunks(live + windowed[0],
+                                       grid + windowed[1], windowed)
+            else:
+                live, grid = tile_chunks(pre, bucket, wa, pt, itemsize)
+                layers = cache_sublayers(self.module)
+                self.stats.tile_chunks(live * layers, grid * layers)
         # KV model for the prefill forward(s): gather reads the row's
         # clamped table, the kernel stops at the depth the row has reached;
         # a draft backend prefills the drafter's arena too
@@ -2967,6 +3101,8 @@ class PagedBatchingDecoder(BatchingDecoder):
         kv_bytes = span_tokens * self._kv_token_bytes
         if self.spec == "draft":
             kv_bytes += span_tokens * self._kv_draft_token_bytes
+        # a window layer attends over the bucket's own keys
+        kv_bytes += bucket * self._window_token_bytes
         self._admits_inflight += 1
         return (packed, kv_bytes, cold, stalled)
 
@@ -2982,6 +3118,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         self._slot_rows[slot] = row
         self._table[slot, :] = 0
         self._table[slot, :len(row.lease.pages)] = row.lease.pages
+        self._wtable[slot, :] = row.lease.window
         row.dispatched = 0
         row.pos_cap = len(row.prompt)  # device cursor lands at plen
         if not row.slot_at:
@@ -3172,8 +3309,62 @@ class PagedBatchingDecoder(BatchingDecoder):
                    if row is not None and row.lease is not None
                    and not row.prefilling
                    for s in range(1, size + 1))
-        layers = cache_sublayers(self.module)
+        layers = cache_sublayers(self.module) - self._window_layers
         return live * layers, size * self.slots * (w // pages) * layers
+
+    def _ring_chunks(self, size: int) -> tuple:
+        """The window layers' part of a chunk of ``size`` steps:
+        ``((live, grid) programs of the decode body over the rows' rings,
+        (live, held) ring pages)``, all window layers. A row's ring is
+        ``window_ring / C`` programs (one where the ring is at most 16
+        pages); those up to the pages the row has written have pages to
+        read, and of the pages it holds a step's query could read the ones
+        its window of keys lies in (the kernel's own clamp and mask,
+        ops/paged_attention.py _decode_kernel)."""
+        from ..ops.paged_attention import walk_chunk_pages
+
+        pt, ring, n = self.page_tokens, self.window_ring, self._window_layers
+        pages = walk_chunk_pages(ring, ring=True)
+        live = held = read = 0
+        for row in self._slot_rows:
+            if row is None or row.lease is None or row.prefilling:
+                continue
+            for s in range(1, size + 1):
+                pos = row.pos_cap + s - 1          # the step's query
+                written = min(pos // pt + 1, ring)
+                live += -(-written // pages)
+                held += ring
+                read += pos // pt - max(pos - self._window + 1, 0) // pt + 1
+        return ((live * n, size * self.slots * (ring // pages) * n),
+                (read * n, held * n))
+
+    def _tile_chunks_by_kind(self, pre: int, bucket: int, wa: int,
+                             itemsize: int) -> tuple:
+        """``(live, grid, (window live, window grid))`` programs of the
+        tile body in one admission of a model whose attention differs by
+        layer: each full layer over the row's table, each window layer over
+        the bucket's own pages under its window, a K/V head a program
+        wherever the kernel gives the heads a grid axis (the same rule,
+        read off the same shapes: ops/paged_attention.py paged_attention)."""
+        from ..ops.paged_attention import tile_chunks, tile_head_groups
+
+        pt = self.page_tokens
+        heads = int(self.module.num_heads)
+        out = [0, 0, 0, 0]
+        for hkv, dk, dv, window in attention_kinds(self.module):
+            groups = tile_head_groups(heads, hkv, dk, dv, bucket, itemsize)
+            if window:
+                live, grid = tile_chunks(
+                    0, bucket, -(-bucket // pt), pt, itemsize, window=window,
+                    groups=groups)
+                out[2] += live
+                out[3] += grid
+            else:
+                live, grid = tile_chunks(pre, bucket, wa, pt, itemsize,
+                                         groups=groups)
+                out[0] += live
+                out[1] += grid
+        return out[0], out[1], (out[2], out[3])
 
     def _dispatch_chunk_paged(self, size: int) -> tuple:
         # the table ships CLAMPED to the batch's live width (see
@@ -3185,10 +3376,13 @@ class PagedBatchingDecoder(BatchingDecoder):
         # the row's final tokens
         w = self._live_table_width(size)
         coloc = self._admits_inflight > 0
+        tables = jnp.asarray(self._table[:, :w].copy())
+        if self._window_layers:
+            # both tables: the full layers' pages and the rows' rings
+            tables = (tables, jnp.asarray(self._wtable.copy()))
         (self._slab, packed), cold = self._run_program(
             "step", (size, w), self._steps[size],
-            self._variables, self._slab,
-            jnp.asarray(self._table[:, :w].copy()),
+            self._variables, self._slab, tables,
             kind="step", steps=size, width=w,
             state_rows=(sum(r is not None and not r.prefilling
                             for r in self._slot_rows)
@@ -3196,8 +3390,19 @@ class PagedBatchingDecoder(BatchingDecoder):
         # one span per step: step s's query sits s positions past pos_cap
         kv_bytes = sum(self._chunk_kv_tokens(w, s)
                        for s in range(1, size + 1)) * self._kv_token_bytes
+        if self._window_layers:
+            # a window layer reads a row's window, whatever its depth
+            kv_bytes += self._window_token_bytes * sum(
+                min(row.pos_cap + s, self._window)
+                for row in self._slot_rows
+                if row is not None and row.lease is not None
+                and not row.prefilling for s in range(1, size + 1))
         if self.stats.walks_kv_chunks:
-            self.stats.walk_chunks(*self._walk_chunks(w, size))
+            live, grid = self._walk_chunks(w, size)
+            ring, ring_pages = (self._ring_chunks(size) if self._window_layers
+                                else ((0, 0), (0, 0)))
+            self.stats.walk_chunks(live + ring[0], grid + ring[1], ring,
+                                   ring_pages)
         self._bump_pos_caps(size)
         for row in self._slot_rows:
             if (row is not None and not row.done and not row.canceled
@@ -3224,6 +3429,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 row.drained = True
                 self._slot_rows[slot] = None
                 self._table[slot, :] = 0
+                self._wtable[slot, :] = 0
                 self._pool.release(row.lease)
                 with self._cond:
                     self._draining.append(row)
@@ -3236,6 +3442,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 row.done = True
                 self._slot_rows[slot] = None
                 self._table[slot, :] = 0
+                self._wtable[slot, :] = 0
                 self._pool.release(row.lease)
                 with self._cond:
                     self._free.append(slot)
@@ -3249,6 +3456,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         else:
             self._slot_rows[slot] = None
             self._table[slot, :] = 0
+            self._wtable[slot, :] = 0
             with self._cond:
                 self._free.append(slot)
 
@@ -3258,8 +3466,11 @@ class PagedBatchingDecoder(BatchingDecoder):
         from .kvpool import KVPool
 
         self._pool = KVPool(self._pool.num_pages, self.page_tokens,
-                            prefix_cache=self._pool.trie is not None)
+                            prefix_cache=self._pool.trie is not None,
+                            window_pages=self.window_arena_pages,
+                            window_ring=self.window_ring)
         self._table[:] = 0
+        self._wtable[:] = 0
 
     # --- mid-stream snapshot / restore / drain (ISSUE 20) ---
 
@@ -3296,6 +3507,8 @@ class PagedBatchingDecoder(BatchingDecoder):
             raise RecurrentStateUnsupported("restoring a mid-stream snapshot")
         if snap.out and not done and self._latent:
             raise LatentCacheUnsupported("restoring a mid-stream snapshot")
+        if snap.out and not done and self._window_layers:
+            raise WindowLayersUnsupported("restoring a mid-stream snapshot")
         if snap.out and not done:
             # mid-stream state only restores into a byte-compatible arena
             if int(snap.page_tokens) != self.page_tokens:
@@ -3468,9 +3681,10 @@ class PagedBatchingDecoder(BatchingDecoder):
         if self.spec == "draft":
             self.stats.snapshot_fail()
             return None
-        if self._recurrent or self._latent:
+        if self._recurrent or self._latent or self._window_layers:
             refusal = (RecurrentStateUnsupported if self._recurrent
-                       else LatentCacheUnsupported)
+                       else LatentCacheUnsupported if self._latent
+                       else WindowLayersUnsupported)
             log.warning("%s: %s (request %s)", self.name,
                         refusal("a mid-stream snapshot"),
                         row.entry.request_id)
@@ -3706,8 +3920,10 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     @property
     def arena_bytes(self) -> int:
-        return self.arena_pages * _kv_page_bytes(
+        return (self.arena_pages * _kv_page_bytes(
             self.module, self.page_tokens, self.kv_quant)
+            + self.window_arena_pages * _window_page_bytes(
+                self.module, self.page_tokens))
 
     def telemetry(self) -> dict:
         snap = super().telemetry()
@@ -3736,6 +3952,11 @@ class PagedBatchingDecoder(BatchingDecoder):
         # sub-layers that hold a paged cache (the depth; twice that where
         # a layer is a double layer of two attentions)
         snap["cache_sublayers"] = float(cache_sublayers(self.module))
+        # of those, the layers whose row holds a ring of pages (a window
+        # layer) and the ones whose row holds a page for every position
+        snap["window_layers"] = float(self._window_layers)
+        snap["full_layers"] = float(
+            cache_sublayers(self.module) - self._window_layers)
         # streams of the model's residual path (1; hyper-connections: n)
         snap["residual_streams"] = float(
             getattr(self.module, "hc_mult", 0) or 1)

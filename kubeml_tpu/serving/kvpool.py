@@ -27,6 +27,23 @@ Kwon et al. 2023) and owns all the HOST bookkeeping:
   held ONLY by the trie are evictable, least-recently-matched leaf first,
   when a fresh allocation runs short.
 
+TWO KINDS OF LEASE in the one manager (a model that mixes window layers
+with full ones, models/gpt.py AttnKind). A row's lease covers, for the full
+layers, a page for every ``page_tokens`` positions it can write, as above;
+and, for the window layers, a RING: ``window_ring`` pages (``window /
+page_tokens + 2``, ops/paged_attention.ring_pages) out of a second arena's
+pages, whatever the row's depth, taken at admission and returned with the
+lease. The page of position ``p`` in a window layer is ring slot ``(p //
+page_tokens) mod window_ring``: a row overwrites its own oldest page as it
+advances, nothing is freed behind it and nothing is shared (the trie knows
+the full layers' pages only, so prefix sharing is off for such a model: a
+shared page would have to say for which layers it is still valid). The ring
+is the simpler of the two forms that bound a window layer's pages a row (a
+shared pool that frees behind the row is the other): its bound holds in
+every program by construction, admission is one comparison, and the two
+pages a row over what a step can read cost a fifth of an arena that is a
+seventh of the full layers' at the depths where window layers pay.
+
 Everything here is plain Python driven from the decode engine thread (one
 owner — the engine serializes admission, retirement and release), so the
 invariants are exact and cheaply checkable: every non-trash page is either
@@ -51,7 +68,9 @@ class PageAllocError(RuntimeError):
 @dataclass
 class PageLease:
     """One admitted row's view of the pool: ``pages[j]`` is the physical
-    page backing logical page ``j`` (positions ``j*pt .. (j+1)*pt-1``)."""
+    page backing logical page ``j`` (positions ``j*pt .. (j+1)*pt-1``) of
+    the full layers, ``window[s]`` the window arena's page at slot ``s`` of
+    the row's ring (empty without window layers)."""
 
     pages: List[int]
     shared: int = 0          # leading pages refcount-shared via the trie
@@ -63,6 +82,7 @@ class PageLease:
     # dispatch samples the first token. Monolithic prefill never moves it,
     # so prefill_pos == prefix_tokens is the knob-off identity.
     prefill_pos: int = 0
+    window: List[int] = field(default_factory=list)
 
 
 class _TrieNode:
@@ -190,7 +210,11 @@ class KVPool:
     """
 
     def __init__(self, num_pages: int, page_tokens: int,
-                 prefix_cache: bool = True):
+                 prefix_cache: bool = True, window_pages: int = 0,
+                 window_ring: int = 0):
+        """``window_pages`` > 0: a second arena of that many pages (its own
+        trash page 0 among them) for the window layers, handed out
+        ``window_ring`` pages a lease."""
         if page_tokens < 1 or (page_tokens & (page_tokens - 1)):
             raise ValueError(
                 f"page_tokens must be a power of two, got {page_tokens}")
@@ -201,6 +225,17 @@ class KVPool:
         self.page_tokens = int(page_tokens)
         self._free: List[int] = list(range(1, num_pages))
         self._ref: List[int] = [0] * num_pages
+        if bool(window_pages) != bool(window_ring) or (
+                window_ring and window_pages <= window_ring):
+            raise ValueError("a window arena holds at least one ring beyond "
+                             "its trash page")
+        if window_ring and prefix_cache:
+            raise ValueError("the prefix trie shares the full layers' pages "
+                             "alone: no prefix cache beside window layers")
+        self.window_pages = int(window_pages)
+        self.window_ring = int(window_ring)
+        self._wfree: List[int] = list(range(1, self.window_pages))
+        self._wheld: List[bool] = [False] * self.window_pages
         self.trie: Optional[PrefixTrie] = (PrefixTrie(self) if prefix_cache
                                            else None)
         # pool-level eviction pressure; prefix hit/saved counters live in
@@ -240,6 +275,18 @@ class KVPool:
         self._ref[page] = r - 1
         if r == 1:
             self._free.append(page)
+
+    def _alloc_ring(self) -> Optional[List[int]]:
+        """A row's ring out of the window arena; ``[]`` without window
+        layers, None (state unchanged) when no ring is left."""
+        n = self.window_ring
+        if n > len(self._wfree):
+            return None
+        out = self._wfree[:n]
+        del self._wfree[:n]
+        for p in out:
+            self._wheld[p] = True
+        return out
 
     def _alloc(self, n: int) -> Optional[List[int]]:
         """Pop ``n`` fresh pages, evicting trie-only pages as needed;
@@ -284,8 +331,9 @@ class KVPool:
         enabling spec mode can never create a mid-flight OOM (and, clamped
         at ``max_positions``, never 400s a request the plain engine
         accepts: the worst case stays ``pages_for(max_len)``)."""
-        return self.pages_for(self.total_positions(
+        return (self.pages_for(self.total_positions(
             prompt_len, max_new, lookahead, max_positions)) <= self.capacity
+            and self.window_ring <= max(self.window_pages - 1, 0))
 
     def admit(self, prompt: Sequence[int], max_new: int,
               lookahead: int = 0,
@@ -306,14 +354,18 @@ class KVPool:
             shared = self.trie.match(prompt, (plen - 1) // self.page_tokens)
         for p in shared:  # retain BEFORE _alloc so eviction can't take them
             self._retain(p)
-        fresh = self._alloc(need - len(shared))
+        # both kinds or neither: the ring first (it takes nothing back out
+        # of the trie), then the full layers' pages
+        ring = self._alloc_ring()
+        fresh = None if ring is None else self._alloc(need - len(shared))
         if fresh is None:
             for p in shared:
                 self._release_one(p)
+            self._free_ring(ring or [])
             return None
         pre = len(shared) * self.page_tokens
         return PageLease(pages=shared + fresh, shared=len(shared),
-                         prefix_tokens=pre, prefill_pos=pre)
+                         prefix_tokens=pre, prefill_pos=pre, window=ring)
 
     def reserve(self, total_tokens: int) -> Optional[PageLease]:
         """Reserve fresh PRIVATE pages for ``total_tokens`` positions with
@@ -323,10 +375,13 @@ class KVPool:
         through this pool's trie, or matching this pool's cached blocks in
         place of them, would mix arenas. None when the pool can't cover it
         (the snapshot stays queued, same as a refused admit)."""
-        fresh = self._alloc(self.pages_for(total_tokens))
+        ring = self._alloc_ring()
+        fresh = (None if ring is None
+                 else self._alloc(self.pages_for(total_tokens)))
         if fresh is None:
+            self._free_ring(ring or [])
             return None
-        return PageLease(pages=fresh)
+        return PageLease(pages=fresh, window=ring)
 
     def register_prefix(self, prompt: Sequence[int], lease: PageLease) -> None:
         """Cache a just-dispatched prefill's full prompt blocks for future
@@ -343,6 +398,14 @@ class KVPool:
         lease.released = True
         for p in lease.pages:
             self._release_one(p)
+        self._free_ring(lease.window)
+
+    def _free_ring(self, ring: Sequence[int]) -> None:
+        for p in ring:
+            if not self._wheld[p]:
+                raise PageAllocError(f"double free of window page {p}")
+            self._wheld[p] = False
+            self._wfree.append(p)
 
     # --- invariants (tests + telemetry) ---
 
@@ -367,11 +430,29 @@ class KVPool:
         trie_pages = self.trie.pages() if self.trie is not None else []
         if len(trie_pages) != len(set(trie_pages)):
             raise PageAllocError("trie maps two blocks onto one page")
+        # the window arena: every page but its trash page is free or in
+        # exactly one ring, and rings are whole
+        wfree = set(self._wfree)
+        if len(wfree) != len(self._wfree):
+            raise PageAllocError("window free list holds duplicates")
+        if self.window_pages and (TRASH_PAGE in wfree
+                                  or self._wheld[TRASH_PAGE]):
+            raise PageAllocError("window trash page escaped reservation")
+        wheld = sum(self._wheld)
+        for p in range(1, self.window_pages):
+            if self._wheld[p] == (p in wfree):
+                raise PageAllocError(
+                    f"window page {p} is "
+                    f"{'both held and free' if self._wheld[p] else 'neither held nor free'}")
+        if self.window_ring and wheld % self.window_ring:
+            raise PageAllocError("window pages held are no whole rings")
         return {
             "free": len(free_set),
             "held": held,
             "trie_pages": len(trie_pages),
             "refs_total": sum(self._ref),
+            "window_free": len(wfree),
+            "window_held": wheld,
         }
 
     def telemetry(self) -> dict:
@@ -383,4 +464,8 @@ class KVPool:
             "page_tokens": float(self.page_tokens),
             "prefix_cache_pages": float(self.trie.nodes
                                         if self.trie is not None else 0),
+            # the second kind of lease: the window layers' arena
+            "window_pages_total": float(max(self.window_pages - 1, 0)),
+            "window_pages_free": float(len(self._wfree)),
+            "window_ring_pages": float(self.window_ring),
         }

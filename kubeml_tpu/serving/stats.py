@@ -132,6 +132,23 @@ class DecoderStats:
         self.walk_chunks_grid = 0
         self.tile_chunks_live = 0
         self.tile_chunks_grid = 0
+        # the same four split by layer kind, for a model that mixes window
+        # layers with full ones (``window_layers`` > 0, set by the engine;
+        # absent otherwise): ``*_window`` are the window layers' part of the
+        # totals above, which keep their meaning. A window layer's decode
+        # grid is a ring a row (one program where the ring is at most 16
+        # pages), an admit's the bucket's own pages of which a tile's window
+        # meets two chunks. ``window_pages_held`` / ``window_pages_live``:
+        # ring pages the live rows hold, a step and window layer, and those
+        # of them a step's query could read (the pages its ``window`` keys
+        # lie in): the lease's bound against its use
+        self.window_layers = 0
+        self.walk_chunks_live_window = 0
+        self.walk_chunks_grid_window = 0
+        self.tile_chunks_live_window = 0
+        self.tile_chunks_grid_window = 0
+        self.window_pages_held = 0
+        self.window_pages_live = 0
         self.goodput_tokens = 0       # tokens delivered to a live waiter
         self.wasted_tokens = 0        # tokens routed to an aborted request
         # shared-prefix reuse (paged engine, serving/kvpool.py): admissions
@@ -356,19 +373,30 @@ class DecoderStats:
             self.moe_assignments_zero += int(zero)
             self.moe_assignments_absent += int(absent)
 
-    def walk_chunks(self, live: int, grid: int) -> None:
+    def walk_chunks(self, live: int, grid: int, window: tuple = (0, 0),
+                    ring_pages: tuple = (0, 0)) -> None:
         """One dispatched decode chunk's page-walk programs, all steps and
-        attention layers: ``live`` of ``grid`` had pages to read."""
+        attention layers: ``live`` of ``grid`` had pages to read;
+        ``window`` is the window layers' ``(live, grid)`` among them and
+        ``ring_pages`` their ``(live, held)`` ring pages."""
         with self._lock:
             self.walk_chunks_live += int(live)
             self.walk_chunks_grid += int(grid)
+            self.walk_chunks_live_window += int(window[0])
+            self.walk_chunks_grid_window += int(window[1])
+            self.window_pages_live += int(ring_pages[0])
+            self.window_pages_held += int(ring_pages[1])
 
-    def tile_chunks(self, live: int, grid: int) -> None:
+    def tile_chunks(self, live: int, grid: int,
+                    window: tuple = (0, 0)) -> None:
         """One dispatched prefill's page-walk programs, all query tiles and
-        attention layers: ``live`` of ``grid`` had pages to read."""
+        attention layers: ``live`` of ``grid`` had pages to read;
+        ``window`` is the window layers' ``(live, grid)`` among them."""
         with self._lock:
             self.tile_chunks_live += int(live)
             self.tile_chunks_grid += int(grid)
+            self.tile_chunks_live_window += int(window[0])
+            self.tile_chunks_grid_window += int(window[1])
 
     def fetch_started(self) -> None:
         with self._lock:
@@ -708,6 +736,13 @@ class DecoderStats:
                 out["walk_chunks_grid"] = float(self.walk_chunks_grid)
                 out["tile_chunks_live"] = float(self.tile_chunks_live)
                 out["tile_chunks_grid"] = float(self.tile_chunks_grid)
+                if self.window_layers:
+                    for name in ("walk_chunks_live_window",
+                                 "walk_chunks_grid_window",
+                                 "tile_chunks_live_window",
+                                 "tile_chunks_grid_window",
+                                 "window_pages_held", "window_pages_live"):
+                        out[name] = float(getattr(self, name))
             # speculative-decoding series only exist once a spec step ran:
             # dense decoders / spec-off engines keep a clean exposition
             # (absence reads as "not speculating", like the paged gauges)

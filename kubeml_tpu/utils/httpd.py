@@ -120,6 +120,17 @@ class Router:
         raise KubeMLError(f"no route for {path}", 404)
 
 
+class _Server(ThreadingHTTPServer):
+    """The stdlib server with a deeper accept queue: its default of 5 resets
+    a connection whenever more clients than that connect between two
+    accepts. A closed loop's 80 clients open at once, each with a prompt of
+    some 25 KB to upload: 6 of one window's 249 requests ended in
+    ``ConnectionResetError`` before the program saw them (PR 44's chip run;
+    PR 33 had lost 2 of 480 the same way)."""
+
+    request_queue_size = 128
+
+
 class Service:
     """One HTTP service: a Router bound to a port, run on a daemon thread."""
 
@@ -337,7 +348,7 @@ class Service:
             def do_DELETE(self):
                 self._handle("DELETE")
 
-        self._server = ThreadingHTTPServer((self.host, self.port), _Handler)
+        self._server = _Server((self.host, self.port), _Handler)
         self._server.daemon_threads = True
         if self.port == 0:
             self.port = self._server.server_address[1]

@@ -191,4 +191,33 @@ def kernel_cases():
         jax.grad(lambda q, k, v: flash(q, k, v).astype(jnp.float32).sum(),
                  argnums=(0, 1, 2)),
         (qkv, qkv, qkv))
+    # MiMo-V2-Flash's published shapes (ISSUE 44): 64 query heads of 192
+    # with V heads of 128 (a K head as 128 + a tail of 64) on 4 K/V heads in a
+    # full layer and 8 under a window of 128 with a sink; 64 rows. A decode
+    # step of a full layer under both table widths the cell reaches (16
+    # pages a program), of a window layer over the rows' rings of 10 pages
+    # (one program a row); and the 4,096-position admit of each kind, one
+    # K/V head a program (64 heads' carries would be 24 MiB): the full
+    # layer's through the row's 256-page table, the window layer's over the
+    # bucket's own 256 pages
+    mimo = dict(v_head_dim=128, value_scale=0.707, interpret=False)
+    for tag, hkv, window, width, nrows, L in (
+            ("full-L1-P256", 4, 0, 256, 64, 1),
+            ("full-L1-P288", 4, 0, 288, 64, 1),
+            ("window-L1-ring10", 8, 128, 10, 64, 1),
+            ("full-admit-L4096", 4, 0, 256, 1, 4096),
+            ("window-admit-L4096", 8, 128, 256, 1, 4096)):
+        pool = 18433 if not window else 641 if L == 1 else 257
+        args = (_sds((nrows, L, 64, 192), jnp.bfloat16),
+                _sds((pool, PT, kv_row_width(hkv, 192, 128)), jnp.bfloat16),
+                _sds((nrows, width), jnp.int32), _sds((nrows,), jnp.int32))
+        if window:
+            cases[f"paged_attention-mimo-v2-{tag}"] = (
+                lambda q, kv, t, p, s, h=hkv, w=window: paged_attention(
+                    q, kv, t, p, kv_heads=h, window=w, sink=s, **mimo),
+                args + (_sds((64,), jnp.float32),))
+        else:
+            cases[f"paged_attention-mimo-v2-{tag}"] = (
+                lambda q, kv, t, p, h=hkv: paged_attention(
+                    q, kv, t, p, kv_heads=h, **mimo), args)
     return cases

@@ -322,6 +322,19 @@ UNPANELED = {
     # the same walk's tile body, in the prefill and admission programs
     "kubeml_serving_tile_chunks_grid_total": "kernel-specific; ad-hoc only",
     "kubeml_serving_tile_chunks_live_total": "kernel-specific; ad-hoc only",
+    # PR 44: the window layers' part of the four above, and the second
+    # kind of lease's bound against its use; the benchmark reads them
+    # (window_live_chunk_share, window_pages_share)
+    "kubeml_serving_walk_chunks_grid_window_total":
+        "kernel-specific; ad-hoc only",
+    "kubeml_serving_walk_chunks_live_window_total":
+        "kernel-specific; ad-hoc only",
+    "kubeml_serving_tile_chunks_grid_window_total":
+        "kernel-specific; ad-hoc only",
+    "kubeml_serving_tile_chunks_live_window_total":
+        "kernel-specific; ad-hoc only",
+    "kubeml_serving_window_pages_held_total": "benchmark-read; ad-hoc only",
+    "kubeml_serving_window_pages_live_total": "benchmark-read; ad-hoc only",
 }
 
 

@@ -303,6 +303,157 @@ def test_tile_body_parity(case, dtype, heads):
                                    atol=tol, rtol=tol)
 
 
+def kinds_oracle(q, k_tok, v_tok, pages, positions, window, sink, vscale,
+                 ring):
+    """Plain numpy for what a layer's kind adds (ISSUE 44): K and V heads of
+    their own widths, a window of keys, a sink in the denominator, a value
+    scale; ``ring``: the table is a window layer's ring (slot s holds the
+    newest logical page congruent to s at or before the query's own)."""
+    q, k_tok, v_tok = (np.asarray(a, np.float32) for a in (q, k_tok, v_tok))
+    B, L, H, D = q.shape
+    P, pt, Hkv = pages.shape[1], k_tok.shape[1], k_tok.shape[2]
+    out = np.zeros((B, L, H, v_tok.shape[-1]), np.float32)
+    for b in range(B):
+        k = np.repeat(k_tok[pages[b]].reshape(P * pt, Hkv, -1), H // Hkv, 1)
+        v = np.repeat(v_tok[pages[b]].reshape(P * pt, Hkv, -1), H // Hkv, 1)
+        col = np.arange(P * pt)
+        k_pos = col
+        if ring:
+            cur = positions[b] // pt
+            k_pos = (cur - (cur - col // pt) % P) * pt + col % pt
+        q_pos = positions[b] + np.arange(L)
+        seen = (k_pos[None] <= q_pos[:, None]) & (k_pos[None] >= 0)
+        if window:
+            seen &= k_pos[None] > q_pos[:, None] - window
+        s = np.einsum("lhd,thd->hlt", q[b], k) / np.sqrt(D)
+        s = np.where(seen[None], s, -1e30)
+        if sink is not None:
+            s = np.concatenate([s, np.broadcast_to(
+                np.asarray(sink)[:, None, None], (H, L, 1))], -1)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p = (p / p.sum(-1, keepdims=True))[..., :P * pt]
+        out[b] = vscale * np.einsum("hlt,thd->lhd", np.where(seen[None], p, 0),
+                                    v)
+    return out
+
+
+KIND_CASES = {
+    # name: (B, L, H, Hkv, D, Dv, pt, P, window, sink, value scale,
+    #        positions, ring)
+    # a window layer's decode step over rings of 4 pages of 4: rows short of
+    # one turn, on a page's edge, and several turns in
+    "ring-first-turn": (4, 1, 8, 2, 24, 16, 4, 4, 8, True, 0.707,
+                        [0, 3, 7, 9], True),
+    "ring-edges": (4, 1, 8, 2, 24, 16, 4, 4, 8, True, 0.707,
+                   [15, 16, 17, 31], True),
+    "ring-many-turns": (4, 1, 8, 2, 24, 16, 4, 4, 8, True, 0.707,
+                        [40, 41, 63, 100], True),
+    # the published widths: 192 / 128 (a K head as 128 + a tail of 64), ring of 10
+    "ring-published": (3, 1, 16, 8, 192, 128, 16, 10, 128, True, 0.707,
+                       [5, 200, 3000], True),
+    "full-published": (2, 1, 16, 4, 192, 128, 16, 32, 0, False, 0.707,
+                       [300, 511], False),
+    # a K width whose remainder divides no lane row (200 = 128 + 72) is
+    # padded to whole rows, in both bodies
+    "full-padded-width": (2, 1, 4, 2, 200, 128, 16, 8, 0, False, 1.0,
+                          [5, 100], False),
+    "tile-padded-width": (1, 40, 4, 2, 200, 128, 16, 8, 0, True, 0.5, [3],
+                          False),
+    # heads whose V half begins off a multiple of their own width
+    "full-odd-widths": (1, 1, 4, 1, 12, 8, 4, 32, 0, False, 1.0, [21],
+                        False),
+    # the tile body: a window over the bucket's own pages (the mask cuts
+    # inside the first live chunk, chunks before it never run), a sink, a
+    # value scale; and a full layer at the two widths
+    "tile-window": (2, 64, 8, 2, 24, 16, 4, 16, 8, True, 0.707, [0, 0],
+                    False),
+    "tile-window-published": (1, 512, 4, 2, 192, 128, 16, 32, 128, True,
+                              0.707, [0], False),
+    "tile-full-published": (1, 300, 4, 2, 192, 128, 16, 32, 0, False, 0.707,
+                            [0], False),
+    # tiles that start off a chunk's edge: a window's keys then lie in
+    # three chunks, all the grid walks from the tile's first live one
+    "tile-window-unaligned": (2, 520, 4, 2, 192, 128, 16, 64, 128, True,
+                              0.707, [7, 250], False),
+    "tile-sink-alone": (1, 40, 4, 2, 16, 16, 4, 16, 0, True, 1.0, [3], False),
+    # 64 heads: a K/V head a program under a grid axis over them
+    "tile-grouped-full": (1, 512, 64, 4, 192, 128, 16, 32, 0, False, 0.707,
+                          [0], False),
+    "tile-grouped-window": (1, 512, 64, 8, 192, 128, 16, 32, 128, True, 0.707,
+                            [0], False),
+}
+
+
+@pytest.mark.kernel
+@pytest.mark.parametrize("case", sorted(KIND_CASES))
+def test_attention_kinds_parity(case):
+    """Both bodies under what MiMo-V2-Flash's layers add, against plain
+    numpy: interpret mode, float32."""
+    from kubeml_tpu.ops.paged_attention import (tile_head_groups,
+                                                unpack_kv_rows)
+
+    (B, L, H, Hkv, D, Dv, pt, P, window, use_sink, vscale, positions,
+     ring) = KIND_CASES[case]
+    rng = np.random.default_rng(len(case))
+    k_tok = rng.standard_normal((B * P + 1, pt, Hkv, D)).astype(np.float32)
+    v_tok = rng.standard_normal((B * P + 1, pt, Hkv, Dv)).astype(np.float32)
+    rows = pack_kv_rows(jnp.asarray(k_tok), jnp.asarray(v_tok))
+    assert rows.shape[-1] == kv_row_width(Hkv, D, Dv)
+    back = unpack_kv_rows(rows, Hkv, D, Dv)
+    assert (np.asarray(back[0]) == k_tok).all()
+    assert (np.asarray(back[1]) == v_tok).all()
+    pages = 1 + np.arange(B * P, dtype=np.int32).reshape(B, P)
+    q = rng.standard_normal((B, L, H, D)).astype(np.float32)
+    sink = (1.0 + rng.standard_normal((H,))).astype(np.float32) \
+        if use_sink else None
+    positions = np.asarray(positions, np.int32)
+    got = paged_attention(
+        jnp.asarray(q), rows, jnp.asarray(pages), jnp.asarray(positions),
+        kv_heads=Hkv, v_head_dim=Dv if Dv != D else 0, window=window,
+        sink=None if sink is None else jnp.asarray(sink), value_scale=vscale,
+        interpret=True)
+    want = kinds_oracle(q, k_tok, v_tok, pages, positions, window, sink,
+                        vscale, ring)
+    assert got.shape == want.shape
+    assert float(np.abs(np.asarray(got) - want).max()) < 5e-6
+    assert (tile_head_groups(H, Hkv, D, Dv, L, 4) > 1) == ("grouped" in case)
+    if (D, Dv) == (192, 128):
+        # 320 lanes a K/V head hold its 320 values: the K heads' 128-lane
+        # main parts, their 64-lane tails two to a lane row, the V heads
+        assert rows.shape[-1] == Hkv * 320
+        assert (np.asarray(rows[..., 128:256]) == k_tok[:, :, 1, :128]).all()
+        assert (np.asarray(rows[..., Hkv * 128 + 64:Hkv * 128 + 128])
+                == k_tok[:, :, 1, 128:]).all()
+
+
+@pytest.mark.kernel
+@pytest.mark.parametrize("position,n,width,window,groups,want", [
+    # a 4,096-position admit of a window of 128 at 16 tokens a page: 16
+    # tiles of 256 queries, each walking 3 chunk steps from its first live
+    # chunk (a window's keys can lie in no more, however aligned) where the
+    # table has 16; its window meets its own chunk and the one before (the
+    # first tile: one)
+    (0, 4096, 256, 128, 1, (31, 48)),
+    (0, 4096, 256, 128, 8, (248, 384)),
+    # without a window: the causal triangle, as before
+    (0, 4096, 256, 0, 1, (136, 256)),
+    (0, 4096, 256, 0, 4, (544, 1024)),
+    # one tile whose window starts inside the second chunk
+    (600, 64, 64, 128, 1, (2, 2)),
+])
+def test_tile_chunks_mirror_counts_a_window(position, n, width, window,
+                                            groups, want):
+    """The host's twin of the tile body's clamp knows the window's lower
+    end and the heads' grid axis."""
+    from kubeml_tpu.ops.paged_attention import tile_chunks
+
+    assert tile_chunks(position, n, width, 16, 2, window=window,
+                       groups=groups) == want
+    assert walk_chunk_pages(10, ring=True) == 10    # a ring: one program
+    assert walk_chunk_pages(10) == 2
+    assert walk_chunk_pages(32, ring=True) == 16
+
+
 @pytest.mark.kernel
 @pytest.mark.parametrize("case,L,quantized,grid", [
     ("decode step", 1, False, (3, 2)),          # (rows, P / 16 pages)

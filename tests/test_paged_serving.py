@@ -93,6 +93,73 @@ def test_pool_capacity_check():
     assert not pool.can_admit(8, 10)  # 17 positions
 
 
+@pytest.mark.parametrize("step", ["admit", "around_the_ring", "release",
+                                  "rings_run_out", "pages_run_out",
+                                  "reserve", "double_free", "sizes"])
+def test_pool_two_kinds_of_lease(step):
+    """A model with window layers: a lease is the full layers' pages for
+    every position AND a ring of the window arena's pages, whatever the
+    depth; both or neither, and ``check`` accounts for both kinds after
+    every move."""
+    pool = KVPool(33, 4, prefix_cache=False, window_pages=9, window_ring=4)
+    a = pool.admit(np.arange(1, 9), 8)        # 15 positions -> 4 pages
+    assert len(a.pages) == 4 and len(a.window) == 4
+    assert 0 not in a.window                  # the window arena's trash page
+    if step == "admit":
+        assert pool.check() == {"free": 28, "held": 4, "trie_pages": 0,
+                                "refs_total": 4, "window_free": 4,
+                                "window_held": 4}
+        tel = pool.telemetry()
+        assert (tel["window_pages_total"], tel["window_pages_free"],
+                tel["window_ring_pages"]) == (8.0, 4.0, 4.0)
+    elif step == "around_the_ring":
+        # the page of position p in a window layer is slot (p // 4) mod 4
+        # of the lease's ring: a row that advances re-uses its own pages
+        # and the lease never grows
+        b = pool.admit(np.arange(1, 5), 100)  # 103 positions -> 26 pages
+        assert len(b.pages) == 26 and len(b.window) == 4
+        slots = [b.window[(p // 4) % 4] for p in range(103)]
+        assert set(slots) == set(b.window) and slots[0] == slots[16]
+        assert not set(a.window) & set(b.window)
+        assert pool.check()["window_held"] == 8
+    elif step == "release":
+        pool.release(a)
+        pool.release(a)                       # idempotent per lease
+        assert pool.check() == {"free": 32, "held": 0, "trie_pages": 0,
+                                "refs_total": 0, "window_free": 8,
+                                "window_held": 0}
+    elif step == "rings_run_out":
+        b = pool.admit(np.arange(1, 5), 2)
+        assert pool.admit(np.arange(1, 5), 2) is None   # no third ring
+        assert pool.free_pages() == 32 - 4 - 2          # nothing taken
+        pool.release(b)
+        assert pool.admit(np.arange(1, 5), 2) is not None
+        pool.check()
+    elif step == "pages_run_out":
+        assert pool.admit(np.arange(1, 5), 200) is None  # 51 pages > 28
+        assert pool.check()["window_held"] == 4          # its ring came back
+    elif step == "reserve":
+        b = pool.reserve(10)
+        assert len(b.pages) == 3 and len(b.window) == 4
+        assert pool.reserve(10) is None and pool.check()["held"] == 7
+    elif step == "double_free":
+        pool.release(a)
+        with pytest.raises(PageAllocError, match="window page"):
+            pool._free_ring(a.window)
+    else:
+        assert pool.can_admit(8, 9)
+        with pytest.raises(ValueError, match="prefix"):
+            KVPool(33, 4, prefix_cache=True, window_pages=9, window_ring=4)
+        with pytest.raises(ValueError, match="ring"):
+            KVPool(33, 4, prefix_cache=False, window_pages=4, window_ring=4)
+        with pytest.raises(ValueError, match="ring"):
+            KVPool(33, 4, prefix_cache=False, window_pages=9)
+        # one kind: what the pool always was
+        plain = KVPool(5, 4, prefix_cache=False)
+        assert plain.admit(np.arange(1, 5), 1).window == []
+        assert plain.check()["window_free"] == 0
+
+
 def test_prefix_trie_match_insert_and_sharing():
     pool = KVPool(33, 4)
     prompt = np.arange(1, 14)  # 13 tokens: 3 full blocks + 1
