@@ -292,6 +292,8 @@ class Config:
     # half the bytes of its float32 checkpoint files: the leaves are cast
     # one by one as they reach the device (serving.quant.cast_tree), so the
     # wide tree is never resident. Int8 leaves (serving_quantize) are left.
+    # Empty, a leaf the module only ever casts to a narrower floating type
+    # is held in that type, every other as restored (docs/design.md §26).
     serving_param_dtype: str = field(
         default_factory=lambda: os.environ.get(
             "KUBEML_SERVING_PARAM_DTYPE", ""))

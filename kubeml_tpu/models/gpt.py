@@ -10,8 +10,10 @@ as ring attention over the ``sp`` axis when a mesh with sp > 1 is attached
 
 ``dtype`` is the computation dtype (bf16 compute / f32 params mixed precision):
 matmuls run in ``dtype``, the norms and attention softmax stay f32, parameters
-are initialised and trained f32 (a server may hold them narrower:
-``Config.serving_param_dtype``), and logits are returned f32 for the loss.
+are initialised and trained f32, and logits are returned f32 for the loss. A
+server holds in ``dtype`` every parameter this file only ever casts to it (the
+products' kernels and biases; the norms' and both embeddings' f32 values are
+used), or all in ``Config.serving_param_dtype`` (docs/design.md §26).
 
 One block, configured: LayerNorm or RMSNorm; a GELU MLP at a ratio, SwiGLU at
 a width, or routed experts with a shared one (models/experts.py); multi-head

@@ -60,11 +60,25 @@ def _float_types(variables) -> str:
     """The floating types a tree's leaves are held in, as a span shows them
     ("float32", "bfloat16,float32")."""
     import jax
-    import jax.numpy as jnp
 
-    return ",".join(sorted({
-        str(l.dtype) for l in jax.tree.leaves(variables)
-        if jnp.issubdtype(getattr(l, "dtype", jnp.int32), jnp.floating)}))
+    from ..serving.quant import is_floating
+
+    return ",".join(sorted({str(l.dtype) for l in jax.tree.leaves(variables)
+                            if is_floating(l)}))
+
+
+def _decodes_tokens(module) -> bool:
+    """Whether ``module`` is a token-in LM with a decode path: it has a
+    ``max_len`` and its call takes ``decode`` and ``positions``."""
+    if module is None or getattr(module, "max_len", None) is None:
+        return False
+    import inspect
+
+    try:
+        params = inspect.signature(module.__call__).parameters
+    except (TypeError, ValueError):
+        return False
+    return "decode" in params and "positions" in params
 
 
 def _tree_bytes(variables) -> int:
@@ -165,6 +179,9 @@ class ParameterServer:
         # seconds a cached tree's restore and hold took, until the decoder
         # built on it takes them into its stats (_get_decoder)
         self._serving_startup: Dict[str, Dict[str, float]] = {}
+        # leaves of a cached tree that the hold's rule narrowed (_held), for
+        # the telemetry of every decoder built on it
+        self._serving_narrowed: Dict[str, int] = {}
         # (model, vars, epoch version, native.weights.FetchCache) — the
         # FetchCache makes per-epoch refreshes pull only the leaves whose
         # manifest version moved (delta fetch)
@@ -1344,15 +1361,7 @@ class ParameterServer:
         if not self.cfg.serving_batcher:
             return None
         module = getattr(model, "module", None)
-        if module is None or getattr(module, "max_len", None) is None:
-            return None
-        import inspect
-
-        try:
-            params = inspect.signature(module.__call__).parameters
-        except (TypeError, ValueError):
-            return None
-        if "decode" not in params or "positions" not in params:
+        if not _decodes_tokens(module):
             return None
         with self._lock:
             cached = self._decoders.get(model_id)
@@ -1373,6 +1382,8 @@ class ParameterServer:
         # hold (once: a decoder rebuilt on a cached tree spent neither)
         with self._lock:
             spent = self._serving_startup.pop(model_id, {})
+            decoder.stats.param_leaves_narrowed = self._serving_narrowed.get(
+                model_id, 0)
         spent["decoder"] = time.monotonic() - t0
         for phase, seconds in spent.items():
             decoder.stats.startup(phase, seconds)
@@ -1886,11 +1897,14 @@ class ParameterServer:
                 span.attrs.update(leaves=len(jax.tree.leaves(variables)),
                                   bytes=_tree_bytes(variables))
         t1 = time.monotonic()
+        narrowed = 0
         if not placed:
-            variables = self._held(variables)
+            variables, narrowed = self._held(
+                variables, getattr(model, "module", None))
         with self._lock:
             self._serving_startup[model_id] = {
                 "restore": t1 - t0, "hold": time.monotonic() - t1}
+            self._serving_narrowed[model_id] = narrowed
         return (model, variables, mtime, mesh)
 
     def _restore_serving(self, model_id: str, kind: str, tag: str) -> tuple:
@@ -1961,13 +1975,30 @@ class ParameterServer:
             variables = from_storage_tree(variables)
         return (model, variables, mesh, mesh is not None)
 
-    def _held(self, variables):
-        """The served tree in ``Config.serving_param_dtype`` (empty: as the
-        checkpoint has it), cast leaf by leaf on the device. A tree already
-        placed on a serving mesh keeps its type: its shardings were derived
-        for the leaves as restored. A ``ps.serving.hold`` span: the types
-        ``from`` and ``to``, ``bytes`` as held, and what the casts compiled
-        on this thread, which is the hold's and not an engine program's."""
+    def _held(self, variables, module) -> tuple:
+        """(the served tree as it is held on the device, the leaves the
+        rule narrowed). With ``Config.serving_param_dtype`` set every
+        floating leaf is cast to it. Empty, the module is asked what it
+        does with each leaf (``serving.quant.held_types``: its forward
+        traced abstractly): **a leaf whose every use is a cast to one and
+        the same narrower floating type is held in that type**, the same
+        bits the programs would make of it every step and every admit;
+        every other leaf stays as the checkpoint has it. The rule is
+        skipped, and the tree kept whole, for a tree that holds quantized
+        leaves, for a process that quantizes at serve time (the quantizer
+        must see the checkpoint's own values), for a module that is no
+        token-in LM and where the trace fails. A tree already placed on a
+        serving mesh never comes here: its shardings were derived for the
+        leaves as restored. A ``ps.serving.hold`` span: the types ``from``
+        and ``to``, ``bytes`` as held, ``narrowed`` and ``narrowed_bytes``
+        (the leaves the rule cast, and their bytes as held), ``kept`` (the
+        floating leaves still in the type they came in), and what the casts
+        compiled on this thread, which is the hold's and not an engine
+        program's."""
+        import jax
+
+        from ..serving import quant
+
         want = (self.cfg.serving_param_dtype or "").lower()
         if want not in ("", "bfloat16", "float32"):
             log.warning("KUBEML_SERVING_PARAM_DTYPE=%r not recognized "
@@ -1978,17 +2009,33 @@ class ParameterServer:
         before = clock.read()
         with tracing.get_tracer().span("ps.serving.hold",
                                        service="ps") as span:
-            if span is not None:
-                span.attrs["from"] = _float_types(variables)
+            restored = variables
+            types = []
             if want:
-                from ..serving.quant import cast_tree
-
-                variables = cast_tree(variables, want)
+                variables = quant.cast_tree(variables, want)
+            elif (self.cfg.serving_quantize != "int8"
+                  and not quant.is_quantized_tree(variables)
+                  and _decodes_tokens(module)):
+                try:
+                    types = quant.held_types(module, variables)
+                except Exception:
+                    log.debug("tracing the serving forward failed; holding "
+                              "the tree as restored", exc_info=True)
+                else:
+                    variables = quant.cast_leaves(variables, types)
+            cast = [i for i, to in enumerate(types) if to is not None]
             if span is not None:
-                span.attrs.update(to=_float_types(variables),
-                                  bytes=_tree_bytes(variables),
-                                  **clock.since(before))
-        return variables
+                held = jax.tree.leaves(variables)
+                span.attrs["from"] = _float_types(restored)
+                span.attrs.update(
+                    to=_float_types(variables), bytes=_tree_bytes(variables),
+                    narrowed=len(cast),
+                    narrowed_bytes=sum(int(held[i].nbytes) for i in cast),
+                    kept=sum(was.dtype == now.dtype for was, now in zip(
+                        jax.tree.leaves(restored), held)
+                        if quant.is_floating(now)),
+                    **clock.since(before))
+        return variables, len(cast)
 
     def _load_serving(self, model_id: str):
         """(model, variables, mtime, serving mesh) for a FINISHED job from
@@ -2010,6 +2057,7 @@ class ParameterServer:
                     evicted = next(iter(self._serving_cache))
                     self._serving_cache.pop(evicted)
                     self._serving_startup.pop(evicted, None)
+                    self._serving_narrowed.pop(evicted, None)
         return cached
 
     def _infer_from_checkpoint(self, model_id: str, data) -> list:

@@ -41,6 +41,7 @@ extends the HBM-bound analysis the round-4 engine is built on
 
 from __future__ import annotations
 
+import collections
 from typing import Any, NamedTuple
 
 import jax
@@ -120,21 +121,127 @@ def dequantize_tree(variables: dict, dtype=jnp.bfloat16) -> dict:
     return jax.tree.map(one, variables, is_leaf=_is_q)
 
 
+def is_floating(leaf) -> bool:
+    return jnp.issubdtype(getattr(leaf, "dtype", jnp.int32), jnp.floating)
+
+
+# casts cast_leaves keeps in flight: a leaf's way to the device overlaps the
+# casts before it, and the wide copies alive stay a few leaves, not a tree
+_CASTS_AHEAD = 2
+
+
+def cast_leaves(variables: dict, types: list) -> dict:
+    """The tree with leaf ``i`` (in tree order, a QuantizedTensor one leaf)
+    held in ``types[i]`` on the device, or as it is where that is None:
+    each leaf is put on the device and cast there by itself, and its wide
+    copy dropped before the third one after it arrives, so the peak is the
+    narrow tree plus a few wide leaves."""
+    leaves, treedef = jax.tree.flatten(variables, is_leaf=_is_q)
+    out, ahead = [], collections.deque()
+    for leaf, to in zip(leaves, types, strict=True):
+        if to is not None:
+            leaf = jnp.asarray(leaf).astype(to)
+            ahead.append(leaf)
+            if len(ahead) > _CASTS_AHEAD:
+                jax.block_until_ready(ahead.popleft())
+        out.append(leaf)
+    jax.block_until_ready(list(ahead))
+    return treedef.unflatten(out)
+
+
 def cast_tree(variables: dict, dtype) -> dict:
     """The tree with every floating leaf held in ``dtype`` on the device
-    (``Config.serving_param_dtype``): each leaf is put on the device and
-    cast there by itself, and its wide copy dropped before the next one
-    arrives, so the peak is the narrow tree plus one wide leaf. Quantized
-    leaves and integer leaves pass through."""
+    (``Config.serving_param_dtype``), leaf by leaf as :func:`cast_leaves`
+    does. Quantized leaves and integer leaves pass through."""
     dtype = jnp.dtype(dtype)
+    return cast_leaves(variables, [
+        dtype if not _is_q(leaf) and is_floating(leaf) else None
+        for leaf in jax.tree.leaves(variables, is_leaf=_is_q)])
 
-    def one(leaf):
-        if _is_q(leaf) or not jnp.issubdtype(
-                getattr(leaf, "dtype", jnp.int32), jnp.floating):
-            return leaf
-        return jax.block_until_ready(jnp.asarray(leaf).astype(dtype))
 
-    return jax.tree.map(one, variables, is_leaf=_is_q)
+def _note_uses(jaxpr, which: dict, uses: list) -> None:
+    """Into ``uses[i]`` what ``jaxpr`` does with the variable ``which`` maps
+    to ``i``: the type a ``convert_element_type`` gives it, None for any
+    other use (being an output is one). An operand of a nested jit and a
+    constant of a scan ARE the value inside, so they are followed into the
+    inner program; every other equation that holds a jaxpr is a use."""
+    from jax.extend.core import Var
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        inner, followed = None, 0
+        if name in ("jit", "pjit"):
+            inner, followed = eqn.params["jaxpr"].jaxpr, len(eqn.invars)
+        elif name == "scan":
+            inner, followed = (eqn.params["jaxpr"].jaxpr,
+                               eqn.params["num_consts"])
+        passed = {}
+        for i, var in enumerate(eqn.invars):
+            leaf = which.get(var) if isinstance(var, Var) else None
+            if leaf is None:
+                continue
+            if i < followed:
+                passed[inner.invars[i]] = leaf
+            elif name == "convert_element_type":
+                uses[leaf].append(jnp.dtype(eqn.params["new_dtype"]))
+            else:
+                uses[leaf].append(None)
+        if passed:
+            _note_uses(inner, passed, uses)
+    for var in jaxpr.outvars:
+        if isinstance(var, Var) and var in which:
+            uses[which[var]].append(None)
+
+
+def narrowing_casts(jaxpr, leaves: int) -> list:
+    """For each of the first ``leaves`` inputs of ``jaxpr``: the narrower
+    floating type EVERY use of it casts it to, one and the same, or None.
+    A floating input with such a type can be held in it and the program
+    computes the same bits: what enters each product is the cast's result
+    either way. An input that is used any other way, cast to two types, not
+    used, not floating, or handed to something this cannot see through
+    (``_note_uses``) gets None: the answer errs to the type it has."""
+    uses = [[] for _ in range(leaves)]
+    _note_uses(jaxpr, {v: i for i, v in enumerate(jaxpr.invars[:leaves])},
+               uses)
+    out = []
+    for var, seen in zip(jaxpr.invars, uses):
+        to = seen[0] if len(set(seen)) == 1 else None
+        narrower = (to is not None and is_floating(var.aval)
+                    and jnp.issubdtype(to, jnp.floating)
+                    and to.itemsize < var.aval.dtype.itemsize)
+        out.append(to if narrower else None)
+    return out
+
+
+def held_types(module, variables: dict) -> list:
+    """The type to hold each leaf of a token-in LM's ``variables`` in, in
+    tree order, None where it stays as it is: the module's forward is
+    traced abstractly (shapes and types of the leaves; no device memory, no
+    arithmetic) and :func:`narrowing_casts` asked what it does with each
+    leaf. A module computing in bfloat16 over float32 parameters casts its
+    products' kernels and biases and nothing else; one computing in its
+    parameters' type casts nothing. What is traced is a one-token decode
+    apply (what ``models.generation.init_cache`` sizes a cache with): every
+    layer goes through the one traced block (models/gpt.py
+    ``_decode_block``), a tenth of the plain forward's trace at 36 layers;
+    a model that decodes only through pages (latent attention) refuses it
+    and is traced by its plain forward over eight tokens. Either stands for
+    the engines' programs, which run the same layers' casts
+    (tests/test_held_types.py reads the paged engine's own). Raises what
+    the plain forward's trace raises (a module that takes no tokens)."""
+    abstract = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(np.shape(l), l.dtype), variables)
+
+    def traced(length, **kw):
+        return jax.make_jaxpr(lambda p, t: module.apply(p, t, **kw))(
+            abstract, jax.ShapeDtypeStruct((1, length), jnp.int32)).jaxpr
+
+    try:
+        jaxpr = traced(1, decode=True, mutable=["cache"])
+    except Exception:
+        jaxpr = traced(8)
+    return narrowing_casts(jaxpr, len(jax.tree.leaves(abstract)))
 
 
 def quantized_dot(x, qt: QuantizedTensor, *, dtype=None, impl: str = None):

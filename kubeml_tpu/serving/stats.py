@@ -220,6 +220,10 @@ class DecoderStats:
         # histogram sums, and the persistent cache's hits and writes. They
         # grow at a first call and nowhere else
         self.startup_seconds = dict.fromkeys(STARTUP_PHASES, 0.0)
+        # leaves of the served tree held narrower than the checkpoint has
+        # them because the programs only ever cast them (the parameter
+        # server's hold sets it; 0 where an option decided the type)
+        self.param_leaves_narrowed = 0
         self.compile_phases = dict.fromkeys(COMPILE_PHASES, 0.0)
         self.compile_storm_per_min = 0.0
         self._storm_logged_at = 0.0
@@ -721,6 +725,7 @@ class DecoderStats:
             }
             for phase, seconds in self.startup_seconds.items():
                 out[f"startup_{phase}_seconds"] = seconds
+            out["param_leaves_narrowed"] = float(self.param_leaves_narrowed)
             for key, name in COMPILE_PHASES.items():
                 out[name] = float(self.compile_phases[key])
             compiles_per_min = self._compile_series.rate(
