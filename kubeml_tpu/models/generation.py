@@ -120,8 +120,9 @@ def init_paged_cache(module, variables, batch: int, table_pages: int) -> dict:
 
 def supports_paged_decode(module) -> bool:
     """Whether ``module`` can serve through the paged KV-cache engine:
-    it must expose the ``pages``/``seq_lens`` decode kwargs plus the
-    clonable ``page_tokens``/``kv_pages`` arena fields, and not interleave
+    it must expose the ``pages``/``seq_lens`` decode kwargs and an
+    admission's ``head_positions``, plus the clonable
+    ``page_tokens``/``kv_pages`` arena fields, and not interleave
     ``moe_every`` blocks (parallel/moe.py's training-side block: its
     attention has no paged path). A block's own kinds all serve: latent
     attention (``mla``) and routed experts behind dense layers
@@ -136,7 +137,8 @@ def supports_paged_decode(module) -> bool:
         params = inspect.signature(module.__call__).parameters
     except (TypeError, ValueError):
         return False
-    return "pages" in params and "seq_lens" in params and "positions" in params
+    return all(name in params for name in (
+        "pages", "seq_lens", "positions", "head_positions"))
 
 
 def has_latent_cache(module) -> bool:

@@ -997,10 +997,19 @@ class CausalTransformer(nn.Module):
     @nn.compact
     def __call__(self, token_ids, train: bool = False, decode: bool = False,
                  return_hidden: bool = False, positions=None, pages=None,
-                 seq_lens=None, exit_layer: Optional[int] = None, rows=None):
+                 seq_lens=None, exit_layer: Optional[int] = None, rows=None,
+                 head_positions=None):
         # ``rows`` [B] (recurrent models, decode only): each batch row's
         # place in the cache's per-row state, for programs whose rows are
         # not the state's (an admission); None = row b is state row b
+        # ``head_positions`` [B] (traced ints in [0, L)): the ONE position
+        # of each row, within this call's L, that goes on to the output
+        # head; the logits come back [B, 1, vocab]. An admission samples
+        # one token a row, and the read-out, ln_f and lm_head are all
+        # per-position, so the row is gathered before them: no [L, vocab]
+        # product, no float32 logits of the whole bucket. None = every
+        # position (training, generate, a decode step, and the speculative
+        # verify, which accepts against all k + 1 rows)
         # ``exit_layer`` (a TRACE-TIME int in [1, depth]) runs only the
         # first ``exit_layer`` blocks, then ln_f + lm_head — the early-exit
         # self-drafting head for speculative decoding (models.generation /
@@ -1195,6 +1204,9 @@ class CausalTransformer(nn.Module):
                 x = block_cls(self.num_heads, self.mlp_ratio, self.dropout,
                               name=name, **fields)(x, valid, train, decode,
                                                    **at)
+        if head_positions is not None:
+            x = jnp.take_along_axis(x, head_positions[:, None, None], axis=1)
+            L = 1
         if self.hc_mult:
             # the read-out: the streams' sum, in float32 as the norm is
             x = x.astype(jnp.float32).reshape(

@@ -203,6 +203,10 @@ SERVING_COUNTERS = {
     "kubeml_serving_prefill_pad_tokens_total": (
         "prefill_pad_tokens", "Padding tokens computed at admission (prompt "
                               "bucket + repeated-row padding)"),
+    "kubeml_serving_prefill_head_positions_total": (
+        "prefill_head_positions", "Positions the admission programs' output "
+                                  "heads multiplied: the one a row that is "
+                                  "sampled from, not the bucket"),
     "kubeml_serving_goodput_tokens_total": (
         "goodput_tokens", "Tokens delivered to a live waiter (useful-token "
                           "goodput vs device-step throughput)"),

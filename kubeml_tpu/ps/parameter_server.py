@@ -69,7 +69,8 @@ def _float_types(variables) -> str:
 
 def _decodes_tokens(module) -> bool:
     """Whether ``module`` is a token-in LM with a decode path: it has a
-    ``max_len`` and its call takes ``decode`` and ``positions``."""
+    ``max_len`` and its call takes what the engines pass it: ``decode``,
+    ``positions`` and an admission's ``head_positions``."""
     if module is None or getattr(module, "max_len", None) is None:
         return False
     import inspect
@@ -78,7 +79,8 @@ def _decodes_tokens(module) -> bool:
         params = inspect.signature(module.__call__).parameters
     except (TypeError, ValueError):
         return False
-    return "decode" in params and "positions" in params
+    return all(name in params
+               for name in ("decode", "positions", "head_positions"))
 
 
 def _tree_bytes(variables) -> int:

@@ -1086,15 +1086,15 @@ class BatchingDecoder:
         k, Lb = prompts.shape
         variables = self._dense_vars(variables)
         cache_k = init_cache(self.module, variables, k)
-        logits, vs = self.module.apply(
-            {**variables, "cache": cache_k}, prompts, decode=True,
-            mutable=["cache"])
-        row_caches = vs["cache"]
         # bucket padding means positions >= plen hold garbage K/V; their
         # validity is trimmed at insert below. Next-token logits come from
-        # each row's last REAL prompt token (runtime gather at plen-1).
-        last = jnp.take_along_axis(
-            logits, (plens - 1)[:, None, None], axis=1)[:, 0].astype(jnp.float32)
+        # each row's last REAL prompt token: the module gathers that one
+        # position (runtime, at plen-1) before its head
+        logits, vs = self.module.apply(
+            {**variables, "cache": cache_k}, prompts, decode=True,
+            head_positions=plens - 1, mutable=["cache"])
+        row_caches = vs["cache"]
+        last = logits[:, 0].astype(jnp.float32)
 
         use, nxt_keys = _split_rows(keys)
         firsts = _sample_rows(last, use, temps, topks)  # [k]
@@ -1701,7 +1701,9 @@ class BatchingDecoder:
         t0 = self._span_clock()
         self._turn_from = 0.0
         self._cond.wait()
-        if t0:
+        # a wait that outlives the tracing it began under (close() wakes
+        # the thread after the tracer went off) records nothing
+        if t0 and self._tracer.enabled:
             self._tracer.add_span("engine.wait_work", t0,
                                   self._tracer.now() - t0)
 
@@ -1789,7 +1791,7 @@ class BatchingDecoder:
 
     def _run_program(self, program: str, sig: tuple, fn, *args, kind: str,
                      steps: int = 0, width: int = 0, group=None,
-                     state_rows: int = 0):
+                     state_rows: int = 0, head_positions: int = 0):
         """Dispatch one jitted program through the compile tracker: the
         first call per (program, shape signature) traces + XLA-compiles
         synchronously before the async dispatch, so its wall here IS the
@@ -1804,7 +1806,10 @@ class BatchingDecoder:
         record's (admit, step, spec, pchunk), ``steps`` the decode steps in
         the program, ``width`` its page-table width, ``state_rows`` the rows
         whose recurrent state it writes (0 for a model without one)
-        (``moe_layers``: its routed-expert layers, 0 likewise) — under a profiler
+        (``moe_layers``: its routed-expert layers, 0 likewise),
+        ``head_positions`` the positions a prefill program's output head
+        takes (the one a row it samples from; 0 on any other program)
+        — under a profiler
         annotation of the same name, and an admitting program (``group``
         set) closes the ``engine.admit`` span that began where its rows
         were taken from the queue. A first call's span also carries
@@ -1846,6 +1851,7 @@ class BatchingDecoder:
                 "engine.dispatch", tracer.at(t0), t1 - t0, requests, seq=seq,
                 program=kind, steps=steps, width=width, cold=cold,
                 state_rows=state_rows, moe_layers=self._moe_layers,
+                head_positions=head_positions,
                 rows_live=sum(r is not None for r in self._slot_rows),
                 depth=self._depth, ahead=len(self._inflight), **first)
             # a later group of the same wave is prepared from here on
@@ -1931,7 +1937,7 @@ class BatchingDecoder:
             self._variables, self._slab, jnp.asarray(prompts),
             jnp.asarray(plens), jnp.asarray(slots), jnp.asarray(max_news),
             jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(eoss),
-            jnp.asarray(keys), kind="admit", group=group)
+            jnp.asarray(keys), kind="admit", group=group, head_positions=k)
         now = time.monotonic()
         real_tokens = 0
         for slot, row in group:
@@ -1944,8 +1950,10 @@ class BatchingDecoder:
         self.stats.admitted_wave()
         # prefill padding accounting: the program computes k x bucket token
         # positions; everything beyond the real prompts (bucket padding +
-        # the rows repeated to pad the group to S) is padding compute
-        self.stats.admit_tokens(real_tokens, k * bucket - real_tokens)
+        # the rows repeated to pad the group to S) is padding compute; the
+        # head takes one position a program row
+        self.stats.admit_tokens(real_tokens, k * bucket - real_tokens,
+                                head_positions=k)
         self._admits_inflight += 1
         # one prefill forward attends over the fresh [k, max_len] caches
         return ("admit", group, packed,
@@ -2720,14 +2728,14 @@ class PagedBatchingDecoder(BatchingDecoder):
         # (zeros first where base is 0: a reused slot; the row's own state
         # where a chunked prefill goes on)
         kw = {"rows": rowids} if self._recurrent else {}
+        # the one position sampled from, the suffix's last real token, is
+        # all the head is given: logits [1, 1, vocab], not the bucket's
         logits, vs = self.module.apply(
             {**variables, "cache": slab.cache}, suffix, decode=True,
-            positions=base, pages=ptbl, seq_lens=slens, mutable=["cache"],
-            **kw)
+            positions=base, pages=ptbl, seq_lens=slens,
+            head_positions=slens - 1, mutable=["cache"], **kw)
         cache = vs["cache"]
-        last = jnp.take_along_axis(
-            logits, (slens - 1)[:, None, None], axis=1)[:, 0].astype(
-                jnp.float32)
+        last = logits[:, 0].astype(jnp.float32)
         use, nxt_keys = _split_rows(keys)
         firsts = _sample_rows(last, use, temps, topks)
         hit_eos = (eoss >= 0) & (firsts == eoss)
@@ -2776,9 +2784,12 @@ class PagedBatchingDecoder(BatchingDecoder):
             variables, slab, ptbl, suffix, base, slens, rowids, max_news,
             temps, topks, eoss, keys)
         dvars = self._dense_draft_vars(draft_variables)
+        # the drafter's logits are dropped: only its cache is wanted, and
+        # one row keeps its head's product over the bucket out of the trace
         _, dvs = self.draft_module.apply(
             {**dvars, "cache": draft_cache}, suffix, decode=True,
-            positions=base, pages=ptbl, seq_lens=slens, mutable=["cache"])
+            positions=base, pages=ptbl, seq_lens=slens,
+            head_positions=slens - 1, mutable=["cache"])
         return slab2, dvs["cache"], packed
 
     def _spec_step_impl(self, variables, slab, pages, draft_variables,
@@ -3057,7 +3068,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                 (key, np.uint32)))
         span = dict(kind=kind, width=wa,
                     group=[(slot, row)] if kind == "admit" else None,
-                    state_rows=1 if self._recurrent else 0)
+                    state_rows=1 if self._recurrent else 0, head_positions=1)
         # the prefill program is keyed (suffix bucket, table width) — both
         # are compile shapes on the paged engine. A draft backend's program
         # prefills the drafter's arena through the same one-row arguments
@@ -3072,7 +3083,8 @@ class PagedBatchingDecoder(BatchingDecoder):
                 self._variables, self._slab, *args, **span)
         # prefill accounting: only the unshared suffix is computed —
         # prefix-cached tokens are the measured FLOP saving, padding is
-        # what the bucket adds to the row's own tokens
+        # what the bucket adds to the row's own tokens, and the head takes
+        # the row's one sampled position
         self.stats.admit_tokens(take, bucket - take)
         if self.stats.walks_kv_chunks:
             # the page walk's tile body: the bucket's queries from the

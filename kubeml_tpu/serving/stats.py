@@ -96,6 +96,7 @@ class DecoderStats:
         self.idle_slot_steps = 0      # no resident row (free capacity)
         self.prefill_tokens = 0       # real prompt tokens prefilled
         self.prefill_pad_tokens = 0   # bucket + row padding tokens computed
+        self.prefill_head_positions = 0  # positions their output heads took
         # routed-expert layers, decode steps only: token-to-expert
         # assignments given to experts held here (live rows x top_k x
         # expert layers a step where every choice is one: what enters the
@@ -354,14 +355,19 @@ class DecoderStats:
             self.prefix_hits += 1
             self.prefix_tokens_saved += int(tokens_saved)
 
-    def admit_tokens(self, real: int, padding: int) -> None:
+    def admit_tokens(self, real: int, padding: int,
+                     head_positions: int = 1) -> None:
         """Prefill token accounting for one admission program: ``real``
         prompt tokens vs ``padding`` computed-but-useless tokens (prompt
         bucket padding; in the slot engine also the repeated rows padding
-        the program to S — a paged admission program carries one row)."""
+        the program to S — a paged admission program carries one row).
+        ``head_positions`` of them went through the output head: the one
+        a row that is sampled from (until PR 46 every position computed,
+        ``real + padding``)."""
         with self._lock:
             self.prefill_tokens += int(real)
             self.prefill_pad_tokens += int(padding)
+            self.prefill_head_positions += int(head_positions)
             self.hc_positions_admit += (
                 int(real) + int(padding)) * self.hc_sublayers
 
@@ -694,6 +700,7 @@ class DecoderStats:
                 "idle_slot_steps": float(self.idle_slot_steps),
                 "prefill_tokens": float(self.prefill_tokens),
                 "prefill_pad_tokens": float(self.prefill_pad_tokens),
+                "prefill_head_positions": float(self.prefill_head_positions),
                 "moe_assignments": float(self.moe_assignments),
                 "moe_experts_touched": float(self.moe_experts_touched),
                 "moe_assignments_zero": float(self.moe_assignments_zero),

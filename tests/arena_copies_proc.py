@@ -17,6 +17,15 @@ a relayout of a weight cannot be taken for one of the pool. LongCat-Flash's
 two layers are the two attentions of one double layer, with four small
 experts on its side branch.
 
+An admit is compiled as the engine calls it: the one sampled position
+handed to the module (``head_positions``), so the head multiplies one row.
+The two admit-bound configurations' admits carry their published vocabulary
+and bfloat16 weights, as their cells hold them (PR 46), and any array of
+``[bucket, vocabulary]`` that the compiled program's entry computation
+makes (the head's product over the whole bucket, or its float32 convert)
+is reported beside the copies. The head's own weight there is larger than
+the arena, so a relayout of it would be reported too, and should be.
+
 Prints ``OK <case>`` / ``COPIES <case>: <n> <first few>`` per case; exit 0
 when no case holds a forbidden operation, 1 when one does, 77 when this
 installation cannot describe a TPU topology (the caller skips)."""
@@ -46,6 +55,9 @@ LATENT_SHAPES = {
     "longcat-flash-omni": (6144, 64, (1536, 512, 128, 64, 128), True,
                            64, 128, 512),
 }
+# the vocabulary an admit is compiled at, where the head is a fifth or a
+# quarter of an admit (extract, rag); 512 elsewhere, as in every step
+HEAD_VOCAB = {"glm-4.7-flash": 154880, "xing4.0-29b-a4b": 131072}
 CASES = {f"{name}-{case}" for name in (*SHAPES, *LATENT_SHAPES)
          for case in ("step", "admit")}
 
@@ -79,6 +91,25 @@ def pool_sized(hlo: str, floor: int) -> list:
                 and _SPACE.sub("", dest) == _SPACE.sub("", src)):
             op = "move"
         out.append((op, dims))
+    return out
+
+
+def bucket_by_vocab(hlo: str, bucket: int, vocab: int) -> list:
+    """``(operation, result dims)`` of every instruction of the entry
+    computation, parameters apart, whose result is ``[bucket, vocab]``
+    (under leading ones): what a head over all of an admit's positions
+    leaves behind. A fused computation's own lines are no arrays in memory
+    (a one-row head multiplies the weight by its row broadcast inside
+    one), and GLM's head weight is itself 2,048 wide: a parameter."""
+    dims = re.compile(rf"\w+\[(?:1,)*{bucket},{vocab}\]")
+    entry = hlo[hlo.find("\nENTRY "):]
+    out = []
+    for line in entry[:entry.find("\n}")].splitlines():
+        m = re.search(rf" = \(?({_SHAPE}).*? ([\w-]+)\(", line)
+        if m and m.group(2) != "parameter" and dims.match(m.group(1)):
+            shape = m.group(1)
+            out.append((m.group(2), shape[shape.index("[") + 1:
+                                          shape.index("]")]))
     return out
 
 
@@ -148,16 +179,21 @@ def main() -> int:
     i32 = jnp.int32
     for name, module, key, rows, table, bucket, walks in models:
 
-        def forward(variables, cache, ids, positions, pages, seq_lens):
-            logits, upd = module.apply(
-                {**variables, "cache": cache}, ids, decode=True,
-                positions=positions, pages=pages, seq_lens=seq_lens,
-                mutable=["cache"])
-            return logits, upd["cache"]
-
         for case, (b, length) in (("step", (rows, 1)), ("admit", (1, bucket))):
+            admit = case == "admit"
+            vocab = HEAD_VOCAB.get(name, 512) if admit else 512
+            sized = module.clone(vocab_size=vocab)
+
+            def forward(variables, cache, ids, positions, pages, seq_lens):
+                logits, upd = sized.apply(
+                    {**variables, "cache": cache}, ids, decode=True,
+                    positions=positions, pages=pages, seq_lens=seq_lens,
+                    head_positions=seq_lens - 1 if admit else None,
+                    mutable=["cache"])
+                return logits, upd["cache"]
+
             full = nn.meta.unbox(jax.eval_shape(
-                lambda: module.init(
+                lambda: sized.init(
                     jax.random.PRNGKey(0), jnp.zeros((b, length), i32),
                     decode=True, positions=jnp.zeros((b,), i32),
                     pages=jnp.zeros((b, table), i32),
@@ -166,9 +202,13 @@ def main() -> int:
                       jax.tree_util.tree_leaves_with_path(full["cache"])
                       if path[-1].key == key]
             assert len(arenas) == 2, (name, len(arenas))
+            params = full["params"]
+            if vocab != 512:
+                params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+                    s.shape, jnp.bfloat16), params)
             vec = jax.ShapeDtypeStruct((b,), i32)
             hlo = jax.jit(forward, donate_argnums=(1,)).lower(
-                on_chip({"params": full["params"]}), on_chip(full["cache"]),
+                on_chip({"params": params}), on_chip(full["cache"]),
                 on_chip(jax.ShapeDtypeStruct((b, length), i32)),
                 on_chip(vec),
                 on_chip(jax.ShapeDtypeStruct((b, table), i32)),
@@ -180,6 +220,8 @@ def main() -> int:
             calls = len(_WALK.findall(hlo))
             if calls != walks[case]:
                 found.append(("page-walk calls", str(calls)))
+            if vocab != 512:
+                found += bucket_by_vocab(hlo, bucket, vocab)
             if found:
                 failed += 1
                 print(f"COPIES {name}-{case}: {len(found)} {found[:6]}",

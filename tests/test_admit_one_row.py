@@ -362,3 +362,202 @@ def test_spec_backends_admit_one_row(served, backend):
         assert len(calls) == len(ps) and tel["spec_steps"] > 0
         assert chk["held"] == chk["trie_pages"]
     assert outs[0] == outs[1] == [one_shot(m, variables, p, 8) for p in ps]
+
+
+# --- the sampled row is taken before the output head (ISSUE 46) -----------
+#
+# An admission samples one token a row, and the hyper-connection read-out,
+# ``ln_f`` and ``lm_head`` are all per-position: handed ``head_positions``
+# the module gathers that one position before them and returns
+# ``[B, 1, vocab]``. Held here: the row is the one ``take_along_axis`` picked
+# from the bucket's logits, for every kind of block; no admission program of
+# either engine holds a ``[*, bucket, vocab]`` array any more, while the
+# speculative verify still holds all ``k + 1`` rows; and the count
+# ``prefill_head_positions`` is one a program row.
+
+import jax.numpy as jnp  # noqa: E402
+
+from kubeml_tpu.models.gpt import MuP  # noqa: E402
+from kubeml_tpu.utils import tracing  # noqa: E402
+
+import test_glm_moe_lite as glm  # noqa: E402
+import test_hyper_connections as hcx  # noqa: E402
+
+ROWS, TABLE = 3, 8    # an admit of three rows under 8 pages of 8 tokens
+
+
+def _blocks(name, served, model):  # noqa: F811
+    """(module, tree) of one kind of block, all float32."""
+    if name == "gpt2":
+        return served
+    if name == "mup_head":
+        m = CausalTransformer(vocab_size=VOCAB, max_len=96, embed_dim=64,
+                              depth=2, num_heads=4,
+                              mup=MuP(embedding=3.0, lm_head=0.125))
+        return m, m.init(jax.random.PRNGKey(1), np.zeros((1, 8), np.int32))
+    if name == "falcon_h1":           # recurrent, and a muP head of its own
+        return model[2], model[3]
+    family = {"latent_experts": glm, "hyper_connected": hcx}[name]
+    return family.build(family.tiny_cfg())[2:]
+
+
+def _admit_logits(m, tree, ids, lens, head_positions):
+    """The module called as an admission calls it: ``ids`` [ROWS, bucket]
+    from position 0 through each row's own pages."""
+    kw = dict(page_tokens=PT * 2, kv_pages=ROWS * TABLE + 1,
+              paged_attn="pallas")
+    at = {}
+    if m.ssm is not None:
+        kw["state_rows"] = ROWS
+        at["rows"] = jnp.arange(ROWS)
+    m = m.clone(**kw)
+    cache = init_paged_cache(m, tree, ROWS, TABLE)
+    pages = 1 + np.arange(ROWS * TABLE, dtype=np.int32).reshape(ROWS, TABLE)
+    logits, _ = jax.jit(lambda cache, hp: m.apply(
+        {**tree, "cache": cache}, jnp.asarray(ids), decode=True,
+        positions=jnp.zeros((ROWS,), jnp.int32), pages=jnp.asarray(pages),
+        seq_lens=jnp.asarray(lens), head_positions=hp, mutable=["cache"],
+        **at))(cache, head_positions)
+    return np.asarray(logits)
+
+
+@pytest.mark.parametrize("block", ["gpt2", "falcon_h1", "latent_experts",
+                                   "hyper_connected", "mup_head"])
+def test_the_head_takes_the_one_row_it_is_given(served, model,  # noqa: F811
+                                                block):
+    m, tree = _blocks(block, served, model)
+    bucket, lens = 32, np.asarray([32, 5, 19], np.int32)
+    rng = np.random.default_rng(4)
+    ids = np.zeros((ROWS, bucket), np.int32)
+    for i, n in enumerate(lens):
+        ids[i, :n] = rng.integers(1, m.vocab_size, size=n)
+    with jax.default_matmul_precision("highest"):
+        whole = _admit_logits(m, tree, ids, lens, None)
+        one = _admit_logits(m, tree, ids, lens, jnp.asarray(lens - 1))
+        # and in a plain forward (no cache), at any position
+        at = np.asarray([0, 17, 31], np.int32)
+        plain = np.asarray(m.apply(tree, jnp.asarray(ids)))
+        plain_one = np.asarray(m.apply(tree, jnp.asarray(ids),
+                                       head_positions=jnp.asarray(at)))
+    assert whole.shape == (ROWS, bucket, m.vocab_size)
+    assert one.shape == plain_one.shape == (ROWS, 1, m.vocab_size)
+    assert one.dtype == whole.dtype == np.float32
+    want = np.take_along_axis(whole, (lens - 1)[:, None, None], axis=1)
+    assert np.abs(want).max() > 1e-3      # a muP head scales, not zeroes
+    np.testing.assert_allclose(one, want, rtol=0, atol=1e-5)
+    np.testing.assert_allclose(
+        plain_one, np.take_along_axis(plain, at[:, None, None], axis=1),
+        rtol=0, atol=1e-5)
+
+
+def _abstract(args):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), args)
+
+
+def program_spy(dec):
+    """Every program the engine dispatches: its record's kind, the jitted
+    function and its arguments' shapes (a donated slab is gone after the
+    call; its shape is all a trace needs)."""
+    seen, run = [], dec._run_program
+
+    def wrapped(program, sig, fn, *args, **kw):
+        seen.append((kw["kind"], fn, _abstract(args)))
+        return run(program, sig, fn, *args, **kw)
+
+    dec._run_program = wrapped
+    return seen
+
+
+def shapes_of(jaxpr):
+    """The shape of every value a jaxpr makes, inner jaxprs included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(getattr(v.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from shapes_of(sub)
+
+
+def over_the_vocabulary(fn, args):
+    """The second-to-last sizes of the ``[*, n, vocab]`` values in the trace
+    of ``fn``: the positions its heads multiplied, a row of the batch."""
+    return {s[-2] for s in shapes_of(fn.trace(*args).jaxpr.jaxpr)
+            if len(s) >= 3 and s[-1] == VOCAB}
+
+
+def _draft(served):
+    dm = CausalTransformer(vocab_size=VOCAB, max_len=96, embed_dim=32,
+                           depth=1, num_heads=4)
+    return dict(spec="draft", draft_module=dm, spec_k=3, spec_adaptive=False,
+                draft_variables=dm.init(jax.random.PRNGKey(5),
+                                        np.zeros((1, 8), np.int32)))
+
+
+@pytest.mark.parametrize("engine", ["paged", "paged_chunked", "paged_draft",
+                                    "slot"])
+def test_no_admission_program_holds_the_buckets_logits(served, engine):
+    """Traced as the engine called them. The bucket is 16 and no other
+    size of these models is, so a ``[*, 16, vocab]`` value could only be a
+    head over the bucket; the walker is shown to find one in the module
+    called without the argument, and the verify's ``k + 1`` rows."""
+    from kubeml_tpu.serving.batcher import BatchingDecoder
+
+    m, variables = served
+    kw = {"paged": {}, "paged_chunked": dict(prefill_chunk_tokens=16),
+          "paged_draft": _draft(served)}.get(engine)
+    dec = (gpt_engine(served, **kw) if kw is not None else BatchingDecoder(
+        m, variables, slots=SLOTS, chunk_steps=1, bucket_min=BUCKET_MIN))
+    seen = program_spy(dec)
+    try:
+        serve(dec, prompts([40, 12] if engine == "paged_chunked" else [12],
+                           seed=11), 6, True)
+        tel = dec.telemetry()
+    finally:
+        dec.close()
+    prefills = [p for p in seen if p[0] in ("admit", "pchunk")]
+    assert {p[0] for p in prefills} == (
+        {"admit", "pchunk"} if engine == "paged_chunked" else {"admit"})
+    for kind, fn, args in prefills:
+        # one position a program row went through a head, no bucket
+        assert over_the_vocabulary(fn, args) == {1}, (kind, engine)
+    rows = SLOTS if engine == "slot" else 1
+    assert tel["prefill_head_positions"] == rows * len(prefills)
+    if engine == "paged_draft":
+        verifies = [over_the_vocabulary(fn, args)
+                    for kind, fn, args in seen if kind == "spec"]
+        # the verify accepts against all k + 1 rows (beside them the
+        # drafter's one-token heads and its k proposals, stacked)
+        assert verifies and all(3 + 1 in v for v in verifies)
+    # the walker sees a bucket's head where there is one
+    whole = jax.jit(lambda v, ids: m.apply(v, ids))
+    assert over_the_vocabulary(
+        whole, _abstract((variables, np.zeros((1, 16), np.int32)))) == {16}
+
+
+def test_prefill_head_positions_is_one_a_program_on_count_and_span(served):
+    """Admissions and intermediate chunks alike: the counter grows by one a
+    prefill program, and the program's ``engine.dispatch`` span says so; a
+    step's span says 0."""
+    t = tracing.get_tracer()
+    was_on = t.enabled
+    t.clear()
+    t.enabled = True
+    dec = gpt_engine(served, prefill_chunk_tokens=16)
+    calls = spy(dec)
+    try:
+        serve(dec, prompts([40, 37, 12], seed=7), 4, True)
+        tel = dec.telemetry()
+        spans = [s for s in t.spans() if s.name == "engine.dispatch"]
+    finally:
+        dec.close()
+        t.enabled = was_on
+        t.clear()
+    assert len(calls) == 3 + 2 * 2      # three admits, two chunks of two rows
+    assert tel["prefill_head_positions"] == len(calls)
+    assert tel["prefill_tokens"] + tel["prefill_pad_tokens"] == 16 * len(calls)
+    by_program = {}
+    for s in spans:
+        by_program.setdefault(s.attrs["program"], []).append(
+            s.attrs["head_positions"])
+    assert by_program["admit"] == [1] * 3 and by_program["pchunk"] == [1] * 4
+    assert set(by_program["step"]) == {0}

@@ -12,6 +12,13 @@ paged forward pass for a described v5e, in a subprocess (it loads the TPU
 plugin, which the test session must not), once for all cases, at the six
 configurations' attention shapes, and holds the count of pool-sized copies
 at zero in each (tests/arena_copies_proc.py says what it counts).
+
+The same compiles hold the admits to a head of one row (PR 46): an admit
+is compiled as the engine calls it, the sampled position handed to the
+module, GLM's and Xing's at their published vocabularies, and an array of
+``[2048, vocabulary]`` in the compiled program fails the admit's case
+(with the argument left out both programs hold a float32 fusion of
+``[1, 2048, vocabulary]``: the check was seen to find it).
 """
 
 import subprocess
@@ -20,7 +27,7 @@ from pathlib import Path
 
 import pytest
 
-from arena_copies_proc import CASES, pool_sized
+from arena_copies_proc import CASES, bucket_by_vocab, pool_sized
 
 
 @pytest.fixture(scope="module")
@@ -63,3 +70,34 @@ def test_paged_programs_hold_no_pool_sized_copy(compiled, case):
 ])
 def test_pool_sized_reads_an_hlo_line(line, want):
     assert pool_sized(line, 513 * 20 * 16 * 64) == want
+
+
+_FUSED = ("%fused_computation.20 (param_0.7: bf16[2048,154880], "
+          "param_1.7: bf16[2048]) -> bf16[154880] {\n"
+          "  %param_0.7 = bf16[2048,154880]{1,0:T(8,128)(2,1)} parameter(0)\n"
+          "  %broadcast.313 = bf16[2048,154880]{1,0} broadcast(%param_1.7), "
+          "dimensions={0}\n"
+          "  ROOT %reduce.1 = bf16[154880]{0} reduce(%multiply.2, %c), "
+          "dimensions={0}\n}\n\n"
+          "ENTRY %main.9 (p.1: bf16[2048,154880]) -> f32[1,1,154880] {\n"
+          "  %p.1 = bf16[2048,154880]{1,0:T(8,128)(2,1)} parameter(0)\n")
+
+
+@pytest.mark.parametrize("entry,want", [
+    # the head over the whole bucket, and its float32 logits
+    ("  %fusion.9 = f32[1,2048,154880]{2,1,0:T(8,128)} fusion(%x, %p.1), "
+     "kind=kOutput, calls=%fused_computation.9\n"
+     "  %convert.3 = f32[2048,154880]{1,0:T(8,128)} convert(%fusion.8)\n",
+     [("fusion", "1,2048,154880"), ("convert", "2048,154880")]),
+    # one row: the weight (GLM's is itself 2,048 wide) is a parameter, and
+    # the row broadcast against it inside a fused computation is no array
+    ("  %fusion.9 = bf16[154880]{0:T(1024)(128)(2,1)} fusion(%p.1, %row), "
+     "kind=kInput, calls=%fused_computation.20\n"
+     "  ROOT %convert.3 = f32[1,1,154880]{2,1,0:T(1,128)} convert(%b.8)\n",
+     []),
+    # a relayout of the head's weight is an array of that shape all the same
+    ("  %copy.4 = bf16[2048,154880]{0,1:T(8,128)(2,1)} copy(%p.1)\n",
+     [("copy", "2048,154880")]),
+])
+def test_bucket_by_vocab_reads_the_entry_computation(entry, want):
+    assert bucket_by_vocab(_FUSED + entry + "}\n", 2048, 154880) == want

@@ -279,6 +279,8 @@ UNPANELED = {
     "kubeml_serving_chunks_total": "denominator of per-chunk rates",
     "kubeml_serving_fetcher_utilization": "pipeline debug gauge",
     "kubeml_serving_prefill_tokens_total": "input to goodput ratio panel",
+    "kubeml_serving_prefill_head_positions_total":
+        "one a program row since PR 46; a count to test against, ad-hoc only",
     "kubeml_serving_spec_steps_total": "denominator of spec accept rate",
     "kubeml_serving_spec_accept_rate": "ratio derived on-panel from totals",
     "kubeml_serving_requests_submitted_total": "completed/failed charted",
