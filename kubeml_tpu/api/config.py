@@ -321,14 +321,6 @@ class Config:
     # prefill and the detection of completions; the slot engine runs at it.
     serving_pipeline: int = field(
         default_factory=lambda: _env_int("KUBEML_SERVING_PIPELINE", 6))
-    # concurrent result-fetch threads (each fetch pays the host<->device
-    # round trip; short-request workloads are fetch-pipeline-bound)
-    serving_fetchers: int = field(
-        default_factory=lambda: _env_int("KUBEML_SERVING_FETCHERS", 6))
-    # size decode chunks down to the earliest completion under queue
-    # pressure (measured neutral on chip; kept for drain phases)
-    serving_pressure_sizing: bool = field(
-        default_factory=lambda: _env_bool("KUBEML_SERVING_PRESSURE_SIZING", True))
     # serving overload protection: queued decode rows past this depth are
     # refused at admission with 429 + Retry-After (0 = unbounded). The
     # serving path must shed load under a burst, never queue unboundedly.

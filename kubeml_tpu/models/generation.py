@@ -45,6 +45,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .cache_spec import CacheSpec, cache_spec
 from .gpt import PAD_ID
 
 
@@ -89,7 +90,8 @@ def init_cache(module, variables, batch: int) -> dict:
                         vars_out["cache"])
 
 
-def init_paged_cache(module, variables, batch: int, table_pages: int) -> dict:
+def init_paged_cache(module, variables, batch: int, table_pages: int,
+                     cache: Optional[CacheSpec] = None) -> dict:
     """A zeroed PAGED KV-cache pytree: per-layer physical page arenas of
     token rows ``[kv_pages, page_tokens, W]`` (K‖V; a latent model's
     ``latent_pages``; the module carries ``kv_pages`` /
@@ -99,11 +101,13 @@ def init_paged_cache(module, variables, batch: int, table_pages: int) -> dict:
     :func:`init_cache`, ``variables`` may be an abstract tree (the
     quantized path sizes the arena without materializing dense weights).
     The arena shape is independent of ``batch`` — prefill programs of any
-    row count share the same cache tree."""
+    row count share the same cache tree. ``cache``: the module's
+    :func:`~kubeml_tpu.models.cache_spec.cache_spec`, from a caller that
+    holds it already."""
     dummy = jnp.zeros((batch, 1), jnp.int32)
     pos = jnp.zeros((batch,), jnp.int32)
     pages = jnp.zeros((batch, table_pages), jnp.int32)
-    ring = window_ring(module)
+    ring = (cache or cache_spec(module)).ring_pages(module.page_tokens)
     if ring:
         # window layers' arenas (``window_pages`` pages) are addressed
         # through a ring a row beside the full layers' table
@@ -139,95 +143,6 @@ def supports_paged_decode(module) -> bool:
         return False
     return all(name in params for name in (
         "pages", "seq_lens", "positions", "head_positions"))
-
-
-def has_latent_cache(module) -> bool:
-    """Whether ``module``'s paged arena holds latents (``mla`` set:
-    multi-head latent attention, one vector a token and layer, no K/V
-    heads): the serving layer then refuses what is laid out by K/V heads
-    (int8 page scales, KMS1 frames) and the slot engine's dense cache."""
-    return getattr(module, "mla", None) is not None
-
-
-def expert_layers(module) -> int:
-    """Layers of ``module`` whose feed-forward is routed experts
-    (``mlp="experts"``: all but the ``dense_layers`` leading ones;
-    ``mlp="shortcut"``: one in every double layer); 0 for
-    every other model, the training-side ``moe_every`` interleaving among
-    them (it has no paged path at all)."""
-    if getattr(module, "mlp", None) not in ("experts", "shortcut"):
-        return 0
-    return max(0, int(module.depth) - int(getattr(module, "dense_layers", 0)))
-
-
-def cache_sublayers(module) -> int:
-    """Sub-layers of ``module`` that hold a paged cache: its depth times
-    what its layers' class says a layer holds (one; two where a layer is a
-    double layer of two attentions, models/gpt.py ShortcutBlock). What
-    sizes, reads and counts caches asks this, not ``depth``. 0 when the
-    module doesn't expose its depth."""
-    depth = int(getattr(module, "depth", 0) or 0)
-    return depth * getattr(getattr(module, "layer_cls", None),
-                           "cache_sublayers", 1)
-
-
-def attention_kinds(module) -> list:
-    """``(K/V heads, K head size, V head size, window)`` of each sub-layer
-    of ``module`` that holds a paged K/V cache, in the stack's order
-    (``window`` 0: a full layer, whose row holds a page for every 
-    ``page_tokens`` positions; > 0: a window layer, whose row holds a ring,
-    models/gpt.py AttnKind). Empty when the module doesn't expose the
-    transformer geometry or pages latents."""
-    heads = getattr(module, "num_heads", None)
-    embed = getattr(module, "embed_dim", None)
-    if not heads or not embed or getattr(module, "mla", None) is not None:
-        return []
-    head_dim = int(getattr(module, "head_dim", 0) or int(embed) // int(heads))
-    v_dim = int(getattr(module, "v_head_dim", 0) or head_dim)
-    if getattr(module, "attn_kinds", ()):
-        kinds = [module.attn_kind(i) for i in range(int(module.depth))]
-        return [(int(a.num_kv_heads or heads), head_dim, v_dim, int(a.window))
-                for a in kinds]
-    kv_heads = int(getattr(module, "num_kv_heads", 0) or heads)
-    return [(kv_heads, head_dim, v_dim, 0)] * cache_sublayers(module)
-
-
-def window_layers(module) -> int:
-    """Layers of ``module`` whose attention sees a window of keys and whose
-    paged cache is a ring a row (``attn_kinds`` with ``window`` > 0): the
-    serving layer then keeps a second kind of lease and refuses what knows
-    one kind of page (prefix sharing, int8 pages, KMS1 frames, speculation,
-    the slot engine's dense cache)."""
-    return sum(1 for *_, window in attention_kinds(module) if window)
-
-
-def window_ring(module) -> int:
-    """Pages of a window layer's ring a row at the module's ``page_tokens``
-    (``ops/paged_attention.ring_pages`` of the widest window; every window
-    layer's ring is that wide, so one table serves them all); 0 without
-    window layers or before the serving layer cloned the page size in."""
-    windows = [w for *_, w in attention_kinds(module) if w]
-    pt = int(getattr(module, "page_tokens", 0) or 0)
-    if not windows or not pt:
-        return 0
-    from ..ops.paged_attention import ring_pages
-
-    return ring_pages(max(windows), pt)
-
-
-def residual_sublayers(module) -> int:
-    """Sub-layers of ``module`` whose residual path mixes several streams
-    (``hc_mult`` > 0, ops/hyper_connection.py: two a layer); 0 for the single
-    stream every other model has."""
-    return 2 * int(module.depth) if getattr(module, "hc_mult", 0) else 0
-
-
-def has_recurrent_state(module) -> bool:
-    """Whether ``module`` keeps per-row recurrent state in its cache beside
-    the attention's K/V (a Mamba-2 mixer, ``ssm`` set): the serving layer
-    then has to carry that state with a row, and refuses what moves pages
-    alone (prefix sharing, speculative rollback, KMS1 frames)."""
-    return getattr(module, "ssm", None) is not None
 
 
 def _sample(logits, rng, temperature: float, top_k: Optional[int]):

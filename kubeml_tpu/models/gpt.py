@@ -652,8 +652,8 @@ class GPTBlock(nn.Module):
     value_scale: float = 1.0
     window: int = 0
     sink: bool = False
-    # paged caches a layer of this class holds (models/generation.py
-    # cache_sublayers): one attention, one cache
+    # paged caches a layer of this class holds (models/cache_spec.py
+    # cache_spec): one attention, one cache
     cache_sublayers: ClassVar[int] = 1
 
     def _hc_maps(self, name: str, width: int):

@@ -19,9 +19,9 @@ lane rows, and XLA answered 4.5 of them with two copies of the pool a layer
 in every program (ops/mla_attention.py says what that cost). The write
 stores the pad lanes as zeros, the trash page's too, so they are zero for
 ever and a reader may multiply over them. Two figures follow: the STORED
-bytes, ``R`` a token (``serving/batcher.py _kv_page_bytes``: what the pool
-occupies), and the LIVE bytes, ``dc + dr`` (``latent_width``; ``_kv_width``,
-the KV-read counter and the benchmark's ``costs/mla_latent.py``: what
+bytes, ``R`` a token (``models/cache_spec.py CacheSpec.page_bytes``: what the
+pool occupies), and the LIVE bytes, ``dc + dr`` (``latent_width``;
+``CacheSpec.token_bytes``, the KV-read counter and the benchmark's ``costs/mla_latent.py``: what
 attention needs).
 Two forms of the one function read it:
 

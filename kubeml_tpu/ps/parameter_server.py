@@ -1431,10 +1431,9 @@ class ParameterServer:
             quantize=quantize,
             int8_matmul=self.cfg.int8_matmul,
             pipeline_depth=self.cfg.serving_pipeline,
-            fetchers=self.cfg.serving_fetchers,
-            pressure_sizing=self.cfg.serving_pressure_sizing,
             queue_limit=self.cfg.serving_queue_limit,
-            shed_policy=self.cfg.serving_shed_policy)
+            shed_policy=self.cfg.serving_shed_policy,
+            compile_storm_per_min=self.cfg.compile_storm_per_min)
         # paged engine (KUBEML_SERVING_PAGED, default on) for capable
         # models on an unmeshed device: paged KV arena + block allocator,
         # page-budget admission, shared-prefix reuse. Meshed serving and
@@ -1690,7 +1689,8 @@ class ParameterServer:
         for mid, d in decoders.items():
             try:
                 if hasattr(d, "drain"):
-                    frames = d.drain(grace)
+                    frames = d.drain(self.cfg.drain_grace if grace is None
+                                     else grace)
                 else:
                     d.retire()
                     frames = []

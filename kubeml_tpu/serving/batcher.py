@@ -44,16 +44,15 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..api.errors import KubeMLError
-from ..models.generation import (GenerationInputError, attention_kinds,
-                                 cache_sublayers,
-                                 init_cache)
+from ..models.cache_spec import PROPERTIES, CacheSpec, cache_spec
+from ..models.generation import GenerationInputError, init_cache
 from ..models.gpt import PAD_ID, block_traces
 from ..utils import tracing
 from .stats import COMPILE_PHASES
@@ -78,62 +77,78 @@ class DecoderClosed(KubeMLError):
         super().__init__("decoder is shut down", 503)
 
 
-class RecurrentStateUnsupported(KubeMLError):
-    """What the engine does not do for a model that carries recurrent state
-    beside its pages (a Mamba-2 mixer): speculative decoding rolls back
-    pages, a KMS1 frame carries pages, the slot engine prefills padded rows
-    — none of them moves the state. Refused by name, never served from a
-    state that was not rolled back or carried."""
+# What a property of a model's caches (models/cache_spec.py PROPERTIES)
+# does to a feature of the engines: "refuse" (409, CacheFeatureUnsupported)
+# or "off" (served with the feature switched off, said once in the log). A
+# pair that is not here is served. docs/design.md section 27 holds the same
+# table beside the reasons; tests/test_cache_spec.py spells every cell out.
+FEATURES = ("slot_engine", "prefix_sharing", "chunked_prefill", "int8_pages",
+            "spec_self", "spec_draft", "snapshot")
+CACHE_FEATURES = {
+    ("recurrent", "slot_engine"): "refuse",
+    ("recurrent", "prefix_sharing"): "off",
+    ("recurrent", "spec_self"): "refuse",
+    ("recurrent", "spec_draft"): "refuse",
+    ("recurrent", "snapshot"): "refuse",
+    ("latent", "slot_engine"): "refuse",
+    ("latent", "int8_pages"): "refuse",
+    ("latent", "snapshot"): "refuse",
+    **{("window", feature): "refuse" for feature in FEATURES},
+    ("experts", "spec_self"): "refuse",
+}
+# a property in a refusal's words, and why nothing that moves, shares or
+# scales pages alone covers it
+_PROPERTY_WORDS = {
+    "recurrent": ("a model with recurrent state",
+                  "only its KV pages would be moved, not the state beside "
+                  "them"),
+    "latent": ("a model with a latent KV cache",
+               "its pages hold one latent vector a token, not K and V heads"),
+    "window": ("a model with window layers",
+               "a window layer's row holds a ring of pages, not a page for "
+               "every position"),
+    "experts": ("a model with routed-expert layers",
+                "an early-exit drafter stops inside the stack, where the "
+                "layers that differ most between tokens have not run"),
+}
+_FEATURE_WORDS = {
+    "slot_engine": "the slot engine",
+    "prefix_sharing": "prefix sharing (serving_prefix_cache)",
+    "chunked_prefill": "chunked prefill (prefill_chunk_tokens)",
+    "int8_pages": "int8 page storage (kv_quant=int8)",
+    "spec_self": "speculative decoding (spec='self')",
+    "spec_draft": "speculative decoding (spec='draft')",
+    "snapshot": "taking or restoring a mid-stream snapshot",
+}
 
-    def __init__(self, what: str):
+
+class CacheFeatureUnsupported(KubeMLError):
+    """A feature of the engines that a property of the model's caches
+    refuses (:data:`CACHE_FEATURES`). Refused by name, never served wrong:
+    from a state that was not rolled back or carried, from keys a ring has
+    dropped, from pages scaled or framed by heads they do not have."""
+
+    def __init__(self, prop: str, feature: str):
+        self.property, self.feature = prop, feature
+        words, why = _PROPERTY_WORDS[prop]
         super().__init__(
-            f"{what} is not supported for a model with recurrent state: "
-            f"only its KV pages would be moved, not the state beside them",
+            f"{_FEATURE_WORDS[feature]} is not supported for {words}: {why}",
             409)
 
 
-class LatentCacheUnsupported(KubeMLError):
-    """What the engine does not do for a model whose pages hold latents
-    (multi-head latent attention: one vector a token, no heads, no V arena):
-    int8 page storage scales a page per K/V head, a KMS1 frame is laid out
-    as K and V pages, the slot engine keeps a dense per-row cache the model
-    does not have. Refused by name until each learns the latent layout."""
-
-    def __init__(self, what: str):
-        super().__init__(
-            f"{what} is not supported for a model with a latent KV cache: "
-            f"its pages hold one latent vector a token, not K and V heads",
-            409)
-
-
-class WindowLayersUnsupported(KubeMLError):
-    """What the engine does not do for a model that mixes window layers with
-    full ones (models/gpt.py AttnKind): a window layer's row holds a RING of
-    pages that it overwrites as it advances (serving/kvpool.py), so nothing
-    that knows one kind of page covers it. The prefix trie shares pages that
-    are valid for every layer and a ring holds a prefix no longer; a
-    chunked prefill, a speculative verify window and a restored snapshot
-    would continue a window layer from keys the ring may have dropped or
-    that a frame does not carry; int8 page scales and KMS1 frames are laid
-    out for one arena a layer of one table; the slot engine keeps a dense
-    cache such a layer has not. Refused by name, never served wrong."""
-
-    def __init__(self, what: str):
-        super().__init__(
-            f"{what} is not supported for a model with window layers: a "
-            f"window layer's row holds a ring of pages, not a page for "
-            f"every position", 409)
-
-
-class ExpertLayersUnsupported(KubeMLError):
-    """What the engine does not do for a model with routed-expert layers:
-    an early-exit drafter (``spec=self``) stops inside the stack, where the
-    layers that differ most between tokens have not run."""
-
-    def __init__(self, what: str):
-        super().__init__(
-            f"{what} is not supported for a model with routed-expert "
-            f"layers", 409)
+def check_cache_features(cache: CacheSpec, asked) -> dict:
+    """Hold the features ``asked`` of an engine to :data:`CACHE_FEATURES`
+    for the properties ``cache`` has: raises :class:`CacheFeatureUnsupported`
+    for the first pair the table refuses (a refusal wins over an "off"),
+    and returns ``{feature: property}`` of those it switches off."""
+    pairs = [(prop, feature) for prop in PROPERTIES
+             if prop in cache.properties
+             for feature in FEATURES if feature in asked]
+    for pair in pairs:
+        if CACHE_FEATURES.get(pair) == "refuse":
+            raise CacheFeatureUnsupported(*pair)
+    return {feature: prop for prop, feature in pairs
+            if CACHE_FEATURES.get((prop, feature)) == "off"}
 
 
 # what an expert layer leaves in the cache a decode apply, in the order of
@@ -454,141 +469,6 @@ def _chunk_cap(tokens: int, page_tokens: int) -> int:
     return cap
 
 
-def _kv_token_bytes(module, layers: Optional[int] = None) -> int:
-    """HBM bytes attention reads per CACHED TOKEN per forward pass: every
-    layer reads the token's K and V rows once — or, under latent attention,
-    its one latent vector once (``_kv_copies``). The KV-read accounting
-    (kubeml_serving_kv_read_bytes_total) multiplies this by the
-    host-modeled gathered-token count per dispatch — a geometry model of
-    the device's read traffic, not a hardware counter. 0 when the module
-    doesn't expose the transformer geometry (accounting is skipped)."""
-    import jax.numpy as jnp
-
-    depth = layers if layers is not None else cache_sublayers(module)
-    width = _kv_width(module)
-    if not depth or not width:
-        return 0
-    itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
-    if getattr(module, "attn_kinds", ()):
-        # attention that differs by layer: a cached token's K and V in the
-        # FULL layers, by each one's own heads and widths (a window layer
-        # reads its window, whatever the depth: _window_token_bytes)
-        return _kinds_sum(module, False, _live_values,
-                          layers=depth) * int(itemsize)
-    # the accounting models STORAGE bytes: an int8-quantized arena
-    # (KUBEML_KV_QUANT, the module carries the resolved mode as a clone
-    # field) reads one byte per cached element — the halving/quartering
-    # must be visible on kubeml_serving_kv_read_bytes_total per caller.
-    # The per-page scale reads (heads x 4B per page per layer) are noise
-    # against page_tokens x embed element reads and stay unmodeled.
-    from ..ops.paged_attention import resolve_kv_quant
-
-    if resolve_kv_quant(getattr(module, "kv_quant", "off")) == "int8":
-        itemsize = 1
-    return int(depth) * _kv_copies(module) * width * int(itemsize)
-
-
-def _kinds_sum(module, windowed: bool, width, layers=None) -> int:
-    """``width(K/V heads, K head size, V head size)`` added up over the
-    module's window layers (``windowed``) or its full ones, of its first
-    ``layers`` layers."""
-    return sum(width(hkv, dk, dv) for hkv, dk, dv, window
-               in attention_kinds(module)[:layers]
-               if bool(window) == windowed)
-
-
-def _live_values(kv_heads: int, k_dim: int, v_dim: int) -> int:
-    """Values a token's K and V hold in one layer."""
-    return kv_heads * (k_dim + v_dim)
-
-
-def _window_token_bytes(module) -> int:
-    """HBM bytes the WINDOW layers read per key a query sees, all of them:
-    K and V by each one's own heads and widths. A step reads at most
-    ``window`` keys a row in such a layer, whatever the row's depth."""
-    import jax.numpy as jnp
-
-    itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
-    return _kinds_sum(module, True, _live_values) * int(itemsize)
-
-
-def _kv_copies(module) -> int:
-    """Arenas a layer keeps: K and V, or the one latent arena both are made
-    from (``models/mla.py``)."""
-    return 1 if getattr(module, "mla", None) is not None else 2
-
-
-def _kv_width(module) -> int:
-    """Elements of K (or of V) one token holds in one layer: the K/V heads
-    (``num_kv_heads``, under grouped-query attention fewer than the query
-    heads) times the head size; under latent attention the latent's width
-    (compressed K/V + the shared rope key), whatever the heads. 0 when the
-    module doesn't expose the transformer geometry."""
-    mla = getattr(module, "mla", None)
-    if mla is not None:
-        return int(mla.latent_width)
-    kv_heads, head_dim = _kv_head_shape(module)
-    return kv_heads * head_dim
-
-
-def _kv_head_shape(module) -> Tuple[int, int]:
-    """``(K/V heads, head size)`` of one layer's attention; ``(0, 0)`` when
-    the module doesn't expose the transformer geometry."""
-    heads = getattr(module, "num_heads", None)
-    embed = getattr(module, "embed_dim", None)
-    if not heads or not embed:
-        return 0, 0
-    return (int(getattr(module, "num_kv_heads", 0) or heads),
-            int(getattr(module, "head_dim", 0) or int(embed) // int(heads)))
-
-
-def _kv_page_bytes(module, page_tokens: int, kv_quant: str = "off") -> int:
-    """HBM bytes ONE physical page occupies across every layer's arena —
-    the unit of the arena byte budget: ``page_tokens`` rows of K‖V as the
-    arena stores them (``ops/paged_attention.kv_row_width``: zero lanes
-    past a narrow model's K and V count, the published models have none),
-    or of the latent arena's stored rows (``MLAConfig.row_width``: 640
-    lanes hold the published 576; ``_kv_width`` is the live values, which
-    the read accounting counts). int8 mode adds the
-    page's per-head f32 scale rows (k_scale/v_scale, [kv_pages, H]) so the
-    capacity derivation charges quantization's real overhead. 0 when the
-    module doesn't expose the transformer geometry."""
-    import jax.numpy as jnp
-
-    from ..ops.paged_attention import kv_row_width
-
-    depth = cache_sublayers(module)
-    kv_heads, head_dim = _kv_head_shape(module)
-    mla = getattr(module, "mla", None)
-    if getattr(module, "attn_kinds", ()) and kv_quant != "int8":
-        # attention that differs by layer: the FULL layers' arenas, each
-        # row by its own heads and widths (the window layers' arenas are
-        # rings a row, sized by _window_page_bytes)
-        itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
-        return (_kinds_sum(module, False, kv_row_width) * int(page_tokens)
-                * int(itemsize))
-    row = (int(mla.row_width) if mla is not None
-           else kv_row_width(kv_heads, head_dim))
-    if not depth or not row:
-        return 0
-    if kv_quant == "int8":
-        return int(depth) * (int(page_tokens) * row + 2 * kv_heads * 4)
-    itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
-    return int(depth) * int(page_tokens) * row * int(itemsize)
-
-
-def _window_page_bytes(module, page_tokens: int) -> int:
-    """HBM bytes ONE page of a ring occupies across the window layers'
-    arenas (``_kv_page_bytes`` is the full layers')."""
-    import jax.numpy as jnp
-
-    from ..ops.paged_attention import kv_row_width
-
-    itemsize = jnp.dtype(getattr(module, "dtype", jnp.float32)).itemsize
-    return (_kinds_sum(module, True, kv_row_width) * int(page_tokens)
-            * int(itemsize))
-
-
 def service_interval(dispatched: float, done: float,
                      prev_done: float) -> tuple:
     """``(svc_s, wait_s, done)`` of one program, taken where it completes.
@@ -712,52 +592,28 @@ class BatchingDecoder:
     chip. One background thread owns the device loop.
     """
 
-    # whether this engine carries a recurrent model's per-row state through
-    # admission, chunked prefill and the decode step (the paged engine does)
-    _recurrent_ok = False
-    # whether this engine's arena can hold latent pages (one vector a token,
-    # models/mla.py): the paged engine's can, a slot cache is per-head K/V
-    _latent_ok = False
-    # whether this engine leases a window layer's ring beside the full
-    # layers' pages (serving/kvpool.py): the paged engine does
-    _window_ok = False
-
+    # the options' defaults are api/config.py Config's; the parameter server
+    # reads the process config and hands every one of them over
+    # (ps/parameter_server.py _new_decoder)
     def __init__(self, module, variables, *, slots: int = DEFAULT_SLOTS,
                  chunk_steps: int = 8, bucket_min: int = 16,
-                 pipeline_depth: Optional[int] = None, name: str = "decoder",
-                 mesh=None, quantize: str = "",
-                 int8_matmul: Optional[bool] = None,
-                 fetchers: Optional[int] = None,
-                 pressure_sizing: Optional[bool] = None,
-                 queue_limit: Optional[int] = None,
-                 shed_policy: Optional[str] = None):
+                 pipeline_depth: int = 6, name: str = "decoder",
+                 mesh=None, quantize: str = "", int8_matmul: bool = False,
+                 fetchers: int = 6, queue_limit: int = 256,
+                 shed_policy: str = "reject",
+                 compile_storm_per_min: float = 6.0,
+                 cache: Optional[CacheSpec] = None):
         cap = getattr(module, "max_len", None)
         if cap is None:
             raise GenerationInputError(
                 "model exposes no max_len attribute; batched decode requires "
                 "a declared KV-cache capacity")
-        from ..models.generation import has_recurrent_state
-
-        # a model with recurrent state beside its K/V (a Mamba-2 mixer):
-        # only the paged engine carries a row's state (_recurrent_ok)
-        self._recurrent = has_recurrent_state(module)
-        if self._recurrent and not self._recurrent_ok:
-            raise RecurrentStateUnsupported("the slot engine")
-        from ..models.generation import expert_layers, has_latent_cache
-
-        self._latent = has_latent_cache(module)
-        if self._latent and not self._latent_ok:
-            raise LatentCacheUnsupported("the slot engine")
-        from ..models.generation import window_layers
-
-        self._window_layers = window_layers(module)
-        if self._window_layers and not self._window_ok:
-            raise WindowLayersUnsupported("the slot engine")
-        # routed-expert layers in the stack: a decode step hands back how
-        # many experts its live rows chose beside its tokens (_step_impl)
-        self._moe_layers = expert_layers(module)
-        self._moe_top_k = (module.experts.num_experts_per_tok
-                           if self._moe_layers else 0)
+        # what the model's caches are, asked once; the paged engine has
+        # asked already (it sizes its arenas first) and hands the answer in
+        if cache is None:
+            cache = cache_spec(module)
+            check_cache_features(cache, {"slot_engine"})
+        self.cache = cache
         self.module = module
         self.max_len = int(cap)
         self.slots = int(slots)
@@ -768,9 +624,8 @@ class BatchingDecoder:
         from .stats import DecoderStats
 
         self.stats = DecoderStats(slots)
-        from ..models.generation import residual_sublayers
-
-        self.stats.hc_sublayers = residual_sublayers(module)
+        self.stats.hc_sublayers = cache.residual_sublayers
+        self.stats.window_layers = cache.window_layers
         # request-id mint: unique across decoder rebuilds of the same model
         # (the per-boot nonce), monotonic within one decoder — the handle
         # `kubeml trace <request-id>` looks serving span trees up by
@@ -793,36 +648,27 @@ class BatchingDecoder:
         # detection, burns dead steps on long requests and stands between
         # a new request's prefill and the device, so the paged engine
         # takes this as the ceiling of run_ahead_depth; the slot engine
-        # runs at it. Defaults live in Config. Explicit args win; None
-        # falls back to the process config.
-        from ..api.config import get_config
-
-        cfg = get_config()
-        self.pipeline_depth = int(pipeline_depth if pipeline_depth is not None
-                                  else cfg.serving_pipeline)
-        self.fetchers = int(fetchers if fetchers is not None
-                            else cfg.serving_fetchers)
+        # runs at it.
+        self.pipeline_depth = int(pipeline_depth)
+        # concurrent result-fetch threads (each fetch pays the host<->device
+        # round trip; short-request workloads are fetch-pipeline-bound)
+        self.fetchers = int(fetchers)
         self.stats.fetchers_total = self.fetchers
         # compile-storm threshold (compiles/min; 0 disables the warning):
         # sustained compiles in steady state mean shape churn — the PR-15
         # regression this knob exists to surface
-        self.stats.compile_storm_per_min = float(cfg.compile_storm_per_min)
+        self.stats.compile_storm_per_min = float(compile_storm_per_min)
         # admissions dispatched but not yet processed (engine thread only):
         # nonzero while a chunk dispatch shares the device with prefill
         # work — the chunk's decode steps are tagged cause=prefill_colocated
         self._admits_inflight = 0
-        self.pressure_sizing = bool(
-            pressure_sizing if pressure_sizing is not None
-            else cfg.serving_pressure_sizing)
         # overload protection: queued rows past queue_limit are refused at
         # admission with 429 + Retry-After (0 = unbounded); shed_policy
         # "oldest" instead sheds the longest-queued request to admit the new
         # one — under sustained overload the queue must bound WAIT, not just
         # depth (an unbounded queue serves nobody within their deadline)
-        self.queue_limit = int(queue_limit if queue_limit is not None
-                               else cfg.serving_queue_limit)
-        self.shed_policy = str(shed_policy if shed_policy is not None
-                               else cfg.serving_shed_policy)
+        self.queue_limit = int(queue_limit)
+        self.shed_policy = str(shed_policy)
         self.name = name
         # weight-only int8 (serving/quant.py): halves the per-step weight
         # HBM traffic and footprint; the dequantize is traced inside the
@@ -850,8 +696,7 @@ class BatchingDecoder:
         # to be quant-aware: the CausalTransformer family is; MoE expert
         # stacks (3-d einsum params) are not, so they keep the dequantize
         # path.
-        self.int8_matmul = (quantize == "int8") and bool(
-            int8_matmul if int8_matmul is not None else cfg.int8_matmul)
+        self.int8_matmul = (quantize == "int8") and bool(int8_matmul)
         if self.int8_matmul and getattr(module, "moe_every", 0):
             log.warning(
                 "%s: KUBEML_INT8_MATMUL does not cover MoE expert params; "
@@ -910,7 +755,7 @@ class BatchingDecoder:
         # reads per cached token per forward pass. The dense slab engine
         # reads its full [S, max_len] stripes every step; the paged engine
         # overrides per dispatch with the table geometry actually shipped.
-        self._kv_token_bytes = _kv_token_bytes(module)
+        self._kv_token_bytes = cache.token_bytes()
         self._pending: deque = deque()
         self._slot_rows: List[Optional[_Row]] = [None] * self.slots
         # rows whose slot was pre-freed but whose results are still in
@@ -1002,7 +847,7 @@ class BatchingDecoder:
     def _apply_step(self, variables, cache, tok, pos, pages=None, live=None):
         variables = self._dense_vars(variables)
         kw = {} if pages is None else {"pages": pages}
-        if self._recurrent or self._moe_layers:
+        if self.cache.recurrent or self.cache.expert_layers:
             # a row that is not live (retired, or mid-chunked-prefill) must
             # keep its recurrent state: a step is one position, and a
             # sequence length of 0 leaves state and convolution tail alone
@@ -1832,7 +1677,7 @@ class BatchingDecoder:
                 tracer.add_span("engine.admit", began, tracer.at(t0) - began,
                                 rows=len(group), requests=requests,
                                 state_rows=state_rows,
-                                moe_layers=self._moe_layers)
+                                moe_layers=self.cache.expert_layers)
             annotation = jax.profiler.TraceAnnotation("engine.dispatch",
                                                       seq=seq)
         # ONE call site, traced or not: a program's source locations are
@@ -1850,7 +1695,7 @@ class BatchingDecoder:
             self._engine_span(
                 "engine.dispatch", tracer.at(t0), t1 - t0, requests, seq=seq,
                 program=kind, steps=steps, width=width, cold=cold,
-                state_rows=state_rows, moe_layers=self._moe_layers,
+                state_rows=state_rows, moe_layers=self.cache.expert_layers,
                 head_positions=head_positions,
                 rows_live=sum(r is not None for r in self._slot_rows),
                 depth=self._depth, ahead=len(self._inflight), **first)
@@ -1977,8 +1822,7 @@ class BatchingDecoder:
                 size = t
         with self._cond:
             pressure = bool(self._pending)
-        if (self.pressure_sizing and pressure
-                and len(self._chunk_sizes) > 1):
+        if pressure and len(self._chunk_sizes) > 1:
             soonest = min((n for n in self._remaining_steps() if n > 0),
                           default=needed)
             for t in self._chunk_sizes:  # smallest size covering `soonest`
@@ -2044,7 +1888,7 @@ class BatchingDecoder:
                     self._complete_row(slot, row)
             return tokens
         _, packed, snapshot, kv_bytes, cold, coloc = rec
-        if self._moe_layers:
+        if self.cache.expert_layers:
             # the block's last columns are each step's counts (_step_impl):
             # the experts its live rows chose, their assignments to held and
             # to identity experts; all assignments follow from the rows that
@@ -2052,8 +1896,8 @@ class BatchingDecoder:
             cols = len(_MOE_COUNTS)
             packed, counts = packed[:, :-cols], packed[:, -cols:].sum(axis=0)
             touched, held, zero = (int(n) for n in counts)
-            made = (int((packed >= 0).sum()) * self._moe_top_k
-                    * self._moe_layers)
+            made = (int((packed >= 0).sum()) * self.cache.experts_per_token
+                    * self.cache.expert_layers)
             self.stats.moe_steps(held, touched, zero, made - held - zero)
         # decode-step histogram feed: the chunk's service time over its
         # steps is the per-step decode latency, and kv_bytes over it the
@@ -2327,88 +2171,94 @@ class PagedBatchingDecoder(BatchingDecoder):
     stays on the dense engine until the arena learns a head-sharded layout.
 
     **Recurrent state beside the pages** — a model with a Mamba-2 mixer
-    (``models.generation.has_recurrent_state``) keeps, per layer, one
+    (``CacheSpec.recurrent``) keeps, per layer, one
     fixed-size state per program row (``ssm_state`` ``[slots, H, N, P]``
     float32 and ``conv_tail``) in the same ``cache`` tree as the arena:
     donated, rebuilt and freed with it. An admission names its row's place
     (``rows``) and the model starts it from zeros (a reused slot), or from
     the row's own state where a chunked prefill continues, and writes the
     state at the prompt's true length; the decode step advances the state
-    of live rows only, in place (ops/ssm.py ``ssm_update``). Prefix sharing
-    is off for such a model (shared pages have no state to go with them),
-    and speculation and KMS1 snapshot / restore are refused by name
-    (:class:`RecurrentStateUnsupported`).
+    of live rows only, in place (ops/ssm.py ``ssm_update``).
 
     **Two kinds of lease** — a model that mixes window layers with full
-    ones (``models.generation.window_layers``) has two arenas a kind: the
+    ones (``CacheSpec.window_layers``) has two arenas a kind: the
     full layers' pages, addressed through ``_table`` as above, and the
     window layers' RINGS, ``window / page_tokens + 2`` pages a row whatever
     its depth (``_wtable`` ``[slots, ring]``; serving/kvpool.py says why a
     ring). Every program takes both tables (``pages = (table, rings)``,
     models/gpt.py), an admit writes only the tail of its bucket into the
     ring, and the window arenas are ``slots`` rings whatever ``max_len``.
-    Refused by name for such a model (:class:`WindowLayersUnsupported`):
-    prefix sharing, chunked prefill, int8 pages, speculation, KMS1 snapshot
-    / restore, and the slot engine.
+
+    What a model's caches refuse or switch off of the options below is one
+    table, :data:`CACHE_FEATURES`, read once here (and at a snapshot).
     """
 
-    _recurrent_ok = True
-    _latent_ok = True
-    _window_ok = True
-
-    def __init__(self, module, variables, *, page_tokens: Optional[int] = None,
-                 pages: Optional[int] = None,
-                 prefix_cache: Optional[bool] = None, mesh=None,
-                 spec: str = "", spec_k: Optional[int] = None,
-                 spec_adaptive: Optional[bool] = None,
+    # the options' defaults are api/config.py Config's, as the slot engine's
+    def __init__(self, module, variables, *, page_tokens: int = 16,
+                 pages: int = 0, prefix_cache: bool = True, mesh=None,
+                 spec: str = "", spec_k: int = 4, spec_adaptive: bool = True,
                  draft_module=None, draft_variables=None,
                  spec_exit_layer: Optional[int] = None,
-                 paged_attn: Optional[str] = None,
-                 kv_quant: Optional[str] = None,
-                 spec_min_accept: Optional[float] = None,
-                 prefill_chunk_tokens: Optional[int] = None,
-                 pool_audit_interval: Optional[float] = None, **kw):
+                 paged_attn: str = "auto", kv_quant: str = "off",
+                 spec_min_accept: float = 0.10,
+                 prefill_chunk_tokens: int = 0,
+                 pool_audit_interval: float = 0.0, **kw):
         if mesh is not None:
             raise ValueError(
                 "paged serving does not run on a mesh yet; use the dense "
                 "BatchingDecoder for sharded serving")
-        from ..models.generation import (expert_layers, has_latent_cache,
-                                         has_recurrent_state,
-                                         supports_paged_decode, window_layers)
+        from ..models.generation import supports_paged_decode
 
-        windowed = window_layers(module) > 0
-        if windowed and spec not in ("", "off", None):
-            raise WindowLayersUnsupported(
-                f"speculative decoding (spec={spec!r})")
         if not supports_paged_decode(module):
             raise GenerationInputError(
                 "module has no paged decode path (pages/seq_lens decode "
                 "kwargs + page_tokens/kv_pages fields); serve it through "
                 "the dense BatchingDecoder")
-        recurrent = has_recurrent_state(module)
-        if recurrent and spec not in ("", "off", None):
-            raise RecurrentStateUnsupported(
-                f"speculative decoding (spec={spec!r})")
-        if expert_layers(module) and spec == "self":
-            raise ExpertLayersUnsupported(
-                "early-exit self-drafting (spec='self')")
         cap = getattr(module, "max_len", None)
         if cap is None:
             raise GenerationInputError(
                 "model exposes no max_len attribute; batched decode requires "
                 "a declared KV-cache capacity")
-        from ..api.config import get_config
-
+        from ..ops.paged_attention import resolve_kv_quant, resolve_paged_attn
         from .kvpool import KVPool
 
-        cfg = get_config()
-        pt = int(page_tokens if page_tokens is not None
-                 else cfg.serving_page_tokens)
+        # --- speculative decoding (KUBEML_SERVING_SPEC=draft|self|off) ---
+        if spec in ("off", None):
+            spec = ""
+        if spec not in ("", "draft", "self"):
+            raise ValueError(f"unknown spec mode {spec!r} "
+                             f"(valid: 'off', 'draft', 'self')")
+        self.spec = spec
+        pt = int(page_tokens)
         slots = int(kw.get("slots", DEFAULT_SLOTS))
         self.page_tokens = pt
+        kvq = resolve_kv_quant(kv_quant)
+        self.kv_quant = kvq
+        # --- chunked prefill (KUBEML_PREFILL_CHUNK_TOKENS, ISSUE 19):
+        # a cold prompt whose unshared suffix exceeds the cap advances one
+        # page-aligned chunk per engine-loop iteration through the same
+        # suffix-prefill program, interleaved with decode chunks, instead
+        # of one monolithic prefill stalling every decoding row. 0 = off.
+        self.prefill_chunk = _chunk_cap(int(prefill_chunk_tokens), pt)
+        # what the model's caches are, asked once, and what they make of
+        # the options handed in: a refusal by name, or a feature off
+        cache = cache_spec(module)
+        asked = {feature for feature, on in (
+            ("prefix_sharing", prefix_cache), ("int8_pages", kvq == "int8"),
+            ("chunked_prefill", self.prefill_chunk),
+            ("spec_self", spec == "self"), ("spec_draft", spec == "draft"),
+        ) if on}
+        self._features_off = check_cache_features(cache, asked)
+        for feature, prop in self._features_off.items():
+            # served without it: said once here, flagged in telemetry()
+            log.warning("%s: %s is off for %s: %s",
+                        kw.get("name", "decoder"), _FEATURE_WORDS[feature],
+                        *_PROPERTY_WORDS[prop])
+        use_trie = bool(prefix_cache) and (
+            "prefix_sharing" not in self._features_off)
         # per-row logical table width: enough pages to address max_len
         self.table_pages = -(-int(cap) // pt)
-        npages = int(pages if pages is not None else cfg.serving_pages)
+        npages = int(pages)
         if npages <= 0:
             # default arena matches the slot engine's worst case (every
             # program row at full depth) plus the reserved trash page —
@@ -2420,49 +2270,17 @@ class PagedBatchingDecoder(BatchingDecoder):
         # page count FROM THE BYTE BUDGET the unquantized arena would
         # occupy, so int8 mode yields ~2x (bf16) / ~4x (f32) the pages at
         # the same HBM spend — capacity, not memory, is the win surfaced.
-        # Modules predating the kv_quant clone field stay unquantized.
-        from ..ops.paged_attention import resolve_kv_quant
-
-        kvq = resolve_kv_quant(kv_quant if kv_quant is not None
-                               else cfg.kv_quant)
-        if not hasattr(module, "kv_quant"):
-            kvq = "off"
-        if kvq == "int8" and has_latent_cache(module):
-            raise LatentCacheUnsupported("int8 page storage (kv_quant=int8)")
-        if kvq == "int8" and windowed:
-            raise WindowLayersUnsupported("int8 page storage (kv_quant=int8)")
-        self.kv_quant = kvq
         if kvq == "int8":
-            bytes_off = _kv_page_bytes(module, pt, "off")
-            bytes_q = _kv_page_bytes(module, pt, "int8")
+            bytes_off = cache.page_bytes(pt, "off")
+            bytes_q = cache.page_bytes(pt, "int8")
             if bytes_off and bytes_q:
                 budget = (npages - 1) * bytes_off
                 npages = max(npages, budget // bytes_q + 1)
-        use_trie = bool(prefix_cache if prefix_cache is not None
-                        else cfg.serving_prefix_cache)
-        # a prefix hit hands a row the PAGES of a shared prefix; the
-        # recurrent state after that prefix is nowhere, so for such a model
-        # sharing is wrong, not slow: off, said once, flagged in telemetry
-        self._prefix_off_recurrent = bool(recurrent and use_trie)
-        if self._prefix_off_recurrent:
-            log.warning(
-                "%s: prefix sharing is off: the model has recurrent state "
-                "and the prefix trie holds pages only",
-                kw.get("name", "decoder"))
-            use_trie = False
-        if windowed and use_trie:
-            raise WindowLayersUnsupported(
-                "prefix sharing (serving_prefix_cache)")
         # the second kind of lease: a ring a program row in the window
         # layers' arenas, whatever max_len (0 / 0 without window layers)
-        from ..ops.paged_attention import ring_pages
-
-        # (every window layer's ring is as wide as the widest window's)
-        self._window = max((w for *_, w in attention_kinds(module)),
-                           default=0)
-        self.window_ring = ring_pages(self._window, pt) if windowed else 0
+        self.window_ring = cache.ring_pages(pt)
         self.window_arena_pages = (slots * self.window_ring + 1
-                                   if windowed else 0)
+                                   if self.window_ring else 0)
         self._pool = KVPool(npages, pt, prefix_cache=use_trie,
                             window_pages=self.window_arena_pages,
                             window_ring=self.window_ring)
@@ -2471,23 +2289,10 @@ class PagedBatchingDecoder(BatchingDecoder):
         # gather, ops/paged_attention.py): resolved HERE and cloned onto
         # the module, so the impl is part of the module identity every jit
         # trace sees — toggling the knob builds a fresh decoder with fresh
-        # programs, never a stale one. Modules predating the field keep
-        # the gather path.
-        from ..ops.paged_attention import resolve_paged_attn
-
-        impl = resolve_paged_attn(paged_attn if paged_attn is not None
-                                  else cfg.paged_attn)
-        if not hasattr(module, "paged_attn"):
-            impl = "gather"
+        # programs, never a stale one.
+        impl = resolve_paged_attn(paged_attn)
         self.paged_attn = impl
-        # --- speculative decoding (KUBEML_SERVING_SPEC=draft|self|off) ---
-        if spec in ("off", None):
-            spec = ""
-        if spec not in ("", "draft", "self"):
-            raise ValueError(f"unknown spec mode {spec!r} "
-                             f"(valid: 'off', 'draft', 'self')")
-        self.spec = spec
-        k_cap = int(spec_k if spec_k is not None else cfg.spec_k)
+        k_cap = int(spec_k)
         self.spec_exit_layer = 0
         self.draft_module = None
         self._draft_variables = None
@@ -2514,12 +2319,9 @@ class PagedBatchingDecoder(BatchingDecoder):
             # (and reads it through the same attention impl + storage mode
             # — the doubled page count must not double the draft arena's
             # bytes)
-            dkw = ({"paged_attn": impl}
-                   if hasattr(draft_module, "paged_attn") else {})
-            if hasattr(draft_module, "kv_quant"):
-                dkw["kv_quant"] = kvq
-            self.draft_module = draft_module.clone(page_tokens=pt,
-                                                   kv_pages=npages, **dkw)
+            self.draft_module = draft_module.clone(
+                page_tokens=pt, kv_pages=npages, paged_attn=impl,
+                kv_quant=kvq)
         elif spec == "self":
             depth = getattr(module, "depth", None)
             e = int(spec_exit_layer if spec_exit_layer
@@ -2536,14 +2338,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         # sustained acceptance sits below KUBEML_SPEC_MIN_ACCEPT instead
         # disables permanently (spec.py) — a mismatched draft checkpoint
         # degrades to plain decode, not a latent throughput regression.
-        min_acc = float(spec_min_accept if spec_min_accept is not None
-                        else cfg.spec_min_accept)
         self._spec_ctl = (AdaptiveK(
-            k_cap,
-            adaptive=bool(spec_adaptive if spec_adaptive is not None
-                          else cfg.spec_adaptive),
-            allow_off=(spec == "self"),
-            min_accept=(min_acc if spec == "draft" else 0.0))
+            k_cap, adaptive=bool(spec_adaptive), allow_off=(spec == "self"),
+            min_accept=(float(spec_min_accept) if spec == "draft" else 0.0))
             if spec else None)
         self._spec_disabled_logged = False
         # worst-case page reservation must cover the verify lookahead: a
@@ -2552,34 +2349,32 @@ class PagedBatchingDecoder(BatchingDecoder):
         self._spec_lookahead = k_cap if spec else 0
         # the arena dims ride the module as clone fields so the flax cache
         # variables know their shapes (params are untouched by the clone)
-        clone_kw = dict(page_tokens=pt, kv_pages=npages)
-        if hasattr(module, "paged_attn"):
-            clone_kw["paged_attn"] = impl
-        if hasattr(module, "kv_quant"):
-            clone_kw["kv_quant"] = kvq
-        if recurrent:
+        clone_kw = dict(page_tokens=pt, kv_pages=npages, paged_attn=impl,
+                        kv_quant=kvq)
+        if cache.recurrent:
             # one recurrent state per program row, beside the arena
             clone_kw["state_rows"] = slots
-        if windowed:
+        if self.window_ring:
             clone_kw["window_pages"] = self.window_arena_pages
         module = module.clone(**clone_kw)
-        super().__init__(module, variables, mesh=None, **kw)
-        self.stats.window_layers = self._window_layers
-        self._window_token_bytes = _window_token_bytes(module)
+        super().__init__(module, variables, mesh=None, cache=cache, **kw)
+        self._kv_token_bytes = cache.token_bytes(kvq)
+        self._window_token_bytes = cache.window_token_bytes()
         # a decode step's K/V page walk takes the kernel's decode body (one
         # query a row, the arena in the compute type): its grid is counted
         # at each chunk dispatch (_walk_chunks), the tile body's at each
         # prefill dispatch (_run_prefill)
         self.stats.walks_kv_chunks = (
-            impl == "pallas" and kvq == "off" and not self._latent)
+            impl == "pallas" and kvq == "off" and cache.latent is None)
         # drafter KV-read constant for the spec accounting: the early-exit
         # self-drafter reads only its truncated stack's layers; a separate
         # draft model reads its own geometry
         if spec == "self":
-            self._kv_draft_token_bytes = _kv_token_bytes(
-                module, layers=self.spec_exit_layer)
+            self._kv_draft_token_bytes = cache.token_bytes(
+                kvq, first=self.spec_exit_layer)
         elif spec == "draft":
-            self._kv_draft_token_bytes = _kv_token_bytes(self.draft_module)
+            self._kv_draft_token_bytes = cache_spec(
+                self.draft_module).token_bytes(kvq)
         else:
             self._kv_draft_token_bytes = 0
         if spec == "draft":
@@ -2634,17 +2429,6 @@ class PagedBatchingDecoder(BatchingDecoder):
         self._table = np.zeros((self.slots, self.table_pages), np.int32)
         # the window layers' rings, a row each (zeroed like _table's rows)
         self._wtable = np.zeros((self.slots, self.window_ring), np.int32)
-        # --- chunked prefill (KUBEML_PREFILL_CHUNK_TOKENS, ISSUE 19):
-        # a cold prompt whose unshared suffix exceeds the cap advances one
-        # page-aligned chunk per engine-loop iteration through the same
-        # suffix-prefill program, interleaved with decode chunks, instead
-        # of one monolithic prefill stalling every decoding row. 0 = off.
-        self.prefill_chunk = _chunk_cap(
-            int(prefill_chunk_tokens if prefill_chunk_tokens is not None
-                else cfg.prefill_chunk_tokens), pt)
-        if self._window_layers and self.prefill_chunk:
-            raise WindowLayersUnsupported(
-                "chunked prefill (prefill_chunk_tokens)")
         # rows mid-prefill: (slot, row) pairs holding program rows + leases
         # whose prompts still have undispatched chunks; the turn flag
         # alternates the last pipeline slot between a prefill chunk and a
@@ -2656,9 +2440,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         # seconds under the engine lock; a tripped invariant fires the
         # errorhook and routes through fault recovery (snapshot-and-replay)
         # instead of decoding through silent accounting corruption. 0 = off
-        self.pool_audit_interval = float(
-            pool_audit_interval if pool_audit_interval is not None
-            else cfg.pool_audit_interval)
+        self.pool_audit_interval = float(pool_audit_interval)
         self._next_audit = 0.0
         # graceful-drain rendezvous: drain() posts a _DrainReq; the engine
         # thread quiesces the dispatch chain, snapshots stragglers, and
@@ -2692,7 +2474,8 @@ class PagedBatchingDecoder(BatchingDecoder):
 
         dense_abstract = jax.eval_shape(self._dense_vars, self._variables)
         return self._slab_from_cache(init_paged_cache(
-            self.module, dense_abstract, self.slots, self.table_pages))
+            self.module, dense_abstract, self.slots, self.table_pages,
+            cache=self.cache))
 
     def _init_slab(self) -> _Slab:
         slab = super()._init_slab()
@@ -2727,7 +2510,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         # a recurrent model scatters the row's state into its program row
         # (zeros first where base is 0: a reused slot; the row's own state
         # where a chunked prefill goes on)
-        kw = {"rows": rowids} if self._recurrent else {}
+        kw = {"rows": rowids} if self.cache.recurrent else {}
         # the one position sampled from, the suffix's last real token, is
         # all the head is given: logits [1, 1, vocab], not the bucket's
         logits, vs = self.module.apply(
@@ -3048,7 +2831,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         ptbl = np.zeros((wa,), np.int32)
         pgs = row.lease.pages[:wa]
         ptbl[:len(pgs)] = pgs
-        if self._window_layers:
+        if self.window_ring:
             # both tables: the full layers' pages and the row's ring
             ptbl = (ptbl, np.asarray(row.lease.window, np.int32))
         if kind == "admit":
@@ -3060,7 +2843,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         # keys: each gets its one row here
         i32 = np.int32
         one = lambda value, dtype: jnp.asarray(np.asarray(value, dtype)[None])
-        args = (tuple(one(t, i32) for t in ptbl) if self._window_layers
+        args = (tuple(one(t, i32) for t in ptbl) if self.window_ring
                 else one(ptbl, i32),) + tuple(
             one(value, dtype) for value, dtype in (
                 (suffix, i32), (pre, i32), (take, i32), (slot, i32),
@@ -3068,7 +2851,8 @@ class PagedBatchingDecoder(BatchingDecoder):
                 (key, np.uint32)))
         span = dict(kind=kind, width=wa,
                     group=[(slot, row)] if kind == "admit" else None,
-                    state_rows=1 if self._recurrent else 0, head_positions=1)
+                    state_rows=1 if self.cache.recurrent else 0,
+                    head_positions=1)
         # the prefill program is keyed (suffix bucket, table width) — both
         # are compile shapes on the paged engine. A draft backend's program
         # prefills the drafter's arena through the same one-row arguments
@@ -3091,19 +2875,18 @@ class PagedBatchingDecoder(BatchingDecoder):
             # row's cursor, every attention layer
             from ..ops.paged_attention import tile_chunks
 
-            itemsize = jnp.dtype(
-                getattr(self.module, "dtype", jnp.float32)).itemsize
-            if self._window_layers:
+            if self.window_ring:
                 # a window layer walks the bucket's own pages, and a layer
                 # of either kind a K/V head a program where the heads have
                 # a grid axis
                 live, grid, windowed = self._tile_chunks_by_kind(
-                    pre, bucket, wa, itemsize)
+                    pre, bucket, wa)
                 self.stats.tile_chunks(live + windowed[0],
                                        grid + windowed[1], windowed)
             else:
-                live, grid = tile_chunks(pre, bucket, wa, pt, itemsize)
-                layers = cache_sublayers(self.module)
+                live, grid = tile_chunks(pre, bucket, wa, pt,
+                                         self.cache.itemsize)
+                layers = self.cache.sublayers
                 self.stats.tile_chunks(live * layers, grid * layers)
         # KV model for the prefill forward(s): gather reads the row's
         # clamped table, the kernel stops at the depth the row has reached;
@@ -3321,7 +3104,7 @@ class PagedBatchingDecoder(BatchingDecoder):
                    if row is not None and row.lease is not None
                    and not row.prefilling
                    for s in range(1, size + 1))
-        layers = cache_sublayers(self.module) - self._window_layers
+        layers = self.cache.full_layers
         return live * layers, size * self.slots * (w // pages) * layers
 
     def _ring_chunks(self, size: int) -> tuple:
@@ -3335,7 +3118,8 @@ class PagedBatchingDecoder(BatchingDecoder):
         ops/paged_attention.py _decode_kernel)."""
         from ..ops.paged_attention import walk_chunk_pages
 
-        pt, ring, n = self.page_tokens, self.window_ring, self._window_layers
+        pt, ring = self.page_tokens, self.window_ring
+        n, window = self.cache.window_layers, self.cache.window
         pages = walk_chunk_pages(ring, ring=True)
         live = held = read = 0
         for row in self._slot_rows:
@@ -3346,12 +3130,11 @@ class PagedBatchingDecoder(BatchingDecoder):
                 written = min(pos // pt + 1, ring)
                 live += -(-written // pages)
                 held += ring
-                read += pos // pt - max(pos - self._window + 1, 0) // pt + 1
+                read += pos // pt - max(pos - window + 1, 0) // pt + 1
         return ((live * n, size * self.slots * (ring // pages) * n),
                 (read * n, held * n))
 
-    def _tile_chunks_by_kind(self, pre: int, bucket: int, wa: int,
-                             itemsize: int) -> tuple:
+    def _tile_chunks_by_kind(self, pre: int, bucket: int, wa: int) -> tuple:
         """``(live, grid, (window live, window grid))`` programs of the
         tile body in one admission of a model whose attention differs by
         layer: each full layer over the row's table, each window layer over
@@ -3360,15 +3143,16 @@ class PagedBatchingDecoder(BatchingDecoder):
         read off the same shapes: ops/paged_attention.py paged_attention)."""
         from ..ops.paged_attention import tile_chunks, tile_head_groups
 
-        pt = self.page_tokens
+        pt, itemsize = self.page_tokens, self.cache.itemsize
         heads = int(self.module.num_heads)
         out = [0, 0, 0, 0]
-        for hkv, dk, dv, window in attention_kinds(self.module):
-            groups = tile_head_groups(heads, hkv, dk, dv, bucket, itemsize)
-            if window:
+        for layer in self.cache.layers:
+            groups = tile_head_groups(heads, layer.kv_heads, layer.k_dim,
+                                      layer.v_dim, bucket, itemsize)
+            if layer.window:
                 live, grid = tile_chunks(
-                    0, bucket, -(-bucket // pt), pt, itemsize, window=window,
-                    groups=groups)
+                    0, bucket, -(-bucket // pt), pt, itemsize,
+                    window=layer.window, groups=groups)
                 out[2] += live
                 out[3] += grid
             else:
@@ -3389,7 +3173,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         w = self._live_table_width(size)
         coloc = self._admits_inflight > 0
         tables = jnp.asarray(self._table[:, :w].copy())
-        if self._window_layers:
+        if self.window_ring:
             # both tables: the full layers' pages and the rows' rings
             tables = (tables, jnp.asarray(self._wtable.copy()))
         (self._slab, packed), cold = self._run_program(
@@ -3398,20 +3182,21 @@ class PagedBatchingDecoder(BatchingDecoder):
             kind="step", steps=size, width=w,
             state_rows=(sum(r is not None and not r.prefilling
                             for r in self._slot_rows)
-                        if self._recurrent else 0))
+                        if self.cache.recurrent else 0))
         # one span per step: step s's query sits s positions past pos_cap
         kv_bytes = sum(self._chunk_kv_tokens(w, s)
                        for s in range(1, size + 1)) * self._kv_token_bytes
-        if self._window_layers:
+        if self.window_ring:
             # a window layer reads a row's window, whatever its depth
+            window = self.cache.window
             kv_bytes += self._window_token_bytes * sum(
-                min(row.pos_cap + s, self._window)
+                min(row.pos_cap + s, window)
                 for row in self._slot_rows
                 if row is not None and row.lease is not None
                 and not row.prefilling for s in range(1, size + 1))
         if self.stats.walks_kv_chunks:
             live, grid = self._walk_chunks(w, size)
-            ring, ring_pages = (self._ring_chunks(size) if self._window_layers
+            ring, ring_pages = (self._ring_chunks(size) if self.window_ring
                                 else ((0, 0), (0, 0)))
             self.stats.walk_chunks(live + ring[0], grid + ring[1], ring,
                                    ring_pages)
@@ -3515,13 +3300,8 @@ class PagedBatchingDecoder(BatchingDecoder):
         done = bool(snap.out) and (
             len(snap.out) >= snap.max_new
             or (snap.eos >= 0 and snap.out[-1] == snap.eos))
-        if snap.out and not done and self._recurrent:
-            raise RecurrentStateUnsupported("restoring a mid-stream snapshot")
-        if snap.out and not done and self._latent:
-            raise LatentCacheUnsupported("restoring a mid-stream snapshot")
-        if snap.out and not done and self._window_layers:
-            raise WindowLayersUnsupported("restoring a mid-stream snapshot")
         if snap.out and not done:
+            check_cache_features(self.cache, {"snapshot"})
             # mid-stream state only restores into a byte-compatible arena
             if int(snap.page_tokens) != self.page_tokens:
                 raise KubeMLError(
@@ -3538,18 +3318,15 @@ class PagedBatchingDecoder(BatchingDecoder):
                     "mid-stream restore is unsupported under spec='draft' "
                     "(the drafter's separate arena is not captured); "
                     "resubmit the prompt", 409)
-            depth = cache_sublayers(self.module) or None
-            if depth is not None and len(snap.layers) != int(depth):
+            mine = self.cache.layers
+            if mine and len(snap.layers) != len(mine):
                 raise KubeMLError(
                     f"snapshot has {len(snap.layers)} layers, model has "
-                    f"{depth}", 409)
-            heads = int(getattr(self.module, "num_heads", 0))
-            hd = (int(getattr(self.module, "embed_dim", 0)) // heads
-                  if heads else 0)
-            want = (self.page_tokens, heads, hd)
-            for layer in snap.layers:
+                    f"{len(mine)}", 409)
+            for layer, held in zip(snap.layers, mine):
+                want = (self.page_tokens, held.kv_heads, held.k_dim)
                 got = tuple(int(x) for x in layer.k.shape[1:])
-                if heads and got != want:
+                if got != want:
                     raise KubeMLError(
                         f"snapshot layer {layer.name!r} page shape {got} "
                         f"!= engine page shape {want}", 409)
@@ -3693,12 +3470,10 @@ class PagedBatchingDecoder(BatchingDecoder):
         if self.spec == "draft":
             self.stats.snapshot_fail()
             return None
-        if self._recurrent or self._latent or self._window_layers:
-            refusal = (RecurrentStateUnsupported if self._recurrent
-                       else LatentCacheUnsupported if self._latent
-                       else WindowLayersUnsupported)
-            log.warning("%s: %s (request %s)", self.name,
-                        refusal("a mid-stream snapshot"),
+        try:
+            check_cache_features(self.cache, {"snapshot"})
+        except CacheFeatureUnsupported as refusal:
+            log.warning("%s: %s (request %s)", self.name, refusal,
                         row.entry.request_id)
             self.stats.snapshot_fail()
             return None
@@ -3709,9 +3484,10 @@ class PagedBatchingDecoder(BatchingDecoder):
                                                self.page_tokens)
             if row.lease is None or len(row.lease.pages) < npg:
                 raise kvsnap.SnapshotError("row holds no page lease")
+            held = self.cache.layers[0]
             layers = (kvsnap.gather_pages(self._slab.cache,
                                           list(row.lease.pages[:npg]),
-                                          *_kv_head_shape(self.module))
+                                          held.kv_heads, held.k_dim)
                       if npg else [])
             snap = kvsnap.RequestSnapshot(
                 model=self.name, request_id=row.entry.request_id,
@@ -3809,7 +3585,7 @@ class PagedBatchingDecoder(BatchingDecoder):
         else:
             self.stats.pool_audit(True)
 
-    def drain(self, grace: Optional[float] = None) -> List[bytes]:
+    def drain(self, grace: float) -> List[bytes]:
         """Graceful shutdown (checkpoint-and-yield for serving): stop
         admitting (submit 429s with Retry-After), give live rows up to
         ``grace`` seconds (KUBEML_DRAIN_GRACE) to run out, then snapshot
@@ -3818,10 +3594,6 @@ class PagedBatchingDecoder(BatchingDecoder):
         The PS writes them under KUBEML_SNAP_DIR and replays them through
         :meth:`submit_snapshot` on next boot. Returns [] when everything
         finished inside the grace window."""
-        if grace is None:
-            from ..api.config import get_config
-
-            grace = float(get_config().drain_grace)
         deadline = time.monotonic() + max(0.0, grace)
         with self._cond:
             self._drain_mode = True
@@ -3932,10 +3704,10 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     @property
     def arena_bytes(self) -> int:
-        return (self.arena_pages * _kv_page_bytes(
-            self.module, self.page_tokens, self.kv_quant)
-            + self.window_arena_pages * _window_page_bytes(
-                self.module, self.page_tokens))
+        return (self.arena_pages * self.cache.page_bytes(
+            self.page_tokens, self.kv_quant)
+            + self.window_arena_pages * self.cache.ring_page_bytes(
+                self.page_tokens))
 
     def telemetry(self) -> dict:
         snap = super().telemetry()
@@ -3963,35 +3735,32 @@ class PagedBatchingDecoder(BatchingDecoder):
         snap["block_traces"] = float(block_traces())
         # sub-layers that hold a paged cache (the depth; twice that where
         # a layer is a double layer of two attentions)
-        snap["cache_sublayers"] = float(cache_sublayers(self.module))
+        snap["cache_sublayers"] = float(self.cache.sublayers)
         # of those, the layers whose row holds a ring of pages (a window
         # layer) and the ones whose row holds a page for every position
-        snap["window_layers"] = float(self._window_layers)
-        snap["full_layers"] = float(
-            cache_sublayers(self.module) - self._window_layers)
+        snap["window_layers"] = float(self.cache.window_layers)
+        snap["full_layers"] = float(self.cache.full_layers)
         # streams of the model's residual path (1; hyper-connections: n)
-        snap["residual_streams"] = float(
-            getattr(self.module, "hc_mult", 0) or 1)
+        snap["residual_streams"] = float(self.cache.residual_streams)
         # recurrent state beside the pages: layers that keep one, its bytes
         # over all program rows, and whether it switched prefix sharing off
         snap["recurrent_layers"] = float(self._recurrent_layers)
         snap["recurrent_state_bytes"] = float(self._recurrent_bytes)
         snap["prefix_cache_off_recurrent"] = (
-            1.0 if self._prefix_off_recurrent else 0.0)
+            1.0 if "prefix_sharing" in self._features_off else 0.0)
         # a latent arena: values one token holds in one layer, once, and
         # the lanes its row is stored in (both 0 for a model that pages K
         # and V heads); routed-expert layers and the bytes of their stacked
         # expert weights (a decode step reads the share of them its rows
         # chose: moe_experts_touched)
-        snap["kv_latent_width"] = float(
-            self.module.mla.latent_width if self._latent else 0)
+        latent = self.cache.latent
+        snap["kv_latent_width"] = float(latent.latent_width if latent else 0)
         snap["kv_latent_row_width"] = float(
-            self.module.mla.row_width if self._latent else 0)
-        snap["moe_layers"] = float(self._moe_layers)
+            latent.latent_row_width if latent else 0)
+        snap["moe_layers"] = float(self.cache.expert_layers)
         # experts of a layer whose weights are here (all, or this chip's
         # share of them)
-        snap["moe_experts_held"] = float(
-            self.module.experts.held_range[1] if self._moe_layers else 0)
+        snap["moe_experts_held"] = float(self.cache.experts_held)
         snap["expert_param_bytes"] = float(self._expert_param_bytes)
         if self._spec_ctl is not None:
             # current adaptive speculation depth (0 = retreated to plain

@@ -21,17 +21,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.models import falcon_h1 as builder  # noqa: E402
 from benchmark.reference import falcon_h1 as reference  # noqa: E402
-from kubeml_tpu.api.errors import KubeMLError  # noqa: E402
 from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
-from kubeml_tpu.models.generation import (generate, has_recurrent_state,  # noqa: E402
-                                          init_paged_cache)
+from kubeml_tpu.models.cache_spec import cache_spec  # noqa: E402
+from kubeml_tpu.models.generation import generate, init_paged_cache  # noqa: E402
 from kubeml_tpu.ops import ssm  # noqa: E402
 from kubeml_tpu.ops.paged_attention import (pack_kv_rows,  # noqa: E402
                                             paged_attention)
-from kubeml_tpu.serving.batcher import (BatchingDecoder,  # noqa: E402
-                                        PagedBatchingDecoder,
-                                        RecurrentStateUnsupported,
-                                        _kv_page_bytes, _kv_token_bytes)
+from kubeml_tpu.serving.batcher import PagedBatchingDecoder  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 VOCAB = 97
@@ -96,7 +92,7 @@ def prompts(n, lo, hi, seed=0):
 
 def test_whole_model_matches_reference(model):
     cfg, weights, module, tree = model
-    assert has_recurrent_state(module)
+    assert cache_spec(module).recurrent
     ids = prompts(1, 37, 37)[0]
     with jax.default_matmul_precision("highest"):
         got = module.apply(tree, ids[None])[0]
@@ -241,12 +237,13 @@ def test_gqa_page_walk_kernel(H, Hkv, D, L):
 def test_kv_bytes_count_kv_heads(model):
     _, _, module, _ = model
     # 2 layers x (K and V) x 2 K/V heads x 32 x 4 bytes
-    assert _kv_token_bytes(module) == 2 * 2 * 2 * 32 * 4
-    assert _kv_page_bytes(module, 8) == 8 * _kv_token_bytes(module)
+    spec = cache_spec(module)
+    assert spec.token_bytes() == 2 * 2 * 2 * 32 * 4
+    assert spec.page_bytes(8) == 8 * spec.token_bytes()
     from kubeml_tpu.models.gpt import CausalTransformer
     gpt = CausalTransformer(vocab_size=11, max_len=16, embed_dim=64,
                             depth=3, num_heads=4)
-    assert _kv_token_bytes(gpt) == 3 * 2 * 64 * 4      # as before GQA
+    assert cache_spec(gpt).token_bytes() == 3 * 2 * 64 * 4   # as before GQA
 
 
 # --- the paged path: module level ----------------------------------------
@@ -469,25 +466,3 @@ def test_reused_slot_starts_from_zero_state(model):
         finally:
             dec.close()
     assert after == fresh
-
-
-def test_recurrent_refusals_are_named(model):
-    _, _, module, tree = model
-    with pytest.raises(RecurrentStateUnsupported, match="speculative"):
-        engine(model, spec="self")
-    with pytest.raises(RecurrentStateUnsupported, match="slot engine"):
-        BatchingDecoder(module, tree, slots=2)
-    dec = engine(model, prefix_cache=True)
-    try:
-        assert dec._pool.trie is None
-        assert dec.telemetry()["prefix_cache_off_recurrent"] == 1.0
-        from kubeml_tpu.serving import kvsnap
-        snap = kvsnap.RequestSnapshot(
-            model=dec.name, request_id="r", page_tokens=PT, kv_quant="none",
-            spec="off", prompt=[1, 2, 3], out=[4], max_new=5, temp=0.0,
-            topk=0, eos=-1, key=(0, 0), layers=[])
-        with pytest.raises(RecurrentStateUnsupported, match="snapshot"):
-            dec.submit_snapshot(snap)
-        assert isinstance(RecurrentStateUnsupported("x"), KubeMLError)
-    finally:
-        dec.close()

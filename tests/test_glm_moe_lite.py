@@ -24,24 +24,19 @@ from latent_rows import pad_lanes_are_zero  # noqa: E402
 
 from benchmark.models import glm_moe_lite as builder  # noqa: E402
 from benchmark.reference import glm_moe_lite as reference  # noqa: E402
-from kubeml_tpu.api.errors import KubeMLError  # noqa: E402
 from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
 from kubeml_tpu.models import gpt  # noqa: E402
 from kubeml_tpu.models import experts as experts_mod  # noqa: E402
 from kubeml_tpu.models.experts import ExpertsConfig, route  # noqa: E402
-from kubeml_tpu.models.generation import (expert_layers, has_latent_cache,  # noqa: E402
-                                          init_paged_cache,
+from kubeml_tpu.models.cache_spec import cache_spec  # noqa: E402
+from kubeml_tpu.models.generation import (init_paged_cache,  # noqa: E402
                                           supports_paged_decode)
 from kubeml_tpu.models.mla import MLAConfig  # noqa: E402
 from kubeml_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
 from kubeml_tpu.ops.mla_attention import (latent_row_width,  # noqa: E402
                                           mla_attn, mla_attn_gather,
                                           pad_lanes)
-from kubeml_tpu.serving.batcher import (BatchingDecoder,  # noqa: E402
-                                        ExpertLayersUnsupported,
-                                        LatentCacheUnsupported,
-                                        PagedBatchingDecoder, _kv_page_bytes,
-                                        _kv_token_bytes)
+from kubeml_tpu.serving.batcher import PagedBatchingDecoder  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 # float32 against float32 at precision "highest": what is left is the order
@@ -112,7 +107,8 @@ def prompts(n, lo, hi, seed=0):
 
 def test_whole_model_matches_reference(model):
     cfg, weights, module, tree = model
-    assert has_latent_cache(module) and expert_layers(module) == 2
+    spec = cache_spec(module)
+    assert spec.latent is not None and spec.expert_layers == 2
     assert supports_paged_decode(module)
     assert float(jnp.abs(weights["b_r"]).max()) > 0.05   # a bias that bites
     ids = prompts(1, 37, 37)[0]
@@ -138,7 +134,7 @@ def test_moe_every_models_are_still_refused():
                                 depth=2, num_heads=2, moe_every=2)
     assert not supports_paged_decode(old)
     assert supports_paged_decode(gpt.GPTTiny())
-    assert expert_layers(old) == 0 and not has_latent_cache(old)
+    assert not cache_spec(old).properties
 
 
 def test_selection_uses_the_bias_and_weights_do_not():
@@ -279,15 +275,15 @@ def test_a_latent_page_is_576_values_a_token_once(model):
     glm = ns["Model"]().build()
     assert glm.mla.latent_width == 576 and glm.depth == 6
     # bfloat16: 1,152 B a token and layer, 6,912 B over the six layers
-    assert _kv_token_bytes(glm) == 6 * 576 * 2 == 6912
+    assert cache_spec(glm).token_bytes() == 6 * 576 * 2 == 6912
     # stored in whole 128-lane rows: 640 lanes, 1,280 B a token and layer
     assert glm.mla.row_width == 640
-    assert _kv_page_bytes(glm, 16) == 16 * 6 * 640 * 2
+    assert cache_spec(glm).page_bytes(16) == 16 * 6 * 640 * 2
     # a latent that fills its rows is stored as it is
     assert dataclasses.replace(glm.mla, qk_rope_head_dim=128).row_width == 640
     # expanded K and V for the same token would be 20 x (256 + 256) x 2 B
     mha = glm.clone(mla=None, head_dim=256)
-    assert _kv_token_bytes(mha) == 6 * 2 * 20 * 256 * 2 == 122880
+    assert cache_spec(mha).token_bytes() == 6 * 2 * 20 * 256 * 2 == 122880
     # the arrays agree: the tiny model's arena, leaf by leaf
     _, _, module, tree = model
     m = module.clone(page_tokens=8, kv_pages=33)
@@ -295,7 +291,7 @@ def test_a_latent_page_is_576_values_a_token_once(model):
     arenas = [l for path, l in jax.tree_util.tree_leaves_with_path(cache)
               if getattr(path[-1], "key", "") == "latent_pages"]
     assert [a.shape for a in arenas] == [(33, 8, 128)] * 3
-    assert sum(a.nbytes for a in arenas) == 33 * _kv_page_bytes(m, 8)
+    assert sum(a.nbytes for a in arenas) == 33 * cache_spec(m).page_bytes(8)
     assert not any(getattr(path[-1], "key", "") == "kv_rows"
                    for path, _ in jax.tree_util.tree_leaves_with_path(cache))
 
@@ -560,25 +556,8 @@ def test_engine_spans_name_the_expert_layers(model):
 
 
 def test_latent_and_expert_refusals_are_named(model):
+    """The model's own refusal; what the engines refuse for its caches is
+    tests/test_cache_spec.py's table."""
     _, _, module, tree = model
-    with pytest.raises(ExpertLayersUnsupported, match="spec='self'"):
-        engine(model, spec="self")
-    with pytest.raises(LatentCacheUnsupported, match="slot engine"):
-        BatchingDecoder(module, tree, slots=2)
-    with pytest.raises(LatentCacheUnsupported, match="int8"):
-        engine(model, kv_quant="int8")
     with pytest.raises(ValueError, match="expert models"):
         module.apply(tree, jnp.ones((1, 4), jnp.int32), exit_layer=1)
-    dec = engine(model)
-    try:
-        from kubeml_tpu.serving import kvsnap
-        snap = kvsnap.RequestSnapshot(
-            model=dec.name, request_id="r", page_tokens=PT, kv_quant="none",
-            spec="off", prompt=[1, 2, 3], out=[4], max_new=5, temp=0.0,
-            topk=0, eos=-1, key=(0, 0), layers=[])
-        with pytest.raises(LatentCacheUnsupported, match="snapshot"):
-            dec.submit_snapshot(snap)
-        assert isinstance(LatentCacheUnsupported("x"), KubeMLError)
-        assert isinstance(ExpertLayersUnsupported("x"), KubeMLError)
-    finally:
-        dec.close()

@@ -31,19 +31,15 @@ from benchmark.reference import xing4 as reference  # noqa: E402
 from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
 from kubeml_tpu.models import experts as experts_mod  # noqa: E402
 from kubeml_tpu.models import gpt  # noqa: E402
-from kubeml_tpu.models.generation import (expert_layers, has_latent_cache,  # noqa: E402
-                                          init_paged_cache,
-                                          residual_sublayers,
+from kubeml_tpu.models.cache_spec import cache_spec  # noqa: E402
+from kubeml_tpu.models.generation import (init_paged_cache,  # noqa: E402
                                           supports_paged_decode)
 from kubeml_tpu.models.mla import MLAConfig  # noqa: E402
 from kubeml_tpu.ops import hyper_connection as hc  # noqa: E402
 from kubeml_tpu.ops.attention import dot_product_attention  # noqa: E402
 from kubeml_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
 from kubeml_tpu.ops.rotary import YarnScaling, rope_frequencies  # noqa: E402
-from kubeml_tpu.serving.batcher import (BatchingDecoder,  # noqa: E402
-                                        ExpertLayersUnsupported,
-                                        LatentCacheUnsupported,
-                                        PagedBatchingDecoder)
+from kubeml_tpu.serving.batcher import PagedBatchingDecoder  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 # float32 against float32 at precision "highest": what is left is the order
@@ -124,9 +120,10 @@ def force_kernels(monkeypatch):
 def test_whole_model_matches_reference(model):
     cfg, weights, module, tree = model
     assert module.hc_mult == 4 and module.dense_layers == 2
-    assert has_latent_cache(module) and expert_layers(module) == 3
-    assert residual_sublayers(module) == 10 and supports_paged_decode(module)
-    assert residual_sublayers(gpt.GPTTiny()) == 0
+    spec = cache_spec(module)
+    assert spec.latent is not None and spec.expert_layers == 3
+    assert spec.residual_sublayers == 10 and supports_paged_decode(module)
+    assert cache_spec(gpt.GPTTiny()).residual_sublayers == 0
     ids = prompts(1, 37, 37)[0]
     with jax.default_matmul_precision("highest"):
         got, seen = module.apply(tree, ids[None], mutable=["intermediates"])
@@ -639,22 +636,15 @@ def test_four_streams_are_another_program(model):
 # --- (h) what is refused by name stays refused -------------------------------
 
 
-@pytest.mark.parametrize("case", ["spec_self", "slot_engine", "int8_pages",
-                                  "exit_layer", "dense_cache", "mixer",
+# (what the engines refuse for the model's caches: tests/test_cache_spec.py)
+
+
+@pytest.mark.parametrize("case", ["exit_layer", "dense_cache", "mixer",
                                   "moe_every", "flash_scale"])
 def test_refusals_are_still_named(model, case):
     _, _, module, tree = model
     ids = jnp.ones((1, 4), jnp.int32)
-    if case == "spec_self":
-        with pytest.raises(ExpertLayersUnsupported, match="spec='self'"):
-            engine(model, spec="self")
-    elif case == "slot_engine":
-        with pytest.raises(LatentCacheUnsupported, match="slot engine"):
-            BatchingDecoder(module, tree, slots=2)
-    elif case == "int8_pages":
-        with pytest.raises(LatentCacheUnsupported, match="int8"):
-            engine(model, kv_quant="int8")
-    elif case == "exit_layer":
+    if case == "exit_layer":
         with pytest.raises(ValueError, match="expert models"):
             module.apply(tree, ids, exit_layer=1)
     elif case == "dense_cache":

@@ -96,3 +96,29 @@ def test_declarations_match_the_tree():
     assert not gone, (
         f"no longer occurs, so strike it from KNOWN_DEBTS and from "
         f"ROADMAP.md D13: {gone}")
+
+
+# --- the serving engines are told, they do not look ------------------------
+
+_BATCHER = (ROOT / "serving" / "batcher.py").read_text()
+# what a model's caches are is ``models/cache_spec.py``'s to read, once
+_CACHE_FIELDS = ("mla", "attn_kinds", "num_kv_heads", "head_dim", "ssm",
+                 "hc_mult", "kv_quant", "paged_attn", "num_heads", "embed_dim")
+
+
+def test_the_engines_read_no_process_config():
+    """The parameter server maps ``Config`` to constructor arguments
+    (``ps/parameter_server.py _new_decoder``); an engine that read the
+    process config again would decide every option twice."""
+    assert "get_config" not in _BATCHER
+
+
+def test_serving_sniffs_no_cache_field():
+    import re
+
+    sniff = re.compile(r"(?:get|has)attr\(\s*(?:self\.)?(?:draft_)?module,\s*"
+                       r"[\"'](?:%s)[\"']" % "|".join(_CACHE_FIELDS))
+    found = {path.name: sniff.findall(path.read_text())
+             for path in sorted((ROOT / "serving").glob("*.py"))}
+    assert not any(found.values()), (
+        f"ask models/cache_spec.py instead: {found}")

@@ -30,13 +30,11 @@ from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
 from kubeml_tpu.models import experts as experts_mod  # noqa: E402
 from kubeml_tpu.models import gpt  # noqa: E402
 from kubeml_tpu.models.experts import ExpertMLP, ExpertsConfig  # noqa: E402
-from kubeml_tpu.models.generation import (cache_sublayers,  # noqa: E402
-                                          expert_layers, has_latent_cache,
-                                          init_paged_cache,
+from kubeml_tpu.models.cache_spec import cache_spec  # noqa: E402
+from kubeml_tpu.models.generation import (init_paged_cache,  # noqa: E402
                                           supports_paged_decode)
 from kubeml_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
-from kubeml_tpu.serving.batcher import (ExpertLayersUnsupported,  # noqa: E402
-                                        PagedBatchingDecoder)
+from kubeml_tpu.serving.batcher import PagedBatchingDecoder  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 # float32 against float32 at precision "highest": what is left is the order
@@ -119,9 +117,10 @@ def test_whole_model_matches_reference(share):
     assert module.mlp == "shortcut" and module.depth == 2
     assert module.experts.held_range == share
     assert module.mla.mla_scale_q_lora and module.mla.mla_scale_kv_lora
-    assert has_latent_cache(module) and supports_paged_decode(module)
-    assert expert_layers(module) == 2 and cache_sublayers(module) == 4
-    assert cache_sublayers(gpt.GPTTiny()) == 2
+    spec = cache_spec(module)
+    assert spec.latent is not None and supports_paged_decode(module)
+    assert spec.expert_layers == 2 and spec.sublayers == 4
+    assert cache_spec(gpt.GPTTiny()).sublayers == 2
     ids = prompts(1, 37, 37)[0]
     with jax.default_matmul_precision("highest"):
         got, seen = module.apply(tree, ids[None], mutable=["intermediates"])
@@ -581,15 +580,15 @@ def test_the_held_controls_touch_the_held_part_alone(model):
 # --- (e) what is refused by name stays refused -------------------------------
 
 
-@pytest.mark.parametrize("case", ["spec_self", "exit_layer", "dense_layers",
-                                  "hc", "bare_block"])
+# (what the engines refuse for the model's caches: tests/test_cache_spec.py)
+
+
+@pytest.mark.parametrize("case", ["exit_layer", "dense_layers", "hc",
+                                  "bare_block"])
 def test_refusals_are_named(model, case):
     _, _, module, tree = model
     ids = jnp.ones((1, 4), jnp.int32)
-    if case == "spec_self":
-        with pytest.raises(ExpertLayersUnsupported, match="spec='self'"):
-            engine(model, spec="self")
-    elif case == "exit_layer":
+    if case == "exit_layer":
         with pytest.raises(ValueError, match="expert models"):
             module.apply(tree, ids, exit_layer=1)
     elif case == "dense_layers":

@@ -29,21 +29,16 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.models import mimo_v2 as builder  # noqa: E402
 from benchmark.reference import mimo_v2 as reference  # noqa: E402
-from kubeml_tpu.api.errors import KubeMLError  # noqa: E402
 from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
 from kubeml_tpu.models import experts as experts_mod  # noqa: E402
 from kubeml_tpu.models import gpt  # noqa: E402
 from kubeml_tpu.models.experts import ExpertMLP, ExpertsConfig  # noqa: E402
-from kubeml_tpu.models.generation import (attention_kinds,  # noqa: E402
-                                          cache_sublayers, expert_layers,
-                                          init_paged_cache,
-                                          supports_paged_decode,
-                                          window_layers, window_ring)
+from kubeml_tpu.models.cache_spec import cache_spec  # noqa: E402
+from kubeml_tpu.models.generation import (init_paged_cache,  # noqa: E402
+                                          supports_paged_decode)
 from kubeml_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
 from kubeml_tpu.ops.paged_attention import ring_pages  # noqa: E402
-from kubeml_tpu.serving.batcher import (BatchingDecoder,  # noqa: E402
-                                        PagedBatchingDecoder,
-                                        WindowLayersUnsupported)
+from kubeml_tpu.serving.batcher import PagedBatchingDecoder  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 # float32 against float32 at precision "highest": what is left is the order
@@ -120,14 +115,15 @@ def force_kernels(monkeypatch):
 
 def test_whole_model_matches_reference(model):
     cfg, weights, module, tree = model
-    kinds = attention_kinds(module)
-    assert [w for *_, w in kinds] == [0, 8, 8, 8, 8, 0, 8]
-    assert [k[:3] for k in kinds[:2]] == [(2, 24, 16), (4, 24, 16)]
-    assert window_layers(module) == 5 and cache_sublayers(module) == 7
-    assert expert_layers(module) == 6 and supports_paged_decode(module)
-    assert window_ring(module) == 0            # no page size cloned in yet
-    assert window_ring(module.clone(page_tokens=PT)) == RING == ring_pages(
-        WINDOW, PT)
+    spec = cache_spec(module)
+    assert [l.window for l in spec.layers] == [0, 8, 8, 8, 8, 0, 8]
+    assert [(l.kv_heads, l.k_dim, l.v_dim) for l in spec.layers[:2]] == [
+        (2, 24, 16), (4, 24, 16)]
+    assert spec.window_layers == 5 and spec.sublayers == 7
+    assert spec.expert_layers == 6 and supports_paged_decode(module)
+    # no page size cloned in yet
+    assert spec.ring_pages(module.page_tokens) == 0
+    assert spec.ring_pages(PT) == RING == ring_pages(WINDOW, PT)
     assert ring_pages(128, 16) == 10           # the published window
     assert set(tree["params"]["block_1"]["attn"]) == {
         "query", "key", "value", "proj", "sink"}
@@ -464,45 +460,15 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(kernel, monkeypatch):
 # --- (e) what is refused by name stays refused -------------------------------
 
 
-@pytest.mark.parametrize("case", [
-    "prefix_sharing", "int8_pages", "spec_self", "spec_draft",
-    "chunked_prefill", "slot_engine", "snapshot", "dense_cache",
-    "one_table", "narrow_ring", "latent"])
+# (what the engines refuse for the model's caches: tests/test_cache_spec.py)
+
+
+@pytest.mark.parametrize("case", ["dense_cache", "one_table", "narrow_ring",
+                                  "latent"])
 def test_refusals_are_named(model, case):
     _, _, module, tree = model
     ids = jnp.ones((1, 4), jnp.int32)
-    if case == "prefix_sharing":
-        with pytest.raises(WindowLayersUnsupported, match="prefix sharing"):
-            engine(model, prefix_cache=True)
-    elif case == "int8_pages":
-        with pytest.raises(WindowLayersUnsupported, match="int8"):
-            engine(model, kv_quant="int8")
-    elif case == "spec_self":
-        with pytest.raises(WindowLayersUnsupported, match="spec='self'"):
-            engine(model, spec="self")
-    elif case == "spec_draft":
-        with pytest.raises(WindowLayersUnsupported, match="spec='draft'"):
-            engine(model, spec="draft")
-    elif case == "chunked_prefill":
-        with pytest.raises(WindowLayersUnsupported, match="chunked prefill"):
-            engine(model, prefill_chunk_tokens=16)
-    elif case == "slot_engine":
-        with pytest.raises(WindowLayersUnsupported, match="slot engine"):
-            BatchingDecoder(module, tree, slots=2)
-    elif case == "snapshot":
-        dec = engine(model)
-        try:
-            from kubeml_tpu.serving import kvsnap
-            snap = kvsnap.RequestSnapshot(
-                model=dec.name, request_id="r", page_tokens=PT,
-                kv_quant="none", spec="off", prompt=[1, 2, 3], out=[4],
-                max_new=5, temp=0.0, topk=0, eos=-1, key=(0, 0), layers=[])
-            with pytest.raises(WindowLayersUnsupported, match="snapshot"):
-                dec.submit_snapshot(snap)
-            assert isinstance(WindowLayersUnsupported("x"), KubeMLError)
-        finally:
-            dec.close()
-    elif case == "dense_cache":
+    if case == "dense_cache":
         with pytest.raises(ValueError, match="paged arena only"):
             module.apply(tree, ids, decode=True, mutable=["cache"])
     elif case == "one_table":

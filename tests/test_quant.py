@@ -116,8 +116,11 @@ def test_native_int8_matmul_token_parity(served, monkeypatch):
         for impl in ("dot", "pallas"):
             monkeypatch.setenv("KUBEML_INT8_MATMUL_IMPL", impl)
             set_config(Config())
+            # the engines read no process config: the knob travels as the
+            # parameter server hands it over (_new_decoder)
             dec = BatchingDecoder(m, variables, slots=3, chunk_steps=4,
-                                  quantize="int8")
+                                  quantize="int8",
+                                  int8_matmul=get_config().int8_matmul)
             try:
                 assert dec.int8_matmul  # the env knob reached the engine
                 entries = [dec.submit(GenerateRequest(
