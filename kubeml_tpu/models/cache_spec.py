@@ -5,7 +5,10 @@
 that keeps a paged cache, in the stack's order, and what rides beside the
 caches (recurrent state, routed experts, residual streams). A serving engine
 asks once, at its construction, and reads every size, sum and property off
-the answer; nothing above this module looks at the fields again. How a row
+the answer; nothing above this module looks at the fields again. ``layers``
+lists the sub-layers that PAGE (a layer whose token mixer keeps a recurrent
+state and no keys, models/gated_deltanet.py, is not among them: it counts in
+``state_layers``), so a stack of 8 layers may hold 2 arenas. How a row
 is STORED stays where the arenas are written (``ops/paged_attention.py
 kv_row_width``, ``models/mla.py MLAConfig.row_width``) and is called from
 here.
@@ -60,8 +63,11 @@ class CacheSpec:
     accounting is skipped."""
 
     layers: Tuple[CacheLayer, ...] = ()
-    # rows carry recurrent state beside their pages (a Mamba-2 mixer)
-    recurrent: bool = False
+    # layers whose program rows carry recurrent state (a Mamba-2 mixer
+    # beside attention; a Gated DeltaNet mixer in its place), and the bytes
+    # one row's state takes in one of them, its convolution's tail included
+    state_layers: int = 0
+    state_row_bytes: int = 0
     # layers whose feed-forward is routed experts, the choices a token
     # makes in each, and the experts of a layer whose weights are here
     expert_layers: int = 0
@@ -75,6 +81,16 @@ class CacheSpec:
     @property
     def sublayers(self) -> int:
         return len(self.layers)
+
+    @property
+    def recurrent(self) -> bool:
+        """Rows carry recurrent state beside (or in place of) pages."""
+        return self.state_layers > 0
+
+    def state_bytes(self, rows: int) -> int:
+        """HBM bytes the recurrent state of ``rows`` program rows takes,
+        all layers that keep one."""
+        return rows * self.state_layers * self.state_row_bytes
 
     @cached_property
     def window_layers(self) -> int:
@@ -168,6 +184,7 @@ def cache_spec(module) -> CacheSpec:
                         "cache_sublayers", 1)
     mla = getattr(module, "mla", None)
     layers: Tuple[CacheLayer, ...] = ()
+    linear = 0   # layers whose mixer keeps a state and no pages
     if mla is not None:
         layers = (CacheLayer(latent_width=int(mla.latent_width),
                              latent_row_width=int(mla.row_width)),
@@ -177,8 +194,9 @@ def cache_spec(module) -> CacheSpec:
         v_dim = int(getattr(module, "v_head_dim", 0) or k_dim)
         if getattr(module, "attn_kinds", ()):
             kinds = [module.attn_kind(i) for i in range(depth)]
+            linear = sum(1 for a in kinds if a.linear)
             by_layer = [(int(a.num_kv_heads or heads), int(a.window))
-                        for a in kinds]
+                        for a in kinds if not a.linear]
         else:
             by_layer = [(int(getattr(module, "num_kv_heads", 0) or heads),
                          0)] * depth
@@ -194,9 +212,15 @@ def cache_spec(module) -> CacheSpec:
         if getattr(module, "mlp", None) in ("experts", "shortcut") else 0)
     experts = module.experts if expert_layers else None
     streams = int(getattr(module, "hc_mult", 0) or 0)
+    # a model's one kind of state: a mixer beside attention in every layer,
+    # or the mixer of its linear layers
+    ssm = getattr(module, "ssm", None)
+    mixer = ssm if ssm is not None else getattr(module, "gdn", None)
+    state_layers = depth if ssm is not None else linear
     return CacheSpec(
         layers=layers,
-        recurrent=getattr(module, "ssm", None) is not None,
+        state_layers=state_layers,
+        state_row_bytes=int(mixer.state_row_bytes) if state_layers else 0,
         expert_layers=expert_layers,
         experts_per_token=int(experts.num_experts_per_tok) if experts else 0,
         experts_held=int(experts.held_range[1]) if experts else 0,

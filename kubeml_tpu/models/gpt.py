@@ -30,7 +30,14 @@ SwiGLUs, and an expert layer on a shortcut around the second pair. And the ATTEN
 / ``attn_pattern``, :class:`AttnKind`): full layers beside window layers,
 each kind with its own K/V head count, rotary base, window and sink, over K
 heads and V heads of different widths with rotary on a share of a head
-(MiMo-V2-Flash's ``hybrid_layer_pattern``). GPT-2 is
+(MiMo-V2-Flash's ``hybrid_layer_pattern``). Or a layer's token mixer may not
+be attention at all: a kind with ``linear`` set runs a Gated DeltaNet mixer
+(models/gated_deltanet.py) in attention's place, keeps a recurrent state a
+program row and no pages. The norms may sit on each branch's OUTPUT
+(``norm_at="output"``: Olmo 2's block), the whole query and key projections
+may be normed (``qk_norm``), and a stack may have no positional term at all
+(``pos="none"``): Olmo-Hybrid is those three with three linear layers to
+every full one. GPT-2 is
 the defaults; Falcon-H1 is rmsnorm +
 swiglu + GQA + rope + ssm + mup; GLM-4.7-Flash is rmsnorm + rope + mla +
 experts behind one dense layer; Xing4.0 is that with YaRN rotary on four
@@ -55,6 +62,7 @@ from ..ops.attention import dot_product_attention
 from ..ops.hyper_connection import HCConfig, hc_post, hc_pre
 from ..parallel.ring import ring_attention
 from .experts import ExpertMLP, ExpertsConfig
+from .gated_deltanet import GatedDeltaNet, GDNConfig
 from .layers import QuantizableDense
 from .mamba2 import Mamba2Mixer, SSMConfig
 from .mla import MLAConfig, MLAttention
@@ -110,12 +118,17 @@ class AttnKind:
     query sees that many keys, its own among them, and the layer's paged
     cache is a RING of ``ops/paged_attention.ring_pages`` pages a row
     (serving/kvpool.py); 0: every key before it. ``sink``: one learned logit
-    a head joins the softmax's denominator and takes no value."""
+    a head joins the softmax's denominator and takes no value. ``linear``:
+    the layer's token mixer is not attention but the stack's Gated DeltaNet
+    mixer (``CausalTransformer.gdn``; Olmo-Hybrid's ``layer_types``
+    ``linear_attention``): it keeps a recurrent state a program row and NO
+    paged cache, and the other fields say nothing of it."""
 
     num_kv_heads: int = 0
     rope_theta: float = 10000.0
     window: int = 0
     sink: bool = False
+    linear: bool = False
 
 
 class CausalSelfAttention(nn.Module):
@@ -183,6 +196,10 @@ class CausalSelfAttention(nn.Module):
     value_scale: float = 1.0
     window: int = 0
     sink: bool = False
+    # RMSNorm over the WHOLE query and key projections (all heads' lanes
+    # together, before the split into heads; Olmo 2's ``q_norm``/``k_norm``)
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
 
     @nn.nowrap   # no scope of its own: the walk is ``%attn`` in every layer
     def _window_paged(self, ckv, q, k, v, positions, pages, seq_lens, valid,
@@ -293,8 +310,14 @@ class CausalSelfAttention(nn.Module):
             kernel_init=_part(names)(nn.initializers.lecun_normal()),
             use_bias=self.use_bias, dtype=self.dtype,
         )
-        q = dense(H * D, (None, "tp"), "query")(x).reshape(B, L, H, D)
-        k = dense(Hkv * D, (None, "tp"), "key")(x).reshape(B, L, Hkv, D)
+        # ``qk_norm``: the whole projection normed, before its heads split
+        normed = lambda name, t: nn.RMSNorm(
+            name=name, dtype=jnp.float32, epsilon=self.qk_norm_eps)(
+                t).astype(self.dtype) if self.qk_norm else t
+        q = normed("q_norm", dense(H * D, (None, "tp"), "query")(x)).reshape(
+            B, L, H, D)
+        k = normed("k_norm", dense(Hkv * D, (None, "tp"), "key")(x)).reshape(
+            B, L, Hkv, D)
         v = dense(Hkv * Dv, (None, "tp"), "value")(x).reshape(B, L, Hkv, Dv)
         k = _scaled(k, self.key_mult)
         out_proj = dense(E, ("tp", None), "proj")
@@ -652,6 +675,14 @@ class GPTBlock(nn.Module):
     value_scale: float = 1.0
     window: int = 0
     sink: bool = False
+    qk_norm: bool = False
+    # where ``ln1`` / ``ln2`` sit: on each branch's "input" (``x + f(norm(
+    # x))``) or on its "output" (``x + norm(f(x))``)
+    norm_at: str = "input"
+    # the layer's token mixer is ``gdn``'s Gated DeltaNet, not attention
+    # (AttnKind.linear)
+    linear: bool = False
+    gdn: Optional[GDNConfig] = None
     # paged caches a layer of this class holds (models/cache_spec.py
     # cache_spec): one attention, one cache
     cache_sublayers: ClassVar[int] = 1
@@ -682,6 +713,21 @@ class GPTBlock(nn.Module):
         reads. What it makes is the caller's to keep."""
         mup = self.mup or MuP()
         hc = self.hc
+        if self.norm_at not in ("input", "output"):
+            raise ValueError(f"unknown norm_at {self.norm_at!r} (valid: "
+                             f"'input', 'output')")
+        after = self.norm_at == "output"
+        if (after or self.linear) and (
+                hc is not None or self.ssm is not None or branch is not None):
+            raise ValueError("a norm on a branch's output and a linear "
+                             "mixer are the plain residual block's: no "
+                             "hyper-connections, parallel mixer or side "
+                             "branch around them")
+        # a branch's norm, before it or after it
+        before = lambda name, t: t.astype(self.dtype) if after else _norm(
+            self.norm, name, self.ln_eps)(t).astype(self.dtype)
+        behind = lambda name, t: _norm(self.norm, name, self.ln_eps)(
+            t).astype(self.dtype) if after else t
         if hc is not None:
             if self.ssm is not None or branch is not None:
                 raise ValueError("hyper-connections around a parallel mixer "
@@ -692,8 +738,16 @@ class GPTBlock(nn.Module):
             kernel = None if decode else False
             xs, (x, post, mix) = x, hc_pre(x, self._hc_maps("hc1", E), hc,
                                            kernel=kernel)
-        u = _norm(self.norm, "ln1", self.ln_eps)(x).astype(self.dtype)
-        if self.mla is not None:
+        u = before("ln1", x)
+        if self.linear:
+            if self.gdn is None:
+                raise ValueError("a linear layer needs the stack's GDNConfig "
+                                 "(CausalTransformer.gdn)")
+            y = GatedDeltaNet(self.gdn, dtype=self.dtype,
+                              state_rows=self.state_rows, name="mixer")(
+                u, decode=decode, positions=positions, seq_lens=seq_lens,
+                rows=rows)
+        elif self.mla is not None:
             if (self.window or self.sink or self.v_head_dim
                     or self.partial_rotary_factor != 1.0
                     or self.value_scale != 1.0):
@@ -716,10 +770,13 @@ class GPTBlock(nn.Module):
                 v_head_dim=self.v_head_dim,
                 partial_rotary_factor=self.partial_rotary_factor,
                 value_scale=self.value_scale, window=self.window,
-                sink=self.sink)
-        y = attn(_scaled(u, mup.attention_in), valid, decode=decode,
-                 positions=positions, pages=pages, seq_lens=seq_lens)
-        y = _scaled(y, mup.attention_out)
+                sink=self.sink, qk_norm=self.qk_norm,
+                qk_norm_eps=self.ln_eps)
+        if not self.linear:
+            y = attn(_scaled(u, mup.attention_in), valid, decode=decode,
+                     positions=positions, pages=pages, seq_lens=seq_lens)
+            y = _scaled(y, mup.attention_out)
+        y = behind("ln1", y)
         y = nn.Dropout(self.dropout, deterministic=not train)(y)
         if hc is None:
             x = x + y
@@ -735,7 +792,7 @@ class GPTBlock(nn.Module):
                 _scaled(u, mup.ssm_in), decode=decode, positions=positions,
                 seq_lens=seq_lens, rows=rows)
             x = x + _scaled(m, mup.ssm_out)
-        y = _norm(self.norm, "ln2", self.ln_eps)(x).astype(self.dtype)
+        y = before("ln2", x)
         E = x.shape[-1]
         # the tokens given to experts: a decode apply's real positions (a
         # dead row, a bucket's padding are not), else the non-pad ids
@@ -775,7 +832,7 @@ class GPTBlock(nn.Module):
         else:
             raise ValueError(f"unknown mlp {self.mlp!r} (valid: 'gelu', "
                              f"'swiglu', 'experts')")
-        y = nn.Dropout(self.dropout, deterministic=not train)(y)
+        y = nn.Dropout(self.dropout, deterministic=not train)(behind("ln2", y))
         if hc is not None:
             return hc_post(xs, y, post, mix, kernel=kernel)
         return x + y
@@ -896,8 +953,10 @@ class CausalTransformer(nn.Module):
     ln_eps: float = 1e-6    # GPT-2 uses 1e-5
     attn_bias: bool = False
     # --- positions: "learned" (GPT-2 style absolute table, capped at
-    # max_len) or "rope" (ops.rotary — no table; plain forward extrapolates
-    # past max_len, which then only gates the decode cache capacity) ---
+    # max_len), "rope" (ops.rotary — no table; plain forward extrapolates
+    # past max_len, which then only gates the decode cache capacity) or
+    # "none" (no positional term anywhere: the order of the tokens reaches
+    # the model through its causal mask and its recurrent layers alone) ---
     pos: str = "learned"
     rope_theta: float = 10000.0
     # --- MoE interleaving ---
@@ -982,6 +1041,16 @@ class CausalTransformer(nn.Module):
     partial_rotary_factor: float = 1.0
     value_scale: float = 1.0
     window_pages: int = 0
+    # --- a token mixer that is not attention, by the same pattern: a kind
+    # with ``AttnKind.linear`` is a Gated DeltaNet layer of ``gdn``'s sizes
+    # (models/gated_deltanet.py): a recurrent state a program row
+    # (``state_rows``), no paged cache. ``norm_at``: "input" (``x +
+    # f(norm(x))``) or "output" (``x + norm(f(x))``, Olmo 2's block) for
+    # every layer's two norms. ``qk_norm``: RMSNorm over the whole query and
+    # key projections of every attention layer. ---
+    gdn: Optional[GDNConfig] = None
+    norm_at: str = "input"
+    qk_norm: bool = False
 
     @property
     def layer_cls(self):
@@ -1029,14 +1098,16 @@ class CausalTransformer(nn.Module):
             valid = jnp.ones((B, L), jnp.bool_)
         else:
             valid = token_ids != PAD_ID
-        if self.pos not in ("learned", "rope"):
-            raise ValueError(f"unknown pos {self.pos!r} (valid: 'learned', 'rope')")
+        if self.pos not in ("learned", "rope", "none"):
+            raise ValueError(f"unknown pos {self.pos!r} (valid: 'learned', "
+                             f"'rope', 'none')")
         use_rope = self.pos == "rope"
+        table = self.pos == "learned"
         x = nn.Embed(self.vocab_size, self.embed_dim, name="token_embed",
                      embedding_init=_part((None, "tp"))(nn.initializers.normal(0.02)))(token_ids)
         mup = self.mup or MuP()
         x = _scaled(x, mup.embedding)
-        if not use_rope:
+        if table:
             pos = self.param("pos_embed",
                              _part((None, None, "tp"))(nn.initializers.normal(0.02)),
                              (1, self.max_len, self.embed_dim))
@@ -1054,7 +1125,7 @@ class CausalTransformer(nn.Module):
                 # suffix prefill) — the clip keeps bucket-padding rows,
                 # whose nominal positions can run past the table, from an
                 # out-of-bounds gather (their output is discarded anyway).
-                if use_rope:
+                if not table:
                     x = x.astype(self.dtype)
                 else:
                     pos_full = jnp.clip(
@@ -1064,13 +1135,13 @@ class CausalTransformer(nn.Module):
             else:
                 i0 = cursor.value
                 cursor.value = i0 + L
-                if use_rope:
+                if not table:
                     x = x.astype(self.dtype)  # position enters inside attention
                 else:
                     pos_slice = jax.lax.dynamic_slice(
                         pos, (0, i0, 0), (1, L, self.embed_dim))
                     x = (x + pos_slice).astype(self.dtype)
-        elif use_rope:
+        elif not table:
             x = x.astype(self.dtype)
         else:
             x = (x + pos[:, :L]).astype(self.dtype)
@@ -1101,7 +1172,8 @@ class CausalTransformer(nn.Module):
             mla=self.mla, experts=self.experts,
             v_head_dim=self.v_head_dim,
             partial_rotary_factor=self.partial_rotary_factor,
-            value_scale=self.value_scale,
+            value_scale=self.value_scale, qk_norm=self.qk_norm,
+            norm_at=self.norm_at, gdn=self.gdn,
             hc=HCConfig(self.hc_mult, self.hc_sinkhorn_iters, self.hc_eps,
                         self.hc_clamp, self.ln_eps) if self.hc_mult else None)
         if self.attn_kinds:
@@ -1145,7 +1217,7 @@ class CausalTransformer(nn.Module):
             a = self.attn_kinds[kind[0]]
             return {**shared_fields, "mlp": kind[1], "num_kv_heads": a.num_kv_heads,
                     "rope_theta": a.rope_theta, "window": a.window,
-                    "sink": a.sink, "kv_pages": (
+                    "sink": a.sink, "linear": a.linear, "kv_pages": (
                         self.window_pages if a.window else self.kv_pages)}
 
         def table_of(i):

@@ -69,6 +69,13 @@ class SSMConfig:
     def in_dim(self) -> int:
         return self.d_ssm + self.conv_dim + self.num_heads
 
+    @property
+    def state_row_bytes(self) -> int:
+        """Bytes one program row's state takes in one layer: the float32
+        state and the convolution's tail."""
+        return 4 * (self.num_heads * self.d_state * self.head_dim
+                    + (self.d_conv - 1) * self.conv_dim)
+
 
 def _part(names):
     return lambda init: nn.with_partitioning(init, names)

@@ -352,6 +352,14 @@ SERVING_COUNTERS = {
         "window_pages_live", "Those of kubeml_serving_window_pages_held_total "
                              "a step's query could read: the pages its "
                              "window of keys lies in"),
+    "kubeml_serving_state_rows_moved_total": (
+        "state_rows_moved", "Slab rows a decode step's state kernel read and "
+                            "wrote in each layer that keeps a recurrent "
+                            "state, live or not (absent for a model without "
+                            "recurrent state)"),
+    "kubeml_serving_state_rows_live_total": (
+        "state_rows_live", "Those of kubeml_serving_state_rows_moved_total "
+                           "that belonged to a live row and advanced"),
 }
 # XLA compile counter, labeled {model, program} — rendered from the
 # snapshot's per-program compile-count dict rather than the scalar tables

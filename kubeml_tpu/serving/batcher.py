@@ -2171,14 +2171,20 @@ class PagedBatchingDecoder(BatchingDecoder):
     stays on the dense engine until the arena learns a head-sharded layout.
 
     **Recurrent state beside the pages** — a model with a Mamba-2 mixer
-    (``CacheSpec.recurrent``) keeps, per layer, one
-    fixed-size state per program row (``ssm_state`` ``[slots, H, N, P]``
-    float32 and ``conv_tail``) in the same ``cache`` tree as the arena:
-    donated, rebuilt and freed with it. An admission names its row's place
+    beside attention, or a Gated DeltaNet mixer in its place in some layers
+    (``CacheSpec.state_layers`` of them), keeps, in each such layer, one
+    fixed-size state per program row (``ssm_state`` ``[slots, H, N, P]`` or
+    ``gdn_state`` ``[slots, H / p, d_k, p d_v]``, float32, and
+    ``conv_tail``; ``CacheSpec.state_row_bytes`` a row) in the same
+    ``cache`` tree as the arena: donated, rebuilt and freed with it. A layer
+    whose mixer is the state alone has no arena, so the pool's pages are the
+    other layers'. An admission names its row's place
     (``rows``) and the model starts it from zeros (a reused slot), or from
     the row's own state where a chunked prefill continues, and writes the
     state at the prompt's true length; the decode step advances the state
-    of live rows only, in place (ops/ssm.py ``ssm_update``).
+    of live rows only, in place (ops/ssm.py ``ssm_update``, ops/
+    gated_delta.py ``gdn_update``: the kernel reads and writes every slab
+    row, ``state_rows_moved``, of which ``state_rows_live`` advance).
 
     **Two kinds of lease** — a model that mixes window layers with full
     ones (``CacheSpec.window_layers``) has two arenas a kind: the
@@ -2452,9 +2458,6 @@ class PagedBatchingDecoder(BatchingDecoder):
         self._expert_param_bytes = sum(
             int(l.size) * np.dtype(l.dtype).itemsize for l in _leaves_named(
                 self._variables, "w_gate", "w_up", "w_down"))
-        # recurrent state beside the pages, counted off the slab once it is
-        # built (the engine thread's first turn)
-        self._recurrent_layers = self._recurrent_bytes = 0
 
     # --- capacity & programs ---
 
@@ -2479,9 +2482,6 @@ class PagedBatchingDecoder(BatchingDecoder):
 
     def _init_slab(self) -> _Slab:
         slab = super()._init_slab()
-        state = _leaves_named(slab.cache, "ssm_state", "conv_tail")
-        self._recurrent_layers = len(state) // 2
-        self._recurrent_bytes = sum(int(l.nbytes) for l in state)
         if self.spec == "draft":
             # the drafter's own paged arena (same page ids, its own
             # head/depth dims) — rebuilt with the slab on fault recovery,
@@ -3176,13 +3176,13 @@ class PagedBatchingDecoder(BatchingDecoder):
         if self.window_ring:
             # both tables: the full layers' pages and the rows' rings
             tables = (tables, jnp.asarray(self._wtable.copy()))
+        state_rows = (sum(r is not None and not r.prefilling
+                          for r in self._slot_rows)
+                      if self.cache.recurrent else 0)
         (self._slab, packed), cold = self._run_program(
             "step", (size, w), self._steps[size],
             self._variables, self._slab, tables,
-            kind="step", steps=size, width=w,
-            state_rows=(sum(r is not None and not r.prefilling
-                            for r in self._slot_rows)
-                        if self.cache.recurrent else 0))
+            kind="step", steps=size, width=w, state_rows=state_rows)
         # one span per step: step s's query sits s positions past pos_cap
         kv_bytes = sum(self._chunk_kv_tokens(w, s)
                        for s in range(1, size + 1)) * self._kv_token_bytes
@@ -3205,7 +3205,10 @@ class PagedBatchingDecoder(BatchingDecoder):
             if (row is not None and not row.done and not row.canceled
                     and not row.prefilling):
                 row.dispatched += size
-        self.stats.chunk()
+        # a recurrent model's state kernel reads and writes every slab row
+        self.stats.chunk(state_rows=(
+            size * self.slots if self.cache.recurrent else 0,
+            size * state_rows))
         return ("chunk", packed, list(self._slot_rows), kv_bytes, cold,
                 coloc)
 
@@ -3744,8 +3747,9 @@ class PagedBatchingDecoder(BatchingDecoder):
         snap["residual_streams"] = float(self.cache.residual_streams)
         # recurrent state beside the pages: layers that keep one, its bytes
         # over all program rows, and whether it switched prefix sharing off
-        snap["recurrent_layers"] = float(self._recurrent_layers)
-        snap["recurrent_state_bytes"] = float(self._recurrent_bytes)
+        snap["recurrent_layers"] = float(self.cache.state_layers)
+        snap["recurrent_state_bytes"] = float(
+            self.cache.state_bytes(self.slots))
         snap["prefix_cache_off_recurrent"] = (
             1.0 if "prefix_sharing" in self._features_off else 0.0)
         # a latent arena: values one token holds in one layer, once, and

@@ -150,6 +150,14 @@ class DecoderStats:
         self.tile_chunks_grid_window = 0
         self.window_pages_held = 0
         self.window_pages_live = 0
+        # recurrent state beside the pages (absent for a model without one
+        # and until a step has run): the decode step's state kernel reads
+        # and writes every slab row in each layer that keeps a state
+        # (``state_rows_moved``, rows a step; telemetry's
+        # ``recurrent_layers`` says how many layers), of which the live
+        # rows' advance (``state_rows_live``)
+        self.state_rows_moved = 0
+        self.state_rows_live = 0
         self.goodput_tokens = 0       # tokens delivered to a live waiter
         self.wasted_tokens = 0        # tokens routed to an aborted request
         # shared-prefix reuse (paged engine, serving/kvpool.py): admissions
@@ -292,9 +300,14 @@ class DecoderStats:
         with self._lock:
             self.admission_waves += 1
 
-    def chunk(self) -> None:
+    def chunk(self, state_rows: tuple = (0, 0)) -> None:
+        """One dispatched decode chunk; ``state_rows`` is its ``(moved,
+        live)`` recurrent states over all its steps: slab rows that went
+        through the state kernel, and the live rows among them."""
         with self._lock:
             self.chunks += 1
+            self.state_rows_moved += int(state_rows[0])
+            self.state_rows_live += int(state_rows[1])
 
     def chunk_occupancy(self, steps: int, live: int, dead: int,
                         idle: int, capacity: Optional[int] = None) -> None:
@@ -755,6 +768,9 @@ class DecoderStats:
                                  "tile_chunks_grid_window",
                                  "window_pages_held", "window_pages_live"):
                         out[name] = float(getattr(self, name))
+            if self.state_rows_moved:
+                out["state_rows_moved"] = float(self.state_rows_moved)
+                out["state_rows_live"] = float(self.state_rows_live)
             # speculative-decoding series only exist once a spec step ran:
             # dense decoders / spec-off engines keep a clean exposition
             # (absence reads as "not speculating", like the paged gauges)

@@ -17,6 +17,7 @@ def _sds(shape, dtype):
 
 def kernel_cases():
     from kubeml_tpu.ops.flash_attention import flash_attention
+    from kubeml_tpu.ops.gated_delta import gdn_update
     from kubeml_tpu.ops import hyper_connection as hc
     from kubeml_tpu.ops.grouped_matmul import grouped_matmul
     from kubeml_tpu.ops.int8_matmul import int8_matmul
@@ -220,4 +221,25 @@ def kernel_cases():
             cases[f"paged_attention-mimo-v2-{tag}"] = (
                 lambda q, kv, t, p, h=hkv: paged_attention(
                     q, kv, t, p, kv_heads=h, **mimo), args)
+    # Olmo-Hybrid-7B's published shapes (ISSUE 48): the gated-delta state
+    # of 30 heads of [96, 192] float32, stored two heads side by side as 15
+    # of [96, 384], on a 96-row slab; and the two full layers' walk at 30
+    # K/V heads of 128 (rows of 7,680 lanes), a decode step under the three
+    # table widths the cell reaches and the 512-position admit
+    cases["gdn_update-olmo-hybrid-7b"] = (
+        lambda s, q, k, v, g, b: gdn_update(s, q, k, v, g, b,
+                                            interpret=False),
+        (_sds((96, 15, 96, 384), jnp.float32),
+         _sds((96, 30, 96), jnp.float32), _sds((96, 30, 96), jnp.float32),
+         _sds((96, 30, 192), jnp.float32), _sds((96, 30), jnp.float32),
+         _sds((96, 30), jnp.float32)))
+    for tag, nrows, L, width in (("L1-P32", 96, 1, 32), ("L1-P64", 96, 1, 64),
+                                 ("L1-P96", 96, 1, 96),
+                                 ("admit-L512", 1, 512, 32)):
+        cases[f"paged_attention-olmo-hybrid-{tag}"] = (
+            lambda q, kv, t, p: paged_attention(q, kv, t, p,
+                                                interpret=False),
+            (_sds((nrows, L, 30, 128), jnp.bfloat16),
+             _sds((9217, PT, kv_row_width(30, 128)), jnp.bfloat16),
+             _sds((nrows, width), jnp.int32), _sds((nrows,), jnp.int32)))
     return cases

@@ -3,7 +3,7 @@ allows (ISSUE 47).
 
 ``models/cache_spec.py cache_spec`` is asked once a decoder and
 ``serving/batcher.py CACHE_FEATURES`` is read once a decoder; this file holds
-both to the six tiny configurations of the benchmark's own tests (read, not
+both to the seven tiny configurations of the benchmark's own tests (read, not
 edited; ``data/configs/tiny.json`` stands for both GPT-2 sizes):
 
 * ``TABLE`` spells out every cell as it stood before the table existed
@@ -25,7 +25,7 @@ import jax.numpy as jnp
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.models import (falcon_h1, glm_moe_lite, gpt2,  # noqa: E402
-                              longcat_flash, mimo_v2, xing4)
+                              longcat_flash, mimo_v2, olmo_hybrid, xing4)
 from kubeml_tpu.api.errors import KubeMLError  # noqa: E402
 from kubeml_tpu.models import gpt  # noqa: E402
 from kubeml_tpu.models.cache_spec import PROPERTIES, cache_spec  # noqa: E402
@@ -44,11 +44,12 @@ FAMILIES = {
     "xing": ("data_xing/configs/tiny-xing.json", xing4),
     "longcat": ("data_longcat/configs/tiny-longcat.json", longcat_flash),
     "mimo": ("data_mimo/configs/tiny-mimo.json", mimo_v2),
+    "olmo": ("data_olmo/configs/tiny-olmo.json", olmo_hybrid),
 }
 HAS = {
     "gpt2": set(), "falcon": {"recurrent"}, "glm": {"latent", "experts"},
     "xing": {"latent", "experts"}, "longcat": {"latent", "experts"},
-    "mimo": {"window", "experts"},
+    "mimo": {"window", "experts"}, "olmo": {"recurrent"},
 }
 
 # today's cells, every one; a pair that is not here is served
@@ -300,6 +301,9 @@ PARENT = {
     "xing": ((5, 0, 5, 4, 24, 128, 3, 8, 0, 32), (1351680, 480, 0, 10)),
     "longcat": ((4, 0, 4, 1, 24, 128, 2, 4, 0, 32), (1081344, 384, 0, 0)),
     "mimo": ((7, 5, 2, 1, 0, 0, 6, 4, 4, 128), (876544, 640, 3200, 0)),
+    # no parent: the family is PR 48's, and these are its values then (2 of
+    # its 8 layers page; the keys are the other families')
+    "olmo": ((2, 0, 2, 1, 0, 0, 0, 0, 0, 32), (1081344, 1536, 0, 0)),
 }
 STATIC = ("cache_sublayers", "window_layers", "full_layers",
           "residual_streams", "kv_latent_width", "kv_latent_row_width",
@@ -317,5 +321,66 @@ def test_telemetry_is_the_parents(name):
         assert tuple(tel[k] for k in STATIC) == tuple(map(float, static))
         assert (dec.arena_bytes, dec._kv_token_bytes,
                 dec._window_token_bytes, dec.stats.hc_sublayers) == beside
+    finally:
+        dec.close()
+
+
+# --- layers without pages (PR 48) --------------------------------------------
+
+
+def test_a_stack_with_layers_that_do_not_page_counts_the_ones_that_do():
+    """Olmo-Hybrid's pattern: of 8 layers the 2 full-attention ones page K
+    and V of 6 heads of 16, the 6 linear ones carry a state and no pages.
+    Every sum an engine reads counts the 2; the state is counted beside."""
+    _, module, _ = family("olmo")
+    spec = cache_spec(module)
+    assert module.depth == 8 and len(spec.layers) == 2
+    assert all((l.kv_heads, l.k_dim, l.v_dim, l.window) == (6, 16, 16, 0)
+               for l in spec.layers)
+    assert (spec.sublayers, spec.full_layers, spec.window_layers) == (2, 2, 0)
+    # a cached token: K and V of 6 heads of 16 in each of TWO layers
+    assert spec.token_bytes() == 4 * 2 * 6 * 32
+    assert spec.token_bytes(first=1) == 4 * 6 * 32
+    # 192 values a token and layer stored in 256 lanes, 16 tokens a page
+    assert spec.page_bytes(16) == 16 * 4 * 2 * 256
+    assert spec.window_token_bytes() == spec.ring_page_bytes(16) == 0
+    assert spec.ring_pages(16) == 0 and spec.latent is None
+    # six layers of state: 6 heads x 8 x 16 float32 and 3 taps' tail of
+    # 6 x (8 + 8 + 16) inputs, a program row
+    assert spec.recurrent and spec.state_layers == 6
+    assert spec.state_row_bytes == 4 * (6 * 8 * 16 + 3 * 6 * 32)
+    assert spec.state_bytes(4) == 4 * 6 * spec.state_row_bytes
+    assert spec.properties == {"recurrent"}
+
+
+@pytest.mark.parametrize("name,layers,row", [
+    # a Mamba-2 mixer beside attention in EVERY layer: 4 heads of [16, 8]
+    # float32 and 3 taps' tail of 32 + 2 x 2 x 16 inputs
+    ("falcon", 2, 4 * (4 * 16 * 8 + 3 * 96)),
+    # a Gated DeltaNet mixer IN PLACE of attention in 6 of 8 layers
+    ("olmo", 6, 4 * (6 * 8 * 16 + 3 * 6 * 32)),
+    ("mimo", 0, 0), ("gpt2", 0, 0), ("glm", 0, 0)])
+def test_the_state_beside_the_pages_is_what_it_was(name, layers, row):
+    """Falcon-H1's state is counted as the slab's leaves weighed before
+    (``recurrent_layers``, ``recurrent_state_bytes``); a model without one
+    counts none, and its ``layers`` are its whole stack."""
+    _, module, tree = family(name)
+    spec = cache_spec(module)
+    assert (spec.state_layers, spec.state_row_bytes) == (layers, row)
+    assert spec.recurrent == (layers > 0)
+    if name in ("falcon", "gpt2"):
+        assert spec.sublayers == module.depth
+    dec = deployed(name)
+    try:
+        built = init_paged_cache(dec.module, tree, dec.slots,
+                                 dec.table_pages)
+        state = [leaf for path, leaf
+                 in jax.tree_util.tree_leaves_with_path(built)
+                 if getattr(path[-1], "key", None) in (
+                     "ssm_state", "gdn_state", "conv_tail")]
+        assert sum(a.nbytes for a in state) == spec.state_bytes(dec.slots)
+        tel = dec.telemetry()
+        assert tel["recurrent_layers"] == layers
+        assert tel["recurrent_state_bytes"] == spec.state_bytes(dec.slots)
     finally:
         dec.close()

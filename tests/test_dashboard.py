@@ -337,6 +337,10 @@ UNPANELED = {
         "kernel-specific; ad-hoc only",
     "kubeml_serving_window_pages_held_total": "benchmark-read; ad-hoc only",
     "kubeml_serving_window_pages_live_total": "benchmark-read; ad-hoc only",
+    # PR 48: what the recurrent layers' state kernel moves against what
+    # advances; the benchmark reads their ratio (state_rows_live_share)
+    "kubeml_serving_state_rows_moved_total": "benchmark-read; ad-hoc only",
+    "kubeml_serving_state_rows_live_total": "benchmark-read; ad-hoc only",
 }
 
 
