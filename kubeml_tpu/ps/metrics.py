@@ -330,6 +330,20 @@ SERVING_COUNTERS = {
         "tile_chunks_live", "Those of kubeml_serving_tile_chunks_grid_total "
                             "under their tile's causal depth and the row's: "
                             "the ones that fetch pages and multiply"),
+    "kubeml_serving_latent_walk_trips_run_total": (
+        "latent_walk_trips_run", "Trips of the latent page walk's loop the "
+                                 "decode steps ran, all latent layers: a "
+                                 "program row is ceil(depth / C) trips of C "
+                                 "pages (absent where steps walk no "
+                                 "latents)"),
+    "kubeml_serving_latent_walk_trips_live_total": (
+        "latent_walk_trips_live", "Those of kubeml_serving_latent_walk_trips_"
+                                  "run_total a live row made; the rest are "
+                                  "dead rows', one over the trash page a "
+                                  "step"),
+    "kubeml_serving_latent_walk_pages_total": (
+        "latent_walk_pages", "Pages the trips of kubeml_serving_latent_walk_"
+                             "trips_run_total copied"),
     "kubeml_serving_walk_chunks_grid_window_total": (
         "walk_chunks_grid_window", "The window layers' part of "
                                    "kubeml_serving_walk_chunks_grid_total: a "

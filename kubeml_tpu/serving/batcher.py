@@ -2372,6 +2372,10 @@ class PagedBatchingDecoder(BatchingDecoder):
         # prefill dispatch (_run_prefill)
         self.stats.walks_kv_chunks = (
             impl == "pallas" and kvq == "off" and cache.latent is None)
+        # a latent arena's steps take the latent walk's loop; its trips are
+        # counted at each chunk dispatch too (_latent_walk_trips)
+        self.stats.walks_latents = (
+            impl == "pallas" and cache.latent is not None)
         # drafter KV-read constant for the spec accounting: the early-exit
         # self-drafter reads only its truncated stack's layers; a separate
         # draft model reads its own geometry
@@ -3107,6 +3111,24 @@ class PagedBatchingDecoder(BatchingDecoder):
         layers = self.cache.full_layers
         return live * layers, size * self.slots * (w // pages) * layers
 
+    def _latent_walk_trips(self, w: int, size: int) -> tuple:
+        """``(live, run, pages)`` of the latent walk's loop in one chunk of
+        ``size`` steps over a ``w``-page table, all latent layers: trips a
+        resident row made, trips every program row made, and the pages those
+        copied (ops/mla_attention.py walk_trips, the kernel's own rules).
+        Step ``s``'s query sits at ``pos_cap + s - 1``; a program row that
+        is not resident has a zeroed table row, one trip over the trash
+        page a step."""
+        from ..ops.mla_attention import walk_trips
+
+        at = [row.pos_cap + s for row in self._slot_rows
+              if row is not None and row.lease is not None
+              and not row.prefilling for s in range(size)]
+        dead = size * self.slots - len(at)
+        run, pages = walk_trips(w, self.page_tokens, at, dead_rows=dead)
+        layers = self.cache.full_layers
+        return (run - dead) * layers, run * layers, pages * layers
+
     def _ring_chunks(self, size: int) -> tuple:
         """The window layers' part of a chunk of ``size`` steps:
         ``((live, grid) programs of the decode body over the rows' rings,
@@ -3200,6 +3222,8 @@ class PagedBatchingDecoder(BatchingDecoder):
                                 else ((0, 0), (0, 0)))
             self.stats.walk_chunks(live + ring[0], grid + ring[1], ring,
                                    ring_pages)
+        if self.stats.walks_latents:
+            self.stats.latent_walk(*self._latent_walk_trips(w, size))
         self._bump_pos_caps(size)
         for row in self._slot_rows:
             if (row is not None and not row.done and not row.canceled

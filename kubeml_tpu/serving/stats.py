@@ -133,6 +133,17 @@ class DecoderStats:
         self.walk_chunks_grid = 0
         self.tile_chunks_live = 0
         self.tile_chunks_grid = 0
+        # the latent page walk's loop (ops/mla_attention.py), where the
+        # paged engine's steps take it (``walks_latents``; absent otherwise):
+        # trips the decode steps' kernel made, all latent layers (every
+        # program row is ``ceil(depth / C)`` trips of ``C`` pages, whatever
+        # the table's width), those of them a live row's, and the pages the
+        # trips copied; a trip that is not live is a dead row's, one over
+        # the trash page a step
+        self.walks_latents = False
+        self.latent_walk_trips_live = 0
+        self.latent_walk_trips_run = 0
+        self.latent_walk_pages = 0
         # the same four split by layer kind, for a model that mixes window
         # layers with full ones (``window_layers`` > 0, set by the engine;
         # absent otherwise): ``*_window`` are the window layers' part of the
@@ -409,6 +420,15 @@ class DecoderStats:
             self.walk_chunks_grid_window += int(window[1])
             self.window_pages_live += int(ring_pages[0])
             self.window_pages_held += int(ring_pages[1])
+
+    def latent_walk(self, live: int, run: int, pages: int) -> None:
+        """One dispatched decode chunk's latent-walk trips, all steps and
+        latent layers: ``live`` of the ``run`` were a live row's, and the
+        ``run`` copied ``pages`` pages."""
+        with self._lock:
+            self.latent_walk_trips_live += int(live)
+            self.latent_walk_trips_run += int(run)
+            self.latent_walk_pages += int(pages)
 
     def tile_chunks(self, live: int, grid: int,
                     window: tuple = (0, 0)) -> None:
@@ -768,6 +788,10 @@ class DecoderStats:
                                  "tile_chunks_grid_window",
                                  "window_pages_held", "window_pages_live"):
                         out[name] = float(getattr(self, name))
+            if self.walks_latents:
+                for name in ("latent_walk_trips_live",
+                             "latent_walk_trips_run", "latent_walk_pages"):
+                    out[name] = float(getattr(self, name))
             if self.state_rows_moved:
                 out["state_rows_moved"] = float(self.state_rows_moved)
                 out["state_rows_live"] = float(self.state_rows_live)

@@ -324,6 +324,12 @@ UNPANELED = {
     # the same walk's tile body, in the prefill and admission programs
     "kubeml_serving_tile_chunks_grid_total": "kernel-specific; ad-hoc only",
     "kubeml_serving_tile_chunks_live_total": "kernel-specific; ad-hoc only",
+    # the latent page walk's loop (PR 49); the benchmark reads live / run
+    "kubeml_serving_latent_walk_trips_run_total":
+        "kernel-specific; ad-hoc only",
+    "kubeml_serving_latent_walk_trips_live_total":
+        "kernel-specific; ad-hoc only",
+    "kubeml_serving_latent_walk_pages_total": "kernel-specific; ad-hoc only",
     # PR 44: the window layers' part of the four above, and the second
     # kind of lease's bound against its use; the benchmark reads them
     # (window_live_chunk_share, window_pages_share)

@@ -33,10 +33,12 @@ from kubeml_tpu.models.generation import (init_paged_cache,  # noqa: E402
                                           supports_paged_decode)
 from kubeml_tpu.models.mla import MLAConfig  # noqa: E402
 from kubeml_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
+from kubeml_tpu.ops import mla_attention  # noqa: E402
 from kubeml_tpu.ops.mla_attention import (latent_row_width,  # noqa: E402
                                           mla_attn, mla_attn_gather,
-                                          pad_lanes)
-from kubeml_tpu.serving.batcher import PagedBatchingDecoder  # noqa: E402
+                                          pad_lanes, span_pages, walk_trips)
+from kubeml_tpu.ps.metrics import SERVING_COUNTERS  # noqa: E402
+from kubeml_tpu.serving.batcher import PagedBatchingDecoder, _Row  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 # float32 against float32 at precision "highest": what is left is the order
@@ -228,40 +230,158 @@ def test_grouped_product_equals_a_loop_over_experts(sizes, gated):
                                atol=1e-4)   # (the empty case runs too)
 
 
-# a toy latent (24 values in a 128-lane row), the published one (576 in
-# 640) and one that fills its rows (640: nothing added)
-@pytest.mark.parametrize("dc,dr", [(16, 8), (512, 64), (512, 128)])
-@pytest.mark.parametrize("P,positions", [(16, [0, 17, 63]), (8, [31, 5, 8]),
-                                         (3, [11, 0, 7])])
-def test_latent_page_walk_kernel_equals_gather(P, positions, dc, dr):
+# (table width, positions, dc, dr, heads, page tokens, arena type, retired
+# rows). First the toy shapes: a toy latent (24 values in a 128-lane row),
+# the published one (576 in 640) and one that fills its rows (640: nothing
+# added), under tables narrower than one span of the kernel's loop. Then
+# what a loop over a row's live spans can get wrong, at the published latent
+# and the three cells' head counts: a depth that ends inside a span, on a
+# span's last token and one token past it, a row at position 0 and one that
+# fills its table, under a table of several spans that is no whole number of
+# them, of two spans and of less than one; a retired row, whose table points
+# at the trash page (page 0) and whose cursor stays where its request left
+# it, beside live rows (it reads that one page, whatever its cursor says, and
+# what comes out is the engine's to drop); bfloat16 arenas beside float32
+_WALK_CASES = [
+    pytest.param(P, pos, dc, dr, 4, 4, "float32", (), id=f"P{P}-{dc}+{dr}")
+    for dc, dr in [(16, 8), (512, 64), (512, 128)]
+    for P, pos in [(16, [0, 17, 63]), (8, [31, 5, 8]), (3, [11, 0, 7])]
+] + [
+    pytest.param(80, [300, 511, 512, 0, 1279], 512, 64, 20, 16, "float32",
+                 (), id="spans-2.5-H20-f32"),
+    pytest.param(80, [1023, 1024, 700, 513], 512, 64, 20, 16, "bfloat16",
+                 (2,), id="spans-2.5-H20-bf16-retired"),
+    pytest.param(64, [1023, 40, 512, 511], 512, 64, 64, 16, "bfloat16",
+                 (1,), id="spans-2-H64-bf16-retired"),
+    pytest.param(16, [0, 100, 255], 512, 64, 32, 16, "bfloat16", (),
+                 id="under-a-span-H32-bf16"),
+    pytest.param(16, [255, 16, 15], 512, 64, 32, 16, "float32", (0,),
+                 id="under-a-span-H32-f32-retired"),
+    pytest.param(80, [511, 512, 513, 1279], 16, 8, 4, 16, "float32", (3,),
+                 id="spans-2.5-toy-retired"),
+]
+
+
+@pytest.mark.parametrize("P,positions,dc,dr,H,pt,dtype,retired", _WALK_CASES)
+def test_latent_page_walk_kernel_equals_gather(P, positions, dc, dr, H, pt,
+                                               dtype, retired):
     rng = np.random.default_rng(P)
-    B, H, pt, N = 3, 4, 4, 40
+    B = len(positions)
+    N = max(40, B * P + 1)       # the wide tables' pages all distinct
     W, R = dc + dr, latent_row_width(dc + dr)
     assert R % 128 == 0 and 0 <= R - W < 128
-    q = jnp.asarray(rng.standard_normal((B, H, W)), jnp.float32)
-    live = jnp.asarray(rng.standard_normal((N, pt, W)), jnp.float32)
+    assert max(positions) < P * pt
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    q = jnp.asarray(rng.standard_normal((B, H, W)), dtype)
+    live = jnp.asarray(rng.standard_normal((N, pt, W)), dtype)
     arena = pad_lanes(live, R)                   # the rows as they are stored
     assert arena.shape == (N, pt, R) and (arena is live) == (R == W)
-    pages = jnp.asarray(rng.integers(1, N, (B, P)), jnp.int32)
+    table = (rng.integers(1, N, (B, P)) if N == 40
+             else rng.permutation(N - 1)[:B * P].reshape(B, P) + 1)
+    table[list(retired)] = 0                     # the trash page, all along
+    pages = jnp.asarray(table, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     how = dict(value_dim=dc, scale=W ** -0.5)
-    got = mla_attn(q, arena, pages, pos, **how)
+    # (one trace of the kernel for the calls of one shape)
+    walk = jax.jit(lambda *args: mla_attn(*args, **how))
+    got = walk(q, arena, pages, pos)
     want = mla_attn_gather(q, arena, pages, pos, **how)
-    assert got.shape == (B, H, dc)
-    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert got.shape == (B, H, dc) and got.dtype == q.dtype
+    alive = np.array([b not in retired for b in range(B)])
+
+    def gap(a, b):
+        return float(jnp.abs(a.astype(jnp.float32)
+                             - b.astype(jnp.float32))[alive].max())
+
+    # a retired row's result is dropped; it is some average of finite rows
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+
+    assert gap(got, want) < tol
     # the added lanes add exact zeros: the same call on an arena of the live
     # values alone (the layout before PR 43) gives the same bits
-    assert np.array_equal(got, mla_attn(q, live, pages, pos, **how))
+    assert np.array_equal(got, walk(q, live, pages, pos))
     assert np.array_equal(want, mla_attn_gather(q, live, pages, pos, **how))
     # pages past a row's depth are never looked at: poison them
-    depth = (np.asarray(positions) // pt) + 1
-    poisoned = np.asarray(arena).copy()
-    keep = {int(p) for b in range(B) for p in np.asarray(pages)[b, :depth[b]]}
+    depth = np.where(alive, (np.asarray(positions) // pt) + 1, 1)
+    poisoned = np.asarray(arena.astype(jnp.float32)).copy()
+    keep = {int(p) for b in range(B) for p in table[b, :depth[b]]}
     for p in range(N):
         if p not in keep:
             poisoned[p] = np.nan
-    again = mla_attn(q, jnp.asarray(poisoned), pages, pos, **how)
-    assert float(jnp.abs(again - want).max()) < 1e-5
+    again = walk(q, jnp.asarray(poisoned, dtype), pages, pos)
+    assert gap(again, want) < tol
+    # (a retired row looked at its page of trash and nowhere else)
+    assert bool(jnp.isfinite(again.astype(jnp.float32)).all())
+
+
+# --- the host's mirror of the walk's loop -----------------------------------
+
+
+@pytest.mark.parametrize("w,pt,positions,want", [
+    # the cells' pages of 16: a span is 32 pages, 512 positions. A row at
+    # position 0 is one trip of one page; 511 fills the first span, 512
+    # opens the second; 2,399 is 150 pages, 5 trips; a cursor past the
+    # table walks the table and no further
+    (256, 16, [0], (1, 1)), (256, 16, [511], (1, 32)),
+    (256, 16, [512], (2, 33)), (256, 16, [2399], (5, 150)),
+    (256, 16, [5000], (8, 256)),
+    (256, 16, [0, 511, 512, 2399, 5000], (17, 472)),
+    # a table's width costs nothing: the same rows under 128 and 256 pages
+    (128, 16, [1100, 1500, 2000], (3 + 3 + 4, 69 + 94 + 126)),
+    (256, 16, [1100, 1500, 2000], (3 + 3 + 4, 69 + 94 + 126)),
+    # a table narrower than a span is its row's one trip
+    (16, 16, [0, 100, 255], (3, 1 + 7 + 16)), (3, 4, [11, 0, 7], (3, 6)),
+    # pages of 4: a span is 128 pages
+    (300, 4, [511, 512, 1199], (1 + 2 + 3, 128 + 129 + 300)),
+    (64, 16, [], (0, 0)),
+])
+def test_the_mirror_counts_trips_and_pages_by_hand(w, pt, positions, want):
+    assert walk_trips(w, pt, positions) == want
+    # a row whose table starts at the trash page: a trip of a page, each
+    assert walk_trips(w, pt, positions, dead_rows=3) == (want[0] + 3,
+                                                         want[1] + 3)
+    assert span_pages(w, pt) == min(w, 512 // pt)
+
+
+@pytest.mark.parametrize("P,positions,dead", [
+    (80, [300, 511, 512, 0, 1279], ()), (16, [0, 100, 255], ()),
+    (80, [300, 700, 513], (1,))])
+def test_the_kernel_copies_the_pages_the_mirror_counts(P, positions, dead):
+    """The mirror's rules are the kernel's own (one function each), and what
+    they say is what the kernel reads: a row's pages all distinct, every
+    page past the mirror's count poisoned changes nothing, and the last page
+    inside it poisoned is seen, by that row alone. A dead row's count is
+    the trash page, whatever its cursor."""
+    rng = np.random.default_rng(7)
+    pt, H, dc, dr = 16, 4, 512, 64
+    B, N = len(positions), len(positions) * P + 1
+    q = jnp.asarray(rng.standard_normal((B, H, dc + dr)), jnp.float32)
+    arena = np.asarray(pad_lanes(jnp.asarray(
+        rng.standard_normal((N, pt, dc + dr)), jnp.float32), 640)).copy()
+    table = rng.permutation(N - 1)[:B * P].reshape(B, P) + 1
+    table[list(dead)] = 0
+    how = dict(value_dim=dc, scale=0.1)
+    kernel = jax.jit(lambda *args: mla_attn(*args, **how))
+    walk = lambda a: np.asarray(kernel(
+        q, jnp.asarray(a), jnp.asarray(table, jnp.int32),
+        jnp.asarray(positions, jnp.int32)))
+    want = walk(arena)
+    counted = [walk_trips(P, pt, [], dead_rows=1)[1] if b in dead
+               else walk_trips(P, pt, [p])[1]
+               for b, p in enumerate(positions)]
+    assert sum(counted) == walk_trips(
+        P, pt, [p for b, p in enumerate(positions) if b not in dead],
+        dead_rows=len(dead))[1]
+    past = arena.copy()
+    keep = {int(page) for b, n in enumerate(counted) for page in table[b, :n]}
+    past[[page for page in range(N) if page not in keep]] = np.nan
+    assert np.array_equal(walk(past), want)
+    for b, n in enumerate(counted):
+        last = arena.copy()
+        last[table[b, n - 1]] = np.nan
+        got = walk(last)
+        assert np.isnan(got[b]).all()
+        assert np.array_equal(np.delete(got, b, 0), np.delete(want, b, 0))
 
 
 # --- the latent arena's bytes ----------------------------------------------
@@ -484,6 +604,79 @@ def test_engine_serves_the_reference_tokens(model):
     assert tel["paged_attn_kernel"] == 1.0
     assert tel["param_bytes"] == sum(
         l.size * 4 for l in jax.tree.leaves(tree))
+
+
+def make_row(dec, prompt_len, max_new):
+    ids = np.arange(1, prompt_len + 1).astype(np.int32)
+    lease = dec._pool.admit(ids, max_new, max_positions=dec.max_len)
+    row = _Row(entry=None, index=0, prompt=ids, max_new=max_new, temp=0.0,
+               topk=0, eos=-1, key=np.zeros(2, np.uint32), lease=lease)
+    row.pos_cap = prompt_len
+    return row
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3])
+def test_the_engine_counts_the_walks_trips_dead_rows_too(model, monkeypatch,
+                                                         steps):
+    """A span cut to 16 positions (2 pages of this engine's 8), three latent
+    layers: a row whose next query sits at position 30 holds 4 pages, 2
+    trips; one at 5 holds 1; the two program rows nobody holds (one retired,
+    one never used) walk a page of trash a step. Step 3's query of the deep
+    row, position 32, opens its fifth page and third trip."""
+    monkeypatch.setattr(mla_attention, "_SPAN", 16)
+    dec = engine(model)
+    assert dec.stats.walks_latents and not dec.stats.walks_kv_chunks
+    deep, shallow = make_row(dec, 30, 20), make_row(dec, 5, 8)
+    dec._slot_rows[0], dec._slot_rows[2] = deep, shallow
+    try:
+        w = dec._live_table_width(steps)
+        assert w == 8 and span_pages(w, PT) == 2
+        live, run, pages = dec._latent_walk_trips(w, steps)
+        layers = 3
+        fifth = steps == 3
+        assert live == (steps * (2 + 1) + fifth) * layers
+        assert run == live + steps * 2 * layers
+        assert pages == (steps * (4 + 1 + 1 + 1) + fifth) * layers
+    finally:
+        dec._slot_rows[0] = dec._slot_rows[2] = None
+        for r in (deep, shallow):
+            dec._pool.release(r.lease)
+        dec._pool.check()
+        dec.close()
+
+
+def test_a_latent_engines_snapshot_carries_the_walks_counts(model,
+                                                            monkeypatch):
+    """One request of 7 prompt tokens and 6 new ones on four program rows:
+    five steps after the admit's token, queries at positions 7-11, which
+    hold 1, 2, 2, 2, 2 pages of 8; the other three rows walk one page of
+    trash a step. With a span of one page the row makes 1, 2, 2, 2, 2 trips;
+    the tokens are the reference's all the same."""
+    cfg, weights, module, tree = model
+    monkeypatch.setattr(mla_attention, "_SPAN", 8)
+    p = prompts(1, 7, 7, seed=5)[0]
+    with jax.default_matmul_precision("highest"):
+        dec = engine(model)
+        try:
+            (toks,) = serve(dec, [p], 6)
+            tel = dec.telemetry()
+        finally:
+            dec.close()
+    assert served_gap(cfg, weights, p, toks) < TOL
+    layers, dead = 3, SLOTS - 1
+    assert tel["latent_walk_trips_live"] == (1 + 4 * 2) * layers
+    assert tel["latent_walk_trips_run"] == (1 + 4 * 2 + 5 * dead) * layers
+    assert tel["latent_walk_pages"] == (1 + 4 * 2 + 5 * dead) * layers
+    keys = {key for key, _ in SERVING_COUNTERS.values()}
+    assert {"latent_walk_trips_live", "latent_walk_trips_run",
+            "latent_walk_pages"} <= keys
+
+
+def test_an_engine_that_walks_no_latents_reports_no_trips():
+    from kubeml_tpu.serving.stats import DecoderStats
+
+    snap = DecoderStats(slots=2).snapshot()
+    assert not [k for k in snap if k.startswith("latent_walk_")]
 
 
 def test_block_traces_grow_by_two_a_program(model):

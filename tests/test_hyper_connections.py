@@ -594,10 +594,15 @@ def program_digest(module, tree):
 # layers pads the row it writes and a step the query it walks with (a
 # ``jnp.pad`` is three equations: 865 and 1045 before); everything else of
 # the two are PR 36's, equation for equation. PR 43 left the two K/V
-# families' programs as they were
+# families' programs as they were. glm's "step" was made again on PR 49's
+# tree, which moved it on purpose: the latent walk's kernel is a loop over a
+# row's live spans that copies its pages itself (ops/mla_attention.py; 1063
+# equations before, 236 more a layer: a full span's copies are written
+# out); its "admit", which attends expanded and never calls the walk, is PR
+# 43's still
 PARENT_PROGRAMS = {
     "glm": {"admit": (874, "1c210d6a9a19ffc6"),
-            "step": (1063, "195bd73393f0dde1")},
+            "step": (1771, "0127c67bc0f42ee7")},
     "gpt2": {"admit": (1248, "e5faa9da9aaadbb1"),
              "step": (690, "66df1c13cb8cce27")},
     "falcon": {"admit": (1645, "100bfbb328f0ac69"),

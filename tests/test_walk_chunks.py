@@ -25,8 +25,8 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
 from benchmark.layer_metrics import (  # noqa: E402
-    tile_live_chunk_share_capacity, walk_live_chunk_share,
-    walk_live_chunk_share_capacity)
+    latent_walk_live_share, tile_live_chunk_share_capacity,
+    walk_live_chunk_share, walk_live_chunk_share_capacity)
 from benchmark.layers import Reading  # noqa: E402
 from kubeml_tpu.api.types import GenerateRequest  # noqa: E402
 from kubeml_tpu.models.gpt import CausalTransformer  # noqa: E402
@@ -91,6 +91,28 @@ def test_the_reader_on_a_made_up_snapshot(reader, c0, c1, want):
 ])
 def test_the_tile_reader_on_a_made_up_snapshot(c0, c1, want):
     got = tile_live_chunk_share_capacity.read(reading(c0, c1))
+    assert got == want
+    assert got is None or 0.0 <= got <= 100.0
+
+
+@pytest.mark.parametrize("c0,c1,want", [
+    # the latent walk's loop (PR 49): the window's growth, live trips of the
+    # trips run; the rest were dead rows' over the trash page
+    ({"latent_walk_trips_live": 40.0, "latent_walk_trips_run": 50.0},
+     {"latent_walk_trips_live": 2980.0, "latent_walk_trips_run": 3050.0},
+     98.0),
+    ({"latent_walk_trips_live": 0.0, "latent_walk_trips_run": 0.0},
+     {"latent_walk_trips_live": 64.0, "latent_walk_trips_run": 64.0}, 100.0),
+    # a K/V engine, or the commit before the counters
+    ({"walk_chunks_live": 1.0, "walk_chunks_grid": 2.0},
+     {"walk_chunks_live": 5.0, "walk_chunks_grid": 9.0}, None),
+    ({"latent_walk_trips_run": 5.0}, {"latent_walk_trips_run": 9.0}, None),
+    # no step in the window
+    ({"latent_walk_trips_live": 3.0, "latent_walk_trips_run": 8.0},
+     {"latent_walk_trips_live": 3.0, "latent_walk_trips_run": 8.0}, None),
+])
+def test_the_latent_reader_on_a_made_up_snapshot(c0, c1, want):
+    got = latent_walk_live_share.read(reading(c0, c1))
     assert got == want
     assert got is None or 0.0 <= got <= 100.0
 
