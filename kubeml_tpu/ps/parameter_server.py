@@ -1645,9 +1645,12 @@ class ParameterServer:
     def _densified(variables):
         """Dense view of possibly-int8 serving variables for the paths that
         consume a plain tree (classifier /infer, the one-shot generate
-        fallback) — the batcher consumes QuantizedTensor leaves natively."""
-        from ..serving.quant import dequantize_tree, is_quantized_tree
+        fallback) — the batcher consumes QuantizedTensor leaves natively,
+        and slices a table held by padded rows inside its programs."""
+        from ..serving.quant import (dequantize_tree, is_quantized_tree,
+                                     unpadded)
 
+        variables = unpadded(variables)
         if is_quantized_tree(variables):
             import jax.numpy as jnp
 
@@ -1902,7 +1905,7 @@ class ParameterServer:
         narrowed = 0
         if not placed:
             variables, narrowed = self._held(
-                variables, getattr(model, "module", None))
+                variables, getattr(model, "module", None), mesh)
         with self._lock:
             self._serving_startup[model_id] = {
                 "restore": t1 - t0, "hold": time.monotonic() - t1}
@@ -1977,26 +1980,37 @@ class ParameterServer:
             variables = from_storage_tree(variables)
         return (model, variables, mesh, mesh is not None)
 
-    def _held(self, variables, module) -> tuple:
+    def _held(self, variables, module, mesh=None) -> tuple:
         """(the served tree as it is held on the device, the leaves the
         rule narrowed). With ``Config.serving_param_dtype`` set every
         floating leaf is cast to it. Empty, the module is asked what it
-        does with each leaf (``serving.quant.held_types``: its forward
+        does with each leaf (``serving.quant.held_as``: its forward
         traced abstractly): **a leaf whose every use is a cast to one and
         the same narrower floating type is held in that type**, the same
         bits the programs would make of it every step and every admit;
-        every other leaf stays as the checkpoint has it. The rule is
-        skipped, and the tree kept whole, for a tree that holds quantized
-        leaves, for a process that quantizes at serve time (the quantizer
-        must see the checkpoint's own values), for a module that is no
-        token-in LM and where the trace fails. A tree already placed on a
-        serving mesh never comes here: its shardings were derived for the
-        leaves as restored. A ``ps.serving.hold`` span: the types ``from``
-        and ``to``, ``bytes`` as held, ``narrowed`` and ``narrowed_bytes``
-        (the leaves the rule cast, and their bytes as held), ``kept`` (the
-        floating leaves still in the type they came in), and what the casts
-        compiled on this thread, which is the hold's and not an engine
-        program's."""
+        every other leaf stays as the checkpoint has it. Type, then
+        layout, by the same trace: **a table of which the programs only
+        gather whole rows (the token lookup) is held with a row contiguous
+        on the lanes** (``serving.quant.rows_on_lanes``: nothing but the
+        placement where the device stores the leaf by rows of itself; a
+        ``PaddedRows`` of whole lane rows where it does not, which the
+        engines' programs slice to the table's own width before
+        ``module.apply``). The rule is skipped, and the tree kept whole,
+        for a tree that holds quantized leaves, for a process that
+        quantizes at serve time (the quantizer must see the checkpoint's
+        own values), for a module that is no token-in LM and where the
+        trace fails. A tree already placed on a serving mesh never comes
+        here: its shardings were derived for the leaves as restored; and a
+        tree that the engine of ``mesh`` will place is cast here and no
+        table of it padded: the mesh lays its shards out.
+        A ``ps.serving.hold`` span: the types ``from`` and ``to``, ``bytes``
+        as held, ``narrowed`` and ``narrowed_bytes`` (the leaves the rule
+        cast, and their bytes as held), ``kept`` (the floating leaves still
+        in the type they came in), ``row_tables`` (the tables the rule
+        named) and ``padded_bytes`` (what padding their rows added, 0 where
+        the device stores them by rows as they are), and what the casts and
+        a padding compiled on this thread, which is the hold's and not an
+        engine program's."""
         import jax
 
         from ..serving import quant
@@ -2012,19 +2026,20 @@ class ParameterServer:
         with tracing.get_tracer().span("ps.serving.hold",
                                        service="ps") as span:
             restored = variables
-            types = []
+            types, rows = [], []
             if want:
                 variables = quant.cast_tree(variables, want)
             elif (self.cfg.serving_quantize != "int8"
                   and not quant.is_quantized_tree(variables)
                   and _decodes_tokens(module)):
                 try:
-                    types = quant.held_types(module, variables)
+                    types, rows = quant.held_as(module, variables)
                 except Exception:
                     log.debug("tracing the serving forward failed; holding "
                               "the tree as restored", exc_info=True)
                 else:
-                    variables = quant.cast_leaves(variables, types)
+                    variables = quant.cast_leaves(
+                        variables, types, rows if mesh is None else None)
             cast = [i for i, to in enumerate(types) if to is not None]
             if span is not None:
                 held = jax.tree.leaves(variables)
@@ -2033,6 +2048,8 @@ class ParameterServer:
                     to=_float_types(variables), bytes=_tree_bytes(variables),
                     narrowed=len(cast),
                     narrowed_bytes=sum(int(held[i].nbytes) for i in cast),
+                    row_tables=sum(rows),
+                    padded_bytes=quant.padded_bytes(variables),
                     kept=sum(was.dtype == now.dtype for was, now in zip(
                         jax.tree.leaves(restored), held)
                         if quant.is_floating(now)),

@@ -860,16 +860,21 @@ class BatchingDecoder:
         return logits[:, -1].astype(jnp.float32), vs["cache"]
 
     def _dense_vars(self, variables):
-        """Densify int8 weights INSIDE the traced program (per scan step —
-        the HBM read stays int8 and the convert+scale fuses toward the
-        matmul); identity when not quantized — and identity in NATIVE
-        int8-matmul mode, where the QuantizedTensor leaves flow into
-        ``module.apply`` and the quant-aware dense layers contract them
-        without any dense rebuild (quant.quantized_dot)."""
+        """The held tree as ``module.apply`` takes it, made INSIDE the
+        traced program. A table held with its rows padded to whole lane
+        rows (quant.PaddedRows) is sliced to its own width: a bitcast on
+        the chip, and the lookup gathers from the held array. int8 weights
+        densify (per scan step — the HBM read stays int8 and the
+        convert+scale fuses toward the matmul); identity when not
+        quantized — and identity in NATIVE int8-matmul mode, where the
+        QuantizedTensor leaves flow into ``module.apply`` and the
+        quant-aware dense layers contract them without any dense rebuild
+        (quant.quantized_dot)."""
+        from .quant import dequantize_tree, unpadded
+
+        variables = unpadded(variables)
         if self.quantize != "int8" or self.int8_matmul:
             return variables
-        from .quant import dequantize_tree
-
         return dequantize_tree(variables, dtype=jnp.float32)
 
     def _step_impl(self, variables, slab, pages=None, steps=None):

@@ -130,20 +130,98 @@ def is_floating(leaf) -> bool:
 _CASTS_AHEAD = 2
 
 
-def cast_leaves(variables: dict, types: list) -> dict:
+@jax.tree_util.register_pytree_node_class
+class PaddedRows:
+    """A 2-d table held with each row padded to whole lane rows: ``rows`` is
+    ``[n, padded]`` and its first ``width`` columns are the table. A pytree
+    node, as :class:`QuantizedTensor` is, so the same tree flows through
+    jit and device_put; ``width`` is static, and :func:`unpadded`, traced
+    INSIDE a program, hands ``module.apply`` the table at its own shape: on
+    a TPU that slice is a bitcast (the padded array is how the device
+    stores ``[n, width]`` by rows anyway) and a lookup gathers from the
+    held array where it lies."""
+
+    def __init__(self, rows, width: int):
+        self.rows, self.width = rows, int(width)
+
+    def tree_flatten(self):
+        return (self.rows,), self.width
+
+    @classmethod
+    def tree_unflatten(cls, width, children):
+        return cls(children[0], width)
+
+
+def _is_padded(x) -> bool:
+    return isinstance(x, PaddedRows)
+
+
+def unpadded(variables: dict) -> dict:
+    """The tree with every :class:`PaddedRows` node the table it holds."""
+    return jax.tree.map(
+        lambda l: l.rows[:, :l.width] if _is_padded(l) else l,
+        variables, is_leaf=_is_padded)
+
+
+def padded_bytes(variables: dict) -> int:
+    """The bytes the tree's :class:`PaddedRows` nodes hold beyond their
+    tables."""
+    return sum(int(l.rows.nbytes) * (l.rows.shape[1] - l.width)
+               // l.rows.shape[1]
+               for l in jax.tree.leaves(variables, is_leaf=_is_padded)
+               if _is_padded(l))
+
+
+def held_width(shape, layout) -> int:
+    """The width to hold a 2-d table of ``shape`` at, on a device whose own
+    layout for that shape is ``layout``, so that a row lies contiguous on
+    the lanes: its own where the device stores it by rows already (or says
+    nothing of tiles); else the next whole number of the layout's lane
+    tiles, at which the device's choice is rows. A TPU stores
+    ``f32[50257, 1600]`` as ``{0,1:T(8,128)}``, the VOCABULARY on the lanes
+    (50,257 pads to 50,304, less than 1,600 to 1,664), and a program that
+    gathers rows of such an operand first copies all of it."""
+    width = int(shape[1])
+    if (layout is None or not layout.tiling
+            or tuple(layout.major_to_minor) == (0, 1)):
+        return width
+    lanes = int(layout.tiling[0][-1])
+    return -(-width // lanes) * lanes
+
+
+def rows_on_lanes(leaf):
+    """``leaf``, a 2-d table, on the device with a row contiguous on the
+    lanes: placed, and nothing else, where the device's own layout for it
+    is by rows; else padded on the device to :func:`held_width` as a
+    :class:`PaddedRows` (and left as placed should the device store even
+    that by columns)."""
+    placed = jnp.asarray(leaf)
+    to = held_width(placed.shape, placed.format.layout)
+    if to == placed.shape[1]:
+        return placed
+    rows = jnp.pad(placed, ((0, 0), (0, to - placed.shape[1])))
+    if tuple(rows.format.layout.major_to_minor) != (0, 1):
+        return placed
+    return PaddedRows(rows, placed.shape[1])
+
+
+def cast_leaves(variables: dict, types: list, rows=None) -> dict:
     """The tree with leaf ``i`` (in tree order, a QuantizedTensor one leaf)
     held in ``types[i]`` on the device, or as it is where that is None:
     each leaf is put on the device and cast there by itself, and its wide
     copy dropped before the third one after it arrives, so the peak is the
-    narrow tree plus a few wide leaves."""
+    narrow tree plus a few wide leaves. A leaf with ``rows[i]`` true is
+    then held as :func:`rows_on_lanes` holds a table."""
     leaves, treedef = jax.tree.flatten(variables, is_leaf=_is_q)
     out, ahead = [], collections.deque()
-    for leaf, to in zip(leaves, types, strict=True):
+    for i, (leaf, to) in enumerate(zip(leaves, types, strict=True)):
         if to is not None:
             leaf = jnp.asarray(leaf).astype(to)
             ahead.append(leaf)
             if len(ahead) > _CASTS_AHEAD:
                 jax.block_until_ready(ahead.popleft())
+        if rows is not None and rows[i]:
+            leaf = rows_on_lanes(leaf)
         out.append(leaf)
     jax.block_until_ready(list(ahead))
     return treedef.unflatten(out)
@@ -159,10 +237,25 @@ def cast_tree(variables: dict, dtype) -> dict:
         for leaf in jax.tree.leaves(variables, is_leaf=_is_q)])
 
 
+# a use that is no cast: a gather that takes whole rows of a 2-d operand
+# (a token lookup: every index picks one row, and the slice is all of it)
+ROWS = "rows"
+
+
+def _takes_rows(eqn) -> bool:
+    operand = eqn.invars[0].aval
+    dims = eqn.params["dimension_numbers"]
+    return (len(operand.shape) == 2
+            and tuple(eqn.params["slice_sizes"]) == (1, operand.shape[1])
+            and tuple(dims.start_index_map) == (0,)
+            and tuple(dims.collapsed_slice_dims) == (0,))
+
+
 def _note_uses(jaxpr, which: dict, uses: list) -> None:
     """Into ``uses[i]`` what ``jaxpr`` does with the variable ``which`` maps
-    to ``i``: the type a ``convert_element_type`` gives it, None for any
-    other use (being an output is one). An operand of a nested jit and a
+    to ``i``: the type a ``convert_element_type`` gives it, :data:`ROWS`
+    where it is the operand of a gather of whole rows, None for any other
+    use (being an output is one). An operand of a nested jit and a
     constant of a scan ARE the value inside, so they are followed into the
     inner program; every other equation that holds a jaxpr is a use."""
     from jax.extend.core import Var
@@ -184,6 +277,8 @@ def _note_uses(jaxpr, which: dict, uses: list) -> None:
                 passed[inner.invars[i]] = leaf
             elif name == "convert_element_type":
                 uses[leaf].append(jnp.dtype(eqn.params["new_dtype"]))
+            elif name == "gather" and i == 0 and _takes_rows(eqn):
+                uses[leaf].append(ROWS)
             else:
                 uses[leaf].append(None)
         if passed:
@@ -191,6 +286,15 @@ def _note_uses(jaxpr, which: dict, uses: list) -> None:
     for var in jaxpr.outvars:
         if isinstance(var, Var) and var in which:
             uses[which[var]].append(None)
+
+
+def leaf_uses(jaxpr, leaves: int) -> list:
+    """What ``jaxpr`` does with each of its first ``leaves`` inputs, a list
+    an input (``_note_uses`` says of what)."""
+    uses = [[] for _ in range(leaves)]
+    _note_uses(jaxpr, {v: i for i, v in enumerate(jaxpr.invars[:leaves])},
+               uses)
+    return uses
 
 
 def narrowing_casts(jaxpr, leaves: int) -> list:
@@ -201,35 +305,45 @@ def narrowing_casts(jaxpr, leaves: int) -> list:
     either way. An input that is used any other way, cast to two types, not
     used, not floating, or handed to something this cannot see through
     (``_note_uses``) gets None: the answer errs to the type it has."""
-    uses = [[] for _ in range(leaves)]
-    _note_uses(jaxpr, {v: i for i, v in enumerate(jaxpr.invars[:leaves])},
-               uses)
     out = []
-    for var, seen in zip(jaxpr.invars, uses):
+    for var, seen in zip(jaxpr.invars, leaf_uses(jaxpr, leaves)):
         to = seen[0] if len(set(seen)) == 1 else None
-        narrower = (to is not None and is_floating(var.aval)
+        narrower = (isinstance(to, np.dtype) and is_floating(var.aval)
                     and jnp.issubdtype(to, jnp.floating)
                     and to.itemsize < var.aval.dtype.itemsize)
         out.append(to if narrower else None)
     return out
 
 
-def held_types(module, variables: dict) -> list:
-    """The type to hold each leaf of a token-in LM's ``variables`` in, in
-    tree order, None where it stays as it is: the module's forward is
-    traced abstractly (shapes and types of the leaves; no device memory, no
-    arithmetic) and :func:`narrowing_casts` asked what it does with each
-    leaf. A module computing in bfloat16 over float32 parameters casts its
-    products' kernels and biases and nothing else; one computing in its
-    parameters' type casts nothing. What is traced is a one-token decode
+def gathered_rows(jaxpr, leaves: int) -> list:
+    """For each of the first ``leaves`` inputs of ``jaxpr``: whether all the
+    program does with it is gather whole rows of it (a token table under
+    its lookup). Such a leaf is held with a row contiguous on the lanes
+    (:func:`rows_on_lanes`), which is what the gather reads. A table that
+    something else reads too (a tied head's product) is not named: the
+    answer errs to the leaf as it is."""
+    return [bool(seen) and all(use is ROWS for use in seen)
+            for seen in leaf_uses(jaxpr, leaves)]
+
+
+def held_as(module, variables: dict) -> tuple:
+    """(the type to hold each leaf of a token-in LM's ``variables`` in, None
+    where it stays as it is; whether the leaf is held with its rows on the
+    lanes), both in tree order: the module's forward is traced abstractly
+    (shapes and types of the leaves; no device memory, no arithmetic) and
+    :func:`narrowing_casts` and :func:`gathered_rows` asked what it does
+    with each leaf. A module computing in bfloat16 over float32 parameters
+    casts its products' kernels and biases and nothing else; one computing
+    in its parameters' type casts nothing; either gathers the rows of its
+    token table and of nothing else. What is traced is a one-token decode
     apply (what ``models.generation.init_cache`` sizes a cache with): every
     layer goes through the one traced block (models/gpt.py
     ``_decode_block``), a tenth of the plain forward's trace at 36 layers;
     a model that decodes only through pages (latent attention) refuses it
     and is traced by its plain forward over eight tokens. Either stands for
-    the engines' programs, which run the same layers' casts
-    (tests/test_held_types.py reads the paged engine's own). Raises what
-    the plain forward's trace raises (a module that takes no tokens)."""
+    the engines' programs, which run the same layers' casts and the same
+    lookup (tests/test_held_types.py reads the paged engine's own). Raises
+    what the plain forward's trace raises (a module that takes no tokens)."""
     abstract = jax.tree.map(
         lambda l: jax.ShapeDtypeStruct(np.shape(l), l.dtype), variables)
 
@@ -241,7 +355,8 @@ def held_types(module, variables: dict) -> list:
         jaxpr = traced(1, decode=True, mutable=["cache"])
     except Exception:
         jaxpr = traced(8)
-    return narrowing_casts(jaxpr, len(jax.tree.leaves(abstract)))
+    leaves = len(jax.tree.leaves(abstract))
+    return narrowing_casts(jaxpr, leaves), gathered_rows(jaxpr, leaves)
 
 
 def quantized_dot(x, qt: QuantizedTensor, *, dtype=None, impl: str = None):

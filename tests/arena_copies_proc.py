@@ -26,6 +26,23 @@ makes (the head's product over the whole bucket, or its float32 convert)
 is reported beside the copies. The head's own weight there is larger than
 the arena, so a relayout of it would be reported too, and should be.
 
+Two GPT-2 cases more carry their published token table, ``50257 x 1600``
+and ``50257 x 1280`` float32, in step and admit alike, handed over as the
+parameter server holds a tree (``serving.quant.held_as``: the products'
+operands in bfloat16, the table the lookup gathers held by whole lane rows
+where the described chip would store it by columns) and taken as the
+engines take it (``quant.unpadded`` inside the program). The device's own
+layout for ``f32[50257,1600]`` puts the VOCABULARY on the lanes
+(``{0,1:T(8,128)}``: 50,257 pads less than 1,600), and a program that takes
+it so answers its row gather with a ``copy`` of all 321 MB, every step and
+every admit (PR 50). With the table handed over at its own width, as before
+PR 50 (``quant.held_width`` made to give the width back), the two
+1,600-wide cases fail, ``COPIES gpt2-xl-embed-step: 2 [('copy',
+'50257,1600'), ('move', '257,16,3200')]`` (beside the copy a step then
+carries an arena into fast memory) and ``COPIES gpt2-xl-embed-admit: 1
+[('copy', '50257,1600')]``, and the two 1,280-wide ones pass: 1,280 is ten
+whole lane rows, stored by rows as it is.
+
 Prints ``OK <case>`` / ``COPIES <case>: <n> <first few>`` per case; exit 0
 when no case holds a forbidden operation, 1 when one does, 77 when this
 installation cannot describe a TPU topology (the caller skips)."""
@@ -58,7 +75,11 @@ LATENT_SHAPES = {
 # the vocabulary an admit is compiled at, where the head is a fifth or a
 # quarter of an admit (extract, rag); 512 elsewhere, as in every step
 HEAD_VOCAB = {"glm-4.7-flash": 154880, "xing4.0-29b-a4b": 131072}
-CASES = {f"{name}-{case}" for name in (*SHAPES, *LATENT_SHAPES)
+# name -> (the SHAPES entry it is, its vocabulary): step and admit alike
+# carry the token table, and take the tree as the server holds it
+TABLES = {"gpt2-large-embed": ("gpt2-large", 50257),
+          "gpt2-xl-embed": ("gpt2-xl", 50257)}
+CASES = {f"{name}-{case}" for name in (*SHAPES, *LATENT_SHAPES, *TABLES)
          for case in ("step", "admit")}
 
 _SHAPE = r"\w+\[[0-9,]*\](?:\{[^}]*\})?"
@@ -140,10 +161,32 @@ def main() -> int:
     from kubeml_tpu.models.experts import ExpertsConfig
     from kubeml_tpu.models.gpt import CausalTransformer
     from kubeml_tpu.models.mla import MLAConfig
+    from kubeml_tpu.serving import quant
 
     def on_chip(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=chip), tree)
+
+    def held(module, variables):
+        """``variables`` (abstract) as the server's hold hands them to the
+        engine: each leaf in the type of its rule, a table the lookup
+        gathers at the width ``quant.rows_on_lanes`` would hold it at on
+        the described chip."""
+        leaves, treedef = jax.tree.flatten(variables)
+        types, rows = quant.held_as(module, variables)
+        out = []
+        for s, to, by_rows in zip(leaves, types, rows, strict=True):
+            leaf = jax.ShapeDtypeStruct(s.shape, to or s.dtype, sharding=chip)
+            if by_rows:
+                own = jax.jit(lambda t: t[:1]).lower(leaf).compile()
+                width = quant.held_width(
+                    s.shape, own.input_formats[0][0].layout)
+                if width != s.shape[1]:
+                    leaf = quant.PaddedRows(jax.ShapeDtypeStruct(
+                        (s.shape[0], width), leaf.dtype, sharding=chip),
+                        s.shape[1])
+            out.append(leaf)
+        return treedef.unflatten(out)
 
     def stack(table, rows, **kw):
         return CausalTransformer(
@@ -158,8 +201,9 @@ def main() -> int:
                      num_kv_heads=kv_heads, head_dim=head_dim,
                      attn_bias=True, pos="rope" if kv_heads else "learned"),
          "kv_rows", rows, table, bucket, {"step": 2, "admit": 2})
-        for name, (embed, heads, kv_heads, head_dim, rows, table,
-                   bucket) in SHAPES.items()]
+        for name, (embed, heads, kv_heads, head_dim, rows, table, bucket)
+        in [*SHAPES.items(),
+            *((name, SHAPES[of]) for name, (of, _) in TABLES.items())]]
     for name, (embed, heads, (rq, dc, dn, dr, dv), double, rows, table,
                bucket) in LATENT_SHAPES.items():
         mla = MLAConfig(q_lora_rank=rq, kv_lora_rank=dc, qk_nope_head_dim=dn,
@@ -181,12 +225,14 @@ def main() -> int:
 
         for case, (b, length) in (("step", (rows, 1)), ("admit", (1, bucket))):
             admit = case == "admit"
-            vocab = HEAD_VOCAB.get(name, 512) if admit else 512
+            vocab = (TABLES[name][1] if name in TABLES
+                     else HEAD_VOCAB.get(name, 512) if admit else 512)
             sized = module.clone(vocab_size=vocab)
 
             def forward(variables, cache, ids, positions, pages, seq_lens):
                 logits, upd = sized.apply(
-                    {**variables, "cache": cache}, ids, decode=True,
+                    {**quant.unpadded(variables), "cache": cache}, ids,
+                    decode=True,
                     positions=positions, pages=pages, seq_lens=seq_lens,
                     head_positions=seq_lens - 1 if admit else None,
                     mutable=["cache"])
@@ -203,12 +249,16 @@ def main() -> int:
                       if path[-1].key == key]
             assert len(arenas) == 2, (name, len(arenas))
             params = full["params"]
-            if vocab != 512:
-                params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-                    s.shape, jnp.bfloat16), params)
+            if name in TABLES:
+                variables = held(sized, {"params": params})
+            else:
+                if vocab != 512:
+                    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+                        s.shape, jnp.bfloat16), params)
+                variables = on_chip({"params": params})
             vec = jax.ShapeDtypeStruct((b,), i32)
             hlo = jax.jit(forward, donate_argnums=(1,)).lower(
-                on_chip({"params": params}), on_chip(full["cache"]),
+                variables, on_chip(full["cache"]),
                 on_chip(jax.ShapeDtypeStruct((b, length), i32)),
                 on_chip(vec),
                 on_chip(jax.ShapeDtypeStruct((b, table), i32)),
@@ -220,7 +270,7 @@ def main() -> int:
             calls = len(_WALK.findall(hlo))
             if calls != walks[case]:
                 found.append(("page-walk calls", str(calls)))
-            if vocab != 512:
+            if name in HEAD_VOCAB and admit:
                 found += bucket_by_vocab(hlo, bucket, vocab)
             if found:
                 failed += 1

@@ -19,6 +19,10 @@ module, GLM's and Xing's at their published vocabularies, and an array of
 ``[2048, vocabulary]`` in the compiled program fails the admit's case
 (with the argument left out both programs hold a float32 fusion of
 ``[1, 2048, vocabulary]``: the check was seen to find it).
+
+And they hold the token lookup to the rows it reads (PR 50): GPT-2's
+published table at 1,600 and at 1,280 wide, handed over as the server's hold
+lays it out, in a step and in an admit; no copy of the table in either.
 """
 
 import subprocess

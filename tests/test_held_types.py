@@ -1,7 +1,7 @@
 """What type a served weight is held in (ISSUE 45).
 
 With ``Config.serving_param_dtype`` empty the parameter server asks the
-module what it does with each leaf (``serving.quant.held_types``: the serving
+module what it does with each leaf (``serving.quant.held_as``: the serving
 forward traced abstractly) and holds in the compute type every leaf whose
 every use is a cast to it: the same bits the programs made of it every step
 and every admit, read at half the bytes. Held here: which leaves a GPT-2 stack
@@ -10,7 +10,18 @@ engine's own programs do, that the five families whose leaves are bfloat16
 already keep their trees, that the narrowed tree's logits equal the float32
 tree's to the bit, what the rule makes of uses it cannot see through, and the
 parameter server's hold (its span, its telemetry, serve-time int8 beside
-it)."""
+it).
+
+Type, then layout (ISSUE 50): the same trace names the table of which the
+programs only gather whole rows (``gathered_rows``: the token lookup), and
+the hold keeps that leaf with a row contiguous on the lanes
+(``rows_on_lanes``). A TPU stores ``f32[50257, 1600]`` by columns and every
+program copied all of it before a lookup; such a table is held padded to
+whole lane rows (``PaddedRows``), which the device stores by rows, and the
+engines slice it to its own width inside their programs (``unpadded``). The
+CPU stores every width by rows and never pads, so here the rule that decides
+is fed a TPU's layouts, a padded tree is served beside the plain one, and
+the programs compiled for a described v5e are ``test_arena_copies``'."""
 
 import flax.linen as nn
 import numpy as np
@@ -30,10 +41,14 @@ from test_paged_serving import _finished_job
 from kubeml_tpu.api.types import GenerateRequest
 from kubeml_tpu.models.generation import init_paged_cache
 from kubeml_tpu.serving import quant
-from kubeml_tpu.serving.quant import (cast_leaves, held_types,
+from kubeml_tpu.serving.quant import (cast_leaves, gathered_rows, held_as,
                                       narrowing_casts)
 
 BF16, F32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+
+
+def held_types(module, tree):
+    return held_as(module, tree)[0]
 
 
 def stack(dtype, ln_eps):
@@ -94,9 +109,10 @@ def test_the_decode_apply_answers_as_the_programs_that_serve(program):
         finally:
             dec.close()
     assert [l.dtype for l in leaves] == [F32] * len(leaves)
-    rule = held_types(m, tree)
+    rule, rows = held_as(m, tree)
     assert narrowing_casts(jaxpr, len(leaves)) == rule
     assert BF16 in rule and None in rule
+    assert gathered_rows(jaxpr, len(leaves)) == rows and sum(rows) == 1
 
 
 FAMILIES = {"falcon": falcon, "glm": glm, "xing": xing, "longcat": longcat,
@@ -317,6 +333,209 @@ def test_a_tied_head_is_kept_beside_a_product_that_is_narrowed():
                    "table": None}
 
 
+# --- type, then layout ----------------------------------------------------
+
+
+@pytest.mark.parametrize("family", ["gpt2", "gpt2-float32", "falcon-files",
+                                    *sorted(FAMILIES)])
+def test_the_hold_names_the_table_the_lookup_gathers_and_nothing_else(family):
+    """Not ``pos_embed`` (a slice of three axes), not the head, not a
+    kernel, not an expert stack: the one leaf whose whole rows a gather
+    takes, in every family, whatever type it computes in."""
+    if family.startswith("gpt2"):
+        module, tree = stack(jnp.float32 if family.endswith("32")
+                             else jnp.bfloat16, 4.6e-5)
+    elif family == "falcon-files":
+        module, tree = falcon_files()
+    else:
+        module, tree = family_model(family)
+    _, rows = held_as(module, tree)
+    named = [path for path, by_rows in by_path(tree, rows).items() if by_rows]
+    assert named == ["token_embed/embedding"]
+
+
+def _lookup(w, x):
+    return jnp.take(w, x, axis=0)
+
+
+def _indexed(w, x):
+    return w[x]
+
+
+def _columns(w, x):
+    return w[:, x]
+
+
+def _cast_first(w, x):
+    return jnp.take(w.astype(jnp.bfloat16), x, axis=0)
+
+
+def _part_of_a_row(w, x):
+    return jax.lax.gather(
+        w, x[:, None], jax.lax.GatherDimensionNumbers(
+            offset_dims=(1,), collapsed_slice_dims=(0,),
+            start_index_map=(0,)), slice_sizes=(1, 8))
+
+
+def _one_row_sliced(w, x):
+    return jax.lax.dynamic_slice(w, (x[0], 0), (1, 16))
+
+
+def _looked_up_in_a_jit_in_a_scan(w, x):
+    inner = jax.jit(_lookup)
+    return jax.lax.scan(lambda c, i: (c, inner(w, i)), 0, x)[1]
+
+
+@pytest.mark.parametrize("fn, want", [
+    (_lookup, True), (_indexed, True),
+    (_looked_up_in_a_jit_in_a_scan, True), (_tied, False), (_columns, False),
+    (_cast_first, False), (_part_of_a_row, False), (_one_row_sliced, False),
+    (_through_a_jit_and_a_scan, False), (_unused, False)],
+    ids=lambda v: getattr(v, "__name__", None))
+def test_gathers_of_whole_rows_and_no_other_use_name_a_table(fn, want):
+    """Named: a leaf of which whole rows are gathered and nothing else is
+    done, through a nested jit and as a scan's constant too. Not named: a
+    table a product reads too (a tied head: padded columns would enter it),
+    a gather of columns or of part of a row, a lookup in a cast copy (the
+    copy is what is gathered), a slice, a product."""
+    w = jax.ShapeDtypeStruct((16, 16), jnp.float32)
+    x = jax.ShapeDtypeStruct((4,), jnp.int32)
+    assert gathered_rows(jax.make_jaxpr(fn)(w, x).jaxpr, 1) == [want]
+    cube = jax.ShapeDtypeStruct((2, 16, 16), jnp.float32)
+    if fn in (_lookup, _indexed):
+        assert gathered_rows(jax.make_jaxpr(fn)(cube, x).jaxpr, 1) == [False]
+
+
+def tpu_layout(*major_to_minor):
+    from jax.experimental.layout import Layout
+
+    return Layout(major_to_minor=major_to_minor, tiling=((8, 128),))
+
+
+@pytest.mark.parametrize("shape, layout, want", [
+    # GPT-2-XL's table as a v5e stores it: the vocabulary on the lanes
+    ((50257, 1600), tpu_layout(1, 0), 1664),
+    # gpt2-large's, GLM's, a table one lane row wide: by rows already
+    ((50257, 1280), tpu_layout(0, 1), 1280),
+    ((154880, 2048), tpu_layout(0, 1), 2048),
+    ((101, 128), tpu_layout(0, 1), 128),
+    # by columns though its rows are whole: padding would change nothing
+    ((50304, 1664), tpu_layout(1, 0), 1664),
+    # a device that says nothing of tiles (the CPU), or of layouts
+    ((101, 48), None, 48),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else None)
+def test_a_table_is_held_at_whole_lane_rows_where_the_device_says_columns(
+        shape, layout, want):
+    assert quant.held_width(shape, layout) == want
+
+
+def test_the_cpu_stores_a_table_by_rows_and_the_hold_pads_nothing():
+    from jax.experimental.layout import Layout
+
+    assert quant.held_width((101, 48), Layout(major_to_minor=(1, 0))) == 48
+    table = np.arange(101 * 48, dtype=np.float32).reshape(101, 48)
+    held = quant.rows_on_lanes(table)
+    assert isinstance(held, jax.Array) and not held.committed
+    assert np.array_equal(held, table)
+    assert quant.rows_on_lanes(held) is held
+
+
+def padded(tree, lanes=128):
+    """``tree`` with its token table held as a chip holds XL's: the rows
+    padded to whole lane rows."""
+    table = tree["params"]["token_embed"]["embedding"]
+    pad = -table.shape[1] % lanes
+    held = jax.tree.map(lambda l: l, tree)
+    held["params"]["token_embed"]["embedding"] = quant.PaddedRows(
+        jnp.pad(jnp.asarray(table), ((0, 0), (0, pad))), table.shape[1])
+    return held
+
+
+def test_a_padded_table_is_the_same_table_inside_a_program():
+    """``unpadded`` traced into a program hands ``nn.Embed`` the table's own
+    shape and the same float32 bits; the node is one leaf of the tree, its
+    width static; and the bytes the padding adds are counted."""
+    m, tree = stack(jnp.bfloat16, 4.7e-5)
+    table = tree["params"]["token_embed"]["embedding"]
+    assert table.shape == (101, 48) and table.dtype == F32
+    held = padded(tree)
+    node = held["params"]["token_embed"]["embedding"]
+    assert node.rows.shape == (101, 128) and node.width == 48
+    assert len(jax.tree.leaves(held)) == len(jax.tree.leaves(tree))
+    assert quant.padded_bytes(held) == 101 * 80 * 4
+    assert quant.padded_bytes(tree) == 0
+    ids = jnp.asarray(prompts(2, 8, 8, seed=13))
+    embed = nn.Embed(*table.shape)
+    lookup = jax.jit(lambda t, i: embed.apply({"params": quant.unpadded(
+        {"embedding": t})}, i))
+    rows = [np.asarray(lookup(t, ids)) for t in (table, node)]
+    assert rows[0].any() and np.array_equal(*rows)
+    back = jax.jit(quant.unpadded)(held)
+    for was, now in zip(jax.tree.leaves(tree), jax.tree.leaves(back),
+                        strict=True):
+        assert was.shape == now.shape and np.array_equal(was, now)
+    assert jax.device_put(held)["params"]["token_embed"][
+        "embedding"].width == 48
+
+
+@pytest.mark.parametrize("engine", ["paged", "slot"])
+def test_an_engine_serves_the_same_tokens_from_a_padded_table(engine):
+    """Both engines' programs slice the held table before the module sees
+    it: the served tokens are the plain tree's."""
+    from kubeml_tpu.serving.batcher import BatchingDecoder
+
+    m, tree = stack(jnp.bfloat16, 4.9e-5)
+    ps = prompts(3, 6, 20, seed=14)
+    served = []
+    for t in (tree, padded(tree)):
+        dec = (tiny_engine(m, t) if engine == "paged"
+               else BatchingDecoder(m, t, slots=4, chunk_steps=4))
+        try:
+            held = dec._variables["params"]["token_embed"]["embedding"]
+            assert isinstance(held, quant.PaddedRows) == (t is not tree)
+            served.append(serve(dec, ps, 5))
+            if engine == "paged":
+                assert dec.telemetry()["param_bytes"] == sum(
+                    l.nbytes for l in jax.tree.leaves(t))
+        finally:
+            dec.close()
+    assert served[0] == served[1]
+
+
+def test_the_servers_dense_view_is_the_table_at_its_own_width():
+    """``/infer`` and the one-shot fallback take the held tree through
+    ``_densified``: a plain tree, the table as the module made it."""
+    from kubeml_tpu.ps.parameter_server import ParameterServer
+
+    m, tree = stack(jnp.bfloat16, 5.0e-5)
+    dense = ParameterServer._densified(padded(tree))
+    tokens = jnp.asarray(prompts(2, 8, 8, seed=15))
+    assert np.array_equal(m.apply(dense, tokens), m.apply(tree, tokens))
+
+
+def test_a_table_the_device_lays_out_by_rows_comes_back_as_it_went_in(
+        tmp_path):
+    """128 wide, whole lane rows, float32 under a float32 module: nothing to
+    narrow and nothing to lay out, so the hold hands back the very leaves
+    it was given, the table it names among them."""
+    from kubeml_tpu.api.config import Config
+    from kubeml_tpu.models.gpt import CausalTransformer
+    from kubeml_tpu.ps.parameter_server import ParameterServer
+
+    m = CausalTransformer(vocab_size=101, max_len=64, embed_dim=128, depth=2,
+                          num_heads=4, attn_bias=True, ln_eps=4.8e-5)
+    tree = nn.meta.unbox(m.init(jax.random.PRNGKey(2),
+                                np.zeros((1, 8), np.int32)))
+    types, rows = held_as(m, tree)
+    assert types == [None] * len(rows) and sum(rows) == 1
+    held, narrowed = ParameterServer(
+        config=Config(data_root=tmp_path))._held(tree, m)
+    assert narrowed == 0
+    for was, now in zip(jax.tree.leaves(tree), jax.tree.leaves(held),
+                        strict=True):
+        assert now is was and not now.committed
+
+
 # --- the parameter server's hold ------------------------------------------
 
 
@@ -367,6 +586,31 @@ def test_a_finished_job_is_held_as_its_module_uses_it(tmp_path, monkeypatch):
         2 * sum(l.size for l in narrowed) + 4 * sum(l.size for l in kept))
     assert tel["param_leaves_narrowed"] == len(narrowed)
     assert hold.attrs["programs"] >= 1
+    assert hold.attrs["row_tables"] == 1 and hold.attrs["padded_bytes"] == 0
+
+
+@pytest.mark.paged
+def test_a_job_held_by_padded_rows_answers_as_the_plain_hold(tmp_path,
+                                                             monkeypatch):
+    """A device that stores the job's 64-wide table by columns, played by a
+    rule that pads every named table to 128: the hold's tree carries the
+    padded node, its span the bytes, and the first /generate the tokens of
+    the hold that pads nothing."""
+    (tmp_path / "plain").mkdir()
+    (tmp_path / "padded").mkdir()
+    plain, hold, _, leaves = loaded(tmp_path / "plain", monkeypatch)
+    assert hold.attrs["padded_bytes"] == 0
+    monkeypatch.setattr(quant, "held_width",
+                        lambda shape, layout: -(-shape[1] // 128) * 128)
+    out, hold, tel, held = loaded(tmp_path / "padded", monkeypatch)
+    assert out["tokens"] == plain["tokens"]
+    wider = [(a.shape, b.shape) for a, b in zip(leaves, held, strict=True)
+             if a.shape != b.shape]
+    assert len(wider) == hold.attrs["row_tables"] == 1
+    (table, rows), = wider
+    assert rows == (table[0], 128) and table[1] % 128
+    assert hold.attrs["padded_bytes"] == 4 * table[0] * (128 - table[1])
+    assert tel["param_bytes"] == hold.attrs["bytes"]
 
 
 @pytest.mark.paged
