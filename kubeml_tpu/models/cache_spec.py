@@ -8,7 +8,8 @@ asks once, at its construction, and reads every size, sum and property off
 the answer; nothing above this module looks at the fields again. ``layers``
 lists the sub-layers that PAGE (a layer whose token mixer keeps a recurrent
 state and no keys, models/gated_deltanet.py, is not among them: it counts in
-``state_layers``), so a stack of 8 layers may hold 2 arenas. How a row
+``state_layers``), so a stack of 8 layers may hold 2 arenas, of K and V
+heads or of latents alike. How a row
 is STORED stays where the arenas are written (``ops/paged_attention.py
 kv_row_width``, ``models/mla.py MLAConfig.row_width``) and is called from
 here.
@@ -68,6 +69,9 @@ class CacheSpec:
     # one row's state takes in one of them, its convolution's tail included
     state_layers: int = 0
     state_row_bytes: int = 0
+    # values a row's gate carries a step and head in such a layer: 1 (one
+    # decay a head) or the keys' width (one a key channel)
+    state_gate_width: int = 0
     # layers whose feed-forward is routed experts, the choices a token
     # makes in each, and the experts of a layer whose weights are here
     expert_layers: int = 0
@@ -184,17 +188,19 @@ def cache_spec(module) -> CacheSpec:
                         "cache_sublayers", 1)
     mla = getattr(module, "mla", None)
     layers: Tuple[CacheLayer, ...] = ()
-    linear = 0   # layers whose mixer keeps a state and no pages
+    # the stack's kinds of token mixer, where they differ by layer; a
+    # ``linear`` layer keeps a state and no pages
+    kinds = ([module.attn_kind(i) for i in range(depth)]
+             if getattr(module, "attn_kinds", ()) else None)
+    linear = sum(1 for a in kinds if a.linear) if kinds else 0
     if mla is not None:
         layers = (CacheLayer(latent_width=int(mla.latent_width),
                              latent_row_width=int(mla.row_width)),
-                  ) * (depth * per_layer)
+                  ) * ((depth - linear) * per_layer)
     elif heads and embed:
         k_dim = int(getattr(module, "head_dim", 0) or embed // heads)
         v_dim = int(getattr(module, "v_head_dim", 0) or k_dim)
-        if getattr(module, "attn_kinds", ()):
-            kinds = [module.attn_kind(i) for i in range(depth)]
-            linear = sum(1 for a in kinds if a.linear)
+        if kinds:
             by_layer = [(int(a.num_kv_heads or heads), int(a.window))
                         for a in kinds if not a.linear]
         else:
@@ -221,6 +227,8 @@ def cache_spec(module) -> CacheSpec:
         layers=layers,
         state_layers=state_layers,
         state_row_bytes=int(mixer.state_row_bytes) if state_layers else 0,
+        state_gate_width=(int(getattr(mixer, "gate_width", 1))
+                          if state_layers else 0),
         expert_layers=expert_layers,
         experts_per_token=int(experts.num_experts_per_tok) if experts else 0,
         experts_held=int(experts.held_range[1]) if experts else 0,

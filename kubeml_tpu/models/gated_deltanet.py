@@ -9,6 +9,20 @@ those of the ``fla`` layer of that name, arXiv:2412.06464). ``H`` heads, keys
     S_t   = exp(g_t) S_{t-1};  S_t += beta_t k_t (v_t - S_t^T k_t)^T;  o_t = S_t^T q_t
     out   = (RMSNorm_head(o) * silu(x W_g)) W_o
 
+**Kimi Delta Attention** (Kimi-Linear, arXiv:2510.26692; the ``fla`` layer
+``KimiDeltaAttention``; :class:`KDAConfig`) is the same mixer with another
+gate: the decay is one a KEY CHANNEL, made through a low-rank pair with a
+bias a channel; ``beta`` lies in (0, 1); the output gate is a low-rank pair
+under a sigmoid:
+
+    beta  = sigmoid(x W_b)                                                        # [H]
+    g     = -exp(A_log_h) softplus((x W_fa) W_fb + dt_bias)                       # [H, d_k]
+    S_t   = Diag(exp(g_t)) S_{t-1};  the delta rule and o_t as above
+    out   = (RMSNorm_head(o) * sigmoid((x W_ga) W_gb)) W_o
+
+The convolutions, the heads' norms, the three entries and the cache leaves
+are the one module's; ``cfg.gate_width`` (1 or ``d_k``) says which gate runs.
+
 Three entries compute that one function, as models/mamba2.py's do
 (ops/gated_delta.py has the recurrence):
 
@@ -20,7 +34,8 @@ Three entries compute that one function, as models/mamba2.py's do
   gathered at the row's true length, so the bucket a prompt is padded to
   cannot be seen in its state;
 * ``decode=True``, one position, no ``rows``: the engine's decode step, the
-  state advanced in place by the ``gdn_update`` kernel. A row whose
+  state advanced in place by the ``gdn_update`` kernel (named
+  ``kda_update`` in a trace where the gate is per channel). A row whose
   ``seq_lens`` is 0 (not live) keeps its state and its tail.
 
 A layer of this kind keeps NO paged cache. Its state lives in the ``cache``
@@ -38,7 +53,7 @@ the slab's).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, ClassVar, Union
 
 import flax.linen as nn
 import jax
@@ -60,6 +75,9 @@ class GDNConfig:
     neg_eigval: bool = True   # linear_allow_neg_eigval: beta in (0, 2)
     norm_eps: float = 1e-6    # rms_norm_eps
 
+    # what a row's gate carries a step: one decay a head
+    gate_width: ClassVar[int] = 1
+
     @property
     def conv_dim(self) -> int:
         return self.num_heads * (2 * self.key_dim + self.value_dim)
@@ -72,6 +90,29 @@ class GDNConfig:
                     + (self.d_conv - 1) * self.conv_dim)
 
 
+@dataclass(frozen=True)
+class KDAConfig:
+    """Kimi Delta Attention's sizes, under the names of the published
+    ``linear_attn_config``: keys and values are both ``head_dim`` wide, and
+    so is the low rank of the two gate pairs (the ``fla`` layer's
+    ``f_proj`` and ``g_proj``). What the mixer reads of a
+    :class:`GDNConfig` it reads of this under the same names."""
+
+    num_heads: int                    # num_heads
+    head_dim: int                     # head_dim: d_k = d_v = the gates' rank
+    short_conv_kernel_size: int = 4   # short_conv_kernel_size
+    norm_eps: float = 1e-5            # rms_norm_eps
+    neg_eigval: ClassVar[bool] = False   # beta in (0, 1)
+
+    key_dim = property(lambda self: self.head_dim)
+    value_dim = property(lambda self: self.head_dim)
+    d_conv = property(lambda self: self.short_conv_kernel_size)
+    # what a row's gate carries a step: one decay a key channel
+    gate_width = property(lambda self: self.head_dim)
+    conv_dim = GDNConfig.conv_dim
+    state_row_bytes = GDNConfig.state_row_bytes
+
+
 def _part(names):
     return lambda init: nn.with_partitioning(init, names)
 
@@ -81,7 +122,7 @@ def _l2norm(x):
 
 
 class GatedDeltaNet(nn.Module):
-    cfg: GDNConfig
+    cfg: Union[GDNConfig, KDAConfig]
     dtype: Any = jnp.float32
     state_rows: int = 0
 
@@ -99,17 +140,36 @@ class GatedDeltaNet(nn.Module):
         # the recurrence, the gate and the norm
         qkv = jnp.concatenate([proj(H * dk, "q_proj"), proj(H * dk, "k_proj"),
                                proj(H * dv, "v_proj")], axis=-1)
-        a, b = proj(H, "a_proj"), proj(H, "b_proj")
-        gate = proj(H * dv, "g_proj")
+        if c.gate_width == 1:
+            a, b = proj(H, "a_proj"), proj(H, "b_proj")
+            gate = jax.nn.silu(proj(H * dv, "g_proj"))
+        else:
+            # a decay a key channel and the output gate, each through a
+            # low-rank pair (the compute type in between, float32 out)
+            pair = lambda name, width: QuantizableDense(
+                width, name=f"{name}_b_proj", use_bias=False,
+                dtype=self.dtype, kernel_init=_part((None, "tp"))(
+                    nn.initializers.lecun_normal()))(QuantizableDense(
+                        c.head_dim, name=f"{name}_a_proj", use_bias=False,
+                        dtype=self.dtype,
+                        kernel_init=nn.initializers.lecun_normal())(
+                            u)).astype(jnp.float32)
+            a, b = pair("f", H * dk).reshape(B_, L, H, dk), proj(H, "b_proj")
+            gate = jax.nn.sigmoid(pair("g", H * dv))
         conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(),
                             (K, c.conv_dim)).astype(jnp.float32)
         A = jnp.exp(self.param(
             "A_log", lambda k, s: jnp.log(jax.random.uniform(
                 k, s, jnp.float32, 1e-3, 16.0)), (H,)).astype(jnp.float32))
+        # one bias a head, or one a key channel of each head
         dt_bias = self.param("dt_bias", nn.initializers.zeros,
-                             (H,)).astype(jnp.float32)
-        g = -A * jax.nn.softplus(a + dt_bias)                  # [B, L, H]
+                             a.shape[2:]).astype(jnp.float32)
+        # [B, L, H] or [B, L, H, d_k]
+        g = -A.reshape(A.shape + (1,) * (a.ndim - 3)) * jax.nn.softplus(
+            a + dt_bias)
         beta = (2.0 if c.neg_eigval else 1.0) * jax.nn.sigmoid(b)
+        # a position that moves nothing: ``g`` and ``beta`` times 0
+        masked = lambda t, m: t * m.reshape(m.shape + (1,) * (t.ndim - m.ndim))
 
         def conv(window, n=None):
             """silu(conv) of ``window``: [B, K - 1 + n, C] -> [B, n, C], or
@@ -147,9 +207,10 @@ class GatedDeltaNet(nn.Module):
                 window = jnp.concatenate(
                     [tails.value, jnp.moveaxis(qkv, 1, 0)], axis=0)  # [K, R, C]
                 q, k, v = heads(conv(window))
-                lf = live.astype(jnp.float32)[:, None]
+                lf = live.astype(jnp.float32)
                 o, state.value = gdn_update(state.value, q, k, v,
-                                            g[:, 0] * lf, beta[:, 0] * lf)
+                                            masked(g[:, 0], lf),
+                                            masked(beta[:, 0], lf))
                 tails.value = jnp.where(live[None, :, None], window[1:],
                                         tails.value)
                 o = o[:, None]
@@ -166,9 +227,9 @@ class GatedDeltaNet(nn.Module):
                 window = jnp.concatenate([tail, qkv], axis=1)
                 q, k, v = heads(conv(window, L))
                 valid = (jnp.arange(L)[None, :] < sl[:, None]).astype(
-                    jnp.float32)[:, :, None]
-                o, S1 = gdn_chunked(q, k, v, g * valid, beta * valid,
-                                    init_state=S0)
+                    jnp.float32)
+                o, S1 = gdn_chunked(q, k, v, masked(g, valid),
+                                    masked(beta, valid), init_state=S0)
                 # the last K - 1 inputs before the row's true length:
                 # window[i] is the input at position i - (K - 1)
                 last = jnp.take_along_axis(
@@ -181,7 +242,7 @@ class GatedDeltaNet(nn.Module):
         scale = self.param("norm_scale", nn.initializers.ones, (dv,))
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                               + c.norm_eps) * scale.astype(jnp.float32)
-        y = o.reshape(B_, L, H * dv) * jax.nn.silu(gate)
+        y = o.reshape(B_, L, H * dv) * gate
         return QuantizableDense(
             E, name="o_proj", use_bias=False, dtype=self.dtype,
             kernel_init=_part(("tp", None))(nn.initializers.lecun_normal()))(

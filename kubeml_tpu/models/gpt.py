@@ -37,7 +37,9 @@ program row and no pages. The norms may sit on each branch's OUTPUT
 (``norm_at="output"``: Olmo 2's block), the whole query and key projections
 may be normed (``qk_norm``), and a stack may have no positional term at all
 (``pos="none"``): Olmo-Hybrid is those three with three linear layers to
-every full one. GPT-2 is
+every full one. Kimi-Linear is the same pattern UNDER latent attention:
+three Kimi Delta Attention layers (a decay a key channel) to every latent
+layer that rotates nothing, a state beside a latent arena. GPT-2 is
 the defaults; Falcon-H1 is rmsnorm +
 swiglu + GQA + rope + ssm + mup; GLM-4.7-Flash is rmsnorm + rope + mla +
 experts behind one dense layer; Xing4.0 is that with YaRN rotary on four
@@ -50,7 +52,7 @@ import dataclasses
 import functools
 import threading
 from dataclasses import dataclass
-from typing import Any, Callable, ClassVar, Optional, Tuple
+from typing import Any, Callable, ClassVar, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -62,7 +64,7 @@ from ..ops.attention import dot_product_attention
 from ..ops.hyper_connection import HCConfig, hc_post, hc_pre
 from ..parallel.ring import ring_attention
 from .experts import ExpertMLP, ExpertsConfig
-from .gated_deltanet import GatedDeltaNet, GDNConfig
+from .gated_deltanet import GatedDeltaNet, GDNConfig, KDAConfig
 from .layers import QuantizableDense
 from .mamba2 import Mamba2Mixer, SSMConfig
 from .mla import MLAConfig, MLAttention
@@ -119,10 +121,13 @@ class AttnKind:
     cache is a RING of ``ops/paged_attention.ring_pages`` pages a row
     (serving/kvpool.py); 0: every key before it. ``sink``: one learned logit
     a head joins the softmax's denominator and takes no value. ``linear``:
-    the layer's token mixer is not attention but the stack's Gated DeltaNet
+    the layer's token mixer is not attention but the stack's delta-rule
     mixer (``CausalTransformer.gdn``; Olmo-Hybrid's ``layer_types``
-    ``linear_attention``): it keeps a recurrent state a program row and NO
-    paged cache, and the other fields say nothing of it."""
+    ``linear_attention``, Kimi-Linear's ``kda_layers``): it keeps a recurrent
+    state a program row and NO paged cache, and the other fields say nothing
+    of it. Under latent attention (``CausalTransformer.mla``) a kind is
+    ``linear`` or it is the stack's latent attention, which has no field
+    here to differ by."""
 
     num_kv_heads: int = 0
     rope_theta: float = 10000.0
@@ -682,7 +687,7 @@ class GPTBlock(nn.Module):
     # the layer's token mixer is ``gdn``'s Gated DeltaNet, not attention
     # (AttnKind.linear)
     linear: bool = False
-    gdn: Optional[GDNConfig] = None
+    gdn: Optional[Union[GDNConfig, KDAConfig]] = None
     # paged caches a layer of this class holds (models/cache_spec.py
     # cache_spec): one attention, one cache
     cache_sublayers: ClassVar[int] = 1
@@ -990,8 +995,9 @@ class CausalTransformer(nn.Module):
     # it (the serving layer clones that in with the arena's sizes; 0 = the
     # batch). ``mup``: the constant multipliers of a muP checkpoint.
     # ``mla``: multi-head latent attention in place of K/V heads
-    # (models/mla.py; rotary positions; its paged arena holds one latent a
-    # token, ``mla.latent_width`` values, and it has no dense decode cache).
+    # (models/mla.py; rotary positions, or none at all with its
+    # ``mla_use_nope``; its paged arena holds one latent a token,
+    # ``mla.latent_width`` values, and it has no dense decode cache).
     # ``mlp="experts"`` with ``experts``: routed experts and a shared one
     # (models/experts.py), after ``dense_layers`` leading layers whose MLP
     # is a SwiGLU of ``mlp_dim``: the one layer pattern a stack can have.
@@ -1042,13 +1048,17 @@ class CausalTransformer(nn.Module):
     value_scale: float = 1.0
     window_pages: int = 0
     # --- a token mixer that is not attention, by the same pattern: a kind
-    # with ``AttnKind.linear`` is a Gated DeltaNet layer of ``gdn``'s sizes
-    # (models/gated_deltanet.py): a recurrent state a program row
-    # (``state_rows``), no paged cache. ``norm_at``: "input" (``x +
+    # with ``AttnKind.linear`` is a delta-rule layer of ``gdn``'s sizes
+    # (models/gated_deltanet.py: a ``GDNConfig``'s Gated DeltaNet, one decay
+    # a head, or a ``KDAConfig``'s Kimi Delta Attention, one a key channel):
+    # a recurrent state a program row (``state_rows``), no paged cache. The
+    # pattern holds under ``mla`` too: a layer is then ``linear`` or the
+    # stack's latent attention, and only the latter holds a latent arena.
+    # ``norm_at``: "input" (``x +
     # f(norm(x))``) or "output" (``x + norm(f(x))``, Olmo 2's block) for
     # every layer's two norms. ``qk_norm``: RMSNorm over the whole query and
     # key projections of every attention layer. ---
-    gdn: Optional[GDNConfig] = None
+    gdn: Optional[Union[GDNConfig, KDAConfig]] = None
     norm_at: str = "input"
     qk_norm: bool = False
 
@@ -1182,9 +1192,15 @@ class CausalTransformer(nn.Module):
                 raise ValueError(
                     f"attn_pattern names a kind of attn_kinds for each of "
                     f"the {self.depth} layers")
-            if self.mla is not None or self.mlp == "shortcut":
-                raise ValueError("attention kinds by layer are K/V-head "
-                                 "attention's, one attention a layer")
+            if self.mlp == "shortcut":
+                raise ValueError("attention kinds by layer are one "
+                                 "attention a layer's, not a double layer's")
+            if self.mla is not None and any(
+                    a != AttnKind(linear=a.linear) for a in self.attn_kinds):
+                raise ValueError("under latent attention a kind of layer "
+                                 "is AttnKind(linear=True) or AttnKind(): "
+                                 "K/V heads, a rotary base, a window and a "
+                                 "sink are K/V-head attention's")
         # the full layers' page table and the window layers' rings
         tables = (tuple(pages) if isinstance(pages, (tuple, list))
                   else (pages, None))
@@ -1199,9 +1215,11 @@ class CausalTransformer(nn.Module):
         if self.mlp == "shortcut" and (self.dense_layers or self.moe_every):
             raise ValueError("a stack of shortcut layers has no other kind "
                              "of layer (dense_layers, moe_every)")
-        if self.mla is not None and not use_rope:
+        if (self.mla is not None and not use_rope
+                and not self.mla.mla_use_nope):
             raise ValueError("latent attention takes rotary positions "
-                             "(pos='rope')")
+                             "(pos='rope') unless it rotates nothing "
+                             "(MLAConfig.mla_use_nope)")
         # the stack's pattern: layer i's MLP kind, and with attn_kinds its
         # attention kind beside it
         mlp_of = lambda i: ("swiglu" if self.mlp == "experts"
@@ -1215,6 +1233,9 @@ class CausalTransformer(nn.Module):
             if not self.attn_kinds:
                 return {**shared_fields, "mlp": kind}
             a = self.attn_kinds[kind[0]]
+            if self.mla is not None:
+                # the kind says linear or latent, and nothing else
+                return {**shared_fields, "mlp": kind[1], "linear": a.linear}
             return {**shared_fields, "mlp": kind[1], "num_kv_heads": a.num_kv_heads,
                     "rope_theta": a.rope_theta, "window": a.window,
                     "sink": a.sink, "linear": a.linear, "kv_pages": (

@@ -44,6 +44,14 @@ stream's (``r`` is not scaled). Both are applied in float32 where the norm
 ends, before the one rounding to the compute type: the arena stores the
 scaled ``c``, and both forms read it as it is.
 
+Two more published keys change two lines (Kimi-Linear). ``q_lora_rank``
+null: the query is projected DIRECTLY, ``q = u W_q`` (one ``q_proj``; no
+``W_dq``, no query norm). ``mla_use_nope`` true: NOTHING is rotated; ``r``
+and ``q_rope`` stay the plain lanes the projections made, and order reaches
+the layer through its causal mask alone. The arena's row, both forms and
+the page walk are the same: a walk scores ``[qa_h | q_rope_h]`` against ``[c
+| r]`` whatever was or was not turned before the write.
+
 Norms are float32; rotary pairs are rotate-half over the ``dr`` rope dims.
 With ``rope_scaling`` (YaRN, ops/rotary.py) the pair frequencies are the
 blended ones and the softmax scale is ``softmax_mscale ** 2 / sqrt(dn + dr)``,
@@ -72,7 +80,7 @@ class MLAConfig:
     """The latent attention's sizes, under the names of the published
     ``config``."""
 
-    q_lora_rank: int
+    q_lora_rank: Optional[int]   # None: the query is projected directly
     kv_lora_rank: int        # dc: the compressed K/V a token caches
     qk_nope_head_dim: int    # dn
     qk_rope_head_dim: int    # dr: the shared rope key a token caches
@@ -81,6 +89,12 @@ class MLAConfig:
     rope_scaling: Optional[YarnScaling] = None   # None: plain rotary
     mla_scale_q_lora: bool = False    # cq times sqrt(E / q_lora_rank)
     mla_scale_kv_lora: bool = False   # c times sqrt(E / kv_lora_rank)
+    mla_use_nope: bool = False        # no rotation of q_rope and r
+
+    def __post_init__(self):
+        if self.q_lora_rank is None and self.mla_scale_q_lora:
+            raise ValueError("mla_scale_q_lora scales a query latent; "
+                             "q_lora_rank is None")
 
     @property
     def latent_width(self) -> int:
@@ -124,11 +138,16 @@ class MLAttention(nn.Module):
             kernel_init=_part(names)(nn.initializers.lecun_normal()))
         norm = lambda name: nn.RMSNorm(name=name, dtype=jnp.float32,
                                        epsilon=c.norm_eps)
-        cq = norm("q_norm")(dense(c.q_lora_rank, (None, None), "q_down")(x))
-        if c.mla_scale_q_lora:
-            cq = cq * math.sqrt(E / c.q_lora_rank)
-        q = dense(H * (dn + dr), (None, "tp"), "q_up")(
-            cq.astype(self.dtype)).reshape(B, L, H, dn + dr)
+        if c.q_lora_rank is None:
+            q = dense(H * (dn + dr), (None, "tp"), "q_proj")(x)
+        else:
+            cq = norm("q_norm")(
+                dense(c.q_lora_rank, (None, None), "q_down")(x))
+            if c.mla_scale_q_lora:
+                cq = cq * math.sqrt(E / c.q_lora_rank)
+            q = dense(H * (dn + dr), (None, "tp"), "q_up")(
+                cq.astype(self.dtype))
+        q = q.reshape(B, L, H, dn + dr)
         ckr = dense(dc + dr, (None, None), "kv_down")(x)
         ckv = norm("kv_norm")(ckr[..., :dc])
         if c.mla_scale_kv_lora:
@@ -160,6 +179,8 @@ class MLAttention(nn.Module):
                                          **own, **masking)
 
         def rotated(q, kr, pos):
+            if c.mla_use_nope:
+                return q, kr[:, :, 0]
             q = jnp.concatenate(
                 [q[..., :dn],
                  apply_rope(q[..., dn:], pos, self.rope_theta, yarn)],

@@ -556,6 +556,12 @@ SERVING_GAUGES = {
                                "row in: kv_latent_width rounded up to whole "
                                "128-lane rows, zeros past the values; 0 for "
                                "a model that pages K/V heads"),
+    "kubeml_serving_state_gate_width": (
+        "state_gate_width", "Values the gate of a recurrent state carries a "
+                            "step and head: 1 (one decay a head) or the "
+                            "keys' width (one a key channel); absent for a "
+                            "model without recurrent state and until a step "
+                            "has moved one"),
     "kubeml_serving_moe_layers": (
         "moe_layers", "Layers of the served model whose feed-forward is "
                       "routed experts (0: none)"),

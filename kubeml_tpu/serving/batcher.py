@@ -3779,6 +3779,11 @@ class PagedBatchingDecoder(BatchingDecoder):
         snap["recurrent_layers"] = float(self.cache.state_layers)
         snap["recurrent_state_bytes"] = float(
             self.cache.state_bytes(self.slots))
+        if "state_rows_moved" in snap:
+            # what a row's gate carries a step and head, beside the counts
+            # of the kernel that applies it: 1 (a decay a head) or the keys'
+            # width (one a key channel): which delta rule the states follow
+            snap["state_gate_width"] = float(self.cache.state_gate_width)
         snap["prefix_cache_off_recurrent"] = (
             1.0 if "prefix_sharing" in self._features_off else 0.0)
         # a latent arena: values one token holds in one layer, once, and

@@ -79,7 +79,13 @@ HEAD_VOCAB = {"glm-4.7-flash": 154880, "xing4.0-29b-a4b": 131072}
 # carry the token table, and take the tree as the server holds it
 TABLES = {"gpt2-large-embed": ("gpt2-large", 50257),
           "gpt2-xl-embed": ("gpt2-xl", 50257)}
-CASES = {f"{name}-{case}" for name in (*SHAPES, *LATENT_SHAPES, *TABLES)
+# Kimi-Linear's stack as run (PR 51): two latent arenas WITHOUT rotation
+# under a direct query, each behind a Kimi Delta Attention layer whose state
+# slab (128 rows of 32 x 128 x 128 float32, 268 MB a layer) the in-place
+# ``kda_update`` kernel and the admit's one-row write must not copy either
+KIMI = "kimi-linear-48b-a3b"
+CASES = {f"{name}-{case}"
+         for name in (*SHAPES, *LATENT_SHAPES, *TABLES, KIMI)
          for case in ("step", "admit")}
 
 _SHAPE = r"\w+\[[0-9,]*\](?:\{[^}]*\})?"
@@ -159,7 +165,8 @@ def main() -> int:
     import flax.linen as nn
 
     from kubeml_tpu.models.experts import ExpertsConfig
-    from kubeml_tpu.models.gpt import CausalTransformer
+    from kubeml_tpu.models.gated_deltanet import KDAConfig
+    from kubeml_tpu.models.gpt import AttnKind, CausalTransformer
     from kubeml_tpu.models.mla import MLAConfig
     from kubeml_tpu.serving import quant
 
@@ -195,12 +202,13 @@ def main() -> int:
             paged_attn="pallas", **kw)
 
     # (name, module, the arena's key, rows, table, bucket, page walks a
-    # step and an admit hold: a latent admit attends without the kernel)
+    # step and an admit hold: a latent admit attends without the kernel;
+    # cache leaves beside the arenas that may not be copied whole either)
     models = [
         (name, stack(table, rows, embed_dim=embed, depth=2, num_heads=heads,
                      num_kv_heads=kv_heads, head_dim=head_dim,
                      attn_bias=True, pos="rope" if kv_heads else "learned"),
-         "kv_rows", rows, table, bucket, {"step": 2, "admit": 2})
+         "kv_rows", rows, table, bucket, {"step": 2, "admit": 2}, ())
         for name, (embed, heads, kv_heads, head_dim, rows, table, bucket)
         in [*SHAPES.items(),
             *((name, SHAPES[of]) for name, (of, _) in TABLES.items())]]
@@ -217,11 +225,21 @@ def main() -> int:
                                    num_heads=heads, norm="rmsnorm",
                                    pos="rope", mla=mla, **kind),
                        "latent_pages", rows, table, bucket,
-                       {"step": 2, "admit": 0}))
+                       {"step": 2, "admit": 0}, ()))
+    models.append((KIMI, stack(
+        192, 128, embed_dim=2304, num_heads=32, norm="rmsnorm", pos="none",
+        depth=4, mlp="swiglu", state_rows=128,
+        mla=MLAConfig(q_lora_rank=None, kv_lora_rank=512,
+                      qk_nope_head_dim=128, qk_rope_head_dim=64,
+                      v_head_dim=128, mla_use_nope=True),
+        attn_kinds=(AttnKind(), AttnKind(linear=True)),
+        attn_pattern=(1, 0, 1, 0), gdn=KDAConfig(num_heads=32, head_dim=128)),
+        "latent_pages", 128, 192, 1024, {"step": 2, "admit": 0},
+        ("gdn_state",)))
 
     failed = 0
     i32 = jnp.int32
-    for name, module, key, rows, table, bucket, walks in models:
+    for name, module, key, rows, table, bucket, walks, beside in models:
 
         for case, (b, length) in (("step", (rows, 1)), ("admit", (1, bucket))):
             admit = case == "admit"
@@ -263,7 +281,11 @@ def main() -> int:
                 on_chip(vec),
                 on_chip(jax.ShapeDtypeStruct((b, table), i32)),
                 on_chip(vec)).compile().as_text()
-            found = pool_sized(hlo, arenas[0].size)
+            whole = arenas + [
+                leaf for path, leaf in
+                jax.tree_util.tree_leaves_with_path(full["cache"])
+                if path[-1].key in beside]
+            found = pool_sized(hlo, min(leaf.size for leaf in whole))
             moves = [f for f in found if f[0] == "move"]
             if case == "admit" and len(moves) <= len(arenas):
                 found = [f for f in found if f[0] != "move"]

@@ -242,4 +242,23 @@ def kernel_cases():
             (_sds((nrows, L, 30, 128), jnp.bfloat16),
              _sds((9217, PT, kv_row_width(30, 128)), jnp.bfloat16),
              _sds((nrows, width), jnp.int32), _sds((nrows,), jnp.int32)))
+    # Kimi-Linear-48B-A3B's published shapes (ISSUE 51): the delta rule
+    # gated per key channel, 32 heads of [128, 128] float32 (one whole lane
+    # row a head: nothing is packed) on a 128-row slab, a gate of 128 values
+    # a head; and the two latent layers' walk at 32 heads over rows of 640
+    # lanes, a decode step under the three table widths the cell reaches
+    cases["kda_update-kimi-linear-48b-a3b"] = (
+        lambda s, q, k, v, g, b: gdn_update(s, q, k, v, g, b,
+                                            interpret=False),
+        (_sds((128, 32, 128, 128), jnp.float32),
+         _sds((128, 32, 128), jnp.float32), _sds((128, 32, 128), jnp.float32),
+         _sds((128, 32, 128), jnp.float32),
+         _sds((128, 32, 128), jnp.float32), _sds((128, 32), jnp.float32)))
+    for width in (64, 128, 192):
+        cases[f"mla_attn-kimi-linear-L1-P{width}"] = (
+            lambda q, a, t, p: mla_attn(q, a, t, p, value_dim=512,
+                                        scale=192 ** -0.5, interpret=False),
+            (_sds((128, 32, latent_row_width(576)), jnp.bfloat16),
+             _sds((24577, PT, latent_row_width(576)), jnp.bfloat16),
+             _sds((128, width), jnp.int32), _sds((128,), jnp.int32)))
     return cases

@@ -3,7 +3,7 @@ allows (ISSUE 47).
 
 ``models/cache_spec.py cache_spec`` is asked once a decoder and
 ``serving/batcher.py CACHE_FEATURES`` is read once a decoder; this file holds
-both to the seven tiny configurations of the benchmark's own tests (read, not
+both to the eight tiny configurations of the benchmark's own tests (read, not
 edited; ``data/configs/tiny.json`` stands for both GPT-2 sizes):
 
 * ``TABLE`` spells out every cell as it stood before the table existed
@@ -25,7 +25,8 @@ import jax.numpy as jnp
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.models import (falcon_h1, glm_moe_lite, gpt2,  # noqa: E402
-                              longcat_flash, mimo_v2, olmo_hybrid, xing4)
+                              kimi_linear, longcat_flash, mimo_v2,
+                              olmo_hybrid, xing4)
 from kubeml_tpu.api.errors import KubeMLError  # noqa: E402
 from kubeml_tpu.models import gpt  # noqa: E402
 from kubeml_tpu.models.cache_spec import PROPERTIES, cache_spec  # noqa: E402
@@ -45,11 +46,14 @@ FAMILIES = {
     "longcat": ("data_longcat/configs/tiny-longcat.json", longcat_flash),
     "mimo": ("data_mimo/configs/tiny-mimo.json", mimo_v2),
     "olmo": ("data_olmo/configs/tiny-olmo.json", olmo_hybrid),
+    "kimi": ("data_kimi/configs/tiny-kimi.json", kimi_linear),
 }
 HAS = {
     "gpt2": set(), "falcon": {"recurrent"}, "glm": {"latent", "experts"},
     "xing": {"latent", "experts"}, "longcat": {"latent", "experts"},
     "mimo": {"window", "experts"}, "olmo": {"recurrent"},
+    # the first model with three properties at once (PR 51)
+    "kimi": {"recurrent", "latent", "experts"},
 }
 
 # today's cells, every one; a pair that is not here is served
@@ -304,6 +308,9 @@ PARENT = {
     # no parent: the family is PR 48's, and these are its values then (2 of
     # its 8 layers page; the keys are the other families')
     "olmo": ((2, 0, 2, 1, 0, 0, 0, 0, 0, 32), (1081344, 1536, 0, 0)),
+    # no parent: the family is PR 51's (2 of its 8 layers hold a latent
+    # arena, 7 route experts; the keys are the other families')
+    "kimi": ((2, 0, 2, 1, 24, 128, 7, 16, 0, 32), (540672, 192, 0, 0)),
 }
 STATIC = ("cache_sublayers", "window_layers", "full_layers",
           "residual_streams", "kv_latent_width", "kv_latent_row_width",
@@ -351,6 +358,40 @@ def test_a_stack_with_layers_that_do_not_page_counts_the_ones_that_do():
     assert spec.state_row_bytes == 4 * (6 * 8 * 16 + 3 * 6 * 32)
     assert spec.state_bytes(4) == 4 * 6 * spec.state_row_bytes
     assert spec.properties == {"recurrent"}
+    assert spec.state_gate_width == 1     # one decay a head
+
+
+def test_a_latent_stack_with_layers_that_hold_a_state():
+    """Kimi-Linear's pattern: of 8 layers the 2 latent-attention ones hold a
+    latent arena (24 live values a token in 128 lanes), the 6 KDA ones carry
+    a state and no pages; 7 route experts. Every sum an engine reads counts
+    the 2 arenas; the state and the experts are counted beside, and the
+    three properties stand together."""
+    _, module, _ = family("kimi")
+    spec = cache_spec(module)
+    assert module.depth == 8 and len(spec.layers) == 2
+    assert all((l.latent_width, l.latent_row_width, l.kv_heads, l.window)
+               == (24, 128, 0, 0) for l in spec.layers)
+    assert spec.latent == spec.layers[0]
+    assert (spec.sublayers, spec.full_layers, spec.window_layers) == (2, 2, 0)
+    # a cached token: one latent of 16 + 8 in each of TWO layers
+    assert spec.token_bytes() == 4 * 2 * 24
+    assert spec.token_bytes(first=1) == 4 * 24
+    # stored in 128 lanes a token and layer, 16 tokens a page
+    assert spec.page_bytes(16) == 16 * 4 * 2 * 128
+    assert spec.window_token_bytes() == spec.ring_page_bytes(16) == 0
+    # six layers of state: 4 heads x 16 x 16 float32 and 3 taps' tail of
+    # 3 x 64 inputs, a program row, gated by 16 values a head
+    assert spec.recurrent and spec.state_layers == 6
+    assert spec.state_row_bytes == 4 * (4 * 16 * 16 + 3 * 192)
+    assert spec.state_bytes(4) == 4 * 6 * spec.state_row_bytes
+    assert spec.state_gate_width == 16
+    assert (spec.expert_layers, spec.experts_per_token,
+            spec.experts_held) == (7, 4, 16)
+    assert spec.properties == {"recurrent", "latent", "experts"}
+    # a stack of latent attention alone still lists every layer
+    assert cache_spec(family("glm")[1]).sublayers == family("glm")[1].depth
+    assert cache_spec(family("glm")[1]).state_gate_width == 0
 
 
 @pytest.mark.parametrize("name,layers,row", [
@@ -359,6 +400,8 @@ def test_a_stack_with_layers_that_do_not_page_counts_the_ones_that_do():
     ("falcon", 2, 4 * (4 * 16 * 8 + 3 * 96)),
     # a Gated DeltaNet mixer IN PLACE of attention in 6 of 8 layers
     ("olmo", 6, 4 * (6 * 8 * 16 + 3 * 6 * 32)),
+    # a Kimi Delta Attention mixer in 6 of 8 layers UNDER latent attention
+    ("kimi", 6, 4 * (4 * 16 * 16 + 3 * 192)),
     ("mimo", 0, 0), ("gpt2", 0, 0), ("glm", 0, 0)])
 def test_the_state_beside_the_pages_is_what_it_was(name, layers, row):
     """Falcon-H1's state is counted as the slab's leaves weighed before

@@ -347,6 +347,9 @@ UNPANELED = {
     # advances; the benchmark reads their ratio (state_rows_live_share)
     "kubeml_serving_state_rows_moved_total": "benchmark-read; ad-hoc only",
     "kubeml_serving_state_rows_live_total": "benchmark-read; ad-hoc only",
+    # PR 51: which delta rule those states follow (a decay a head, or one a
+    # key channel), shown beside the two counters
+    "kubeml_serving_state_gate_width": "static per-model constant",
 }
 
 
